@@ -9,6 +9,12 @@ Single-machine demo (threads-as-replicas + in-process Lighthouse):
 
     python examples/train_ddp.py --local-replicas 2 --steps 50
 
+Demo mode is a CPU demo: every thread-replica creates its state on the
+default device, so on an accelerator host all of them would share chip 0.
+Pass ``--cpu`` (or set ``JAX_PLATFORMS=cpu``) for it; on a TPU the path is
+one process per slice (below), and ``python chip_smoke.py`` is the check
+that this loop runs there at flagship size.
+
 Note: kill-based chaos testing (dashboard kill button, punisher.py) needs
 the one-process-per-replica deployment below — a kill RPC exits the whole
 process, so in demo mode it would take down every thread-replica at once.
@@ -69,7 +75,9 @@ def train(replica_id: str, lighthouse_addr: str, args, log=print) -> dict:
     manager = ft.Manager(
         pg=ft.ProcessGroupTCP(timeout=30.0),
         min_replica_size=args.min_replicas,
-        load_state_dict=lambda sd: state.update(sd),
+        # a live heal delivers host numpy: put it back on the device here,
+        # before the next jitted step touches it
+        load_state_dict=lambda sd: state.update(jax.device_put(sd)),
         state_dict=lambda: {"params": state["params"],
                             "opt_state": state["opt_state"]},
         replica_id=replica_id,
@@ -123,10 +131,15 @@ def train(replica_id: str, lighthouse_addr: str, args, log=print) -> dict:
             # participation: membership changes never change compiled shapes)
             avg_grads = ddp.allreduce_gradients(grads).wait(timeout=30)
 
-            # applies the update only if the group votes to commit
-            state["params"], state["opt_state"], committed = optimizer.step(
-                state["params"], avg_grads, state["opt_state"]
-            )
+            # The vote is where an async-quorum heal lands in `state`, so
+            # the state is read only AFTER it (optimizer.step(...) would
+            # evaluate its arguments before voting and update the
+            # pre-heal params).  The update is one donated jit.
+            committed = manager.should_commit()
+            if committed:
+                state["params"], state["opt_state"] = optimizer.update(
+                    state["params"], avg_grads, state["opt_state"]
+                )
             if committed and manager.current_step() % 10 == 0:
                 log(f"[{replica_id} step {manager.current_step()}] "
                     f"loss={float(loss):.4f} "
@@ -159,11 +172,13 @@ def train(replica_id: str, lighthouse_addr: str, args, log=print) -> dict:
 
 def main(argv=None) -> int:
     args = parse_args(argv)
-    if args.cpu:
-        import jax
-        jax.config.update("jax_platforms", "cpu")
-
     import jax
+
+    from torchft_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    if args.cpu:
+        jax.config.update("jax_platforms", "cpu")
 
     if args.profile_dir:
         jax.profiler.start_trace(args.profile_dir)

@@ -12,6 +12,10 @@ replica deployment below; a kill RPC exits the whole process):
 
     python examples/train_diloco.py --local-replicas 2 --steps 40
 
+Demo mode is a CPU demo (``--cpu``): its thread-replicas all create their
+state on the default device, i.e. on an accelerator host they would share
+chip 0.  On a TPU the path is one process per slice.
+
 Real deployment (one process per slice):
 
     TORCHFT_LIGHTHOUSE=host:port REPLICA_GROUP_ID=0 python examples/train_diloco.py
@@ -138,8 +142,12 @@ def train(replica_id: str, lighthouse_addr: str, args, log=print) -> dict:
 
 def main(argv=None) -> int:
     args = parse_args(argv)
+    import jax
+
+    from torchft_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.cpu:
-        import jax
         jax.config.update("jax_platforms", "cpu")
 
     if args.local_replicas:

@@ -10,8 +10,14 @@ Single-machine demo (2 replica-group threads x 4 virtual CPU devices each):
 
     python examples/train_hsdp.py --local-replicas 2 --steps 20
 
+``--local-replicas`` is a CPU-only demo: it always forces the CPU backend
+with enough virtual devices for every group's mesh, at a toy model size.
+
 Real deployment: one process per slice, TORCHFT_LIGHTHOUSE set, and the
-inner mesh built over the slice's own devices (jax.local_devices()).
+inner mesh built over the slice's own devices (jax.local_devices()).  The
+same composition at flagship width on real chips — two groups on disjoint
+2-chip fsdp meshes with a kill and a live heal back onto the mesh — is
+``python chip_smoke.py --chips 4``.
 """
 
 from __future__ import annotations
@@ -141,6 +147,9 @@ def main(argv=None) -> int:
     args = parse_args(argv)
     import jax
 
+    from torchft_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.local_replicas:
         per = args.fsdp * args.tp
         jax.config.update("jax_platforms", "cpu")

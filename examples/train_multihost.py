@@ -16,9 +16,14 @@ spawned workers):
   that averages gradients — groups can die and rejoin without recompiling
   anything.
 
-Self-launching demo (spawns groups x procs real processes on CPU):
+Self-launching demo (spawns groups x procs real processes):
 
     python examples/train_multihost.py --groups 2 --procs-per-group 2 --steps 4
+
+The demo is CPU-only: a chip belongs to one process at a time, so the
+processes it spawns on this one host are started with ``JAX_PLATFORMS=cpu``
+and ``--cpu-devices`` virtual devices each, and the launching parent never
+initialises a JAX backend.
 
 Streaming DiLoCo across the groups (the BASELINE north-star config),
 with optional whole-group kill+rejoin chaos:
@@ -27,9 +32,11 @@ with optional whole-group kill+rejoin chaos:
         --algo diloco --steps 6 --chaos --step-sleep 0.25
 
 Real deployment: run one process per host with the env/flags below, a
-shared Lighthouse, one store + one coordinator per group:
+shared Lighthouse, one store + one coordinator per group; ``--cpu-devices
+0`` leaves the platform alone, so the worker runs on the host's own
+accelerator:
 
-    python examples/train_multihost.py --worker \
+    python examples/train_multihost.py --worker --cpu-devices 0 \
         --group-id 0 --process-id $HOST_IDX --procs-per-group 4 \
         --coordinator host0:1234 --store-addr host0:2345 \
         --lighthouse host:port
@@ -53,7 +60,8 @@ def parse_args(argv=None):
     p.add_argument("--procs-per-group", type=int, default=2)
     p.add_argument("--steps", type=int, default=4)
     p.add_argument("--cpu-devices", type=int, default=2,
-                   help="virtual CPU devices per process (test mode)")
+                   help="virtual CPU devices per process (the demo); 0 = "
+                        "use the host's own accelerator (real deployment)")
     p.add_argument("--min-replicas", type=int, default=1)
     p.add_argument("--algo", choices=["ddp", "diloco"], default="ddp",
                    help="cross-group algorithm: per-step FT-DDP allreduce, "
@@ -87,13 +95,16 @@ def worker(args) -> int:
         host_sharded_array,
         initialize_multihost,
     )
+    from torchft_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     initialize_multihost(
         coordinator_address=args.coordinator,
         num_processes=args.procs_per_group,
         process_id=args.process_id,
-        platform="cpu",
-        cpu_devices_per_process=args.cpu_devices,
+        platform="cpu" if args.cpu_devices else None,
+        cpu_devices_per_process=args.cpu_devices or None,
     )
 
     import jax
@@ -310,6 +321,8 @@ def launch(args) -> int:
             group_procs.append(subprocess.Popen(
                 cmd, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
                 text=True,
+                # several processes on one host: none may take the chip
+                env={**os.environ, "JAX_PLATFORMS": "cpu"},
             ))
         return group_procs
 

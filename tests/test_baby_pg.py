@@ -405,3 +405,21 @@ def test_wire_gbps_env_reaches_baby_worker(store, monkeypatch):
     finally:
         for pg in pgs2:
             pg.shutdown()
+
+
+@pytest.mark.parametrize("parent", ["tpu,cpu", None])
+def test_worker_is_spawned_cpu_only(monkeypatch, parent):
+    """One process per chip: the byte-moving worker is started with
+    JAX_PLATFORMS=cpu in its environment (spawn copies os.environ at
+    start()), and the parent's own setting comes back afterwards."""
+    import os
+
+    from torchft_tpu.parallel.process_group import _cpu_only_child_env
+
+    if parent is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", parent)
+    with _cpu_only_child_env():
+        assert os.environ["JAX_PLATFORMS"] == "cpu"
+    assert os.environ.get("JAX_PLATFORMS") == parent

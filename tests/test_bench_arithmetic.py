@@ -2,6 +2,7 @@
 peak-TFLOPs lookup are the credibility of the headline MFU number."""
 
 import numpy as np
+import pytest
 
 
 class TestModelFlops:
@@ -52,7 +53,8 @@ class TestModelFlops:
         assert _peak_flops("TPU v5 lite") == 197e12
         assert _peak_flops("TPU v4") == 275e12
         assert _peak_flops("TPU v6e") == 918e12
-        assert _peak_flops("Unknown Chip") is None
+        with pytest.raises(ValueError, match="no published bf16 peak"):
+            _peak_flops("Unknown Chip")
 
 
 class TestCompactTailSummary:

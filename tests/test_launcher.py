@@ -93,6 +93,19 @@ class TestReplicaGroupLauncher:
         codes = launcher.run(timeout=60)
         assert codes == {0: 0, 1: 0}
 
+    def test_refuses_to_spawn_while_holding_an_accelerator(self, monkeypatch):
+        """One process per chip: trainers started by a parent whose own
+        JAX backend sits on the accelerator would fail or hang."""
+        import jax
+
+        jax.devices()  # the backend is up (on the CPU here)
+        launcher = ReplicaGroupLauncher(
+            [sys.executable, "-c", "pass"], replicas=1, lighthouse_addr="x:1"
+        )
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(RuntimeError, match="holds the accelerator"):
+            launcher.run(timeout=5)
+
     def test_max_restarts_exhausted(self, tmp_path):
         script = _script(tmp_path, "import sys; sys.exit(7)\n")
         launcher = ReplicaGroupLauncher(

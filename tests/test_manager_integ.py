@@ -389,3 +389,40 @@ class TestFixedWithSpares:
 
         # bitwise convergence of the survivors
         np.testing.assert_array_equal(results[1]["w"], results[2]["w"])
+
+
+class TestAllreduceReleasesInputs:
+    def test_inputs_die_with_the_callers_reference(self):
+        """A completed managed allreduce must not pin its input leaves: for
+        device gradients that is a whole extra copy of the model held in
+        HBM across the next forward/backward (the PG worker's frame and the
+        error-path closure used to keep them until the NEXT collective).
+        Checked with the cyclic collector off, i.e. by refcount alone."""
+        import gc
+        import weakref
+
+        import jax.numpy as jnp
+
+        server = LighthouseServer(min_replicas=1, join_timeout_ms=100)
+        manager = Manager(
+            pg=ProcessGroupTCP(timeout=10.0), min_replica_size=1,
+            load_state_dict=lambda sd: None, state_dict=lambda: {},
+            replica_id="release", lighthouse_addr=server.address(),
+            group_rank=0, group_world_size=1, timeout=10.0,
+        )
+        gc.disable()
+        try:
+            for step in range(2):
+                manager.start_quorum()
+                grads = {"w": jnp.ones((1024,)) * (step + 1)}
+                ref = weakref.ref(grads["w"])
+                work = manager.allreduce(grads)
+                avg = work.wait(timeout=10)
+                np.testing.assert_array_equal(avg["w"], np.full(1024, step + 1.0))
+                del grads, work
+                assert ref() is None, "input leaf still referenced after wait()"
+                assert manager.should_commit()
+        finally:
+            gc.enable()
+            manager.shutdown()
+            server.shutdown()

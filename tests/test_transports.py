@@ -139,6 +139,32 @@ class TestHTTPTransport:
             sender.shutdown()
             receiver.shutdown()
 
+    def test_read_only_live_state_is_not_received_into(self):
+        """`np.asarray(jax.Array)` — what a `state_dict` that hands out
+        host copies of device state returns — is a READ-ONLY view; it must
+        not be taken as an in-place receive buffer (every fragment would
+        fail to decode and the heal would retry forever)."""
+        import jax
+        import jax.numpy as jnp
+
+        sd = {"w": np.arange(8, dtype=np.float32), "b": np.ones(3, np.float32)}
+        live = jax.tree_util.tree_map(
+            lambda x: np.asarray(jnp.zeros_like(x)), sd
+        )
+        assert not live["w"].flags.writeable
+        sender = HTTPTransport(timeout=10.0)
+        receiver = HTTPTransport(timeout=10.0, state_dict_fn=lambda: live)
+        try:
+            sender.send_checkpoint([1], step=9, state_dict=sd, timeout=10.0)
+            out = receiver.recv_checkpoint(
+                src_rank=0, metadata=sender.metadata(), step=9, timeout=10.0
+            )
+            assert_state_dicts_equal(out, sd)
+            assert out["w"] is not live["w"]
+        finally:
+            sender.shutdown()
+            receiver.shutdown()
+
     def test_inplace_mismatch_falls_back(self):
         sd = sample_state_dict()
         receiver = HTTPTransport(

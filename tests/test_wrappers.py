@@ -60,6 +60,25 @@ class TestOptimizerWrapper:
         assert new_state is state
 
 
+    def test_update_is_one_donated_program_and_does_not_vote(self):
+        """`update` is what a loop calls AFTER its own should_commit():
+        no vote inside, one jitted optax step, params/opt_state donated."""
+        import jax.numpy as jnp
+
+        manager = mock_manager()
+        opt = OptimizerWrapper(manager, optax.adamw(0.1))
+        params = {"w": jnp.full((4,), 3.0)}
+        state = opt.init(params)
+        new_params, new_state = opt.update(
+            params, {"w": np.ones(4, np.float32)}, state
+        )
+        manager.should_commit.assert_not_called()
+        assert isinstance(new_params["w"], jax.Array)
+        assert float(new_params["w"][0]) < 3.0
+        assert params["w"].is_deleted()  # donated: rebind, do not reuse
+        assert int(new_state[0].count) == 1
+
+
 class TestDDP:
     def test_allreduce_gradients(self):
         manager = mock_manager()

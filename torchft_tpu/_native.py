@@ -9,6 +9,7 @@ environment always has g++/make).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -42,16 +43,50 @@ def loaded() -> bool:
     return _lib is not None
 
 
-def _build() -> None:
-    result = subprocess.run(
-        ["make", "-C", _NATIVE_DIR, "-j", str(os.cpu_count() or 2)],
-        capture_output=True,
-        text=True,
-    )
+# Written next to the .so after every build: which CPU it was built ON.
+# The codec object is compiled -march=native (native/Makefile), so a
+# build carried to another machine with the tree (an rsync'd checkout, a
+# disk image) can die on an illegal instruction there; mtimes alone
+# cannot see that.
+_HOST_STAMP = os.path.join(_NATIVE_DIR, ".build_host")
+
+
+def _host_fingerprint() -> str:
+    """Identity of the CPU the build is tuned to: architecture plus the
+    first processor's model and feature flags."""
+    lines = [os.uname().machine]
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if not line.strip():
+                    break  # end of the first processor's block
+                if line.split(":")[0].strip() in ("model name", "flags", "Features"):
+                    lines.append(line.strip())
+    except OSError:
+        pass
+    return hashlib.sha256("\n".join(lines).encode()).hexdigest()
+
+
+def _build(force: bool = False) -> None:
+    cmd = ["make", "-C", _NATIVE_DIR, "-j", str(os.cpu_count() or 2)]
+    if force:
+        cmd.append("-B")
+    result = subprocess.run(cmd, capture_output=True, text=True)
     if result.returncode != 0:
         raise RuntimeError(
             f"native build failed:\n{result.stdout}\n{result.stderr}"
         )
+    with open(_HOST_STAMP, "w") as f:
+        f.write(_host_fingerprint())
+
+
+def _built_elsewhere() -> bool:
+    """True unless the stamp says the .so was built on this CPU."""
+    try:
+        with open(_HOST_STAMP) as f:
+            return f.read().strip() != _host_fingerprint()
+    except OSError:
+        return True
 
 
 def _stale(lib_path: str) -> bool:
@@ -100,6 +135,9 @@ def _find_lib() -> str:
         # hard-fails on functionality unrelated to the new symbols
         if not os.path.exists(repo) or _stale(repo):
             _build()
+        elif _built_elsewhere():
+            # make would no-op on mtimes: rebuild everything for this CPU
+            _build(force=True)
         return repo
     packaged = os.path.join(_PKG_DIR, _LIB_NAME)
     if os.path.exists(packaged):

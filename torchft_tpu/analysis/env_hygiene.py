@@ -56,6 +56,9 @@ _EXTERNAL_NAMES: "Tuple[str, ...]" = (
     "NUM_REPLICA_GROUPS",
     "XLA_FLAGS",
     "JAX_PLATFORMS",
+    # where the operator placed the persistent XLA compile cache
+    # (utils/compile_cache.py sets no directory of its own when it is set)
+    "JAX_COMPILATION_CACHE_DIR",
 )
 
 # The helper module itself is the one sanctioned direct reader.

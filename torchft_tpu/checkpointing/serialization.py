@@ -173,6 +173,10 @@ def deserialize_from(
                     and target.dtype == dtype
                     and target.shape == tuple(meta["shape"])
                     and target.flags.c_contiguous
+                    # np.asarray(jax.Array) is a READ-ONLY view of the
+                    # array's host copy: a state_dict built that way offers
+                    # no buffer to receive into
+                    and target.flags.writeable
                 ):
                     out = target
             if out is None:

@@ -22,6 +22,13 @@ One replica group == one TPU slice == one process here; intra-slice
 parallelism is pjit/ICI inside the trainer, so there is no
 ``workers_per_replica``-style nproc fan-out — that knob becomes the number
 of hosts in the slice's JAX process group, owned by the deployment layer.
+
+A chip belongs to one process at a time.  The supervisor itself never
+touches a JAX device (it refuses to start children once its own process
+holds an accelerator), and every child it starts on THIS host sees the
+same chips: on a TPU host run one replica group per host, or hand each
+child its own chips through ``env``; ``--replicas N`` on one host is
+otherwise a CPU demo (``JAX_PLATFORMS=cpu``).
 """
 
 from __future__ import annotations
@@ -88,6 +95,21 @@ def replica_app_spec(
             }
         )
     return {"name": "torchft_tpu", "roles": roles}
+
+
+def _refuse_if_holding_accelerator() -> None:
+    """The children are trainers and need the chip; a parent whose JAX
+    backend is already up on an accelerator holds it, and they would fail
+    or hang at start-up."""
+    import jax
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized() and jax.default_backend() != "cpu":
+        raise RuntimeError(
+            "this process has initialised a JAX backend on "
+            f"{jax.default_backend()!r} and holds the accelerator; start "
+            "the launcher from a process that does not touch JAX devices"
+        )
 
 
 @dataclass
@@ -165,6 +187,7 @@ class ReplicaGroupLauncher:
         Raises TimeoutError if ``timeout`` elapses first (all groups are
         terminated).
         """
+        _refuse_if_holding_accelerator()
         deadline = None if timeout is None else time.monotonic() + timeout
         try:
             # inside the try: a Popen failure mid-loop must still tear down

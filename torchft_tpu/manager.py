@@ -1320,8 +1320,14 @@ class Manager:
             # Track errors out-of-band: the returned Work must complete
             # cleanly so the training loop proceeds to should_commit.
             out: concurrent.futures.Future = concurrent.futures.Future()
+            # One-shot holder: the error path below hands the inputs
+            # back, but a COMPLETED Work must not keep pinning them — for
+            # device gradients that is a whole extra copy of the model
+            # held in HBM across the next forward/backward.
+            inputs = [send_leaves]
 
             def _done(f: "concurrent.futures.Future[Any]") -> None:
+                held = inputs.pop()
                 self._record_phase("ring", time.perf_counter() - t_submit)
                 # quantized-pipeline accounting for the step digest: the
                 # stats dict is complete once the pipeline finished, i.e.
@@ -1337,7 +1343,7 @@ class Manager:
                         exc if isinstance(exc, Exception) else RuntimeError(str(exc))
                     )
                     out.set_result(
-                        jax.tree_util.tree_unflatten(treedef, send_leaves)
+                        jax.tree_util.tree_unflatten(treedef, held)
                     )
                 else:
                     out.set_result(f.result())

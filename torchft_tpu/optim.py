@@ -10,8 +10,9 @@ module parameters, ``step`` returns the (possibly unchanged) new
 
 from __future__ import annotations
 
-from typing import Any, Optional, Tuple
+from typing import Any, Tuple
 
+import jax
 import optax
 
 from torchft_tpu.manager import Manager
@@ -28,12 +29,23 @@ class OptimizerWrapper:
         opt.begin_step()                       # starts quorum (zero_grad analog)
         grads = grad_fn(params, batch)
         avg = manager.allreduce(grads).wait()
-        params, opt_state, committed = opt.step(params, avg, opt_state)
+        if manager.should_commit():            # an async heal lands HERE
+            params, opt_state = opt.update(params, avg, opt_state)
     """
 
     def __init__(self, manager: Manager, optimizer: optax.GradientTransformation) -> None:
         self._manager = manager
         self._optimizer = optimizer
+        # One program per step instead of one dispatch per leaf-op, with
+        # params and opt_state donated: eager optax keeps mu, nu, their
+        # bias-corrected copies and the updates alive next to the old
+        # state (~8x params for adamw) — at flagship scale that does not
+        # fit a 16 GB chip; the donated program peaks at state + grads.
+        self._update = jax.jit(self._update_fn, donate_argnums=(0, 2))
+
+    def _update_fn(self, params: Any, grads: Any, opt_state: Any) -> "Tuple[Any, Any]":
+        updates, opt_state = self._optimizer.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state
 
     def init(self, params: Any) -> Any:
         return self._optimizer.init(params)
@@ -45,16 +57,28 @@ class OptimizerWrapper:
     # torch-API-compatible alias
     zero_grad = begin_step
 
+    def update(self, params: Any, grads: Any, opt_state: Any) -> "Tuple[Any, Any]":
+        """Apply the optax update unconditionally (no vote): one jitted
+        program; ``params`` and ``opt_state`` are DONATED — rebind to the
+        returned ``(params, opt_state)`` and drop the old references.
+        Host (numpy) inputs are transferred by the call."""
+        return self._update(params, grads, opt_state)
+
     def step(
         self, params: Any, grads: Any, opt_state: Any
     ) -> "Tuple[Any, Any, bool]":
-        """Apply the update iff the group votes to commit.
+        """Vote, then :meth:`update` iff the group commits.
 
         Returns ``(params, opt_state, committed)`` — unchanged on a failed
         commit so the step is retried on consistent state.
+
+        With an async quorum a live heal is applied inside the vote, i.e.
+        AFTER the caller evaluated ``params``/``opt_state`` for this call,
+        so the update would run on the pre-heal state.  Loops that can heal
+        asynchronously call ``manager.should_commit()`` themselves and pass
+        the post-vote state to :meth:`update` (class docstring).
         """
         if not self._manager.should_commit():
             return params, opt_state, False
-        updates, new_opt_state = self._optimizer.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
+        new_params, new_opt_state = self.update(params, grads, opt_state)
         return new_params, new_opt_state, True

@@ -30,7 +30,9 @@ JAX mesh composition lives in torchft_tpu/parallel/device_mesh.py).
 
 from __future__ import annotations
 
+import contextlib
 import logging
+import os
 import pickle
 import queue
 import socket
@@ -53,7 +55,7 @@ from torchft_tpu.utils import linkstats as _linkstats
 from torchft_tpu.utils import lockcheck as _lockcheck
 from torchft_tpu.utils import metrics as _metrics
 from torchft_tpu.utils.bufpool import POOL as _pool
-from torchft_tpu.utils.env import env_float
+from torchft_tpu.utils.env import env_float, env_str
 
 logger = logging.getLogger(__name__)
 
@@ -703,49 +705,59 @@ class ProcessGroupTCP(ProcessGroup):
         return Work(fut)
 
     def _worker_loop(self, gen: int, q: "queue.Queue") -> None:
-        superseded = False
         while True:
             item = q.get()
             if item is None:
                 return
-            item_gen, fn, fut, op = item
-            with self._lock:
-                superseded = self._generation != gen
-                errored = self._errored
-            if superseded or item_gen != gen or errored is not None:
-                # Keep draining so every queued Work resolves — abandoned
-                # futures would hang their waiters forever.
-                fut.set_exception(
-                    errored or _PGAborted("process group reconfigured")
-                )
-                continue
-            self._flight_op = _flightrec.start(
-                op,
-                kind="collective",
-                generation=item_gen,
-                rank=self._rank,
-                world=self._world,
-                replica_id=self._replica_id,
+            self._run_item(gen, *item)
+            # The op closure pins its inputs — device buffers when the
+            # caller passed jax arrays.  Drop it BEFORE blocking on the
+            # next get(): a flagship gradient pytree held across the next
+            # forward/backward is the difference between fitting a 16 GB
+            # chip and not.
+            del item
+
+    def _run_item(
+        self, gen: int, item_gen: int, fn: "Callable[[], Any]", fut: Future,
+        op: str,
+    ) -> None:
+        with self._lock:
+            superseded = self._generation != gen
+            errored = self._errored
+        if superseded or item_gen != gen or errored is not None:
+            # Keep draining so every queued Work resolves — abandoned
+            # futures would hang their waiters forever.
+            fut.set_exception(
+                errored or _PGAborted("process group reconfigured")
             )
-            try:
-                result = fn()
-                with self._flight_swap_lock:
-                    flight_op, self._flight_op = self._flight_op, None
-                if flight_op is not None:
-                    flight_op.finish("ok")
-                fut.set_result(result)
-            except Exception as e:  # noqa: BLE001 - latch every op failure
-                # Flight-recorder dump BEFORE latching: when a wedged
-                # collective dies (deadline, peer reset), the op-level state
-                # — what was in flight, with whom, how far it got — is the
-                # evidence the postmortem needs (reference dumps the NCCL
-                # flight recorder on abort for the same reason,
-                # torchft/process_group.py:89-108,830-838).
-                self._dump_flight(f"collective failed: {e!r}", error=repr(e))
-                with self._lock:
-                    if self._errored is None:
-                        self._errored = e
-                fut.set_exception(e)
+            return
+        self._flight_op = _flightrec.start(
+            op,
+            kind="collective",
+            generation=item_gen,
+            rank=self._rank,
+            world=self._world,
+            replica_id=self._replica_id,
+        )
+        try:
+            result = fn()
+            with self._flight_swap_lock:
+                flight_op, self._flight_op = self._flight_op, None
+            if flight_op is not None:
+                flight_op.finish("ok")
+            fut.set_result(result)
+        except Exception as e:  # noqa: BLE001 - latch every op failure
+            # Flight-recorder dump BEFORE latching: when a wedged
+            # collective dies (deadline, peer reset), the op-level state
+            # — what was in flight, with whom, how far it got — is the
+            # evidence the postmortem needs (reference dumps the NCCL
+            # flight recorder on abort for the same reason,
+            # torchft/process_group.py:89-108,830-838).
+            self._dump_flight(f"collective failed: {e!r}", error=repr(e))
+            with self._lock:
+                if self._errored is None:
+                    self._errored = e
+            fut.set_exception(e)
 
     # -- flight recorder ---------------------------------------------------
 
@@ -1844,6 +1856,32 @@ def _baby_worker(
             pass
 
 
+_spawn_env_lock = threading.Lock()
+
+
+@contextlib.contextmanager
+def _cpu_only_child_env() -> Any:
+    """Children started inside this block cannot take the accelerator.
+
+    A chip belongs to one process at a time and the parent — the trainer —
+    holds it.  The worker only moves host bytes, but it re-imports the
+    user's ``__main__`` on spawn, and anything there that touches a JAX
+    device would make the child reach for the chip and fail or hang.
+    ``spawn`` hands the child a copy of ``os.environ`` as of ``start()``,
+    so the platform is pinned there; the parent's own (already
+    configured) JAX does not re-read it."""
+    with _spawn_env_lock:
+        prev = env_str("JAX_PLATFORMS")
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        try:
+            yield
+        finally:
+            if prev:
+                os.environ["JAX_PLATFORMS"] = prev
+            else:
+                os.environ.pop("JAX_PLATFORMS", None)
+
+
 class ProcessGroupBaby(ProcessGroup):
     """Runs the real PG in a spawned subprocess for crash isolation.
 
@@ -1907,7 +1945,8 @@ class ProcessGroupBaby(ProcessGroup):
             ),
             daemon=True,
         )
-        self._proc.start()
+        with _cpu_only_child_env():
+            self._proc.start()
         child_conn.close()
 
         from torchft_tpu.multiprocessing import _MonitoredPipe

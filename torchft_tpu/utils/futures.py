@@ -23,7 +23,6 @@ import sys
 import threading
 import time
 from concurrent.futures import Future
-from concurrent.futures import TimeoutError as FuturesTimeoutError
 from contextlib import contextmanager
 from datetime import timedelta
 from typing import Callable, Iterator, Optional, TypeVar
@@ -191,9 +190,7 @@ def future_wait(fut: "Future[T]", timeout: "float | timedelta") -> T:
     """Block on ``fut`` for at most ``timeout``; raises TimeoutError."""
     try:
         return fut.result(timeout=_to_seconds(timeout))
-    except (TimeoutError, FuturesTimeoutError):
-        # concurrent.futures.TimeoutError is only an alias of the builtin
-        # from Python 3.11; on 3.10 result() raises the distinct class.
+    except TimeoutError:
         # A future may legitimately complete *with* a TimeoutError (e.g. one
         # produced by future_timeout) — re-raise that as-is rather than
         # misreporting it as this wait expiring.
