@@ -642,6 +642,45 @@ class TestTraceLedger:
         assert "LIKELY CULPRIT: rep_bad" in out
         assert "[trace_error]" in out
 
+    def test_parts_never_count_against_their_whole(self, tmp_path):
+        """ISSUE 24, the one rule for nesting: a key with a dot is contained
+        in the key before the dot, so a summing consumer gives the same
+        total with and without the dotted keys — over ``phase_times()``
+        and over a span file."""
+        from torchft_tpu.manager import PHASE_PARTS, PROTOCOL_PHASES
+
+        whole = {name: 1.0 + i for i, name in enumerate(PROTOCOL_PHASES)}
+        opened = dict(whole, **{part: 0.25 for part in PHASE_PARTS})
+        assert diagnose.ledger_categories(opened) == diagnose.ledger_categories(whole)
+        assert sum(diagnose.ledger_categories(opened).values()) == pytest.approx(
+            sum(whole.values())
+        )
+        assert diagnose.dominant_contributor(
+            {"ring": 1.0, "ring.d2h": 0.9, "ring.pack": 0.9, "commit": 1.5}
+        ) == "protocol"
+        assert set(PROTOCOL_PHASES) == set(diagnose.PHASE_CATEGORY)
+
+        T = "c" * 32
+        root = "ra" + "0" * 14
+        spans = [
+            _span("quorum_round", T, root, None, 0, 1000,
+                  replica_id="rep_a", step=5, quorum_id=2),
+            _span("ring", T, "p1" + "0" * 14, root, 0, 600, replica_id="rep_a"),
+            # heal_recv spans the whole receive and books what its split
+            # phases leave (tracing.phase.exclude): 50 of its 300 ms
+            _span("heal_recv", T, "p2" + "0" * 14, root, 600, 900, seconds=0.05),
+            _span("heal_diff", T, "p3" + "0" * 14, root, 650, 900),
+        ]
+        parts = [
+            _span("ring.d2h", T, "q1" + "0" * 14, "p1" + "0" * 14, 0, 400),
+            _span("ring.wire", T, "q2" + "0" * 14, "p1" + "0" * 14, 400, 600),
+            _span("heal_diff.hash", T, "q3" + "0" * 14, "p3" + "0" * 14, 700, 900),
+        ]
+        without = diagnose.analyze_trace(spans)["steps"][0]["replicas"]["rep_a"]
+        with_parts = diagnose.analyze_trace(spans + parts)["steps"][0]["replicas"]["rep_a"]
+        assert with_parts["categories"] == without["categories"]
+        assert without["categories"] == {"codec": 0.25, "wire": 0.65}
+
     def test_bench_vocabulary_matches(self):
         """bench.py's per-leg dominant field uses this module's mapping —
         pin the vocabulary so the tail stays joinable with the ledger."""

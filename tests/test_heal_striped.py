@@ -473,3 +473,176 @@ class TestStripedHealInteg:
         # deterministic >= 2 assertion lives in the delay-paced
         # TestStripedHeal.test_kill_stripe_source_mid_heal)
         assert _metrics.HEAL_STRIPE_SOURCES.get() >= 1
+
+
+class TestHealOpened:
+    """ISSUE 24: the heal, opened.  A three-replica fleet with a kill,
+    read back from ``Manager.phase_times()`` and the span file: the
+    source-side parts per fragment, the healer's wait for the source, the
+    apply, and the split phases emitted WHEN they happen (they used to be
+    recorded in a row after the heal, with starts rebuilt as end minus
+    duration: four spans that all ended together)."""
+
+    N = 200_000  # a state big enough for the hashing to take milliseconds
+
+    def _replica(self, rid: int, addr: str, steps: int, out: dict) -> None:
+        from torchft_tpu.manager import Manager
+        from torchft_tpu.parallel.process_group import ProcessGroupTCP
+        from torchft_tpu.utils.faults import InjectedFault
+
+        for _ in range(3):
+            params = {f"w{i}": np.zeros(self.N, np.float32) for i in range(6)}
+
+            def load_state_dict(sd, params=params):
+                time.sleep(0.01)  # a device_put would go here
+                params.update({k: np.array(v) for k, v in sd.items()})
+
+            manager = Manager(
+                pg=ProcessGroupTCP(timeout=20.0),
+                min_replica_size=1,
+                load_state_dict=load_state_dict,
+                state_dict=lambda params=params: dict(params),
+                lighthouse_addr=addr,
+                replica_id=f"replica_{rid}",
+                group_rank=0,
+                group_world_size=1,
+                timeout=30.0,
+                quorum_timeout=30.0,
+                init_sync=False,
+            )
+            try:
+                while manager.current_step() < steps:
+                    step = manager.current_step()
+                    faults.check("train.step", replica=f"replica_{rid}", step=step)
+                    manager.start_quorum()
+                    grads = {k: np.full_like(v, step + 1.0) for k, v in params.items()}
+                    avg = manager.allreduce(grads).wait(timeout=30)
+                    if manager.should_commit():
+                        for k in params:
+                            params[k] = params[k] - 0.1 * avg[k]
+                out.setdefault(rid, []).append(manager.phase_times())
+                return
+            except InjectedFault:
+                out.setdefault(rid, []).append(manager.phase_times())
+            finally:
+                manager.shutdown()
+        raise RuntimeError(f"replica {rid} exhausted its attempts")
+
+    def test_heal_parts_and_span_order(self, tmp_path):
+        import json
+        from concurrent.futures import ThreadPoolExecutor
+
+        from torchft_tpu.coordination import LighthouseServer
+        from torchft_tpu.manager import PHASE_PARTS
+        from torchft_tpu.utils import tracing
+
+        from torchft_tpu.utils import flightrecorder, metrics
+
+        flightrecorder.RECORDER.clear()
+        decode_hist = metrics.QUORUM_DURATION.labels(
+            replica_id="replica_1", phase="heal_decode"
+        )
+        decodes_before = decode_hist.get()["count"]
+        path = tmp_path / "spans.jsonl"
+        tracing.uninstall_tracer()
+        tracing.install_tracer(
+            tracing.Tracer(sink=tracing.FileSpanSink(str(path)))
+        )
+        lighthouse = LighthouseServer(
+            min_replicas=2, join_timeout_ms=100, heartbeat_timeout_ms=1000
+        )
+        out: dict = {}
+        try:
+            faults.FAULTS.configure(
+                [FaultRule(site="train.step", replica="replica_1", step=2)]
+            )
+            with ThreadPoolExecutor(max_workers=3) as ex:
+                futs = [
+                    ex.submit(self._replica, i, lighthouse.address(), 5, out)
+                    for i in range(3)
+                ]
+                for f in futs:
+                    f.result(timeout=120)
+        finally:
+            lighthouse.shutdown()
+            tracing.uninstall_tracer()
+
+        # -- the sources: per-fragment parts inside heal_send ------------
+        send_parts = [p for p in PHASE_PARTS if p.startswith("heal_send.")]
+        sources = [
+            ph for rid in (0, 2) for ph in out[rid] if ph.get("heal_send", 0) > 0
+        ]
+        assert sources, "no survivor staged a heal"
+        for ph in sources:
+            assert set(send_parts) <= set(ph), sorted(ph)
+            opened = sum(ph[p] for p in send_parts)
+            assert 0 < opened <= ph["heal_send"]
+            assert ph.get("heal_apply", 0.0) == 0.0
+
+        # -- the healer: the new incarnation of replica 1 ----------------
+        healer = out[1][-1]
+        assert 0 < healer["heal_manifest.wait"] <= healer["heal_manifest"]
+        diff = sum(healer[f"heal_diff.{p}"] for p in ("snapshot", "encode", "hash"))
+        assert 0 < diff <= healer["heal_diff"]
+        assert healer["heal_apply"] >= 0.01  # the user's load, timed at last
+        assert healer["heal_wire"] > 0 and healer["heal_recv"] >= 0
+        assert "heal_send" not in healer
+
+        # -- the trace: emitted when they happen -------------------------
+        spans = [json.loads(l) for l in path.read_text().splitlines() if l]
+        by = {}
+        for s in spans:
+            by.setdefault(s["name"], []).append(s)
+        split = [by[n][0] for n in ("heal_manifest", "heal_diff", "heal_wire")]
+        assert all(len(by[n]) == 1 for n in ("heal_manifest", "heal_diff", "heal_wire"))
+        starts = [s["start_ns"] for s in split]
+        ends = [s["end_ns"] for s in split]
+        assert starts == sorted(starts) and len(set(starts)) == 3
+        assert ends == sorted(ends) and len(set(ends)) == 3
+        for a, b in zip(split, split[1:]):
+            assert a["end_ns"] <= b["start_ns"]  # one after the other
+        # all three, and heal_recv around them, hang off the healer's root
+        (recv,) = by["heal_recv"]
+        assert {s["parent_span_id"] for s in split} == {recv["parent_span_id"]}
+        assert recv["start_ns"] <= starts[0] and ends[-1] <= recv["end_ns"]
+        # heal_recv books what the split leaves, and says so on its span
+        assert recv["attributes"]["seconds"] == pytest.approx(
+            healer["heal_recv"], abs=1e-6
+        )
+        assert healer["heal_recv"] < (recv["end_ns"] - recv["start_ns"]) / 1e9
+        # heal_decode: one span a heal, inside heal_wire, and in it a part
+        # per fragment that moved; one observation and flight record in all
+        (dec,) = by["heal_decode"]
+        assert split[2]["start_ns"] <= dec["start_ns"]
+        assert dec["end_ns"] <= split[2]["end_ns"]
+        assert dec["attributes"]["seconds"] == pytest.approx(
+            healer["heal_decode"], abs=1e-6
+        )
+        assert by["heal_decode.fragment"]
+        for d in by["heal_decode.fragment"]:
+            assert d["parent_span_id"] == dec["span_id"]
+            assert dec["start_ns"] <= d["start_ns"] and d["end_ns"] <= dec["end_ns"]
+            assert "fragment" in d["attributes"]
+        assert 0 < healer["heal_decode.fragment"] <= healer["heal_decode"]
+        assert decode_hist.get()["count"] == decodes_before + 1
+        flight = [
+            r for r in flightrecorder.snapshot()
+            if r.get("kind") == "phase" and r["replica_id"].startswith("replica_1")
+        ]
+        assert [r["op"] for r in flight].count("heal_decode") == 1
+        assert not [r for r in flight if "." in r["op"]]
+        # heal_recv and what it opened say which step was healed TO
+        healed_to = {
+            r["step"] for r in flight if r["op"].startswith("heal_")
+            and r["op"] != "heal_apply"
+        }
+        assert len(healed_to) == 1 and recv["attributes"]["step"] in healed_to
+        assert recv["attributes"]["step"] >= 2  # the kill was at step 2
+        # source-side parts are children of a heal_send span, per fragment
+        send_ids = {s["span_id"] for s in by["heal_send"]}
+        for name in send_parts:
+            assert by[name], name
+            assert {s["parent_span_id"] for s in by[name]} <= send_ids
+            assert all("fragment" in s["attributes"] for s in by[name])
+        (apply_span,) = by["heal_apply"]
+        assert apply_span["start_ns"] >= recv["end_ns"]

@@ -217,6 +217,92 @@ class TestFragmentSpanFamily:
         ), [f.message for f in findings]
 
 
+class TestPhaseCallVocabulary:
+    """ISSUE 24: the vocab pass follows the one span primitive — every
+    ``phase(...)`` / ``_phase(...)`` literal comes from PROTOCOL_PHASES or
+    PHASE_PARTS, and no second naming scheme lives under ``torchft``."""
+
+    MANAGER = (
+        'PROTOCOL_PHASES = ("ring", "commit", "heal_send")\n'
+        'PHASE_PARTS = ("ring.d2h", "heal_send.hash")\n'
+    )
+
+    def _run(self, tmp_path, src):
+        paths = _plant(tmp_path, {
+            "pkg/manager.py": self.MANAGER,
+            "pkg/mod.py": textwrap.dedent(src),
+        })
+        project = Project(str(tmp_path), paths)
+        lint_pass = next(p for p in PASSES if p.id == "span-vocab")
+        results = run_passes(
+            [lint_pass], project, baseline_dir=str(tmp_path / "nb")
+        )
+        return [f for r in results for f in r.findings]
+
+    def test_the_call_forms_of_the_tree_are_clean(self, tmp_path):
+        findings = self._run(
+            tmp_path,
+            """
+            from torchft_tpu.utils import tracing
+
+            class M:
+                def _phase(self, name, **attrs):
+                    return tracing.phase(name, self.acc, **attrs)
+
+                def step(self, sink):
+                    with self._phase("commit"):
+                        pass
+                    ring = self._phase("ring").begin()
+                    with tracing.under(ring), tracing.phase(".d2h"):
+                        pass
+                    with self._phase("heal_send"), tracing.phase(".hash"):
+                        pass
+            """,
+        )
+        assert findings == [], [f.message for f in findings]
+
+    @pytest.mark.parametrize(
+        "call, needle",
+        [
+            ('tracing.phase("ring.made_up", sink)', "ring.made_up"),
+            ('tracing.phase("ring.d2h", sink)', "ring.d2h"),
+            ('tracing.phase(".made_up")', ".made_up"),
+            ('self._phase("made_up")', "made_up"),
+            ('tracing.phase(name_from_elsewhere(), sink)', "not a literal"),
+            (
+                'jax.profiler.TraceAnnotation("torchft::manager::_pg::configure")',
+                "torchft::manager",
+            ),
+            ('jax.profiler.TraceAnnotation("torchft.made_up")', "torchft.made_up"),
+        ],
+    )
+    def test_off_vocabulary_call_is_caught(self, tmp_path, call, needle):
+        findings = self._run(
+            tmp_path,
+            f"""
+            def step(self, sink, tracing, jax):
+                with {call}:
+                    pass
+            """,
+        )
+        assert any(
+            f.pass_id == "span-vocab" and needle in f.message for f in findings
+        ), [f.message for f in findings]
+
+    def test_annotations_outside_the_prefix_are_not_ours(self, tmp_path):
+        findings = self._run(
+            tmp_path,
+            """
+            def step(jax):
+                with jax.profiler.TraceAnnotation("bench.ring"):
+                    pass
+                with jax.profiler.TraceAnnotation("torchft.ring.d2h"):
+                    pass
+            """,
+        )
+        assert findings == [], [f.message for f in findings]
+
+
 class TestBaselineWorkflow:
     def test_write_baseline_then_clean(self, tmp_path, capsys):
         """Grandfathering: --write-baseline makes a dirty tree pass, and

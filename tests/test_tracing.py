@@ -187,6 +187,327 @@ class TestCurrentContext:
         tracing.set_current(None)
 
 
+def _spans(path):
+    return [json.loads(l) for l in path.read_text().splitlines() if l.strip()]
+
+
+@pytest.fixture
+def span_file(tmp_path):
+    """A file-sink tracer and a bound round context; yields (path, ctx)."""
+    path = tmp_path / "spans.jsonl"
+    tracing.install_tracer(tracing.Tracer(sink=tracing.FileSpanSink(str(path))))
+    ctx = tracing.TraceContext("a" * 32, "b" * 16)
+    tracing.set_current(ctx)
+    yield path, ctx
+    tracing.set_current(None)
+
+
+class TestPhase:
+    """``tracing.phase``: the one span primitive (sink, true start and
+    end, parent, nesting by the dot, no jax where jax is not loaded)."""
+
+    def test_feeds_the_sink(self):
+        sink = {}
+        with tracing.phase("ring", sink) as p:
+            time.sleep(0.01)
+        assert p.seconds >= 0.01
+        assert sink == {"ring": p.seconds}
+        with tracing.phase("ring", sink):
+            pass
+        assert sink["ring"] > p.seconds  # accumulates
+        assert tracing.get_tracer() is None  # all of it with tracing off
+
+    def test_exports_true_start_end_and_parent(self, span_file):
+        path, ctx = span_file
+        sink = {}
+        before = time.time_ns()
+        with tracing.phase("commit", sink, replica_id="r0", step=7):
+            time.sleep(0.02)
+        after = time.time_ns()
+        time.sleep(0.05)  # a start rebuilt at export time would land here
+        tracing.uninstall_tracer()
+        (span,) = _spans(path)
+        assert span["name"] == "commit"
+        assert span["trace_id"] == ctx.trace_id
+        assert span["parent_span_id"] == ctx.span_id
+        assert before <= span["start_ns"] <= span["end_ns"] <= after
+        assert (span["end_ns"] - span["start_ns"]) / 1e9 == pytest.approx(
+            sink["commit"], abs=1e-6
+        )
+        assert span["attributes"] == {"replica_id": "r0", "step": 7}
+        assert span["ok"] is True
+
+    def test_failed_phase_is_marked(self, span_file):
+        path, _ = span_file
+        sink = {}
+        with pytest.raises(ValueError):
+            with tracing.phase("commit", sink):
+                raise ValueError("boom")
+        tracing.uninstall_tracer()
+        assert _spans(path)[0]["ok"] is False
+        assert sink["commit"] >= 0.0
+
+    def test_part_lies_inside_its_whole(self, span_file):
+        path, ctx = span_file
+        sink = {}
+        with tracing.phase("heal_send", sink, replica_id="r0"):
+            time.sleep(0.002)
+            with tracing.phase(".hash", fragment="3"):  # its whole's sink
+                time.sleep(0.005)
+            with tracing.phase(".stage", bytes=9):
+                time.sleep(0.002)
+            time.sleep(0.002)
+        tracing.uninstall_tracer()
+        by = {s["name"]: s for s in _spans(path)}
+        assert set(by) == {"heal_send", "heal_send.hash", "heal_send.stage"}
+        whole = by["heal_send"]
+        assert whole["parent_span_id"] == ctx.span_id
+        for name in ("heal_send.hash", "heal_send.stage"):
+            part = by[name]
+            assert part["parent_span_id"] == whole["span_id"]
+            assert whole["start_ns"] <= part["start_ns"]
+            assert part["end_ns"] <= whole["end_ns"]
+            # who is timing flows down the thread
+            assert part["attributes"]["replica_id"] == "r0"
+        assert by["heal_send.hash"]["attributes"]["fragment"] == "3"
+        assert set(sink) == {"heal_send", "heal_send.hash", "heal_send.stage"}
+        assert sink["heal_send.hash"] + sink["heal_send.stage"] <= sink["heal_send"]
+        assert tracing.is_part("heal_send.hash") and not tracing.is_part("heal_send")
+
+    def test_unrelated_phase_inside_is_not_a_part(self, span_file):
+        """``heal_manifest`` opened while ``heal_recv`` is open is a phase
+        of its own: under the round's root, the ledger's children."""
+        path, ctx = span_file
+        sink, below = {}, {}
+        with tracing.phase("heal_recv", sink, step=4):
+            with tracing.phase("heal_manifest", below):
+                pass
+        tracing.uninstall_tracer()
+        by = {s["name"]: s for s in _spans(path)}
+        assert by["heal_manifest"]["parent_span_id"] == ctx.span_id
+        assert by["heal_manifest"]["attributes"] == {"step": 4}
+        assert set(below) == {"heal_manifest"} and set(sink) == {"heal_recv"}
+
+    def test_begin_end_across_threads_and_under(self, span_file):
+        """``ring`` begins on the caller's thread and ends on the worker's;
+        its parts run on the worker, carried there by ``under``."""
+        path, _ = span_file
+        sink = {}
+        ring = tracing.phase("ring", sink, step=1).begin()
+        with tracing.under(ring):
+            whole = tracing.open_phase()
+            queued = tracing.phase(".queue").begin()
+        assert whole is ring and tracing.open_phase() is None
+
+        def worker():
+            queued.end()
+            with tracing.under(whole):
+                with tracing.phase(".d2h", bytes=4):
+                    time.sleep(0.005)
+            ring.end()
+
+        t = threading.Thread(target=worker)
+        t.start()
+        t.join()
+        tracing.uninstall_tracer()
+        by = {s["name"]: s for s in _spans(path)}
+        assert set(sink) == {"ring", "ring.queue", "ring.d2h"}
+        for name in ("ring.queue", "ring.d2h"):
+            assert by[name]["parent_span_id"] == by["ring"]["span_id"]
+            assert by["ring"]["start_ns"] <= by[name]["start_ns"]
+            assert by[name]["end_ns"] <= by["ring"]["end_ns"]
+        assert by["ring.d2h"]["attributes"] == {"step": 1, "bytes": 4}
+        assert sink["ring.queue"] + sink["ring.d2h"] <= sink["ring"]
+
+    def test_lap_accumulates_into_one_span(self, span_file):
+        path, _ = span_file
+        from torchft_tpu.utils import flightrecorder
+
+        flightrecorder.RECORDER.clear()
+        sink, seen = {}, []
+        decode = tracing.phase(
+            "heal_decode", sink, observe=lambda n, s: seen.append((n, s))
+        )
+        for i in range(3):
+            # what a stretch opens is the phase's part
+            with decode.lap(), tracing.phase(".fragment", fragment=str(i)):
+                time.sleep(0.003)
+            assert tracing.open_phase() is None
+            time.sleep(0.01)  # the wire between the stretches
+        assert decode.end() == sink["heal_decode"]
+        never = tracing.phase("heal_decode", sink)
+        assert never.end() == 0.0  # no lap ran: nothing recorded
+        tracing.uninstall_tracer()
+        by = {}
+        for s in _spans(path):
+            by.setdefault(s["name"], []).append(s)
+        (span,) = by["heal_decode"]
+        wall = (span["end_ns"] - span["start_ns"]) / 1e9
+        assert 0.009 <= sink["heal_decode"] < 0.02 < wall
+        assert span["attributes"]["seconds"] == pytest.approx(sink["heal_decode"])
+        # one part span per stretch, one observation and flight record in all
+        assert [s["attributes"]["fragment"] for s in by["heal_decode.fragment"]] == [
+            "0", "1", "2"
+        ]
+        assert {s["parent_span_id"] for s in by["heal_decode.fragment"]} == {
+            span["span_id"]
+        }
+        assert sink["heal_decode.fragment"] <= sink["heal_decode"]
+        assert seen == [("heal_decode", sink["heal_decode"])]
+        recs = [r for r in flightrecorder.snapshot() if r.get("kind") == "phase"]
+        assert [r["op"] for r in recs] == ["heal_decode"]
+
+    def test_exclude_books_less_and_span_keeps_its_ends(self, span_file):
+        path, _ = span_file
+        sink = {}
+        with tracing.phase("heal_recv", sink) as p:
+            time.sleep(0.02)
+            assert p.elapsed() >= 0.02
+            p.exclude(0.015)
+        tracing.uninstall_tracer()
+        (span,) = _spans(path)
+        assert (span["end_ns"] - span["start_ns"]) / 1e9 >= 0.02
+        assert sink["heal_recv"] == pytest.approx(
+            (span["end_ns"] - span["start_ns"]) / 1e9 - 0.015, abs=1e-6
+        )
+        assert span["attributes"]["seconds"] == pytest.approx(sink["heal_recv"])
+
+    def test_cancel_records_nothing(self, span_file):
+        path, _ = span_file
+        sink = {}
+        with tracing.phase("reshard", sink) as p:
+            p.cancel()
+        tracing.uninstall_tracer()
+        assert sink == {} and not path.exists()
+
+    def test_orphan_part_is_only_the_annotation(self, span_file):
+        path, _ = span_file
+        from torchft_tpu.utils import flightrecorder
+
+        n = flightrecorder.RECORDER.total_recorded()
+        with tracing.phase(".snapshot", fragment="0") as p:
+            pass
+        assert p.name == "snapshot" and p.sink is None
+        tracing.uninstall_tracer()
+        assert not path.exists()
+        assert flightrecorder.RECORDER.total_recorded() == n
+
+    def test_flight_ring_takes_phases_not_parts(self):
+        from torchft_tpu.utils import flightrecorder
+
+        flightrecorder.RECORDER.clear()
+        sink = {}
+        with tracing.phase("ring", sink, replica_id="r0", step=2):
+            with tracing.phase(".d2h"):
+                pass
+        recs = [r for r in flightrecorder.snapshot() if r.get("kind") == "phase"]
+        assert [r["op"] for r in recs] == ["ring"]
+        assert recs[0]["replica_id"] == "r0" and recs[0]["step"] == 2
+        assert recs[0]["start_ns"] <= recs[0]["end_ns"]
+
+    def test_observe_gets_phases_not_parts(self):
+        """The histogram's sum over ``phase`` counts no part against its
+        whole; a phase opened inside another inherits ``observe``."""
+        seen = []
+        with tracing.phase("heal_recv", {}, observe=lambda n, s: seen.append(n)):
+            with tracing.phase(".wait"):
+                pass
+            with tracing.phase("heal_manifest", {}):
+                pass
+        assert seen == ["heal_manifest", "heal_recv"]
+
+    def test_sink_loses_no_update_across_threads(self):
+        """Phases end on the caller's, the quorum and the PG worker's
+        threads into one sink: more threads than cores, a short switch
+        interval, and a sum a lost read-modify-write would break."""
+        import sys
+
+        sink = {}
+        n_threads, n_adds = 16, 2000
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            threads = [
+                threading.Thread(
+                    target=lambda: [
+                        tracing.add_seconds(sink, "ring", 1.0)
+                        for _ in range(n_adds)
+                    ]
+                )
+                for _ in range(n_threads)
+            ]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(t.is_alive() for t in threads)
+        assert sink == {"ring": float(n_threads * n_adds)}
+
+    def test_silent_where_jax_is_not_loaded(self, monkeypatch):
+        """The Baby PG's worker process moves host bytes and must not be
+        made to import jax: no annotation where jax is not already in
+        ``sys.modules``, and everything else works."""
+        import sys
+
+        monkeypatch.setattr(tracing, "_TraceAnnotation", None)
+        monkeypatch.delitem(sys.modules, "jax")
+        sink = {}
+        with tracing.phase("ring", sink, bytes=1) as p:
+            with tracing.phase(".wire") as part:
+                assert p._ann is None and part._ann is None
+        assert "jax" not in sys.modules
+        assert tracing._TraceAnnotation is None
+        assert sink["ring.wire"] >= 0.0
+
+    def test_process_group_module_does_not_import_jax(self):
+        import ast
+        import inspect
+
+        from torchft_tpu.parallel import process_group
+
+        for node in ast.walk(ast.parse(inspect.getsource(process_group))):
+            if isinstance(node, ast.Import):
+                assert all(a.name.split(".")[0] != "jax" for a in node.names)
+            if isinstance(node, ast.ImportFrom):
+                assert (node.module or "").split(".")[0] != "jax"
+
+    def test_annotation_lands_in_the_profilers_host_plane(self, tmp_path):
+        """A jitted toy step under ``jax.profiler.trace`` with a phase
+        around it: ``torchft.<name>`` is an event of the host plane, on
+        the clock the device's events are on, with the attributes."""
+        import glob
+
+        import jax
+        import jax.numpy as jnp
+        from jax.profiler import ProfileData
+
+        step = jax.jit(lambda x: (x @ x).sum())
+        x = jnp.ones((128, 128))
+        step(x).block_until_ready()
+        sink = {}
+        with jax.profiler.trace(str(tmp_path)):
+            with tracing.phase("ring", sink, step=3):
+                with tracing.phase(".d2h", bytes=12):
+                    step(x).block_until_ready()
+        (pb,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+        found = {}
+        for plane in ProfileData.from_file(pb).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("torchft."):
+                        found[ev.name] = (ev.start_ns, ev.duration_ns, dict(ev.stats))
+        assert set(found) == {"torchft.ring", "torchft.ring.d2h"}
+        w0, wd, wstats = found["torchft.ring"]
+        p0, pd, pstats = found["torchft.ring.d2h"]
+        assert w0 <= p0 and p0 + pd <= w0 + wd
+        assert wstats["step"] == 3 and pstats["bytes"] == 12
+        assert pd / 1e9 == pytest.approx(sink["ring.d2h"], rel=0.5, abs=2e-3)
+
+
 class TestDisabledPathBudget:
     def test_disabled_injection_is_zero_cost(self):
         """Acceptance bar: the disabled hot path (no tracer installed) —
@@ -204,6 +525,20 @@ class TestDisabledPathBudget:
                 tracing.get_current()
             best = min(best, (time.perf_counter() - t0) / n)
         assert best <= 2.5e-6, f"disabled trace path {best * 1e9:.0f} ns/call"
+        # the one span primitive with tracing off and no profiler session:
+        # one annotation enter/exit, three clock reads, the sink, the
+        # flight record (~4 us here; a healthy step opens some tens of
+        # them against seconds).  Same best-of-batches discipline.
+        sink = {}
+        n = 10_000
+        best = float("inf")
+        for _ in range(5):
+            t0 = time.perf_counter()
+            for _ in range(n):
+                with tracing.phase("ring", sink, replica_id="r0", step=1):
+                    pass
+            best = min(best, (time.perf_counter() - t0) / n)
+        assert best <= 25e-6, f"disabled phase {best * 1e6:.1f} us/span"
 
     def test_disabled_sampling_check_is_cheap(self):
         """Manager.start_quorum's disabled path is one get_tracer() call."""

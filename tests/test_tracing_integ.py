@@ -228,6 +228,86 @@ class TestLiveRoundTrip:
         )
 
 
+class TestRingOpened:
+    """The ``ring`` phase, opened into its parts on the PG worker thread:
+    a two-group ``ProcessGroupTCP`` step at a size where ``ring`` takes
+    tens of milliseconds."""
+
+    N = 2_000_000  # 8 MB of float32 in one solo bucket + a coalesced one
+
+    def _replica(self, replica_id: int, addr: str, steps: int) -> dict:
+        params = {
+            "w": np.zeros(self.N, dtype=np.float32),
+            "b0": np.zeros(16, dtype=np.float32),
+            "b1": np.zeros(16, dtype=np.float32),
+        }
+        manager = Manager(
+            pg=ProcessGroupTCP(timeout=20.0),
+            min_replica_size=2,
+            load_state_dict=lambda sd: params.update(sd),
+            state_dict=lambda: dict(params),
+            lighthouse_addr=addr,
+            replica_id=f"replica_{replica_id}",
+            group_rank=0,
+            group_world_size=1,
+            timeout=20.0,
+            quorum_timeout=20.0,
+            init_sync=False,  # both build the same zeros: no step-0 heal
+        )
+        try:
+            while manager.current_step() < steps:
+                manager.start_quorum()
+                grads = {k: np.ones_like(v) for k, v in params.items()}
+                work = manager.allreduce(grads)
+                avg = work.wait(timeout=30)
+                assert float(avg["w"][0]) == 1.0
+                manager.should_commit()
+            return {
+                "phases": manager.phase_times(),
+                "histogram": set(manager._phase_hist),
+            }
+        finally:
+            manager.shutdown()
+
+    def test_parts_account_for_ring(self, lighthouse, trace_file):
+        from torchft_tpu.manager import PHASE_PARTS
+
+        with ThreadPoolExecutor(max_workers=2) as ex:
+            futs = [
+                ex.submit(self._replica, i, lighthouse.address(), 3)
+                for i in range(2)
+            ]
+            results = [f.result(timeout=120) for f in futs]
+        tracing.uninstall_tracer()
+        ring_parts = {p for p in PHASE_PARTS if p.startswith("ring.")}
+        for res in results:
+            phases = res["phases"]
+            assert ring_parts <= set(phases), sorted(phases)
+            # the histogram's ``phase`` label takes phases, never a part
+            assert "ring" in res["histogram"]
+            assert not {k for k in res["histogram"] if tracing.is_part(k)}
+            opened = sum(phases[p] for p in ring_parts)
+            assert phases["ring"] > 0.03, phases  # tens of ms and more
+            assert opened <= phases["ring"]
+            assert opened >= 0.9 * phases["ring"], (opened, phases["ring"])
+        # in the trace every part is a child of a ring span and lies in it
+        spans = _load_spans(trace_file)
+        by_id = {s["span_id"]: s for s in spans}
+        parts = [s for s in spans if s["name"] in ring_parts]
+        assert {s["name"] for s in parts} == ring_parts
+        for s in parts:
+            whole = by_id[s["parent_span_id"]]
+            assert whole["name"] == "ring"
+            assert whole["start_ns"] <= s["start_ns"]
+            assert s["end_ns"] <= whole["end_ns"]
+            assert s["attributes"]["step"] == whole["attributes"]["step"]
+        # one span per part and bucket, never one per exchange: two
+        # buckets here, so two wire spans per ring
+        rings = [s for s in spans if s["name"] == "ring"]
+        wires = [s for s in parts if s["name"] == "ring.wire"]
+        assert len(wires) == 2 * len(rings)
+
+
 class TestChaosTrace:
     def test_faulted_round_marks_span_and_ledger_names_culprit(
         self, lighthouse, trace_file, capsys

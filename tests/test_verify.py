@@ -349,25 +349,44 @@ class TestPhaseVocabulary:
         assert set(pm.MODEL_PHASE_OPS.values()) <= allowed
 
     def test_manager_phase_vocabulary_matches_recorded_phases(self):
-        """PROTOCOL_PHASES is the closed set _record_phase is called
-        with — scan the source so a new literal cannot drift past it."""
+        """PROTOCOL_PHASES is the closed set of top-level names a
+        ``tracing.phase`` is opened under (``self._phase("...")`` in the
+        Manager, ``_tracing.phase("...")`` in the layers beneath), and
+        PHASE_PARTS the closed set of parts — scan the sources so a new
+        literal cannot drift past either."""
         import ast
         import inspect
 
         from torchft_tpu import manager as mgr
+        from torchft_tpu.checkpointing import fragments, http_transport
+        from torchft_tpu.parallel import process_group
 
-        recorded = set()
-        tree = ast.parse(inspect.getsource(mgr))
-        for node in ast.walk(tree):
-            if (
-                isinstance(node, ast.Call)
-                and isinstance(node.func, ast.Attribute)
-                and node.func.attr == "_record_phase"
-                and node.args
-                and isinstance(node.args[0], ast.Constant)
-            ):
-                recorded.add(node.args[0].value)
-        assert recorded == set(PROTOCOL_PHASES)
+        timed = set()
+        for mod in (mgr, process_group, http_transport, fragments):
+            tree = ast.parse(inspect.getsource(mod))
+            for node in ast.walk(tree):
+                if (
+                    isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr in ("_phase", "phase")
+                    and node.args
+                    and isinstance(node.args[0], ast.Constant)
+                ):
+                    timed.add(node.args[0].value)
+        # no hand-rolled timing beside the primitive
+        assert "_record_phase" not in inspect.getsource(mgr)
+        top = {n for n in timed if "." not in n}
+        assert top == set(PROTOCOL_PHASES)
+        full = {n for n in timed if "." in n and not n.startswith(".")}
+        relative = {n for n in timed if n.startswith(".")}
+        assert full <= set(mgr.PHASE_PARTS)
+        assert all(any(p.endswith(r) for p in mgr.PHASE_PARTS) for r in relative)
+        # every part is timed somewhere, under its full name or its last
+        # component, and its whole is a top-level phase
+        for part in mgr.PHASE_PARTS:
+            whole, _, last = part.rpartition(".")
+            assert whole in PROTOCOL_PHASES
+            assert part in full or "." + last in relative, part
 
 
 class TestVerifyCli:

@@ -5,22 +5,34 @@ spans the fleet emits share ONE name vocabulary — the diagnose ledger
 (``torchft-diagnose --trace``) maps span names to cost categories, and a
 free-form name silently falls out of every report.  Two rules:
 
-**Vocabulary.**  Every ``export_span`` call site must name its span from
-``manager.PROTOCOL_PHASES`` (parsed from the tree, the same canonical
-tuple the flight recorder and the quorum-duration histogram label from),
-the ``quorum_round`` root, or the documented prefix families ``quant.*``
+**Vocabulary.**  Every phase of the protocol is timed through the one
+primitive, ``tracing.phase`` (``Manager._phase`` binds it to a Manager):
+each ``phase(...)`` / ``_phase(...)`` call site must name a literal from
+``manager.PROTOCOL_PHASES``, or a part by a name that starts with a dot
+(``.hash``: a part of whatever phase is open, the one way a part is
+opened), which must be the last component of some entry of
+``manager.PHASE_PARTS`` (a second tuple beside the phases: ``ring.d2h``
+is contained in ``ring``).  The profiler
+annotation of a phase is ``torchft.<name>``; a ``TraceAnnotation`` literal
+under the ``torchft`` prefix anywhere else must be one of those too (no
+second naming scheme).  Every raw ``export_span`` call site must name its
+span from ``manager.PROTOCOL_PHASES`` (parsed from the tree, the same
+canonical tuple the flight recorder and the quorum-duration histogram label
+from), the ``quorum_round`` root, or the documented prefix families ``quant.*``
 (quantized-collective pipeline), ``heal.*`` (checkpoint heal endpoints),
 ``rpc.*`` (native server spans), and ``serving.*`` (weight-serving tier
 publish/fetch/tree-commit) — docs/observability.md "Distributed
 tracing".  One level of indirection is resolved: when the name argument
-is a parameter of the enclosing function (e.g. ``Manager._record_phase``),
+is a parameter of the enclosing function,
 the SAME-MODULE callers' literal arguments are checked instead.
 
 **Flight reach.**  Every traced phase must also reach the flight
 recorder: a function that emits a span must reference the recorder
 within two same-module call hops (the exact rule fault-coverage applies
 to the PG worker and the checkpoint transports) — a trace backend must
-never know something the crash-durable post-mortem dump doesn't.
+never know something the crash-durable post-mortem dump doesn't.  (A
+``phase`` call site needs no reach of its own: the primitive writes the
+flight record of every top-level phase itself.)
 
 ``utils/tracing.py`` itself (the emit implementation) is exempt, as are
 test files.  Waiver: ``# tft-lint: allow(span-vocab)`` on the line.
@@ -57,9 +69,14 @@ EXTRA_SPAN_NAMES = ("quorum_round",)
 _EXEMPT_SUFFIXES = ("utils/tracing.py",)
 
 
-def _protocol_phases(project: Project) -> "Optional[Set[str]]":
-    """Parse ``PROTOCOL_PHASES`` from the tree's manager.py (None when
-    absent — the vocabulary rule then only enforces the families)."""
+#: annotation prefix of the one span primitive (utils/tracing.py)
+ANNOTATION_PREFIX = "torchft"
+
+
+def _manager_tuple(project: Project, var: str) -> "Optional[Set[str]]":
+    """Parse the tuple ``var`` (``PROTOCOL_PHASES`` / ``PHASE_PARTS``) from
+    the tree's manager.py (None when absent — the vocabulary rule then only
+    enforces the families)."""
     path = project.find_file(_MANAGER_FILE)
     if path is None:
         return None
@@ -71,20 +88,33 @@ def _protocol_phases(project: Project) -> "Optional[Set[str]]":
         if (
             isinstance(node, ast.AnnAssign)
             and isinstance(node.target, ast.Name)
-            and node.target.id == "PROTOCOL_PHASES"
+            and node.target.id == var
         ):
             value = node.value
         elif (
             isinstance(node, ast.Assign)
             and len(node.targets) == 1
             and isinstance(node.targets[0], ast.Name)
-            and node.targets[0].id == "PROTOCOL_PHASES"
+            and node.targets[0].id == var
         ):
             value = node.value
         if isinstance(value, (ast.Tuple, ast.List)):
             names = {const_str(e) for e in value.elts}
             return {n for n in names if n is not None}
     return None
+
+
+def _phase_allowed(
+    name: str, phases: "Optional[Set[str]]", parts: "Optional[Set[str]]"
+) -> bool:
+    """A ``phase(...)`` name: a top-level phase, or ``.<last component>``
+    of a part (resolved at run time against the phase open on the
+    thread; the one way a part is opened)."""
+    if phases is None:
+        return True  # no manager.py in the tree: nothing to pin against
+    if name.startswith("."):
+        return any(p.endswith(name) for p in parts or ())
+    return name in phases
 
 
 def _allowed(name: str, phases: "Optional[Set[str]]") -> bool:
@@ -123,6 +153,10 @@ class _EmitCollector(QualnameVisitor):
         self.emits: "List[Tuple[int, str, Optional[ast.AST], str, Set[str]]]" = []
         # function name -> [(call node, lineno)]
         self.calls: "Dict[str, List[ast.Call]]" = {}
+        # ``phase(...)`` / ``_phase(...)`` sites: (call, enclosing params)
+        self.phase_calls: "List[Tuple[ast.Call, Set[str]]]" = []
+        # ``TraceAnnotation(...)`` sites
+        self.annotations: "List[ast.Call]" = []
         self._fn_stack: "List[Tuple[str, Set[str]]]" = []
 
     def _visit_func(self, node: ast.AST) -> None:  # type: ignore[override]
@@ -148,12 +182,18 @@ class _EmitCollector(QualnameVisitor):
             )
         else:
             self.calls.setdefault(leaf, []).append(node)
+            if leaf in ("phase", "_phase"):
+                params = self._fn_stack[-1][1] if self._fn_stack else set()
+                self.phase_calls.append((node, params))
+            elif leaf == "TraceAnnotation":
+                self.annotations.append(node)
         self.generic_visit(node)
 
 
 def run(project: Project) -> "Iterable[Finding]":
     out: "List[Finding]" = []
-    phases = _protocol_phases(project)
+    phases = _manager_tuple(project, "PROTOCOL_PHASES")
+    parts = _manager_tuple(project, "PHASE_PARTS")
 
     for path in project.py_files:
         rel = project.rel(path).replace("\\", "/")
@@ -166,7 +206,7 @@ def run(project: Project) -> "Iterable[Finding]":
             continue
         col = _EmitCollector()
         col.visit(tree)
-        if not col.emits:
+        if not (col.emits or col.phase_calls or col.annotations):
             continue
         reach = _module_flight_reach(tree)
 
@@ -183,6 +223,48 @@ def run(project: Project) -> "Iterable[Finding]":
                     message=message,
                 )
             )
+
+        # the one primitive's call sites: literal names from the two tuples
+        for call, params in col.phase_calls:
+            name_node = _span_name_arg(call)
+            name = const_str(name_node)
+            if name is None:
+                if isinstance(name_node, ast.Name) and name_node.id in params:
+                    continue  # a binder (Manager._phase): its callers are sites
+                flag(
+                    call.lineno,
+                    "non-literal-span-name",
+                    dotted(call.func),
+                    "phase() name is not a literal — the vocabulary cannot "
+                    "be checked statically",
+                )
+            elif not _phase_allowed(name, phases, parts):
+                flag(
+                    call.lineno,
+                    "unknown-span-name",
+                    name,
+                    f"phase name {name!r} is not in manager.PROTOCOL_PHASES, "
+                    f"nor (with a leading dot) the last component of an "
+                    f"entry of manager.PHASE_PARTS — add it there first",
+                )
+        # no second naming scheme under the primitive's annotation prefix
+        for call in col.annotations:
+            name = const_str(_span_name_arg(call))
+            if name is None or not name.startswith(ANNOTATION_PREFIX):
+                continue
+            dotted_prefix = ANNOTATION_PREFIX + "."
+            if phases is not None and not (
+                name.startswith(dotted_prefix)
+                and name[len(dotted_prefix):] in phases | (parts or set())
+            ):
+                flag(
+                    call.lineno,
+                    "unknown-span-name",
+                    name,
+                    f"annotation {name!r} is under the {ANNOTATION_PREFIX!r} "
+                    f"prefix but is not '{ANNOTATION_PREFIX}.<phase or part>' "
+                    f"— time it through tracing.phase instead",
+                )
 
         emitting_fns: "Set[str]" = set()
         for lineno, qual, name_node, fn, params in col.emits:
@@ -289,20 +371,24 @@ def _run_on_project(files: "Dict[str, str]") -> "List[Finding]":
         return list(run(Project(td, paths)))
 
 
-_MANAGER_SRC = 'PROTOCOL_PHASES = ("quorum_rpc", "ring", "commit")\n'
+_MANAGER_SRC = (
+    'PROTOCOL_PHASES = ("quorum_rpc", "ring", "commit", "heal_send")\n'
+    'PHASE_PARTS = ("ring.d2h", "heal_send.hash")\n'
+)
 
 _GOOD_SRC = """
 from torchft_tpu.utils import flightrecorder as _flightrec
 from torchft_tpu.utils import tracing
 
-def _record_phase(name, dt):
-    _flightrec.record(name, kind="phase")
-    tracer = tracing.get_tracer()
-    if tracer is not None:
-        tracer.export_span(name=name, trace_id="t", start_ns=0, end_ns=1)
+def _phase(name, **attrs):
+    return tracing.phase(name, {}, **attrs)
 
-def step(tracer):
-    _record_phase("ring", 0.1)
+def step(tracer, sink):
+    with _phase("ring"):
+        with tracing.phase(".d2h", bytes=1):
+            pass
+    with _phase("heal_send"), tracing.phase(".hash"):
+        pass
     _flightrec.record("quant.pipeline")
     tracer.export_span("quant.pipeline", "t", 0, 1)
     tracer.export_span("heal.send", "t", 0, 1)
@@ -328,6 +414,30 @@ def _phase(name, tracer):
 
 def step(tracer):
     _phase("bogus_phase", tracer)
+"""
+
+_BAD_PHASE_SRC = """
+from torchft_tpu.utils import tracing
+
+def step(sink):
+    with tracing.phase("ring.made_up", sink):
+        pass
+"""
+
+_BAD_PART_SRC = """
+from torchft_tpu.utils import tracing
+
+def step():
+    with tracing.phase(".made_up"):
+        pass
+"""
+
+_BAD_ANNOTATION_SRC = """
+import jax
+
+def step():
+    with jax.profiler.TraceAnnotation("torchft::pg::configure"):
+        pass
 """
 
 _BAD_FLIGHT_SRC = """
@@ -360,13 +470,25 @@ def selftest() -> None:
             f"{PASS_ID}: indirect (parameter) span name not resolved to "
             f"its literal caller (got {sorted(got)})"
         )
+    for what, src in (
+        ("phase() part outside PHASE_PARTS", _BAD_PHASE_SRC),
+        ("relative part outside PHASE_PARTS", _BAD_PART_SRC),
+        ("second annotation scheme under torchft", _BAD_ANNOTATION_SRC),
+    ):
+        got = {f.code for f in _run_on_project({**base, "pkg/bad.py": src})}
+        if "unknown-span-name" not in got:
+            raise SelftestError(
+                f"{PASS_ID}: {what} not caught (got {sorted(got)})"
+            )
 
 
 PASS = LintPass(
     id=PASS_ID,
-    doc="trace-span names come from PROTOCOL_PHASES / quant.* / heal.* / "
-    "rpc.* / serving.* / link.* / fragment.*; every span-emitting "
-    "function also feeds the flight recorder",
+    doc="phase()/_phase() names come from PROTOCOL_PHASES / PHASE_PARTS, "
+    "torchft.* annotations likewise; raw export_span names from "
+    "PROTOCOL_PHASES / quant.* / heal.* / rpc.* / serving.* / link.* / "
+    "fragment.*, and every span-emitting function also feeds the flight "
+    "recorder",
     run=run,
     selftest=selftest,
 )

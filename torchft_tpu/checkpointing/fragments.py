@@ -413,7 +413,10 @@ def iter_heal_fragments(
     iterator lazily yields ``(name, wire_bytes, sha256)`` — each
     ``next()`` performs that fragment's host snapshot + serialize +
     hash, which is what lets the streamed staging overlap a healer's
-    fetch of fragment *i* with the encode of fragment *i+1*.
+    fetch of fragment *i* with the encode of fragment *i+1*.  Each of
+    the three is timed as a part (``.snapshot``, ``.encode``, ``.hash``)
+    of whatever phase the consumer has open: ``heal_send`` on a source,
+    ``heal_diff`` on a healer hashing its own state.
 
     Heal fragments are always ``f32`` wire (bitwise — a healed replica
     must converge exactly), leaf slots split round-robin like
@@ -437,12 +440,24 @@ def iter_heal_fragments(
 
     def gen() -> "Iterator[Tuple[str, bytes, str]]":
         for name in names:
-            frag = {
-                str(slot): leaves[slot]
-                for slot in fragment_slots(name, len(leaves), len(names))
-            }
-            raw = ser.serialize(frag)
-            yield name, raw, hashlib.sha256(raw).hexdigest()
+            slots = fragment_slots(name, len(leaves), len(names))
+            with _tracing.phase(".snapshot", fragment=name):
+                # the device leaves' one copy to the host: serialize then
+                # finds numpy arrays and takes views of them
+                frag = {
+                    str(slot): (
+                        np.asarray(leaves[slot])
+                        if isinstance(leaves[slot], jax.Array)
+                        else leaves[slot]
+                    )
+                    for slot in slots
+                }
+            with _tracing.phase(".encode", fragment=name):
+                raw = ser.serialize(frag)
+            del frag
+            with _tracing.phase(".hash", fragment=name, bytes=len(raw)):
+                digest = hashlib.sha256(raw).hexdigest()
+            yield name, raw, digest
 
     return header, gen()
 
@@ -472,9 +487,10 @@ def stage_heal_checkpoint(
     digests: "Dict[str, str]" = {}
     try:
         for name, raw, digest in frag_iter:
-            transport.stage_streamed_part(
-                step, f"frag:{name}", raw, timeout=timeout
-            )
+            with _tracing.phase(".stage", fragment=name, bytes=len(raw)):
+                transport.stage_streamed_part(
+                    step, f"frag:{name}", raw, timeout=timeout
+                )
             digests[name] = digest
     except BaseException:
         # a torn stage must never linger half-served: retire the slot so

@@ -841,8 +841,13 @@ class HTTPTransport(CheckpointTransport[Any]):
         leaf buffers) overlaps the wire of every in-flight stripe.
 
         Returns ``(state_dict, info)`` where ``info`` carries the phase
-        split (``heal_manifest``/``heal_diff``/``heal_wire``/
-        ``heal_decode``), mode, fragment counts and wire bytes.  Falls
+        split (``phases``: ``heal_manifest``/``heal_diff``/``heal_wire``/
+        ``heal_decode``; ``parts``: what lies inside them,
+        ``heal_manifest.wait``, ``heal_diff.snapshot|encode|hash`` and
+        ``heal_decode.fragment``),
+        mode, fragment counts and wire bytes.  Each is a ``tracing.phase``
+        timed here, when it happens; the Manager folds the seconds into
+        ``phase_times()`` and emits no span of its own for them.  Falls
         back to the legacy single-source whole-document fetch when the
         primary's staged document has no fragments (mixed-config
         fleet)."""
@@ -858,7 +863,9 @@ class HTTPTransport(CheckpointTransport[Any]):
             raise ValueError("striped heal: no sources")
         primary = sources[0]
         deadline = time.monotonic() + timeout
-        phases: "dict[str, float]" = {}
+        # one sink for the four phases and their parts; ``info`` hands
+        # them back apart, by the dot
+        timed: "dict[str, float]" = {}
         info: "dict[str, Any]" = {"sources": len(sources)}
         with _flightrec.track(
             "checkpoint.http.recv", step=step, src_rank=0,
@@ -875,17 +882,28 @@ class HTTPTransport(CheckpointTransport[Any]):
             # the digests (staged last — waits out the source's encode);
             # full mode starts from the digest-less header (staged
             # first) so the stripe overlaps the source's encode.
-            t0 = time.perf_counter()
             want = frags.MANIFEST_FRAG if use_delta else frags.HEADER_FRAG
-            try:
-                mbuf = frags.fetch_raw(
-                    primary, step, f"frag_{want}",
-                    timeout=max(deadline - time.monotonic(), 0.001),
-                    role="heal",
-                )
-            except _uerr.HTTPError as e:
-                if e.code != 404:
-                    raise
+            with _tracing.phase("heal_manifest", timed) as p_manifest:
+                try:
+                    # long-poll and retries while the source has not
+                    # staged it: the healer waiting for the source
+                    with _tracing.phase(".wait"):
+                        mbuf = frags.fetch_raw(
+                            primary, step, f"frag_{want}",
+                            timeout=max(deadline - time.monotonic(), 0.001),
+                            role="heal",
+                        )
+                except _uerr.HTTPError as e:
+                    if e.code != 404:
+                        raise
+                    mbuf = None
+                    p_manifest.cancel()  # a legacy source: no split
+                else:
+                    try:
+                        manifest = frags.decode_manifest(mbuf)
+                    finally:
+                        POOL.give(mbuf)
+            if mbuf is None:
                 # Source staged a legacy whole-document snapshot (mixed
                 # config): take the classic path against the primary.
                 result = self._recv_checkpoint(
@@ -893,13 +911,8 @@ class HTTPTransport(CheckpointTransport[Any]):
                     max(deadline - time.monotonic(), 0.001),
                 )
                 op.update(mode="legacy")
-                info.update(mode="legacy", phases=phases)
+                info.update(mode="legacy", phases={})
                 return frags.maybe_decode_heal_doc(result), info
-            try:
-                manifest = frags.decode_manifest(mbuf)
-            finally:
-                POOL.give(mbuf)
-            phases["heal_manifest"] = time.perf_counter() - t0
 
             names = [str(n) for n in manifest["fragments"]]
             num_leaves = int(manifest["num_leaves"])
@@ -920,38 +933,43 @@ class HTTPTransport(CheckpointTransport[Any]):
 
             # -- diff phase: hash the local state into the source's
             # fragment layout; identical digests need no wire at all.
-            t0 = time.perf_counter()
-            changed = list(names)
-            leaves: "dict[int, Any]" = {}
-            if use_delta:
-                import jax
+            with _tracing.phase("heal_diff", timed):
+                changed = list(names)
+                leaves: "dict[int, Any]" = {}
+                if use_delta:
+                    import jax
 
-                local_leaves = jax.tree_util.tree_flatten(local_state)[0]
-                if len(local_leaves) == num_leaves:
-                    _n, mine = frags.local_fragment_digests(
-                        local_state, len(names)
-                    )
-                    src_digests = manifest.get("digests") or {}
-                    changed = [
-                        n for n in names
-                        if src_digests.get(n) != mine.get(n)
-                    ]
-                    for name in names:
-                        if name not in changed:
-                            for slot in frags.fragment_slots(
-                                name, num_leaves, len(names)
-                            ):
-                                leaves[slot] = local_leaves[slot]
-            phases["heal_diff"] = time.perf_counter() - t0
+                    local_leaves = jax.tree_util.tree_flatten(local_state)[0]
+                    if len(local_leaves) == num_leaves:
+                        _n, mine = frags.local_fragment_digests(
+                            local_state, len(names)
+                        )
+                        src_digests = manifest.get("digests") or {}
+                        changed = [
+                            n for n in names
+                            if src_digests.get(n) != mine.get(n)
+                        ]
+                        for name in names:
+                            if name not in changed:
+                                for slot in frags.fragment_slots(
+                                    name, num_leaves, len(names)
+                                ):
+                                    leaves[slot] = local_leaves[slot]
             mode = "delta" if use_delta else "full"
 
             # -- wire + decode: striped fetch across every source,
             # decode of fragment i overlapping the wire of the rest.
-            decode_busy = [0.0]
             decode_failed: "List[str]" = []
 
             def _decode(name: str, buf: Any, _sha: str) -> None:
-                t_d = time.perf_counter()
+                # heal_decode is one phase a heal, the busy sum of these
+                # stretches; each fragment decoded is a part span in it
+                with p_decode.lap(), _tracing.phase(
+                    ".fragment", fragment=name, bytes=buf.nbytes
+                ):
+                    _decode_into(name, buf)
+
+            def _decode_into(name: str, buf: Any) -> None:
                 try:
                     sub_into = (
                         frags.fragment_into_map(
@@ -984,73 +1002,82 @@ class HTTPTransport(CheckpointTransport[Any]):
                     decode_failed.append(name)
                 finally:
                     POOL.give(buf)
-                decode_busy[0] += time.perf_counter() - t_d
 
-            t0 = time.perf_counter()
-            failover_s = env_float(
-                "TORCHFT_HEAL_FAILOVER_S", 2.0, minimum=0.05
-            )
-            stats = frags.striped_fetch(
-                sources, step, changed, deadline,
-                digests=manifest.get("digests") if use_delta else None,
-                source_budget=failover_s,
-                on_buf=_decode,
-                plane=plane,
-            )
-            wire_bytes = stats["wire_bytes"]
-            failovers = stats["failovers"]
-            sources_used = set(stats["sources_used"])
-
-            if not use_delta and changed:
-                # Deferred verify: the digest manifest (staged last —
-                # the source has finished encoding by the time the
-                # stripe drains) checks every recorded hash.
-                mfull = frags.fetch_raw(
-                    primary, step, f"frag_{frags.MANIFEST_FRAG}",
-                    timeout=max(deadline - time.monotonic(), 0.001),
-                    role="heal",
+            with _tracing.phase("heal_wire", timed) as p_wire:
+                p_decode = _tracing.phase("heal_decode", timed)
+                failover_s = env_float(
+                    "TORCHFT_HEAL_FAILOVER_S", 2.0, minimum=0.05
                 )
-                try:
-                    manifest = frags.decode_manifest(mfull)
-                finally:
-                    POOL.give(mfull)
-            digests = manifest.get("digests") or {}
-            bad = sorted(
-                set(decode_failed)
-                | {
-                    n for n in changed
-                    if n in stats["hashes"]
-                    and digests.get(n, stats["hashes"][n])
-                    != stats["hashes"][n]
-                }
-            )
-            if bad:
-                # Repair pass: mismatched/undecodable fragments refetch
-                # from the PRIMARY alone, digest-verified on receipt; a
-                # decode failure here is terminal (the primary's own
-                # bytes are truth — there is nothing left to fail over
-                # to).
-                _metrics.HEAL_FRAG_FAILOVERS.inc(len(bad))
-                failovers += len(bad)
-                decode_failed.clear()
-                restats = frags.striped_fetch(
-                    [primary], step, bad, deadline,
-                    digests=digests, on_buf=_decode,
+                stats = frags.striped_fetch(
+                    sources, step, changed, deadline,
+                    digests=manifest.get("digests") if use_delta else None,
+                    source_budget=failover_s,
+                    on_buf=_decode,
                     plane=plane,
                 )
-                wire_bytes += restats["wire_bytes"]
-                sources_used |= set(restats["sources_used"])
-                if decode_failed:
-                    raise ValueError(
-                        f"striped heal: fragments {decode_failed} from "
-                        f"the primary verified but failed to decode"
+                wire_bytes = stats["wire_bytes"]
+                failovers = stats["failovers"]
+                sources_used = set(stats["sources_used"])
+
+                if not use_delta and changed:
+                    # Deferred verify: the digest manifest (staged last —
+                    # the source has finished encoding by the time the
+                    # stripe drains) checks every recorded hash.
+                    mfull = frags.fetch_raw(
+                        primary, step, f"frag_{frags.MANIFEST_FRAG}",
+                        timeout=max(deadline - time.monotonic(), 0.001),
+                        role="heal",
                     )
-            loop_wall = time.perf_counter() - t0
-            wire_busy = merged_seconds(stats["spans"])
-            phases["heal_decode"] = decode_busy[0]
-            phases["heal_wire"] = max(
-                wire_busy, loop_wall - decode_busy[0], 0.0
-            )
+                    try:
+                        manifest = frags.decode_manifest(mfull)
+                    finally:
+                        POOL.give(mfull)
+                digests = manifest.get("digests") or {}
+                bad = sorted(
+                    set(decode_failed)
+                    | {
+                        n for n in changed
+                        if n in stats["hashes"]
+                        and digests.get(n, stats["hashes"][n])
+                        != stats["hashes"][n]
+                    }
+                )
+                if bad:
+                    # Repair pass: mismatched/undecodable fragments refetch
+                    # from the PRIMARY alone, digest-verified on receipt; a
+                    # decode failure here is terminal (the primary's own
+                    # bytes are truth — there is nothing left to fail over
+                    # to).
+                    _metrics.HEAL_FRAG_FAILOVERS.inc(len(bad))
+                    failovers += len(bad)
+                    decode_failed.clear()
+                    restats = frags.striped_fetch(
+                        [primary], step, bad, deadline,
+                        digests=digests, on_buf=_decode,
+                        plane=plane,
+                    )
+                    wire_bytes += restats["wire_bytes"]
+                    sources_used |= set(restats["sources_used"])
+                    if decode_failed:
+                        raise ValueError(
+                            f"striped heal: fragments {decode_failed} from "
+                            f"the primary verified but failed to decode"
+                        )
+                # heal_wire: the loop's wall less decode, and no less
+                # than the wire's own busy seconds (decode is a busy sum)
+                p_wire.exclude(
+                    min(
+                        p_decode.end(),
+                        max(
+                            p_wire.elapsed() - merged_seconds(stats["spans"]),
+                            0.0,
+                        ),
+                    )
+                )
+            timed.setdefault("heal_decode", 0.0)  # nothing moved: no decode
+            phases = {
+                k: v for k, v in timed.items() if not _tracing.is_part(k)
+            }
 
             _metrics.HEAL_WIRE_BYTES.labels(mode=mode).inc(wire_bytes)
             # the gauge reports sources that DELIVERED fragments, not
@@ -1083,6 +1110,7 @@ class HTTPTransport(CheckpointTransport[Any]):
                 failovers=failovers,
                 sources_used=len(sources_used),
                 phases=phases,
+                parts={k: v for k, v in timed.items() if k not in phases},
             )
             op.update(
                 mode=mode, fragments=len(names), changed=len(changed),

@@ -121,6 +121,7 @@ class PGTransport(CheckpointTransport[Any]):
     ) -> Any:
         _faults.check("transport.recv", step=step)
         t0 = time.perf_counter()
+        start_ns = time.time_ns()
         # Armed per-transfer deadline (see send_checkpoint): expiry aborts
         # the PG so a dead/stalled sender cannot wedge healing — the
         # receiving replica latches the error and re-heals next quorum.
@@ -226,7 +227,6 @@ class PGTransport(CheckpointTransport[Any]):
             )
             if ctx is not None and ctx.sampled:
                 end_ns = time.time_ns()
-                start_ns = end_ns - int((time.perf_counter() - t0) * 1e9)
                 _flightrec.record(
                     "heal.recv", start_ns=start_ns, step=step,
                     src_rank=src_rank, bytes=nbytes,

@@ -138,6 +138,9 @@ PHASE_CATEGORY = {
     "heal_diff": "codec",
     "heal_wire": "wire",
     "heal_decode": "codec",
+    # the user's load_state_dict of the healed state: host arrays back
+    # onto the device
+    "heal_apply": "compute",
     # online parallelism switching (parallel/layout.py): the reshard
     # slice-diff transfers are wire cost; the commit round is protocol
     "reshard": "wire",
@@ -152,9 +155,14 @@ def ledger_categories(phase_times: "Dict[str, Any]") -> "Dict[str, float]":
     """Fold a phase->duration mapping (``Manager.phase_times`` deltas, or
     a timeline bucket's ``phase_ms``) into ledger categories.  Unknown
     phase names count as ``protocol`` (they are protocol bookkeeping by
-    construction — every traced phase is in ``manager.PROTOCOL_PHASES``)."""
+    construction — every traced phase is in ``manager.PROTOCOL_PHASES``).
+    A name with a dot is a part of the phase before the dot
+    (``manager.PHASE_PARTS``) and is skipped: its seconds are in its
+    whole already."""
     out: "Dict[str, float]" = {}
     for name, dur in phase_times.items():
+        if "." in name:
+            continue
         try:
             v = float(dur)
         except (TypeError, ValueError):
@@ -884,6 +892,12 @@ def apply_wire_split(
 
 
 def _span_dur_s(span: "Dict[str, Any]") -> float:
+    """What the span adds to a sum: the ``seconds`` its phase booked where
+    that differs from its wall (``heal_recv`` is what its split phases
+    leave; ``tracing.phase.exclude``), else end less start."""
+    booked = (span.get("attributes") or {}).get("seconds")
+    if isinstance(booked, (int, float)):
+        return max(float(booked), 0.0)
     try:
         return max(
             (int(span.get("end_ns") or 0) - int(span.get("start_ns") or 0))

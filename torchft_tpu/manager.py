@@ -70,12 +70,14 @@ T = TypeVar("T")
 MANAGER_ADDR_KEY = "manager_addr"
 REPLICA_ID_KEY = "replica_id"
 
-#: Canonical per-step phase vocabulary recorded by ``_record_phase`` (the
-#: quorum_duration histogram labels, flight-recorder phase records, and
-#: per-phase trace spans all use these names).  The tft-verify protocol
-#: model renders its counterexample traces in the same vocabulary
-#: (analysis/protocol_model.MODEL_PHASE_OPS), pinned by a tier-1 test —
-#: add here BEFORE recording a new phase name.
+#: Canonical per-step phase vocabulary: the top-level names a
+#: ``tracing.phase`` is opened under (``Manager._phase`` here, the heal
+#: transport for the striped-heal split).  The quorum_duration histogram
+#: labels, flight-recorder phase records, ``torchft.<name>`` profiler
+#: annotations and per-phase trace spans all use these names.  The
+#: tft-verify protocol model renders its counterexample traces in the same
+#: vocabulary (analysis/protocol_model.MODEL_PHASE_OPS), pinned by a tier-1
+#: test — add here BEFORE timing a new phase.
 PROTOCOL_PHASES = (
     "quorum_wait",
     "quorum_rpc",
@@ -89,11 +91,39 @@ PROTOCOL_PHASES = (
     "heal_diff",
     "heal_wire",
     "heal_decode",
+    # the user's load_state_dict of a healed state (back onto the device)
+    "heal_apply",
     "reshard",
     "layout_commit",
     "host_sync",
     "ring",
     "commit",
+)
+
+#: The parts of those phases, timed where the work is (the PG worker
+#: thread, ``checkpointing/fragments.py``).  A name with a dot is contained
+#: in the phase before the dot: ``phase_times()`` returns parts beside
+#: their wholes, and whatever sums phases skips them (``tracing.is_part``).
+PHASE_PARTS = (
+    # ProcessGroupTCP.allreduce, on its worker thread
+    "ring.queue",  # submit until the worker picks the op up
+    "ring.d2h",  # wait for the device + copy of the leaves to the host
+    "ring.pack",  # bucket concat, pad-in copy (world size 1: the copy)
+    "ring.wire",  # the exchanges: send + receive + waiting for the peer
+    "ring.reduce",  # the in-place ufunc between exchanges
+    "ring.unpack",  # cast back, split, the AVG division and unflatten
+    # fragments.iter_heal_fragments, per fragment, under whoever called
+    "heal_send.snapshot",  # device leaves to host numpy
+    "heal_send.encode",  # serialization.serialize
+    "heal_send.hash",  # sha256
+    "heal_send.stage",  # stage_streamed_part, the native mirror included
+    "heal_diff.snapshot",
+    "heal_diff.encode",
+    "heal_diff.hash",
+    # inside fetch_raw: long-poll until the source staged the manifest
+    "heal_manifest.wait",
+    # one per fragment decoded; heal_decode is their busy sum
+    "heal_decode.fragment",
 )
 
 TIMEOUT_SEC = env_float("TORCHFT_TIMEOUT_SEC", 60.0)
@@ -234,12 +264,12 @@ class Manager:
         # Wall-clock accumulated per protocol phase — the FT-overhead
         # observability surface (the reference only exposes these as
         # profiler spans, torchft/manager.py:385,591,790); consumers read
-        # the non-destructive ``phase_times`` snapshot.  ``_record_phase``
-        # additionally feeds the torchft_quorum_duration_seconds histogram
-        # and, when a tracer is installed, one child span per phase under
-        # the round's root span.
+        # the non-destructive ``phase_times`` snapshot.  ``_phase`` (the
+        # one ``tracing.phase`` primitive) fills it, and additionally feeds
+        # the torchft_quorum_duration_seconds histogram and, when a tracer
+        # is installed, one child span per phase under the round's root.
         self._phase_acc: Dict[str, float] = {}
-        self._phase_lock = threading.Lock()
+        self._summary_lock = threading.Lock()  # the step digest state below
         # Trace context of the in-flight quorum round (None when tracing
         # is off or the step is unsampled).  The trace id is DERIVED FROM
         # THE STEP (tracing.step_trace_id), so every replica group, the
@@ -344,7 +374,7 @@ class Manager:
             self._replica_id.split(":", 1)[0] or self._replica_id
         )
         # Bound metric children cached per replica: the labels() lookup is
-        # ~9 us and _record_phase sits on the step hot path — caching keeps
+        # ~9 us and _observe_phase sits on the step hot path — caching keeps
         # the telemetry cost per phase at the observe() itself (~1 us).
         self._phase_hist: Dict[str, Any] = {}
         self._m_allreduces = metrics.ALLREDUCES.labels(
@@ -360,7 +390,7 @@ class Manager:
         self._m_participants = metrics.PARTICIPANTS.labels(
             replica_id=self._metric_replica_id
         )
-        # Cluster step-timeline digest state (guarded by _phase_lock):
+        # Cluster step-timeline digest state (guarded by _summary_lock):
         # phase_times() snapshot at the last digest, plus codec/wire busy
         # seconds accumulated from quantized collectives since then.  The
         # per-step deltas ride the native manager's lighthouse heartbeat
@@ -621,9 +651,8 @@ class Manager:
         assert (
             self._quorum_future is not None
         ), "must call start_quorum before wait_quorum"
-        t0 = time.perf_counter()
-        self._quorum_future.result()
-        self._record_phase("quorum_wait", time.perf_counter() - t0)
+        with self._phase("quorum_wait"):
+            self._quorum_future.result()
 
     def _async_quorum(
         self, allow_heal: bool, shrink_only: bool, quorum_timeout: float
@@ -634,8 +663,7 @@ class Manager:
         # transports carry it.
         tracing.set_current(self._round_ctx)
         try:
-            t_rpc = time.perf_counter()
-            with jax.profiler.TraceAnnotation("torchft::manager::_client::_quorum"):
+            with self._phase("quorum_rpc"):
 
                 def _quorum_rpc(budget: "Optional[float]") -> Any:
                     # chaos site INSIDE the retry policy: an injected drop
@@ -663,7 +691,6 @@ class Manager:
                 quorum = self._quorum_policy.run(
                     _quorum_rpc, timeout=quorum_timeout, op="manager.quorum"
                 )
-            self._record_phase("quorum_rpc", time.perf_counter() - t_rpc)
         except Exception as e:  # noqa: BLE001 - captured into the protocol
             # Graceful capture (the reference leaves this as a TODO,
             # manager.py:566-567): the replica sits out this step and votes
@@ -708,21 +735,22 @@ class Manager:
         # forbids.  The transfers ride the checkpoint transport, not the
         # PG, so ordering before configure is safe.
         if self._layout is not None:
-            t_lc = time.perf_counter()
             outcome = ""
-            try:
-                faults.check(
-                    "manager.layout_commit",
-                    replica=self._replica_id,
-                    step=quorum.max_step,
-                )
-                outcome = self._layout.maybe_commit(quorum)
-            except Exception as e:  # noqa: BLE001 - degrade, never wedge
-                self._logger.exception(f"layout commit failed: {e}")
-                self._layout.abort_staged(f"layout commit failed: {e}")
-                outcome = "rolled_back"
+            with self._phase("layout_commit") as p_commit:
+                try:
+                    faults.check(
+                        "manager.layout_commit",
+                        replica=self._replica_id,
+                        step=quorum.max_step,
+                    )
+                    outcome = self._layout.maybe_commit(quorum)
+                except Exception as e:  # noqa: BLE001 - degrade, never wedge
+                    self._logger.exception(f"layout commit failed: {e}")
+                    self._layout.abort_staged(f"layout commit failed: {e}")
+                    outcome = "rolled_back"
+                if not outcome:
+                    p_commit.cancel()  # no staged switch to resolve
             if outcome:
-                self._record_phase("layout_commit", time.perf_counter() - t_lc)
                 metrics.LAYOUT_SWITCHES.labels(
                     replica_id=self._metric_replica_id, result=outcome
                 ).inc()
@@ -741,15 +769,15 @@ class Manager:
                     outcome=outcome,
                     layout=str(active.key() if active is not None else None),
                 )
-            t_rs = time.perf_counter()
-            try:
-                staged = self._layout.maybe_stage(self, quorum)
-            except Exception as e:  # noqa: BLE001 - degrade, never wedge
-                self._logger.exception(f"layout staging failed: {e}")
-                self._layout.abort_staged(f"layout staging failed: {e}")
-                staged = True
-            if staged:
-                self._record_phase("reshard", time.perf_counter() - t_rs)
+            with self._phase("reshard") as p_reshard:
+                try:
+                    staged = self._layout.maybe_stage(self, quorum)
+                except Exception as e:  # noqa: BLE001 - degrade, never wedge
+                    self._logger.exception(f"layout staging failed: {e}")
+                    self._layout.abort_staged(f"layout staging failed: {e}")
+                    staged = True
+                if not staged:
+                    p_reshard.cancel()  # the live world still fits the layout
 
         if quorum.quorum_id != self._quorum_id:
             metrics.QUORUM_CHANGES.labels(replica_id=self._metric_replica_id).inc()
@@ -769,15 +797,13 @@ class Manager:
                 f"reconfiguring for quorum_id={quorum.quorum_id} store={store_prefixed_addr}"
             )
             try:
-                t_cfg = time.perf_counter()
-                with jax.profiler.TraceAnnotation("torchft::manager::_pg::configure"):
+                with self._phase("pg_configure"):
                     self._pg.configure(
                         store_prefixed_addr,
                         self._replica_id,
                         quorum.replica_rank,
                         quorum.replica_world_size,
                     )
-                self._record_phase("pg_configure", time.perf_counter() - t_cfg)
                 self._quorum_id = quorum.quorum_id
                 log_event(
                     "reconfigure",
@@ -846,15 +872,14 @@ class Manager:
             and quorum.max_world_size < quorum.replica_world_size
             and self._in_stripe_source_set(quorum)
         ):
-            t_send = time.perf_counter()
             try:
-                self._checkpoint_transport.send_checkpoint_streamed(
-                    dst_ranks=[],
-                    step=quorum.max_step,
-                    state_dict=self._manager_state_dict(),
-                    timeout=self._timeout,
-                )
-                self._record_phase("heal_send", time.perf_counter() - t_send)
+                with self._phase("heal_send"):
+                    self._checkpoint_transport.send_checkpoint_streamed(
+                        dst_ranks=[],
+                        step=quorum.max_step,
+                        state_dict=self._manager_state_dict(),
+                        timeout=self._timeout,
+                    )
                 log_event(
                     "heal",
                     "staged stripe-source checkpoint for healing peers",
@@ -880,10 +905,7 @@ class Manager:
                 self._logger.info(
                     f"peers need recovery from us {quorum.recover_dst_replica_ranks}"
                 )
-                t_send = time.perf_counter()
-                with jax.profiler.TraceAnnotation(
-                    "torchft::manager::_checkpoint_transport::send_checkpoint"
-                ):
+                with self._phase("heal_send"):
                     if streamed_heal:
                         self._checkpoint_transport.send_checkpoint_streamed(
                             dst_ranks=quorum.recover_dst_replica_ranks,
@@ -898,7 +920,6 @@ class Manager:
                             state_dict=self._manager_state_dict(),
                             timeout=self._timeout,
                         )
-                self._record_phase("heal_send", time.perf_counter() - t_send)
                 metrics.HEALS.labels(
                     replica_id=self._metric_replica_id, direction="send"
                 ).inc()
@@ -919,26 +940,24 @@ class Manager:
                     "manager.heal", replica=self._replica_id, step=quorum.max_step
                 )
                 self._healing = True
-                t_recv = time.perf_counter()
-                self._logger.info(
-                    f"healing required, fetching checkpoint metadata from "
-                    f"{quorum.recover_src_manager_address} max_step={quorum.max_step}"
-                )
-                primary_client = ManagerClient(
-                    quorum.recover_src_manager_address,
-                    connect_timeout=self._connect_timeout,
-                )
-                checkpoint_metadata = primary_client._checkpoint_metadata(
-                    self._group_rank, timeout=self._timeout
-                )
-                primary_client.close()
-                assert (
-                    quorum.recover_src_replica_rank is not None
-                ), "must have a recover rank when healing"
-                with jax.profiler.TraceAnnotation(
-                    "torchft::manager::_checkpoint_transport::recv_checkpoint"
-                ):
-                    heal_info: "Dict[str, Any]" = {}
+                heal_info: "Dict[str, Any]" = {}
+                # the step healed TO, on heal_recv and what it opens
+                with self._phase("heal_recv", step=quorum.max_step) as p_recv:
+                    self._logger.info(
+                        f"healing required, fetching checkpoint metadata from "
+                        f"{quorum.recover_src_manager_address} max_step={quorum.max_step}"
+                    )
+                    primary_client = ManagerClient(
+                        quorum.recover_src_manager_address,
+                        connect_timeout=self._connect_timeout,
+                    )
+                    checkpoint_metadata = primary_client._checkpoint_metadata(
+                        self._group_rank, timeout=self._timeout
+                    )
+                    primary_client.close()
+                    assert (
+                        quorum.recover_src_replica_rank is not None
+                    ), "must have a recover rank when healing"
                     if streamed_heal:
                         sources = [checkpoint_metadata]
                         # Stripe only when genuinely BEHIND the cohort:
@@ -949,6 +968,8 @@ class Manager:
                             sources += self._resolve_stripe_sources(
                                 quorum, checkpoint_metadata
                             )
+                        # heal_manifest, heal_diff, heal_wire and
+                        # heal_decode are timed in there, as they happen
                         (
                             self._pending_state_dict,
                             heal_info,
@@ -967,37 +988,19 @@ class Manager:
                                 timeout=self._timeout,
                             )
                         )
-                self.load_state_dict(self._pending_state_dict["torchft"])
-                # loading the torchft dict restores the step; set it anyway
-                # to make reasoning (and tests) simpler
-                self._step = quorum.max_step
-                # Phase split (ISSUE 15): the striped path records its
-                # four sub-phases plus the residue (metadata RPC, source
-                # resolution, reassembly) under the legacy heal_recv
-                # name, so ledger sums stay exact and never double-count
-                # a split phase against its umbrella.
-                heal_phases = heal_info.get("phases") or {}
-                if "heal_manifest" in heal_phases:
-                    self._record_phase(
-                        "heal_manifest", heal_phases["heal_manifest"]
+                    self.load_state_dict(self._pending_state_dict["torchft"])
+                    # loading the torchft dict restores the step; set it anyway
+                    # to make reasoning (and tests) simpler
+                    self._step = quorum.max_step
+                    # Phase split (ISSUE 15): heal_recv is what the four
+                    # split phases leave (metadata RPC, source resolution,
+                    # reassembly), so ledger sums stay exact and never
+                    # count a split phase against its umbrella.
+                    p_recv.exclude(
+                        self._fold_phases(
+                            heal_info.get("phases"), heal_info.get("parts")
+                        )
                     )
-                if "heal_diff" in heal_phases:
-                    self._record_phase("heal_diff", heal_phases["heal_diff"])
-                if "heal_wire" in heal_phases:
-                    self._record_phase("heal_wire", heal_phases["heal_wire"])
-                if "heal_decode" in heal_phases:
-                    self._record_phase(
-                        "heal_decode", heal_phases["heal_decode"]
-                    )
-                self._record_phase(
-                    "heal_recv",
-                    max(
-                        time.perf_counter()
-                        - t_recv
-                        - sum(heal_phases.values()),
-                        0.0,
-                    ),
-                )
                 metrics.HEALS.labels(
                     replica_id=self._metric_replica_id, direction="recv"
                 ).inc()
@@ -1142,44 +1145,48 @@ class Manager:
         of surviving local state.  Returns True when restored (state is
         pending; the standard healing application path applies it).
         Any failure returns False — fresh init, never a wedge."""
-        t0 = time.perf_counter()
         try:
-            faults.check("store.restore", replica=self._replica_id, step=0)
-            own = self._checkpoint_transport.metadata()
-            bases = self._resolve_store_bases(quorum, own)
-            catalogs: "Dict[str, Any]" = {}
-            for base in bases:
-                cat = fragment_store.fetch_catalog(
-                    base, timeout=self._connect_timeout
+            with self._phase("heal_recv") as p_recv:
+                faults.check("store.restore", replica=self._replica_id, step=0)
+                own = self._checkpoint_transport.metadata()
+                bases = self._resolve_store_bases(quorum, own)
+                catalogs: "Dict[str, Any]" = {}
+                for base in bases:
+                    cat = fragment_store.fetch_catalog(
+                        base, timeout=self._connect_timeout
+                    )
+                    if cat:
+                        catalogs[base] = cat
+                plan = fragment_store.select_cut(catalogs)
+                if plan is None:
+                    p_recv.cancel()  # nothing spilled: fresh init
+                    return False
+                version, sources = plan
+                p_recv.attrs["step"] = version  # the step restored TO
+                self._logger.info(
+                    f"cold restore: selected spilled v{version} across "
+                    f"{len(sources)} disk(s)"
                 )
-                if cat:
-                    catalogs[base] = cat
-            plan = fragment_store.select_cut(catalogs)
-            if plan is None:
-                return False
-            version, sources = plan
-            self._logger.info(
-                f"cold restore: selected spilled v{version} across "
-                f"{len(sources)} disk(s)"
-            )
-            self._healing = True
-            (
-                self._pending_state_dict,
-                info,
-            ) = self._checkpoint_transport.recv_checkpoint_striped(
-                sources,
-                step=version,
-                timeout=self._timeout,
-                local_state_fn=self._manager_state_dict,
-                plane="restore",
-            )
-            metrics.STORE_RESTORE_BYTES.labels(
-                mode=info.get("mode", "full")
-            ).inc(int(info.get("wire_bytes") or 0))
-            self.load_state_dict(
-                cast(Dict[str, int], self._pending_state_dict["torchft"])
-            )
-            self._record_phase("heal_recv", time.perf_counter() - t0)
+                self._healing = True
+                (
+                    self._pending_state_dict,
+                    info,
+                ) = self._checkpoint_transport.recv_checkpoint_striped(
+                    sources,
+                    step=version,
+                    timeout=self._timeout,
+                    local_state_fn=self._manager_state_dict,
+                    plane="restore",
+                )
+                metrics.STORE_RESTORE_BYTES.labels(
+                    mode=info.get("mode", "full")
+                ).inc(int(info.get("wire_bytes") or 0))
+                self.load_state_dict(
+                    cast(Dict[str, int], self._pending_state_dict["torchft"])
+                )
+                p_recv.exclude(
+                    self._fold_phases(info.get("phases"), info.get("parts"))
+                )
             metrics.HEALS.labels(
                 replica_id=self._metric_replica_id, direction="recv"
             ).inc()
@@ -1221,8 +1228,10 @@ class Manager:
         self._logger.info("applying pending state dict")
         assert self._load_state_dict_fns, "user load_state_dict is not initialized"
         user_state = cast(Dict[str, Any], pending["user"])
-        for key, load_fn in self._load_state_dict_fns.items():
-            load_fn(user_state[key])
+        with self._phase("heal_apply"):
+            # the user's load puts the healed host arrays back on the device
+            for key, load_fn in self._load_state_dict_fns.items():
+                load_fn(user_state[key])
         self._pending_state_dict = None
 
     # ------------------------------------------------------------------
@@ -1254,36 +1263,35 @@ class Manager:
         self.wait_quorum()
         num_participants = self.num_participants()
 
-        t_host = time.perf_counter()
-        leaves, treedef = jax.tree_util.tree_flatten(value)
-        if should_quantize and self.is_participating():
-            # Leave device arrays on device: the quantized collective runs
-            # the Pallas quantize kernel on-chip (when on TPU) so only the
-            # int8 payload + row scales cross the device→host boundary
-            # (reference wires its Triton kernels the same way,
-            # torchft/collectives.py:297-415).  The device→host hop is then
-            # inside the collective and counted in the ``ring`` phase.
-            # Non-array leaves (Python scalars) still need numpy wrapping
-            # for the dtype checks below.
-            send_leaves: "List[Any]" = [
-                x if isinstance(x, (np.ndarray, jax.Array)) else np.asarray(x)
-                for x in leaves
-            ]
-        elif not self.is_participating():
-            send_leaves = [np.zeros_like(np.asarray(x)) for x in leaves]
-        else:
-            # Leaves pass through unmaterialized: the PG converts on its
-            # worker thread, so the device→host sync overlaps whatever the
-            # caller does next instead of blocking this thread (counted in
-            # the ``ring`` phase; the DiLoCo fragment-overlap pattern
-            # depends on this submit being non-blocking).  Non-array leaves
-            # (Python scalars) still need numpy wrapping for the dtype
-            # checks below.
-            send_leaves = [
-                x if isinstance(x, (np.ndarray, jax.Array)) else np.asarray(x)
-                for x in leaves
-            ]
-        self._record_phase("host_sync", time.perf_counter() - t_host)
+        with self._phase("host_sync"):
+            leaves, treedef = jax.tree_util.tree_flatten(value)
+            if should_quantize and self.is_participating():
+                # Leave device arrays on device: the quantized collective runs
+                # the Pallas quantize kernel on-chip (when on TPU) so only the
+                # int8 payload + row scales cross the device→host boundary
+                # (reference wires its Triton kernels the same way,
+                # torchft/collectives.py:297-415).  The device→host hop is then
+                # inside the collective and counted in the ``ring`` phase.
+                # Non-array leaves (Python scalars) still need numpy wrapping
+                # for the dtype checks below.
+                send_leaves: "List[Any]" = [
+                    x if isinstance(x, (np.ndarray, jax.Array)) else np.asarray(x)
+                    for x in leaves
+                ]
+            elif not self.is_participating():
+                send_leaves = [np.zeros_like(np.asarray(x)) for x in leaves]
+            else:
+                # Leaves pass through unmaterialized: the PG converts on its
+                # worker thread, so the device→host sync overlaps whatever the
+                # caller does next instead of blocking this thread (counted in
+                # the ``ring`` phase; the DiLoCo fragment-overlap pattern
+                # depends on this submit being non-blocking).  Non-array leaves
+                # (Python scalars) still need numpy wrapping for the dtype
+                # checks below.
+                send_leaves = [
+                    x if isinstance(x, (np.ndarray, jax.Array)) else np.asarray(x)
+                    for x in leaves
+                ]
 
         if reduce_op == REDUCE_AVG:
             if not all(_is_floating(x.dtype) for x in send_leaves):
@@ -1299,21 +1307,27 @@ class Manager:
             faults.check(
                 "pg.allreduce", replica=self._replica_id, step=self._step
             )
-            t_submit = time.perf_counter()
-            if should_quantize:
-                from torchft_tpu.ops.collectives import allreduce_quantized
+            # ``ring`` ends in the completion callback, on the PG worker's
+            # thread: the explicit form, no annotation of its own.  Its
+            # parts (PHASE_PARTS ``ring.*``) are timed there, by the PG;
+            # ``under`` makes them ring's, in phase_times() and the trace.
+            ring = self._phase("ring").begin()
+            with tracing.under(ring):
+                if should_quantize:
+                    from torchft_tpu.ops.collectives import allreduce_quantized
 
-                work = allreduce_quantized(
-                    send_leaves, pg_reduce_op, self._pg,
-                    device_quantize=device_quantize,
-                )
-            else:
-                work = self._pg.allreduce(send_leaves, pg_reduce_op)
+                    work = allreduce_quantized(
+                        send_leaves, pg_reduce_op, self._pg,
+                        device_quantize=device_quantize,
+                    )
+                else:
+                    work = self._pg.allreduce(send_leaves, pg_reduce_op)
 
             def _postprocess(reduced: "List[np.ndarray]") -> Any:
-                if reduce_op == REDUCE_AVG:
-                    reduced = [x / num_participants for x in reduced]
-                return jax.tree_util.tree_unflatten(treedef, reduced)
+                with tracing.under(ring), tracing.phase(".unpack"):
+                    if reduce_op == REDUCE_AVG:
+                        reduced = [x / num_participants for x in reduced]
+                    return jax.tree_util.tree_unflatten(treedef, reduced)
 
             chained = work.then(_postprocess)
 
@@ -1328,13 +1342,13 @@ class Manager:
 
             def _done(f: "concurrent.futures.Future[Any]") -> None:
                 held = inputs.pop()
-                self._record_phase("ring", time.perf_counter() - t_submit)
+                ring.end(ok=f.exception() is None)
                 # quantized-pipeline accounting for the step digest: the
                 # stats dict is complete once the pipeline finished, i.e.
                 # before this callback fires
                 qs = getattr(work, "quant_stats", None)
                 if isinstance(qs, dict):
-                    with self._phase_lock:
+                    with self._summary_lock:
                         self._summary_codec_s += float(qs.get("codec_s") or 0.0)
                         self._summary_wire_s += float(qs.get("wire_s") or 0.0)
                 exc = f.exception()
@@ -1412,15 +1426,13 @@ class Manager:
         value (reference manager.py:790-878)."""
         # recovery (send/recv checkpoint) must be complete before committing
         if self._quorum_future is not None:
-            t_q = time.perf_counter()
-            try:
-                self._quorum_future.result()
-            except Exception as e:  # noqa: BLE001
-                self.report_error(
-                    e if isinstance(e, Exception) else RuntimeError(str(e))
-                )
-            finally:
-                self._record_phase("quorum_wait", time.perf_counter() - t_q)
+            with self._phase("quorum_wait"):
+                try:
+                    self._quorum_future.result()
+                except Exception as e:  # noqa: BLE001
+                    self.report_error(
+                        e if isinstance(e, Exception) else RuntimeError(str(e))
+                    )
 
         if (err := self._pg.errored()) is not None:
             self.report_error(err)
@@ -1430,25 +1442,24 @@ class Manager:
 
         enough_replicas = self.num_participants() >= self._min_replica_size
         local_should_commit = enough_replicas and self._errored is None
-        t_commit = time.perf_counter()
-        try:
-            should_commit = self._client.should_commit(
-                self._group_rank,
-                self._step,
-                local_should_commit,
-                timeout=_to_sec(timeout, self._timeout),
-            )
-        except ConnectionError as e:
-            # The vote RPC is non-idempotent (no blind resend — a double-
-            # delivered vote could release the barrier with a stale tally),
-            # so a broken connection surfaces here.  Abstain: latch the
-            # error and treat the step as uncommitted — if the group did
-            # commit without us, our step falls behind and the next quorum
-            # heals us, the same path as any other failed step.
-            self._logger.exception(f"should_commit rpc failed, abstaining: {e}")
-            self.report_error(e)
-            should_commit = False
-        self._record_phase("commit", time.perf_counter() - t_commit)
+        with self._phase("commit"):
+            try:
+                should_commit = self._client.should_commit(
+                    self._group_rank,
+                    self._step,
+                    local_should_commit,
+                    timeout=_to_sec(timeout, self._timeout),
+                )
+            except ConnectionError as e:
+                # The vote RPC is non-idempotent (no blind resend — a double-
+                # delivered vote could release the barrier with a stale tally),
+                # so a broken connection surfaces here.  Abstain: latch the
+                # error and treat the step as uncommitted — if the group did
+                # commit without us, our step falls behind and the next quorum
+                # heals us, the same path as any other failed step.
+                self._logger.exception(f"should_commit rpc failed, abstaining: {e}")
+                self.report_error(e)
+                should_commit = False
         self._m_commits["success" if should_commit else "failure"].inc()
         self._m_participants.set(self.num_participants())
         self._logger.info(
@@ -1515,7 +1526,7 @@ class Manager:
         self._report_step_summary()
 
         # Close the quorum round's root span (children were emitted per
-        # phase from _record_phase, native rpc.* server spans joined via
+        # phase by _phase, native rpc.* server spans joined via
         # the shared trace id); the ``step`` attribute is the step the
         # round RAN, matching the trace-id derivation, so the diagnose
         # ledger joins spans, flight dumps, and the lighthouse timeline
@@ -1547,26 +1558,28 @@ class Manager:
     # introspection
     # ------------------------------------------------------------------
 
-    def _record_phase(self, name: str, dt: float) -> None:
-        """Record one phase timing into every observability surface: the
-        destructive accumulator (bench), the non-destructive
-        torchft_quorum_duration_seconds histogram (scrapers), and — when a
-        tracer is installed — a child span under the round's root span.
-        Called from the caller thread AND the async quorum thread."""
-        with self._phase_lock:
-            self._phase_acc[name] = self._phase_acc.get(name, 0.0) + dt
-        # flight record per phase: the quorum protocol's footprint in the
-        # postmortem timeline (~6 records/step; record() is ~1 us)
-        end_ns = time.time_ns()
-        flightrec.record(
+    def _phase(self, name: str, **attrs: Any) -> tracing.phase:
+        """The one way this module times a phase (``PROTOCOL_PHASES``):
+        ``tracing.phase`` bound to this Manager's accumulator, histogram
+        and identity.  On exit the seconds land in ``phase_times()``, the
+        ``torchft_quorum_duration_seconds`` histogram and the flight ring,
+        and the span, with its true start and end, under the round's root
+        when a tracer is installed; while it is open
+        ``torchft.<name>`` shows in a ``jax.profiler`` trace.  Opened on the
+        caller thread AND the async quorum thread."""
+        return tracing.phase(
             name,
-            kind="phase",
-            start_ns=end_ns - int(dt * 1e9),
-            end_ns=end_ns,
-            replica_id=self._replica_id,
-            quorum_id=self._quorum_id,
-            step=self._step,
+            self._phase_acc,
+            observe=self._observe_phase,
+            **{
+                "replica_id": self._replica_id,
+                "quorum_id": self._quorum_id,
+                "step": self._step,
+                **attrs,
+            },
         )
+
+    def _observe_phase(self, name: str, seconds: float) -> None:
         child = self._phase_hist.get(name)
         if child is None:
             # benign race: concurrent creators both resolve to the same
@@ -1575,58 +1588,76 @@ class Manager:
                 replica_id=self._metric_replica_id, phase=name
             )
             self._phase_hist[name] = child
-        child.observe(dt)
-        tracer = tracing.get_tracer()
-        ctx = self._round_ctx
-        if tracer is not None and ctx is not None:
-            end_ns = time.time_ns()
-            # Phase names come from PROTOCOL_PHASES (pinned by tier-1;
-            # span-vocab lint checks the literal call sites).
-            tracer.export_span(
-                name=name,
-                trace_id=ctx.trace_id,
-                parent_span_id=ctx.span_id,
-                start_ns=end_ns - int(dt * 1e9),
-                end_ns=end_ns,
-                attributes={
-                    "replica_id": self._replica_id,
-                    "quorum_id": self._quorum_id,
-                    "step": self._step,
-                },
-            )
+        child.observe(seconds)
+
+    def _fold_phases(self, *carried: "Optional[Dict[str, float]]") -> float:
+        """Add the seconds the heal transport timed into a dict of its own
+        (the heal ``info``'s ``phases`` and ``parts``) to the accumulator.
+        Their spans were emitted where they happened: none is emitted
+        here.  Returns the seconds of the top-level phases among them."""
+        top = 0.0
+        for timed in carried:
+            for name, seconds in (timed or {}).items():
+                tracing.add_seconds(self._phase_acc, name, seconds)
+                if not tracing.is_part(name):
+                    top += seconds
+        return top
 
     def phase_times(self) -> "Dict[str, float]":
         """Non-destructive snapshot of the cumulative wall-clock seconds
-        spent per protocol phase.  Safe for any number of concurrent
-        consumers (bench takes deltas between snapshots); scrapers should
-        prefer the ``torchft_quorum_duration_seconds`` histogram, which
-        this same data also feeds.
+        spent per protocol phase (``PROTOCOL_PHASES``) and per part of one
+        (``PHASE_PARTS``).  Safe for any number of concurrent consumers
+        (bench takes deltas between snapshots); scrapers should prefer the
+        ``torchft_quorum_duration_seconds`` histogram, which the phases
+        (not their parts) also feed.
+
+        **A key with a dot is contained in the key before the dot**
+        (``ring.d2h`` is part of ``ring``): sum the keys without a dot, or
+        a part counts against its whole (``tracing.is_part``).
 
         Caller-thread keys: ``quorum_wait`` (blocked waiting for the async
         quorum work — the part NOT hidden behind the forward pass; includes
         the wait in ``should_commit``), ``host_sync`` (caller-thread
-        flatten + zero-fill; the device→host materialisation itself runs on
-        the PG worker and lands in ``ring``), ``ring`` (collective
-        submit→completion: device sync, queueing, the wire, and the
-        host-side AVG division chained after the raw collective),
-        ``commit`` (should_commit RPC barrier).
+        flatten + zero-fill), ``ring`` (collective submit→completion),
+        ``heal_apply`` (the user's ``load_state_dict`` of a healed state:
+        host arrays back onto the device), ``commit`` (should_commit RPC
+        barrier).
+
+        ``ring`` is opened into its parts on the PG worker thread
+        (``ProcessGroupTCP``; they sum to ``ring`` within the thread
+        hand-offs): ``ring.queue`` (submit until the worker picks the op
+        up), ``ring.d2h`` (wait for the device + device→host copy of the
+        leaves), ``ring.pack`` (bucket concat and pad-in copy into the ring
+        buffer; at world size 1 the copy), ``ring.wire`` (the 2(w-1)
+        exchanges of each bucket: send + receive + waiting for the peer),
+        ``ring.reduce`` (the in-place ufunc between them), ``ring.unpack``
+        (cast back, split, and the AVG division + unflatten chained after
+        the raw collective).
 
         Async-quorum-thread keys (run inside the executor, so they OVERLAP
         ``quorum_wait`` rather than adding to it — they break down what the
         caller was waiting FOR): ``quorum_rpc`` (the lighthouse-mediated
         quorum round trip), ``pg_configure`` (collective reconfigure on
-        quorum change), ``heal_send`` / ``heal_recv`` (live checkpoint
-        transfer to/from a recovering peer, incl. the metadata fetch),
-        ``reshard`` (online-parallelism-switch staging: plan + slice-diff
-        transfers into the staged buffer) and ``layout_commit`` (the
-        fleet-wide activate/rollback of a staged layout at the commit
-        round) — both only with a LayoutController attached.
-
-        (``pop_phase_times``, the destructive single-consumer drain this
-        replaced, was deprecated in PR 3 and removed in PR 9.)
+        quorum change), ``heal_send`` (staging a live checkpoint for a
+        recovering peer; per fragment ``heal_send.snapshot`` device→host,
+        ``.encode`` serialize, ``.hash`` sha256, ``.stage`` hand-over to
+        the transport), ``heal_manifest`` (fetch of the primary's manifest;
+        ``heal_manifest.wait`` is the long-poll inside it while the source
+        is still encoding), ``heal_diff`` (hashing the local state into the
+        source's layout: ``heal_diff.snapshot|encode|hash``), ``heal_wire``
+        (the striped fetch loop's wall less decode), ``heal_decode`` (busy
+        seconds of fragment decode, one ``heal_decode.fragment`` each),
+        ``heal_recv`` (what those four leave of the receive: metadata RPC,
+        source resolution, reassembly; the whole receive on the legacy
+        path), ``reshard`` (online-parallelism-switch
+        staging: plan + slice-diff transfers into the staged buffer) and
+        ``layout_commit`` (the fleet-wide activate/rollback of a staged
+        layout at the commit round) — both only with a LayoutController
+        attached.
         """
-        with self._phase_lock:
-            return dict(self._phase_acc)
+        # tracing.add_seconds is the accumulator's only writer and this its
+        # only reader: one copy, atomic under the GIL, and no lock to share
+        return dict(self._phase_acc)
 
     def _report_progress(self, inflight_op: str) -> None:
         """Push (step, in-flight op) to the group's native ManagerServer so
@@ -1650,13 +1681,17 @@ class Manager:
         server = self._manager_server
         if server is None:
             return
-        with self._phase_lock:
+        acc = self.phase_times()
+        with self._summary_lock:
+            # the digest is summed by its readers (the timeline's
+            # ledger): whole phases only, never a part beside its whole
             phases = {
                 k: round((v - self._summary_phase_snapshot.get(k, 0.0)) * 1e3, 3)
-                for k, v in self._phase_acc.items()
-                if v - self._summary_phase_snapshot.get(k, 0.0) > 0.0
+                for k, v in acc.items()
+                if not tracing.is_part(k)
+                and v - self._summary_phase_snapshot.get(k, 0.0) > 0.0
             }
-            self._summary_phase_snapshot = dict(self._phase_acc)
+            self._summary_phase_snapshot = acc
             codec_s, self._summary_codec_s = self._summary_codec_s, 0.0
             wire_s, self._summary_wire_s = self._summary_wire_s, 0.0
         try:

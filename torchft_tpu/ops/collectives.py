@@ -240,6 +240,7 @@ class _ChunkPipeline:
         # single-worker FIFO serializes every completion callback)
         self.hop_wire_s: "Dict[str, float]" = {}
         self.t_call = time.perf_counter()
+        self.t_call_ns = time.time_ns()
         # Distributed tracing: capture the submitting thread's context
         # (the Manager's round) at construction — completion callbacks
         # run on PG-worker/driver threads, where the thread-local is not
@@ -279,14 +280,12 @@ class _ChunkPipeline:
         tracer = _tracing.get_tracer()
         ctx = self.trace_ctx
         if tracer is not None and ctx is not None:
-            end_ns = time.time_ns()
             tracer.export_span(
                 name="quant.pipeline",
                 trace_id=ctx.trace_id,
                 parent_span_id=ctx.span_id,
-                start_ns=end_ns
-                - int((time.perf_counter() - self.t_call) * 1e9),
-                end_ns=end_ns,
+                start_ns=self.t_call_ns,
+                end_ns=time.time_ns(),
                 attributes={
                     "collective": self.collective,
                     "wire": self.wire_dtype,
@@ -755,13 +754,12 @@ class _ChunkPipeline:
         tracer = _tracing.get_tracer()
         ctx = self.trace_ctx
         if tracer is not None and ctx is not None:
-            end_ns = time.time_ns()
             tracer.export_span(
                 name="quant.pipeline",
                 trace_id=ctx.trace_id,
                 parent_span_id=ctx.span_id,
-                start_ns=end_ns - int(wall * 1e9),
-                end_ns=end_ns,
+                start_ns=self.t_call_ns,
+                end_ns=time.time_ns(),
                 attributes={
                     "collective": self.collective,
                     "wire": self.wire_dtype,

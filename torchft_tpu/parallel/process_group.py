@@ -54,6 +54,7 @@ from torchft_tpu.utils import flightrecorder as _flightrec
 from torchft_tpu.utils import linkstats as _linkstats
 from torchft_tpu.utils import lockcheck as _lockcheck
 from torchft_tpu.utils import metrics as _metrics
+from torchft_tpu.utils import tracing as _tracing
 from torchft_tpu.utils.bufpool import POOL as _pool
 from torchft_tpu.utils.env import env_float, env_str
 
@@ -1005,17 +1006,27 @@ class ProcessGroupTCP(ProcessGroup):
 
     def allreduce(self, arrays: "List[Any]", op: str = REDUCE_SUM) -> Work:
         deadline_budget = self._timeout
+        # The ring, opened (manager.PHASE_PARTS ``ring.*``): each part is
+        # timed here, where it runs, as a part of the caller's open phase
+        # (the Manager's ``ring``), which is carried to the worker thread:
+        # the seconds land in its sink, the spans are its children.
+        whole = _tracing.open_phase()
+        nbytes = sum(int(getattr(a, "nbytes", 0)) for a in arrays)
+        queued = _tracing.phase(".queue").begin()
 
         def run() -> List[np.ndarray]:
-            # device→host materialization happens HERE, on the PG worker:
-            # for jax-array inputs `_as_numpy` blocks on device compute +
-            # transfer, and doing that on the caller thread would stall it
-            # for the whole sync instead of letting the submit return
-            # immediately (the DiLoCo overlap pattern: outer-grad allreduce
-            # rides behind the next fragment's inner steps).
-            deadline = time.monotonic() + deadline_budget
-            np_arrays = [_as_numpy(a) for a in arrays]
-            return self._allreduce_coalesced(np_arrays, op, deadline)
+            queued.end()
+            with _tracing.under(whole):
+                # device→host materialization happens HERE, on the PG worker:
+                # for jax-array inputs `_as_numpy` blocks on device compute +
+                # transfer, and doing that on the caller thread would stall it
+                # for the whole sync instead of letting the submit return
+                # immediately (the DiLoCo overlap pattern: outer-grad allreduce
+                # rides behind the next fragment's inner steps).
+                deadline = time.monotonic() + deadline_budget
+                with _tracing.phase(".d2h", bytes=nbytes):
+                    np_arrays = [_as_numpy(a) for a in arrays]
+                return self._allreduce_coalesced(np_arrays, op, deadline)
 
         work = self._submit(run, op="allreduce")
         # Wire accounting on the UNQUANTIZED path too (parity with the
@@ -1101,10 +1112,16 @@ class ProcessGroupTCP(ProcessGroup):
         (the reference's bucketized-allreduce idea,
         TORCHFT_USE_BUCKETIZATION, local_sgd.py:29); oversized leaves ring
         solo on the zero-copy path. Order-preserving.
+
+        Timed as the parts ``.pack|wire|reduce|unpack`` of the open phase:
+        one span per part and bucket, never one per exchange.
         """
-        if len(arrays) <= 1 or self._world == 1:
-            # world==1: _allreduce_one is a pure copy; skip bucketing work
+        if self._world == 1:
+            # world==1: the allreduce is a pure copy; skip bucketing work
             # entirely (the post-failure shrunken-group hot path)
+            with _tracing.phase(".pack", bytes=sum(a.nbytes for a in arrays)):
+                return [a.copy() for a in arrays]
+        if len(arrays) <= 1:
             return [self._allreduce_one(a, op, deadline) for a in arrays]
         buckets = self._plan_buckets(
             [(_accumulation_dtype(a.dtype), a.size) for a in arrays]
@@ -1117,73 +1134,83 @@ class ProcessGroupTCP(ProcessGroup):
                 continue
             # cast leaves individually: mixed input dtypes sharing one acc
             # dtype (f16+f32, bf16) may not have a numpy promotion rule
-            flat = np.concatenate(
-                [
-                    np.ascontiguousarray(arrays[i])
-                    .ravel()
-                    .astype(acc_dtype, copy=False)
-                    for i in idxs
-                ]
-            )
-            reduced = self._allreduce_one(flat, op, deadline)
-            off = 0
-            for i in idxs:
-                n = arrays[i].size
-                results[i] = (
-                    reduced[off : off + n]
-                    .astype(arrays[i].dtype, copy=False)
-                    .reshape(arrays[i].shape)
+            with _tracing.phase(".pack", leaves=len(idxs)):
+                flat = np.concatenate(
+                    [
+                        np.ascontiguousarray(arrays[i])
+                        .ravel()
+                        .astype(acc_dtype, copy=False)
+                        for i in idxs
+                    ]
                 )
-                off += n
+            reduced = self._allreduce_one(flat, op, deadline)
+            with _tracing.phase(".unpack", leaves=len(idxs)):
+                off = 0
+                for i in idxs:
+                    n = arrays[i].size
+                    results[i] = (
+                        reduced[off : off + n]
+                        .astype(arrays[i].dtype, copy=False)
+                        .reshape(arrays[i].shape)
+                    )
+                    off += n
         return results  # type: ignore[return-value]
 
     def _allreduce_one(self, array: np.ndarray, op: str, deadline: float) -> np.ndarray:
         w, r = self._world, self._rank
-        if w == 1:
-            return array.copy()
         acc_dtype = _accumulation_dtype(array.dtype)
         inplace_reduce = _REDUCE_UFUNCS[op]
         n = array.size
         chunk = -(-n // w)
-        # single private buffer; chunks are views of it, so ring steps
-        # receive in place and reduce in place — the only full-size copies
-        # are the pad-in and (if dtype widened) the cast back out
-        # buf escapes to the caller as the result view — not poolable;
-        # scratch is private to this call and its size repeats every ring
-        # (page-fault amortization, utils/bufpool.py)
-        buf = np.empty(chunk * w, dtype=acc_dtype)
-        buf[:n] = array.ravel()
-        if chunk * w > n:
-            buf[n:] = 0
-        chunks = [buf[i * chunk : (i + 1) * chunk] for i in range(w)]
-        scratch = _pool.take(chunk, acc_dtype)
+        with _tracing.phase(".pack", bytes=array.nbytes):
+            # single private buffer; chunks are views of it, so ring steps
+            # receive in place and reduce in place — the only full-size copies
+            # are the pad-in and (if dtype widened) the cast back out
+            # buf escapes to the caller as the result view — not poolable;
+            # scratch is private to this call and its size repeats every ring
+            # (page-fault amortization, utils/bufpool.py)
+            buf = np.empty(chunk * w, dtype=acc_dtype)
+            buf[:n] = array.ravel()
+            if chunk * w > n:
+                buf[n:] = 0
+            chunks = [buf[i * chunk : (i + 1) * chunk] for i in range(w)]
+            scratch = _pool.take(chunk, acc_dtype)
 
         nxt, prv = (r + 1) % w, (r - 1) % w
-        # ring reduce-scatter: after w-1 steps, chunk (r+1)%w is fully reduced
-        for step in range(w - 1):
-            send_idx = (r - step) % w
-            recv_idx = (r - step - 1) % w
-            self._exchange(
-                nxt, 100 + step, chunks[send_idx], prv, 100 + step, deadline,
-                recv_out=scratch,
-            )
-            inplace_reduce(chunks[recv_idx], scratch, out=chunks[recv_idx])
-        # ring allgather of the reduced chunks, received straight into place
-        for step in range(w - 1):
-            send_idx = (r - step + 1) % w
-            recv_idx = (r - step) % w
-            self._exchange(
-                nxt, 200 + step, chunks[send_idx], prv, 200 + step, deadline,
-                recv_out=chunks[recv_idx],
-            )
-        _pool.give(scratch)
-        result = buf[:n]
-        if op == REDUCE_AVG:
-            if np.issubdtype(acc_dtype, np.floating):
-                result /= w
-            else:
-                result = result / w
-        return np.asarray(result, dtype=array.dtype).reshape(array.shape)
+        # ring.wire is the wall of the 2(w-1) exchanges less the reduces
+        # between them; ring.reduce accumulates over its w-1 stretches
+        reduce = _tracing.phase(".reduce")
+        with _tracing.phase(
+            ".wire", bytes=2 * (w - 1) * chunk * acc_dtype.itemsize
+        ) as wire:
+            # ring reduce-scatter: after w-1 steps, chunk (r+1)%w is fully reduced
+            for step in range(w - 1):
+                send_idx = (r - step) % w
+                recv_idx = (r - step - 1) % w
+                self._exchange(
+                    nxt, 100 + step, chunks[send_idx], prv, 100 + step, deadline,
+                    recv_out=scratch,
+                )
+                with reduce.lap():
+                    inplace_reduce(chunks[recv_idx], scratch, out=chunks[recv_idx])
+            # ring allgather of the reduced chunks, received straight into place
+            for step in range(w - 1):
+                send_idx = (r - step + 1) % w
+                recv_idx = (r - step) % w
+                self._exchange(
+                    nxt, 200 + step, chunks[send_idx], prv, 200 + step, deadline,
+                    recv_out=chunks[recv_idx],
+                )
+            wire.exclude(reduce.end())
+        with _tracing.phase(".unpack", bytes=array.nbytes):
+            _pool.give(scratch)
+            result = buf[:n]
+            if op == REDUCE_AVG:
+                if np.issubdtype(acc_dtype, np.floating):
+                    result /= w
+                else:
+                    result = result / w
+            return np.asarray(result, dtype=array.dtype).reshape(array.shape)
 
     def allgather(self, array: Any) -> Work:
         np_array = _as_numpy(array)

@@ -29,6 +29,16 @@ utils/metrics.py), grown from the PR-1 single-process span tree into
 
 The disabled path stays zero-cost: with no tracer installed every entry
 point is a ``None`` check (budget-tested like the flight recorder's).
+
+**One span source inside the program.**  :class:`phase` is the one way a
+phase of the protocol is timed, from the Manager down to the PG worker
+and the heal transport: a context manager that opens a
+``jax.profiler.TraceAnnotation("torchft.<name>")`` (so the span lies on
+the device trace's clock, in the host plane of a ``jax.profiler`` trace),
+adds its seconds to a sink (``Manager.phase_times()``), and, when a
+tracer is installed, exports the span with its true start, end and
+parent.  A name with a dot is a *part*, contained in the phase named
+before the dot (docs/observability.md "Phases and parts").
 """
 
 from __future__ import annotations
@@ -37,10 +47,13 @@ import hashlib
 import json
 import logging
 import os
+import sys
 import threading
+import time
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, MutableMapping, Optional
 
+from torchft_tpu.utils import flightrecorder as _flightrec
 from torchft_tpu.utils.otel import BatchedOTLPHTTPExporter, _kv_list
 
 logger = logging.getLogger(__name__)
@@ -329,6 +342,250 @@ def current_traceparent() -> "Optional[str]":
     if ctx is None or not ctx.sampled:
         return None
     return ctx.to_traceparent()
+
+
+# ---------------------------------------------------------------------------
+# phase(): the one span primitive
+# ---------------------------------------------------------------------------
+
+_sink_lock = threading.Lock()
+_TraceAnnotation: Any = None
+
+
+def add_seconds(sink: "MutableMapping[str, float]", name: str, seconds: float) -> None:
+    """``sink[name] += seconds``; phases end on several threads."""
+    with _sink_lock:
+        sink[name] = sink.get(name, 0.0) + seconds
+
+
+def is_part(name: str) -> bool:
+    """The one rule for nesting: a name with a dot is a part, contained in
+    the phase named before the dot.  Whatever SUMS phases takes the names
+    that are not parts, so no part is counted against its whole."""
+    return "." in name
+
+
+def _annotation(name: str, attrs: "Dict[str, Any]") -> Any:
+    """``TraceAnnotation("torchft.<name>")``, or None where jax is not
+    loaded: the jax-free PG worker process must not import it for this."""
+    global _TraceAnnotation
+    cls = _TraceAnnotation
+    if cls is None:
+        jax = sys.modules.get("jax")
+        try:
+            cls = _TraceAnnotation = jax.profiler.TraceAnnotation
+        except AttributeError:  # no jax, or jax still importing
+            return None
+    return cls("torchft." + name, **attrs)
+
+
+def open_phase() -> "Optional[phase]":
+    """The innermost phase open on this thread: what a layer that hands
+    work to another thread carries over, for :class:`under`."""
+    return getattr(_tls, "open", None)
+
+
+class under:
+    """Phases begun on this thread inside the block are parts of ``whole``,
+    a phase that was opened elsewhere (``ring`` begins on the caller's
+    thread; its parts run on the PG worker's)."""
+
+    __slots__ = ("_whole", "_outer")
+
+    def __init__(self, whole: "Optional[phase]") -> None:
+        self._whole = whole
+
+    def __enter__(self) -> None:
+        self._outer = getattr(_tls, "open", None)
+        _tls.open = self._whole
+
+    def __exit__(self, *exc: Any) -> None:
+        _tls.open = self._outer
+
+
+class _Lap:
+    """One of the separate stretches of a phase that accumulates."""
+
+    __slots__ = ("_phase", "_t")
+
+    def __init__(self, p: "phase") -> None:
+        self._phase = p
+
+    def __enter__(self) -> None:
+        p = self._phase
+        if p._t0 is None:
+            p.begin()
+        # what is opened in a stretch is the phase's part, as in a ``with``
+        p._outer = getattr(_tls, "open", None)
+        _tls.open = p
+        self._t = time.perf_counter()
+
+    def __exit__(self, *exc: Any) -> None:
+        p = self._phase
+        p._t_last = time.perf_counter()
+        p._busy += p._t_last - self._t
+        _tls.open = p._outer
+
+
+class phase:
+    """One timed phase of the protocol, on every surface at once.
+
+    ``with phase("heal_send", sink, step=3): ...`` opens
+    ``TraceAnnotation("torchft.heal_send", step=3)`` (only where jax is
+    already loaded) and on exit adds the seconds to ``sink`` under the
+    name, exports the span with its true start and end under its parent
+    when a tracer is installed and the step is sampled, calls
+    ``observe(name, seconds)`` (the Manager's histogram), and writes the
+    flight record of a top-level phase.  With no profiler session and no
+    tracer that is one annotation enter/exit and three clock reads.
+
+    A name that starts with a dot is a part of whatever phase is open on
+    this thread: ``.hash`` inside ``heal_send`` is ``heal_send.hash``, in
+    the same sink, its span a child of ``heal_send``'s; with no phase open
+    it is only the annotation.  :class:`under` carries a whole to another
+    thread (``ring`` to the PG worker).  A part stays out of the flight
+    ring and the histogram, which count phases.  Any phase takes the
+    attributes and ``observe`` of the phase it is opened inside.
+
+    ``begin()`` / ``end()`` are the explicit form for a phase that ends on
+    another thread; it opens no annotation, a thread-bound thing.
+    ``lap()`` is for a phase that runs in stretches (the reduce between
+    the exchanges of a ring, the decode of each fragment of a heal): one
+    span, flight record and observation from the first stretch to the
+    last, the seconds their sum; no annotation either.  ``exclude(s)``
+    takes seconds out of what is added (``heal_recv`` is what its four
+    split phases leave); the span keeps its true ends and carries the
+    seconds as an attribute.  ``cancel()`` records nothing (a layout
+    round that had nothing to do).
+    """
+
+    __slots__ = (
+        "name", "sink", "attrs", "seconds", "_observe", "_ann",
+        "_recorded", "_outer", "_t0", "_t_last", "_busy", "_lap", "_excluded",
+        "_start_ns", "_ctx", "_span_id", "_parent_id", "_whole",
+    )
+
+    def __init__(
+        self,
+        name: str,
+        sink: "Optional[MutableMapping[str, float]]" = None,
+        *,
+        observe: "Optional[Callable[[str, float], None]]" = None,
+        **attrs: Any,
+    ) -> None:
+        outer = getattr(_tls, "open", None)
+        whole = outer if name.startswith(".") else None
+        self._recorded = True
+        if whole is not None:
+            name = whole.name + name
+            sink = whole.sink if sink is None else sink
+        elif name.startswith("."):
+            name, self._recorded = name[1:], False
+        if outer is not None:
+            # who is timing (replica, quorum, step) and the histogram flow
+            # down the thread to whatever is opened beneath
+            observe = outer._observe if observe is None else observe
+            attrs = {**outer.attrs, **attrs}
+        self.name = name
+        self.sink = sink
+        self.attrs = attrs
+        self.seconds = 0.0
+        self._observe = observe
+        self._whole = whole
+        self._ann = None
+        self._t0: "Optional[float]" = None
+        self._t_last = 0.0
+        self._busy = 0.0
+        self._lap: "Optional[_Lap]" = None
+        self._excluded = 0.0
+        self._span_id: "Optional[str]" = None
+
+    def begin(self) -> "phase":
+        if _tracer is not None and self._recorded:
+            # a part is a child of its whole's span, in its whole's trace;
+            # anything else hangs off the context bound to this thread
+            whole = self._whole
+            parent = whole if whole is not None and whole._span_id else None
+            ctx = parent._ctx if parent else getattr(_tls, "ctx", None)
+            if ctx is not None and ctx.sampled:
+                self._ctx, self._span_id = ctx, new_span_id()
+                self._parent_id = parent._span_id if parent else ctx.span_id
+        self._start_ns = time.time_ns()
+        self._t0 = time.perf_counter()
+        return self
+
+    def elapsed(self) -> float:
+        return time.perf_counter() - self._t0 if self._t0 is not None else 0.0
+
+    def exclude(self, seconds: float) -> None:
+        self._excluded += seconds
+
+    def cancel(self) -> None:
+        self._recorded = False
+
+    def lap(self) -> _Lap:
+        if self._lap is None:
+            self._lap = _Lap(self)
+        return self._lap
+
+    def end(self, ok: bool = True) -> float:
+        """Close the phase; returns the seconds added to the sink."""
+        if self._t0 is None:
+            return 0.0  # never begun: no lap ran
+        if self._lap is not None:
+            wall, seconds = self._t_last - self._t0, self._busy
+        else:
+            wall = time.perf_counter() - self._t0
+            seconds = max(wall - self._excluded, 0.0)
+        self._t0 = None
+        self.seconds = seconds
+        if not self._recorded:
+            return seconds
+        name, attrs = self.name, self.attrs
+        if self.sink is not None:
+            add_seconds(self.sink, name, seconds)
+        end_ns = self._start_ns + int(wall * 1e9)
+        if not is_part(name):
+            # the protocol's footprint in the post-mortem ring and in the
+            # histogram, one entry per phase as before; the parts stay out,
+            # so the ring reaches as many steps back and the histogram's
+            # sum over ``phase`` counts no part against its whole
+            _flightrec.record(
+                name, "ok" if ok else "error", self._start_ns, end_ns,
+                kind="phase", **attrs,
+            )
+            if self._observe is not None:
+                self._observe(name, seconds)
+        tracer = _tracer
+        if self._span_id is not None and tracer is not None:
+            if self._lap is not None or self._excluded:
+                attrs = {**attrs, "seconds": seconds}
+            tracer.export_span(
+                name=name,
+                trace_id=self._ctx.trace_id,
+                span_id=self._span_id,
+                parent_span_id=self._parent_id,
+                start_ns=self._start_ns,
+                end_ns=end_ns,
+                attributes=attrs,
+                ok=ok,
+            )
+        return seconds
+
+    def __enter__(self) -> "phase":
+        self._outer = getattr(_tls, "open", None)
+        _tls.open = self
+        self._ann = _annotation(self.name, self.attrs)
+        if self._ann is not None:
+            self._ann.__enter__()
+        return self.begin()
+
+    def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> None:
+        self.end(ok=exc_type is None)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
+        _tls.open = self._outer
 
 
 # ---------------------------------------------------------------------------
