@@ -155,6 +155,99 @@ class TestManagerHappyPath:
         np.testing.assert_allclose(np.asarray(out["s"]), 4.0)
 
 
+class _NoDivisorDummy(ProcessGroupDummy):
+    """A group that takes no divisor, as a subprocess group or a fake."""
+
+    def _allreduce_mean(self, arrays, divisor):
+        return None
+
+
+class TestManagerAverage:
+    """The average is one division by the participant count: in place by
+    the group that owns the reduced buffer, out of place here for a group
+    that takes no divisor, none at all for a divisor of 1; never a write
+    into memory the caller passed in."""
+
+    def _mixed(self):
+        import jax.numpy as jnp
+        import ml_dtypes
+
+        return {
+            "host": np.arange(6, dtype=np.float32) * 3,
+            "dev": jnp.arange(5, dtype=jnp.float32) * 7,
+            "bf16": np.arange(4).astype(ml_dtypes.bfloat16),
+        }
+
+    @pytest.mark.parametrize("pg_kind", ["owner", "no-divisor", "swallowing"])
+    @pytest.mark.parametrize("participants", [1, 2, 3])
+    def test_matches_numpy_and_never_writes_the_input(
+        self, manager_ctx, pg_kind, participants
+    ):
+        build, client, _ = manager_ctx
+        pg = {
+            "owner": ProcessGroupDummy,
+            "no-divisor": _NoDivisorDummy,
+            "swallowing": lambda: ErrorSwallowingProcessGroupWrapper(
+                ProcessGroupDummy()
+            ),
+        }[pg_kind]()
+        manager = build(pg=pg, min_replica_size=1)
+        client._quorum.return_value = make_quorum(
+            replica_world_size=participants, max_world_size=participants
+        )
+        manager.start_quorum()
+        assert manager.num_participants() == participants
+        grads = self._mixed()
+        before = {k: np.array(v) for k, v in grads.items()}
+        out = manager.allreduce(grads).wait(timeout=10)
+        for key, x in before.items():
+            # in the leaf's dtype (numpy alone widens bf16 / int to f32)
+            want = (x / participants).astype(x.dtype)
+            assert out[key].dtype == x.dtype and out[key].shape == x.shape
+            assert out[key].tobytes() == want.tobytes(), key
+            assert not np.shares_memory(out[key], grads["host"])
+            assert np.array(grads[key]).tobytes() == x.tobytes(), key
+        assert manager.errored() is None
+
+    def test_divisor_one_hands_a_device_leaf_through(self, manager_ctx):
+        import jax.numpy as jnp
+
+        build, client, _ = manager_ctx
+        manager = build(min_replica_size=1)
+        client._quorum.return_value = make_quorum(
+            replica_world_size=1, max_world_size=1
+        )
+        manager.start_quorum()
+        dev = jnp.arange(1 << 12, dtype=jnp.float32)
+        out = manager.allreduce({"g": dev}).wait(timeout=10)["g"]
+        # the device-to-host copy itself: no second array, no division
+        assert isinstance(out, np.ndarray) and not out.flags.writeable
+        assert np.shares_memory(out, np.asarray(dev))
+        np.testing.assert_array_equal(out, np.arange(1 << 12))
+
+    @pytest.mark.parametrize("how", ["latched", "op-fails", "swallowed"])
+    def test_errored_pass_through_hands_the_input_back_unwritten(
+        self, manager_ctx, how
+    ):
+        build, client, _ = manager_ctx
+        inner = FakeProcessGroupWrapper(ProcessGroupDummy())
+        pg = ErrorSwallowingProcessGroupWrapper(inner) if how == "swallowed" else inner
+        manager = build(pg=pg)
+        client._quorum.return_value = make_quorum()
+        manager.start_quorum()
+        x = np.arange(4, dtype=np.float32) + 1
+        if how == "latched":
+            manager.report_error(RuntimeError("earlier failure"))
+        else:
+            inner.report_future_error(RuntimeError("injected"))
+        out = manager.allreduce(x).wait(timeout=10)
+        # the step is lost either way; what matters is that nobody took the
+        # caller's array for a buffer of its own and divided it in place
+        np.testing.assert_array_equal(x, np.arange(4) + 1)
+        np.testing.assert_array_equal(out, x)
+        assert manager.errored() is not None or pg.errored() is not None
+
+
 class TestManagerHealing:
     def test_async_heal_applies_on_commit(self, manager_ctx):
         build, client, transport = manager_ctx

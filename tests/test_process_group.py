@@ -618,3 +618,323 @@ class TestBandwidthShaper:
         monkeypatch.delenv("TORCHFT_WIRE_GBPS")
         pg2 = ProcessGroupTCP(timeout=5.0)
         assert pg2._bucket is None
+
+
+# ---------------------------------------------------------------------------
+# The plain ring's contract (PR 25): no copy of what came off the device, a
+# leased ring buffer, the average in place by the buffer's owner
+# ---------------------------------------------------------------------------
+
+
+def _contract_leaves(rank):
+    """Mixed leaves whose sums are exact in every dtype (small integers),
+    so any order of additions gives the same bits as plain numpy.  Sizes
+    that need padding at world sizes 2 and 3; one leaf over BUCKET_BYTES
+    rings alone, the small ones share a bucket."""
+    import ml_dtypes
+
+    rng = np.random.default_rng(100 + rank)
+
+    def ints(shape, lo=-40, hi=40):
+        return rng.integers(lo, hi, size=shape)
+
+    return [
+        ints(7).astype(np.float32),
+        ints((1 << 20) + 3).astype(np.float32),
+        ints(5, -8, 8).astype(ml_dtypes.bfloat16),
+        ints(11).astype(np.int32),
+        ints((3, 5)).astype(np.float32),
+        ints(2).astype(np.float64),
+        # dimensions in another order in memory, as a leaf comes off a TPU
+        # when its last dimension is no multiple of 128
+        ints((6, 4)).astype(np.float32).T,
+        np.swapaxes(ints((2, 5, 3)).astype(np.float32), 1, 2),
+    ]
+
+
+def _numpy_reduce(per_rank, op, divisor=None):
+    """What plain numpy gives: the leaves stacked over ranks, reduced in
+    the accumulation dtype, divided there, cast back."""
+    from torchft_tpu.parallel.process_group import _accumulation_dtype
+
+    out = []
+    for leaves in zip(*per_rank):
+        acc = _accumulation_dtype(leaves[0].dtype)
+        stack = np.stack([x.astype(acc) for x in leaves])
+        if op == REDUCE_MAX:
+            total = stack.max(axis=0)
+        else:
+            total = stack.sum(axis=0, dtype=acc)
+        by = len(per_rank) if op == REDUCE_AVG else divisor
+        if by not in (None, 1):
+            total = total / by if acc.kind != "f" else (total / acc.type(by))
+        out.append(np.asarray(total).astype(leaves[0].dtype))
+    return out
+
+
+def _assert_same_bits(got, want):
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert g.tobytes() == w.tobytes()
+
+
+def _world(store, world, prefix):
+    if world == 1:
+        pg = ProcessGroupTCP(timeout=20.0)
+        pg.configure("", "rank0", 0, 1)
+        return [pg]
+    return make_group(store, world, prefix)
+
+
+def _shutdown(pgs):
+    for pg in pgs:
+        pg.shutdown()
+
+
+@pytest.fixture
+def pack_spans(tmp_path):
+    """Runs ``fn()`` under an open ``ring`` phase with a file-sink tracer
+    installed; returns the ``ring.pack`` spans' attributes."""
+    import json
+
+    from torchft_tpu.utils import tracing
+
+    def run(fn):
+        path = tmp_path / "spans.jsonl"
+        path.unlink(missing_ok=True)  # the sink appends
+        tracing.install_tracer(
+            tracing.Tracer(sink=tracing.FileSpanSink(str(path)))
+        )
+        tracing.set_current(tracing.TraceContext("a" * 32, "b" * 16))
+        try:
+            ring = tracing.phase("ring", {}).begin()
+            with tracing.under(ring):
+                out = fn()
+            ring.end()
+        finally:
+            tracing.set_current(None)
+            tracing.uninstall_tracer()
+        spans = [json.loads(l) for l in path.read_text().splitlines() if l]
+        return out, [
+            s["attributes"] for s in spans if s["name"] == "ring.pack"
+        ]
+
+    return run
+
+
+class TestRingContract:
+    @pytest.mark.parametrize("op", [REDUCE_SUM, REDUCE_AVG, REDUCE_MAX])
+    @pytest.mark.parametrize("world", [1, 2, 3])
+    def test_bit_identical_to_numpy(self, store, world, op):
+        pgs = _world(store, world, f"contract-{world}-{op}")
+        data = [_contract_leaves(r) for r in range(world)]
+        before = [[x.copy() for x in leaves] for leaves in data]
+        want = _numpy_reduce(data, op)
+
+        def run(rank, _):
+            return pgs[rank].allreduce(data[rank], op).wait(timeout=30)
+
+        results = run_parallel(world, run)
+        for rank, got in enumerate(results):
+            _assert_same_bits(got, want)
+            # the caller's arrays: not written, not part of the result
+            _assert_same_bits(data[rank], before[rank])
+            for g, x in zip(got, data[rank]):
+                assert not np.shares_memory(g, x)
+        _shutdown(pgs)
+
+    @pytest.mark.parametrize("world,divisor", [(1, 3), (2, 3), (3, 2), (2, 1)])
+    def test_mean_by_a_divisor_that_is_not_the_world_size(
+        self, store, world, divisor
+    ):
+        """The Manager's average: the sum over the group divided by the
+        live participant count, by the group, in the accumulation dtype."""
+        pgs = _world(store, world, f"mean-{world}-{divisor}")
+        data = [_contract_leaves(r) for r in range(world)]
+        floats = [i for i, x in enumerate(data[0]) if x.dtype.kind != "i"]
+        data = [[leaves[i] for i in floats] for leaves in data]
+        before = [[x.copy() for x in leaves] for leaves in data]
+        want = _numpy_reduce(data, REDUCE_SUM, divisor)
+
+        def run(rank, _):
+            work = pgs[rank]._allreduce_mean(data[rank], divisor)
+            return work.wait(timeout=30)
+
+        for rank, got in enumerate(run_parallel(world, run)):
+            _assert_same_bits(got, want)
+            _assert_same_bits(data[rank], before[rank])
+        _shutdown(pgs)
+
+    def test_random_floats_agree_across_ranks(self, store):
+        # each chunk is reduced in one fixed order on one rank, so every
+        # rank holds the same bits whatever the values
+        world = 3
+        pgs = make_group(store, world, "bits")
+        rng = np.random.default_rng(7)
+        data = [
+            [rng.standard_normal(1001).astype(np.float32),
+             rng.standard_normal((1 << 20) + 5).astype(np.float32)]
+            for _ in range(world)
+        ]
+
+        def run(rank, _):
+            return pgs[rank].allreduce(data[rank], REDUCE_AVG).wait(timeout=30)
+
+        results = run_parallel(world, run)
+        for got in results[1:]:
+            _assert_same_bits(got, results[0])
+        np.testing.assert_allclose(
+            results[0][0], sum(d[0] for d in data) / world, rtol=1e-5, atol=1e-6
+        )
+        _shutdown(pgs)
+
+    @pytest.mark.parametrize("kind", ["tcp", "dummy"])
+    def test_device_leaf_at_world_one_is_handed_through(self, kind, pack_spans):
+        """A ``jax.Array`` leaf at world size 1 costs the device-to-host
+        copy and nothing more: no pool allocation, no bytes copied."""
+        import jax.numpy as jnp
+
+        from torchft_tpu.utils.bufpool import POOL
+
+        if kind == "tcp":
+            pg = ProcessGroupTCP(timeout=20.0)
+            pg.configure("", "rank0", 0, 1)
+        else:
+            pg = ProcessGroupDummy()
+        dev = jnp.arange(1 << 16, dtype=jnp.float32)
+        host = np.arange(8, dtype=np.float32)
+        misses, hits = POOL.misses, POOL.hits
+
+        def run():
+            return pg.allreduce([dev, host], REDUCE_AVG).wait(timeout=20)
+
+        (got_dev, got_host), packs = pack_spans(run)
+        assert (POOL.misses, POOL.hits) == (misses, hits)
+        (pack,) = packs
+        assert pack["copied"] == host.nbytes and pack["handed"] == dev.nbytes
+        np.testing.assert_array_equal(got_dev, np.arange(1 << 16))
+        assert not got_dev.flags.writeable  # straight off the device
+        # the caller's host leaf is copied: the result is its own memory
+        assert got_host.flags.writeable
+        assert not np.shares_memory(got_host, host)
+        got_host += 1
+        np.testing.assert_array_equal(host, np.arange(8))
+        pg.shutdown()
+
+    def test_pack_says_what_was_copied_and_whether_the_pool_hit(
+        self, store, pack_spans
+    ):
+        world = 2
+        pgs = make_group(store, world, "packattrs")
+        n = (1 << 20) + 3  # odd: one padded tail chunk
+        big = [np.full(n, r + 1.0, np.float32) for r in range(world)]
+        half = -(-n // world)
+
+        def rank0_packs():
+            # the other rank runs without an open phase: its parts are
+            # annotations only, so the spans are rank 0's
+            def run(rank, _):
+                if rank == 0:
+                    return pack_spans(
+                        lambda: pgs[0].allreduce([big[0]]).wait(timeout=30)[0]
+                    )
+                return pgs[1].allreduce([big[1]]).wait(timeout=30)[0], None
+
+            return run_parallel(world, run)[0]
+
+        from torchft_tpu.utils.bufpool import POOL
+
+        POOL.clear()
+        first, packs = rank0_packs()
+        np.testing.assert_array_equal(first, np.full(n, 3.0))
+        (pack,) = packs
+        # only the chunk with the zero-padded tail is copied in
+        assert pack["handed"] == half * 4 and pack["copied"] == (n - half) * 4
+        assert pack["pool"] == "miss"
+        del first
+        second, packs = rank0_packs()
+        assert packs[0]["pool"] == "hit"
+        np.testing.assert_array_equal(second, np.full(n, 3.0))
+        _shutdown(pgs)
+
+    def test_ring_buffer_counter(self, store):
+        from torchft_tpu.utils import metrics
+        from torchft_tpu.utils.bufpool import POOL
+
+        world = 2
+        pgs = make_group(store, world, "ringctr")
+        x = np.ones(300_001, np.float32)  # a size no other test leases
+
+        def count(result):
+            return metrics.RING_BUFFERS.labels(
+                replica_id="rank0", result=result
+            ).get()
+
+        def run(rank, _):
+            pgs[rank].allreduce([x]).wait(timeout=30)
+
+        POOL.clear()
+        hit, miss = count("hit"), count("miss")
+        run_parallel(world, run)
+        assert (count("hit"), count("miss")) == (hit, miss + 1)
+        for _ in range(3):
+            run_parallel(world, run)
+        assert (count("hit"), count("miss")) == (hit + 3, miss + 1)
+        _shutdown(pgs)
+
+    @pytest.mark.parametrize("case", ["swallowed", "failed-op"])
+    def test_error_fallback_hands_the_input_back_unwritten(self, case):
+        """The error-swallowing fallback's result IS the caller's array: a
+        division in place on it, by anyone who guessed it owned the
+        result, would write the caller's memory."""
+        x = np.arange(6, dtype=np.float32)
+        if case == "swallowed":
+            pg = ErrorSwallowingProcessGroupWrapper(ProcessGroupDummy())
+            pg.report_error(RuntimeError("down"))
+        else:
+            inner = FakeProcessGroupWrapper(ProcessGroupDummy())
+            inner.report_future_error(RuntimeError("injected"))
+            pg = ErrorSwallowingProcessGroupWrapper(inner)
+        (got,) = pg._allreduce_mean([x], 2).wait(timeout=5)
+        assert got is x and pg.errored() is not None
+        np.testing.assert_array_equal(x, np.arange(6))
+
+    def test_a_group_that_takes_no_divisor_says_so(self):
+        from torchft_tpu.parallel.process_group import (
+            ProcessGroupBabyTCP,
+            ProcessGroupWrapper,
+        )
+
+        assert ProcessGroupBabyTCP()._allreduce_mean([np.ones(2)], 2) is None
+        # and a wrapper passes the answer on
+        wrapped = ProcessGroupWrapper(ProcessGroupBabyTCP())
+        assert wrapped._allreduce_mean([np.ones(2)], 2) is None
+
+    def test_a_leaf_in_another_memory_order_is_copied_in_once(
+        self, store, pack_spans
+    ):
+        """No ``ravel()`` into fresh memory first: the strided leaf goes
+        into the leased buffer in one pass, and the result is C order."""
+        world = 2
+        pgs = make_group(store, world, "strided")
+        n = (1 << 20) + 8
+        leaves = [
+            np.arange(2 * n, dtype=np.float32).reshape(2, n).T * (r + 1)
+            for r in range(world)
+        ]
+        assert not leaves[0].flags.c_contiguous
+
+        def run(rank, _):
+            if rank == 0:
+                return pack_spans(
+                    lambda: pgs[0].allreduce([leaves[0]]).wait(timeout=30)[0]
+                )
+            return pgs[1].allreduce([leaves[1]]).wait(timeout=30)[0], None
+
+        got, packs = run_parallel(world, run)[0]
+        (pack,) = packs
+        assert pack["copied"] == leaves[0].nbytes and pack["handed"] == 0
+        assert got.flags.c_contiguous and got.shape == (n, 2)
+        np.testing.assert_array_equal(got, leaves[0] + leaves[1])
+        _shutdown(pgs)

@@ -108,10 +108,10 @@ PHASE_PARTS = (
     # ProcessGroupTCP.allreduce, on its worker thread
     "ring.queue",  # submit until the worker picks the op up
     "ring.d2h",  # wait for the device + copy of the leaves to the host
-    "ring.pack",  # bucket concat, pad-in copy (world size 1: the copy)
+    "ring.pack",  # bucket concat, pad-in of a widened leaf or a tail, copy of a host leaf
     "ring.wire",  # the exchanges: send + receive + waiting for the peer
     "ring.reduce",  # the in-place ufunc between exchanges
-    "ring.unpack",  # cast back, split, the AVG division and unflatten
+    "ring.unpack",  # the division in place, cast back, split, unflatten
     # fragments.iter_heal_fragments, per fragment, under whoever called
     "heal_send.snapshot",  # device leaves to host numpy
     "heal_send.encode",  # serialization.serialize
@@ -1252,6 +1252,14 @@ class Manager:
         with the input (zeroed) value and the error is tracked for
         ``should_commit`` (reference manager.py:385-467).
 
+        The result is host arrays, private to the caller for as long as it
+        holds them: it never aliases an ``np.ndarray`` that was passed in,
+        and nothing writes to it again (a ring buffer returns to the pool
+        only when the last view of the result is gone).  It may be
+        read-only where it came straight off the device (a ``jax.Array``
+        leaf at world size 1 is the device→host copy itself): copy before
+        writing into it.  Only the error path above hands the input back.
+
         ``device_quantize`` (quantized path only): quantize on-chip with
         the Pallas kernel before the device→host copy; ``None`` = auto
         (on when every leaf is a jax array on a TPU backend) — forwarded
@@ -1312,6 +1320,12 @@ class Manager:
             # parts (PHASE_PARTS ``ring.*``) are timed there, by the PG;
             # ``under`` makes them ring's, in phase_times() and the trace.
             ring = self._phase("ring").begin()
+            # The average is one division by the live participant count.
+            # The group that runs the ring owns the buffer it reduced into
+            # and divides there, in place; one that takes no divisor says
+            # so (None), and the sum is divided here, into new arrays: this
+            # side cannot tell whose memory a result is.
+            divide = reduce_op == REDUCE_AVG and num_participants != 1
             with tracing.under(ring):
                 if should_quantize:
                     from torchft_tpu.ops.collectives import allreduce_quantized
@@ -1321,12 +1335,24 @@ class Manager:
                         device_quantize=device_quantize,
                     )
                 else:
-                    work = self._pg.allreduce(send_leaves, pg_reduce_op)
+                    work = None
+                    if divide:
+                        work = self._pg._allreduce_mean(
+                            send_leaves, num_participants
+                        )
+                        divide = work is None
+                    if work is None:
+                        work = self._pg.allreduce(send_leaves, pg_reduce_op)
 
             def _postprocess(reduced: "List[np.ndarray]") -> Any:
                 with tracing.under(ring), tracing.phase(".unpack"):
-                    if reduce_op == REDUCE_AVG:
-                        reduced = [x / num_participants for x in reduced]
+                    if divide:
+                        # (bf16 / int is float32 in numpy: cast back, so a
+                        # result has its leaf's dtype whoever divided)
+                        reduced = [
+                            (x / num_participants).astype(x.dtype, copy=False)
+                            for x in reduced
+                        ]
                     return jax.tree_util.tree_unflatten(treedef, reduced)
 
             chained = work.then(_postprocess)
@@ -1337,16 +1363,19 @@ class Manager:
             # One-shot holder: the error path below hands the inputs
             # back, but a COMPLETED Work must not keep pinning them — for
             # device gradients that is a whole extra copy of the model
-            # held in HBM across the next forward/backward.
-            inputs = [send_leaves]
+            # held in HBM across the next forward/backward.  Nor the raw
+            # Work: a future keeps its callbacks, so this callback would
+            # close a cycle around the raw result, which at world size 1 is
+            # the leaves' own host arrays.
+            inputs = [(send_leaves, work)]
 
             def _done(f: "concurrent.futures.Future[Any]") -> None:
-                held = inputs.pop()
+                held, raw = inputs.pop()
                 ring.end(ok=f.exception() is None)
                 # quantized-pipeline accounting for the step digest: the
                 # stats dict is complete once the pipeline finished, i.e.
                 # before this callback fires
-                qs = getattr(work, "quant_stats", None)
+                qs = getattr(raw, "quant_stats", None)
                 if isinstance(qs, dict):
                     with self._summary_lock:
                         self._summary_codec_s += float(qs.get("codec_s") or 0.0)
@@ -1627,12 +1656,17 @@ class Manager:
         (``ProcessGroupTCP``; they sum to ``ring`` within the thread
         hand-offs): ``ring.queue`` (submit until the worker picks the op
         up), ``ring.d2h`` (wait for the device + device→host copy of the
-        leaves), ``ring.pack`` (bucket concat and pad-in copy into the ring
-        buffer; at world size 1 the copy), ``ring.wire`` (the 2(w-1)
-        exchanges of each bucket: send + receive + waiting for the peer),
-        ``ring.reduce`` (the in-place ufunc between them), ``ring.unpack``
-        (cast back, split, and the AVG division + unflatten chained after
-        the raw collective).
+        leaves), ``ring.pack`` (bucket concat, the lease of the ring buffer
+        and what is copied into it: a leaf that widens, a zero-padded tail;
+        at world size 1 the copy of a leaf the caller passed as host
+        memory; its attributes say bytes ``copied``, bytes ``handed``
+        through uncopied and whether the buffer was a ``pool`` hit),
+        ``ring.wire`` (the 2(w-1) exchanges of each bucket: send + receive
+        + waiting for the peer), ``ring.reduce`` (the ufunc between them,
+        which is also a chunk's first write into the buffer),
+        ``ring.unpack`` (the division by the participant count, in place on
+        the ring's buffer, cast back, split, and the unflatten chained
+        after the raw collective).
 
         Async-quorum-thread keys (run inside the executor, so they OVERLAP
         ``quorum_wait`` rather than adding to it — they break down what the

@@ -37,6 +37,7 @@ import pickle
 import queue
 import socket
 import struct
+import sys
 import threading
 import time
 import uuid
@@ -105,6 +106,46 @@ def _accumulation_dtype(dtype: np.dtype) -> np.dtype:
 def _as_numpy(x: Any) -> np.ndarray:
     """Host view of an array (device->host copy for jax arrays)."""
     return np.asarray(x)
+
+
+def _off_device(x: Any) -> bool:
+    """True for a ``jax.Array``: its host array (:func:`_as_numpy`) is
+    read-only memory the caller cannot write, so a result may be that array
+    itself.  Anything else may be memory the caller still writes and is
+    copied.  (No import: the Baby worker process stays free of jax.)"""
+    jax = sys.modules.get("jax")
+    return jax is not None and isinstance(x, jax.Array)
+
+
+def _divide(a: np.ndarray, divisor: "Optional[int]") -> np.ndarray:
+    """``a / divisor`` in ``a``'s dtype: in place where ``a`` can be
+    written, which only the owner of ``a`` may ask for; nothing at all for
+    a divisor of 1 (``x / 1`` is ``x`` bit for bit) or none."""
+    if divisor is None or divisor == 1:
+        return a
+    if a.flags.writeable and _is_float_dtype(a.dtype):
+        a /= divisor
+        return a
+    return np.asarray(a / divisor, dtype=a.dtype)
+
+
+def _allreduce_alone(
+    arrays: "List[np.ndarray]", owned: "List[bool]", divisor: "Optional[int]"
+) -> "List[np.ndarray]":
+    """Allreduce at world size 1: the result is the input.  A leaf that
+    came off the device (``owned``) is handed through as it is; one the
+    caller passed as host memory is copied, because the result must not
+    alias what the caller can still write.  ``.pack`` says how many bytes
+    went which way."""
+    scaled = divisor not in (None, 1)
+    copied = sum(a.nbytes for a, own in zip(arrays, owned) if scaled or not own)
+    with _tracing.phase(
+        ".pack", copied=copied, handed=sum(a.nbytes for a in arrays) - copied
+    ):
+        return [
+            _divide(a if own else a.copy(), divisor)
+            for a, own in zip(arrays, owned)
+        ]
 
 
 def _check_recv_buffer(out: np.ndarray, shape: Any, dtype: str) -> None:
@@ -186,7 +227,23 @@ class ProcessGroup(ABC):
     # -- collectives -------------------------------------------------------
 
     @abstractmethod
-    def allreduce(self, arrays: "List[Any]", op: str = REDUCE_SUM) -> Work: ...
+    def allreduce(self, arrays: "List[Any]", op: str = REDUCE_SUM) -> Work:
+        """Resolves to one host array per leaf, in the leaf's shape and
+        dtype.  The result is private to the caller for as long as the
+        caller holds it (or any view of it); it never aliases an
+        ``np.ndarray`` the caller passed in; it may be read-only when it
+        came straight off the device (a ``jax.Array`` leaf at world size
+        1): copy before writing into it."""
+
+    def _allreduce_mean(
+        self, arrays: "List[Any]", divisor: int
+    ) -> "Optional[Work]":
+        """The sum over the group divided by ``divisor`` (the Manager's
+        live participant count, which is not always ``size()``), the
+        division done in place by the group, which owns the buffer it
+        reduced into.  ``None`` says this group does not take a divisor
+        (a subprocess group, a fake): the caller allreduces and divides."""
+        return None
 
     @abstractmethod
     def allgather(self, array: Any) -> Work:
@@ -261,7 +318,19 @@ class ProcessGroupDummy(ProcessGroup):
         return self._world
 
     def allreduce(self, arrays: "List[Any]", op: str = REDUCE_SUM) -> Work:
-        return completed_work([_as_numpy(a).copy() for a in arrays])
+        return self._alone(arrays, None)
+
+    def _allreduce_mean(self, arrays: "List[Any]", divisor: int) -> Work:
+        return self._alone(arrays, divisor)
+
+    def _alone(self, arrays: "List[Any]", divisor: "Optional[int]") -> Work:
+        return completed_work(
+            _allreduce_alone(
+                [_as_numpy(a) for a in arrays],
+                [_off_device(a) for a in arrays],
+                divisor,
+            )
+        )
 
     def allgather(self, array: Any) -> Work:
         return completed_work([_as_numpy(array).copy()])
@@ -443,12 +512,28 @@ class ProcessGroupTCP(ProcessGroup):
         self._flight_op: "Optional[_flightrec.FlightOp]" = None
         self._flight_swap_lock = _lockcheck.lock("pg.tcp.flight_swap")
         self._replica_id = ""
+        self._bind_metrics()
         self._lock = _lockcheck.lock("pg.tcp.state")
         self._worker: Optional[threading.Thread] = None
         self._sender: "Optional[concurrent_futures.ThreadPoolExecutor]" = None
         self._queue: "queue.Queue[Optional[Tuple[int, Callable[[], Any], Future]]]" = (
             queue.Queue()
         )
+
+    def _bind_metrics(self) -> None:
+        """``torchft_ring_buffers_total`` children by pool hit, under the
+        stable replica id (what precedes the ``:<uuid>`` of an incarnation,
+        as the Manager labels its series)."""
+        self._metric_replica_id = (
+            self._replica_id.split(":", 1)[0] or self._replica_id
+        )
+        self._m_ring_buffers = {
+            hit: _metrics.RING_BUFFERS.labels(
+                replica_id=self._metric_replica_id,
+                result="hit" if hit else "miss",
+            )
+            for hit in (True, False)
+        }
 
     def set_bandwidth(self, gbps: "Optional[float]") -> None:
         """(Re)shape egress to ``gbps`` decimal GB/s; None removes the cap.
@@ -514,6 +599,7 @@ class ProcessGroupTCP(ProcessGroup):
         # configure try-block, which latches it and re-forms next quorum
         _faults.check("pg.reconfigure", replica=replica_id)
         self._replica_id = replica_id
+        self._bind_metrics()
         t_cfg_ns = time.time_ns()
         self._teardown()
         deadline = time.monotonic() + self._timeout
@@ -1005,6 +1091,17 @@ class ProcessGroupTCP(ProcessGroup):
     # -- collectives -------------------------------------------------------
 
     def allreduce(self, arrays: "List[Any]", op: str = REDUCE_SUM) -> Work:
+        return self._allreduce(arrays, op, None)
+
+    def _allreduce_mean(self, arrays: "List[Any]", divisor: int) -> Work:
+        return self._allreduce(arrays, REDUCE_SUM, divisor)
+
+    def _allreduce(
+        self, arrays: "List[Any]", op: str, divisor: "Optional[int]"
+    ) -> Work:
+        """``divisor``: what the reduced sum is divided by, in place, by
+        the ring that owns the buffer; ``REDUCE_AVG`` is the divisor
+        ``size()``."""
         deadline_budget = self._timeout
         # The ring, opened (manager.PHASE_PARTS ``ring.*``): each part is
         # timed here, where it runs, as a part of the caller's open phase
@@ -1026,7 +1123,13 @@ class ProcessGroupTCP(ProcessGroup):
                 deadline = time.monotonic() + deadline_budget
                 with _tracing.phase(".d2h", bytes=nbytes):
                     np_arrays = [_as_numpy(a) for a in arrays]
-                return self._allreduce_coalesced(np_arrays, op, deadline)
+                by = self._world if op == REDUCE_AVG else divisor
+                if self._world == 1:
+                    # the post-failure shrunken-group hot path
+                    return _allreduce_alone(
+                        np_arrays, [_off_device(a) for a in arrays], by
+                    )
+                return self._allreduce_coalesced(np_arrays, op, by, deadline)
 
         work = self._submit(run, op="allreduce")
         # Wire accounting on the UNQUANTIZED path too (parity with the
@@ -1102,7 +1205,11 @@ class ProcessGroupTCP(ProcessGroup):
         return total
 
     def _allreduce_coalesced(
-        self, arrays: "List[np.ndarray]", op: str, deadline: float
+        self,
+        arrays: "List[np.ndarray]",
+        op: str,
+        divisor: "Optional[int]",
+        deadline: float,
     ) -> "List[np.ndarray]":
         """Bucketized allreduce of a gradient pytree's leaves.
 
@@ -1116,13 +1223,10 @@ class ProcessGroupTCP(ProcessGroup):
         Timed as the parts ``.pack|wire|reduce|unpack`` of the open phase:
         one span per part and bucket, never one per exchange.
         """
-        if self._world == 1:
-            # world==1: the allreduce is a pure copy; skip bucketing work
-            # entirely (the post-failure shrunken-group hot path)
-            with _tracing.phase(".pack", bytes=sum(a.nbytes for a in arrays)):
-                return [a.copy() for a in arrays]
         if len(arrays) <= 1:
-            return [self._allreduce_one(a, op, deadline) for a in arrays]
+            return [
+                self._allreduce_one(a, op, divisor, deadline) for a in arrays
+            ]
         buckets = self._plan_buckets(
             [(_accumulation_dtype(a.dtype), a.size) for a in arrays]
         )
@@ -1130,7 +1234,9 @@ class ProcessGroupTCP(ProcessGroup):
         for acc_dtype, idxs, _ in buckets:
             if len(idxs) == 1:
                 i = idxs[0]
-                results[i] = self._allreduce_one(arrays[i], op, deadline)
+                results[i] = self._allreduce_one(
+                    arrays[i], op, divisor, deadline
+                )
                 continue
             # cast leaves individually: mixed input dtypes sharing one acc
             # dtype (f16+f32, bf16) may not have a numpy promotion rule
@@ -1143,7 +1249,7 @@ class ProcessGroupTCP(ProcessGroup):
                         for i in idxs
                     ]
                 )
-            reduced = self._allreduce_one(flat, op, deadline)
+            reduced = self._allreduce_one(flat, op, divisor, deadline)
             with _tracing.phase(".unpack", leaves=len(idxs)):
                 off = 0
                 for i in idxs:
@@ -1156,61 +1262,114 @@ class ProcessGroupTCP(ProcessGroup):
                     off += n
         return results  # type: ignore[return-value]
 
-    def _allreduce_one(self, array: np.ndarray, op: str, deadline: float) -> np.ndarray:
+    def _allreduce_one(
+        self,
+        array: np.ndarray,
+        op: str,
+        divisor: "Optional[int]",
+        deadline: float,
+    ) -> np.ndarray:
+        """One ring over one buffer.  A gradient byte is written on the
+        host only by an operation that changes it, and only into memory
+        that is already faulted:
+
+        - the buffer is leased from the pool (``utils/bufpool.py``): the
+          result is a view of it, and its memory comes back when the caller
+          has dropped the last view of the result, not before;
+        - the source is not copied in.  In the reduce-scatter a rank
+          receives every chunk but its own exactly once, so the first
+          write of chunk ``c`` is its reduce, ``buf[c] = src[c] +
+          received``; the rank's own chunk is sent from the source at step
+          0 and written by the allgather.  Only what the source does not
+          hold as a whole chunk, in the accumulation dtype and in C order,
+          is copied: a leaf that widens (bf16, ints), one that came off
+          the device with its dimensions in another order, and the
+          zero-padded tail;
+        - the division (``REDUCE_AVG``, the Manager's participant count)
+          is one in-place pass over the buffer, none for a divisor of 1.
+        """
         w, r = self._world, self._rank
         acc_dtype = _accumulation_dtype(array.dtype)
-        inplace_reduce = _REDUCE_UFUNCS[op]
+        reduce_into = _REDUCE_UFUNCS[op]
         n = array.size
         chunk = -(-n // w)
-        with _tracing.phase(".pack", bytes=array.nbytes):
-            # single private buffer; chunks are views of it, so ring steps
-            # receive in place and reduce in place — the only full-size copies
-            # are the pad-in and (if dtype widened) the cast back out
-            # buf escapes to the caller as the result view — not poolable;
-            # scratch is private to this call and its size repeats every ring
-            # (page-fault amortization, utils/bufpool.py)
-            buf = np.empty(chunk * w, dtype=acc_dtype)
-            buf[:n] = array.ravel()
-            if chunk * w > n:
-                buf[n:] = 0
+        # The source as the ring orders it, where that is a view.  What
+        # comes off a TPU is not always: a leaf whose last dimension is no
+        # multiple of 128 arrives in the device's order of dimensions, as
+        # strides (models' w_down, embed), and is copied in.
+        src = array.reshape(-1) if array.flags.c_contiguous else None
+        # chunks read straight from the source; the rest starts at ``lo``
+        direct = (
+            n // chunk
+            if chunk and src is not None and src.dtype == acc_dtype
+            else 0
+        )
+        lo = direct * chunk
+        buf, hit = _pool.lease(chunk * w, acc_dtype)
+        self._m_ring_buffers[hit].inc()
+        with _tracing.phase(
+            ".pack",
+            copied=(n - lo) * acc_dtype.itemsize,
+            handed=lo * acc_dtype.itemsize,
+            pool="hit" if hit else "miss",
+        ):
+            if direct:
+                buf[lo:n] = src[lo:]
+            else:
+                # one pass, cast and strides included, into warm memory
+                buf[:n].reshape(array.shape)[...] = array
+            buf[n:] = 0
+            # chunks are views of the one buffer, so ring steps receive in
+            # place and reduce in place; scratch is private to this call
+            # and its size repeats every ring
             chunks = [buf[i * chunk : (i + 1) * chunk] for i in range(w)]
+            # where a chunk's own values are until its first write
+            own = [
+                src[i * chunk : (i + 1) * chunk] if i < direct else chunks[i]
+                for i in range(w)
+            ]
             scratch = _pool.take(chunk, acc_dtype)
 
         nxt, prv = (r + 1) % w, (r - 1) % w
         # ring.wire is the wall of the 2(w-1) exchanges less the reduces
         # between them; ring.reduce accumulates over its w-1 stretches
         reduce = _tracing.phase(".reduce")
-        with _tracing.phase(
-            ".wire", bytes=2 * (w - 1) * chunk * acc_dtype.itemsize
-        ) as wire:
-            # ring reduce-scatter: after w-1 steps, chunk (r+1)%w is fully reduced
-            for step in range(w - 1):
-                send_idx = (r - step) % w
-                recv_idx = (r - step - 1) % w
-                self._exchange(
-                    nxt, 100 + step, chunks[send_idx], prv, 100 + step, deadline,
-                    recv_out=scratch,
-                )
-                with reduce.lap():
-                    inplace_reduce(chunks[recv_idx], scratch, out=chunks[recv_idx])
-            # ring allgather of the reduced chunks, received straight into place
-            for step in range(w - 1):
-                send_idx = (r - step + 1) % w
-                recv_idx = (r - step) % w
-                self._exchange(
-                    nxt, 200 + step, chunks[send_idx], prv, 200 + step, deadline,
-                    recv_out=chunks[recv_idx],
-                )
-            wire.exclude(reduce.end())
-        with _tracing.phase(".unpack", bytes=array.nbytes):
+        try:
+            with _tracing.phase(
+                ".wire", bytes=2 * (w - 1) * chunk * acc_dtype.itemsize
+            ) as wire:
+                # ring reduce-scatter: after w-1 steps, chunk (r+1)%w is
+                # fully reduced
+                for step in range(w - 1):
+                    send_idx = (r - step) % w
+                    recv_idx = (r - step - 1) % w
+                    self._exchange(
+                        nxt, 100 + step,
+                        chunks[send_idx] if step else own[send_idx],
+                        prv, 100 + step, deadline, recv_out=scratch,
+                    )
+                    with reduce.lap():
+                        reduce_into(
+                            own[recv_idx], scratch, out=chunks[recv_idx]
+                        )
+                # ring allgather of the reduced chunks, received straight
+                # into place
+                for step in range(w - 1):
+                    send_idx = (r - step + 1) % w
+                    recv_idx = (r - step) % w
+                    self._exchange(
+                        nxt, 200 + step, chunks[send_idx], prv, 200 + step,
+                        deadline, recv_out=chunks[recv_idx],
+                    )
+                wire.exclude(reduce.end())
+        finally:
             _pool.give(scratch)
-            result = buf[:n]
-            if op == REDUCE_AVG:
-                if np.issubdtype(acc_dtype, np.floating):
-                    result /= w
-                else:
-                    result = result / w
-            return np.asarray(result, dtype=array.dtype).reshape(array.shape)
+        with _tracing.phase(".unpack", bytes=array.nbytes):
+            # a leaf that widened is cast back into a new array, and the
+            # ring buffer's lease ends here
+            return np.asarray(
+                _divide(buf[:n], divisor), dtype=array.dtype
+            ).reshape(array.shape)
 
     def allgather(self, array: Any) -> Work:
         np_array = _as_numpy(array)
@@ -1400,6 +1559,14 @@ class ProcessGroupWrapper(ProcessGroup):
             self._pg.allreduce(arrays, op),
             lambda: [_as_numpy(a) for a in arrays],
         )
+
+    def _allreduce_mean(
+        self, arrays: "List[Any]", divisor: int
+    ) -> "Optional[Work]":
+        work = self._pg._allreduce_mean(arrays, divisor)
+        if work is None:
+            return None
+        return self._wrap(work, lambda: [_as_numpy(a) for a in arrays])
 
     def allgather(self, array: Any) -> Work:
         return self._wrap(self._pg.allgather(array), lambda: [_as_numpy(array)])

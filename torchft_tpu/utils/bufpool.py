@@ -14,14 +14,29 @@ caching allocator, so the framework carries a small explicit one.
 
 Contract: ``take`` returns an UNINITIALIZED array (np.empty semantics);
 ``give`` hands memory back — the caller must guarantee no other live
-reference (views included) escapes.  Never ``give`` a buffer the caller
-returned to user code.
+reference (views included) escapes.  A buffer that is returned to user
+code is never ``give``n: it is ``lease``d, and the lease ends with the
+life of the last array that views it, not at a call the user could
+outlive.
+
+``lease`` is for a result that escapes (the TCP ring's buffer, which the
+caller gets back as the reduced gradients): an UNINITIALIZED 1-d array
+whose memory returns to the pool by itself when it and every view of it
+have been dropped — a slice kept by user code, a ``jax.device_put`` still
+reading it, a sender thread still writing it to a socket all hold it.
+Leased memory is kept outside ``TORCHFT_BUFPOOL_MB``: the pool keeps at
+most as many bytes of it as the program itself had on lease at once,
+which is what a steady state asks for again (four replica groups in one
+process lease four gradients), and sizes nobody asked for lately go
+first.  ``TORCHFT_BUFPOOL_MB=0`` turns both off.
 """
 
 from __future__ import annotations
 
 import threading
-from typing import Dict, List, Tuple
+import weakref
+from collections import OrderedDict, deque
+from typing import Deque, Dict, List, Tuple
 
 import numpy as np
 
@@ -38,6 +53,16 @@ class BufferPool:
         self._lock = threading.Lock()
         self.hits = 0
         self.misses = 0
+        # leases: free memory by byte size, least recently returned first
+        self._lease_free: "OrderedDict[int, List[np.ndarray]]" = OrderedDict()
+        self._lease_held = 0  # bytes in _lease_free
+        self._lease_out = 0  # bytes on lease now
+        self._lease_peak = 0  # most bytes ever on lease at once
+        # A lease ends in a finalizer, on whichever thread drops the last
+        # view and possibly inside a garbage collection that interrupted
+        # take()/lease() under the lock: it only appends here (atomic), and
+        # the next call that holds the lock does the bookkeeping.
+        self._lease_ended: "Deque[np.ndarray]" = deque()
 
     def take(self, shape, dtype=np.float32) -> np.ndarray:
         dt = np.dtype(dtype)
@@ -76,10 +101,77 @@ class BufferPool:
             self._free.setdefault(key, []).append(base)
             self._held += base.nbytes
 
+    def lease(self, size: int, dtype=np.float32) -> "Tuple[np.ndarray, bool]":
+        """``(buffer, hit)``: an uninitialized 1-d array of ``size``
+        elements for a result that escapes to the caller, and whether its
+        memory was recycled.  There is nothing to give back: the memory
+        returns to the pool when the array and all its views are gone."""
+        dt = np.dtype(dtype)
+        nbytes = int(size) * dt.itemsize
+        if nbytes == 0:
+            return np.empty(0, dt), True  # nothing to recycle or to fault
+        mem = None
+        with self._lock:
+            self._settle_leases()
+            lst = self._lease_free.get(nbytes)
+            if lst:
+                mem = lst.pop()
+                if not lst:
+                    del self._lease_free[nbytes]
+                self._lease_held -= nbytes
+                self.hits += 1
+            else:
+                self.misses += 1
+            self._lease_out += nbytes
+            self._lease_peak = max(self._lease_peak, self._lease_out)
+        hit = mem is not None
+        if mem is None:
+            mem = np.empty(nbytes, np.uint8)
+        # numpy collapses a view's ``base`` to the first array that owns
+        # its memory or whose own base is no array: over a memoryview that
+        # is ``buf`` itself, so every view of the result keeps ``buf``
+        # alive, and ``mem`` stays the pool's
+        buf = np.frombuffer(memoryview(mem), dtype=dt)
+        weakref.finalize(buf, self._lease_ended.append, mem).atexit = False
+        return buf, hit
+
+    def _settle_leases(self) -> None:
+        """Book the leases that ended since the last call (lock held)."""
+        while self._lease_ended:
+            mem = self._lease_ended.popleft()
+            n = mem.nbytes
+            self._lease_out -= n
+            if self.max_bytes == 0:
+                continue
+            while self._lease_held + n > self._lease_peak:
+                stale = next((k for k in self._lease_free if k != n), None)
+                if stale is None:
+                    break
+                lst = self._lease_free[stale]
+                lst.pop()
+                if not lst:
+                    del self._lease_free[stale]
+                self._lease_held -= stale
+            if self._lease_held + n > self._lease_peak:
+                continue  # more than was ever out at once: the OS reclaims
+            self._lease_free.setdefault(n, []).append(mem)
+            self._lease_free.move_to_end(n)
+            self._lease_held += n
+
+    @property
+    def leased_bytes(self) -> int:
+        """Bytes on lease now: handed out and not yet dropped."""
+        with self._lock:
+            self._settle_leases()
+            return self._lease_out
+
     def clear(self) -> None:
         with self._lock:
             self._free.clear()
             self._held = 0
+            self._settle_leases()
+            self._lease_free.clear()
+            self._lease_held = 0
 
 
 # Process-wide default pool: collective staging buffers repeat sizes

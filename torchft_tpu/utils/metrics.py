@@ -1168,6 +1168,14 @@ PG_WIRE_WAIT = counter(
     "the process total",
     ("peer",),
 )
+RING_BUFFERS = counter(
+    "torchft_ring_buffers_total",
+    "Ring buffers ProcessGroupTCP leased for a plain allreduce (one per "
+    "bucket and ring; parallel/process_group.py), by whether the memory "
+    "was recycled from the pool (hit: already faulted) or newly allocated "
+    "(miss); hit / (hit + miss) over a step is the ring-buffer reuse share",
+    ("replica_id", "result"),
+)
 LINK_GOODPUT = gauge(
     "torchft_link_goodput_bytes_per_s",
     "Passively measured link goodput by peer host and transfer plane "
