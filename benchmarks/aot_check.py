@@ -32,13 +32,13 @@ def check(cell_name: str, overrides: dict) -> dict:
     from jax.sharding import SingleDeviceSharding
 
     from benchmarks.harness import files, model
-    from torchft_tpu.ops import flash_attention
 
-    # code that asks the backend would take its CPU (interpret) branch
-    flash_attention._interpret = lambda: False
     cell = files.load_workload(cell_name)
     config = files.load_config(cell["config"])
     family = files.load_family(config["family"])
+    # code that asks the backend would take its CPU (interpret) branch: the
+    # family steers what its program needs steered
+    getattr(family, "aot_prepare", lambda: None)()
     traffic = dict(files.load_traffic(cell["traffic"]))
     traffic.update({k: v for k, v in overrides.items() if k in traffic})
     sizes = model.sizes_of(config, {k: v for k, v in overrides.items() if k not in traffic})
@@ -51,7 +51,7 @@ def check(cell_name: str, overrides: dict) -> dict:
         (traffic["batch_per_group"], traffic["seq_len"]), jnp.int32, sharding=chip)
     out = {"cell": cell_name, "params": family.n_params(sizes),
            "batch": traffic["batch_per_group"], "seq": traffic["seq_len"],
-           "remat_policy": sizes["remat_policy"], "attn_impl": sizes["attn_impl"]}
+           **{key: sizes[key] for key in family.ASSUMED_KEYS}}
     t0 = time.perf_counter()
     lowered = family.make_grad_step(sizes, traffic["seq_len"]).lower(params, toks)
     out["mosaic_in_hlo"] = "tpu_custom_call" in lowered.as_text()

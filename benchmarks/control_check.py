@@ -42,12 +42,13 @@ def control_numbers(workload: str, seed: int, operand_dtype: str, preset=None) -
     sizes = model.sizes_of(config, preset.get("config"))
     family = files.load_family(config["family"])
     devices = model.reference_devices(jax.devices()[:cell["chips"]], traffic)
-    batches = model.setup_batches(sizes, traffic, seed)
+    batches = model.setup_batches(model.vocab_rows(family, sizes), traffic, seed)
     make = jax.jit(family.make_weights_fn(sizes))
     sides = {}
     for label, dtype in (("reference", None), ("control", operand_dtype)):
         out = reference.run(family.reference_loss, make(model.seed_key(seed)), batches, sizes,
-                            model.hyper(sizes), devices, operand_dtype=dtype)
+                            model.hyper(sizes), devices, operand_dtype=dtype,
+                            stacked=family.STACKED)
         sides[label] = out
     control = dict(sides["control"])
     control["losses"] = {(s, g): v for s, row in enumerate(control["losses"])

@@ -82,6 +82,7 @@ def run_cell(
     files.check_traffic(traffic)
     sizes = model.sizes_of(config, preset.get("config"))
     family = files.load_family(config["family"])
+    files.check_config(config, files.load_config_entry(cell["config"])["reduced"], family)
     family.check(sizes)
 
     device = device_info()
@@ -126,7 +127,7 @@ def run_cell(
     records, kills = shared.records, shared.kills
     t0, _ = stats.interval(records)
     end_to_end, counts = stats.end_to_end(
-        records, kills, traffic["batch_per_group"] * traffic["seq_len"], t0 - t_begin)
+        records, kills, traffic["batch_per_group"] * traffic["seq_len"], t0 - t_begin, seconds)
     print(f"window: {counts}", flush=True)
     print(f"end_to_end: {end_to_end}", flush=True)
     lead = [r for r in stats.measured(records) if r["group"] == 0]
@@ -144,31 +145,42 @@ def run_cell(
 
     # the reference runs with the program's state freed, outside set-up and window
     t_ref = time.perf_counter()
-    batches = model.setup_batches(sizes, traffic, seed)
+    batches = model.setup_batches(model.vocab_rows(family, sizes), traffic, seed)
     ref_devices = model.reference_devices(devices, traffic)
     weights = jax.jit(family.make_weights_fn(sizes))(model.seed_key(seed))
-    ref = reference.run(family.reference_loss, weights, batches, sizes, model.hyper(sizes), ref_devices)
+    ref = reference.run(family.reference_loss, weights, batches, sizes, model.hyper(sizes), ref_devices,
+                        stacked=family.STACKED)
     del weights
     print(f"reference: {time.perf_counter() - t_ref:.1f} s on {len(ref_devices)} chip(s)", flush=True)
     numbers = correct.against_reference(shared.first, ref)
     numbers.update(correct.trajectory(records, kills))
     numbers.update(shared.ring_check)
-    is_correct = correct.judge(numbers, {**files.load_limits(workload), **preset.get("limits", {})})
+    is_correct, compared = correct.judge(
+        numbers, {**files.load_limits(workload), **preset.get("limits", {})})
 
     result: Dict[str, Any] = {
         "correct": is_correct, "attempted": counts["attempted"], "failed": counts["failed"],
     }
     if traced:
+        grad_module = "jit_" + grad_step.__name__
+        # what the program calls the grad step's operations, from the
+        # executable the window ran: read here, outside set-up and window
+        names = {grad_module: trace.hlo_names(
+            shared.grad_step_compiled[devices[0].id].as_text())}
         try:
             reduced = trace.reduce(
                 trace.load(trace.find_xplane(trace_dir), loop.SPAN_PREFIX),
-                [d.id for d in devices], groups_on_chip)
+                [d.id for d in devices], groups_on_chip, names=names)
         finally:
             shutil.rmtree(trace_dir, ignore_errors=True)
+        print(f"trace: {len(reduced['ops'])} device operations, their own seconds "
+              f"{sum(op['seconds'] for op in reduced['ops']) / len(devices)!r} a chip against busy_s "
+              f"{reduced['busy_s']!r}; {sum(1 for op in reduced['ops'] if op['op_name'])} carry the "
+              "program's name", flush=True)
         run = {
             "records": records, "kills": kills, "trace": reduced, "sizes": sizes,
-            "traffic": traffic, "device_kind": device["kind"],
-            "grad_module": "jit_" + grad_step.__name__,
+            "traffic": traffic, "device_kind": device["kind"], "family": family,
+            "grad_module": grad_module,
             "flops_per_group_step": family.flops_per_step(
                 sizes, traffic["batch_per_group"], traffic["seq_len"]),
         }
@@ -186,4 +198,5 @@ def run_cell(
             name: {"value": end_to_end[name], "unit": unit}
             for name, unit in files.reported("end_to_end", workload).items()}
     result["device"] = device
+    result["compared"] = compared
     return result

@@ -67,13 +67,17 @@ def trajectory(records: "List[Dict[str, Any]]", kills: "List[Dict[str, Any]]") -
     }
 
 
-def judge(numbers: Dict[str, float], limits: Dict[str, float]) -> bool:
+def judge(numbers: Dict[str, float], limits: Dict[str, float]) -> "Tuple[bool, Dict[str, Any]]":
     """Every number against its limit; a number without a limit, a limit
-    without a number and a number that is not finite all fail."""
-    verdict = True
+    without a number and a number that is not finite all fail.  Returns the
+    verdict and, for the result's line, each number beside its limit (``None``
+    where one is missing or not finite)."""
+    verdict, compared = True, {}
     for name in sorted(set(numbers) | set(limits)):
         value, limit = numbers.get(name, float("nan")), limits.get(name, float("nan"))
         ok = bool(np.isfinite(value) and np.isfinite(limit) and value <= limit)
         verdict = verdict and ok
         print(f"correct: {name} = {value!r} limit {limit!r} {'ok' if ok else 'FAIL'}", flush=True)
-    return verdict
+        compared[name] = {"value": float(value) if np.isfinite(value) else None,
+                          "limit": float(limit) if np.isfinite(limit) else None, "ok": ok}
+    return verdict, compared

@@ -69,6 +69,9 @@ class Shared:
         # outputs + temporaries of the compiled grad step, by device id: the
         # runtime's byte counters see buffers only (PERF.md section 4)
         self.grad_step_bytes: "Dict[int, int]" = {}
+        # the executable each chip's groups call: a traced run reads the
+        # program's names of its operations from it, after the window
+        self.grad_step_compiled: "Dict[int, Any]" = {}
 
     def add(self, rec: Dict[str, Any]) -> None:
         with self.lock:
@@ -162,8 +165,9 @@ def group_loop(
     make_weights = jax.jit(make, out_shardings=on_dev)
     init_opt = jax.jit(tx.init, out_shardings=on_dev)
     key = jax.device_put(model.seed_key(seed), on_dev)
-    norms_of = jax.jit(leaf_norms)
-    change_of = jax.jit(lambda p, k: delta_norms(p, make(k)))
+    norms_of = jax.jit(lambda t: leaf_norms(t, family.STACKED))
+    change_of = jax.jit(lambda p, k: delta_norms(p, make(k), family.STACKED))
+    vocab = model.vocab_rows(family, sizes)
     traced = 0
     # one compile per placement, inspected before it runs (chip_smoke.py's
     # compile_grad_step): the executable the set-up steps and the window call
@@ -174,6 +178,7 @@ def group_loop(
     with shared.lock:
         shared.grad_step_bytes[device.id] = int(
             analysis.output_size_in_bytes + analysis.temp_size_in_bytes)
+        shared.grad_step_compiled.setdefault(device.id, grad_step)
 
     for incarnation in range(2 if kill_at is not None else 1):
         params = make_weights(key)
@@ -227,7 +232,7 @@ def group_loop(
                 t_start = time.perf_counter()
                 with _span("heal" if healing else "step", group=i, step=step):
                     toks = jax.device_put(
-                        model.tokens_for(sizes["vocab_size"], batch, seq, seed, i, step), on_dev)
+                        model.tokens_for(vocab, batch, seq, seed, i, step), on_dev)
                     with _span("quorum", group=i):
                         optimizer.begin_step()
                     live_bytes = (device.memory_stats() or {}).get("bytes_in_use", 0)

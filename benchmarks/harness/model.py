@@ -32,10 +32,16 @@ def tokens_for(vocab: int, batch: int, seq: int, seed: int, group: int, step: in
     return rng.integers(0, vocab, (batch, seq), dtype=np.int32)
 
 
-def setup_batches(sizes: Dict[str, Any], traffic: Dict[str, Any], seed: int) -> "list[list[np.ndarray]]":
+def vocab_rows(family: Any, sizes: Dict[str, Any]) -> int:
+    """The rows of the vocabulary this chip holds: the traffic draws its ids
+    from them (a sliced vocabulary is a smaller vocabulary)."""
+    return sizes[family.CUT_KEYS["vocab"]]
+
+
+def setup_batches(vocab: int, traffic: Dict[str, Any], seed: int) -> "list[list[np.ndarray]]":
     """``[step][group]``: the rows of the set-up steps the reference follows."""
-    return [[tokens_for(sizes["vocab_size"], traffic["batch_per_group"], traffic["seq_len"],
-                        seed, g, step) for g in range(traffic["groups"])]
+    return [[tokens_for(vocab, traffic["batch_per_group"], traffic["seq_len"], seed, g, step)
+             for g in range(traffic["groups"])]
             for step in range(traffic["warmup_steps"])]
 
 
@@ -44,9 +50,11 @@ def reference_devices(devices: "list[Any]", traffic: Dict[str, Any]) -> "list[An
     return devices if traffic["batch_per_group"] % len(devices) == 0 else devices[:1]
 
 
+HYPER_KEYS = ("learning_rate", "adam_b1", "adam_b2", "adam_eps", "weight_decay")
+
+
 def hyper(sizes: Dict[str, Any]) -> Dict[str, float]:
-    return {k: sizes[k] for k in (
-        "learning_rate", "adam_b1", "adam_b2", "adam_eps", "weight_decay")}
+    return {k: sizes[k] for k in HYPER_KEYS}
 
 
 def optimizer(sizes: Dict[str, Any]) -> Any:

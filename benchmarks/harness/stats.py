@@ -1,6 +1,6 @@
 """From the loop's records to the end-to-end metrics.  A rate is taken over
-all the work and all the time of the measured interval; a percentile is
-nearest-rank over every step that qualifies, with its sample count printed."""
+all the work and all the time of the window; a percentile is nearest-rank over
+every step that qualifies, with its sample count printed."""
 
 from __future__ import annotations
 
@@ -31,6 +31,24 @@ def interval(records: "List[Dict[str, Any]]") -> Tuple[float, float]:
     return min(r["t_start"] for r in m), max(r["t_end"] for r in m)
 
 
+def window_close(records: "List[Dict[str, Any]]", kills: "List[Dict[str, Any]]",
+                 seconds: float) -> float:
+    """The window closes ``seconds`` after the first measured step starts; a
+    recovery still under way then is waited for, so that a window holds the
+    whole of every failure it holds the start of."""
+    t0, t1 = interval(records)
+    recovered = [k["t_recovered"] for k in kills if k["t_recovered"] is not None]
+    return min(t1, max([t0 + seconds] + recovered))
+
+
+def share_inside(r: Dict[str, Any], close: float) -> float:
+    """The part of a step's time that lies before the window's close: 1 for a
+    step that ended inside, pro rata for the one that straddles the close."""
+    if r["t_end"] <= close:
+        return 1.0
+    return max(0.0, (close - r["t_start"]) / (r["t_end"] - r["t_start"]))
+
+
 def recovery_steps(records: "List[Dict[str, Any]]", kills: "List[Dict[str, Any]]") -> "set[int]":
     """The step numbers from each kill through the step its new incarnation
     first commits (the healing step): by number, not by the clock, so that
@@ -58,17 +76,27 @@ def step_times(records: "List[Dict[str, Any]]", kills: "List[Dict[str, Any]]") -
 
 def end_to_end(
     records: "List[Dict[str, Any]]", kills: "List[Dict[str, Any]]",
-    tokens_per_group_step: int, setup_s: float,
+    tokens_per_group_step: int, setup_s: float, seconds: float,
 ) -> Tuple[Dict[str, float], Dict[str, Any]]:
     """The end-to-end metrics a run can report (their units are
     ``BENCHMARK.json``'s), and the counts behind them.  ``recover_s`` and
-    ``survivor_stall_s`` exist only where a group was killed."""
+    ``survivor_stall_s`` exist only where a group was killed.
+
+    ``tokens_per_s`` is over the window of ``seconds``: the tokens of every
+    group-step committed inside it, the step in flight at its close counted
+    for the part of its time that lies inside, over the window's length.  The
+    loop runs on to a step boundary all groups agree on; a rate over that
+    longer interval would depend on how many whole steps happen to follow a
+    recovery of fixed cost (a step more or less moved it by 3.5 % on four
+    chips, PERF.md section 2), so it is printed beside the counts only."""
     t0, t1 = interval(records)
+    close = window_close(records, kills, seconds)
     m = measured(records)
     trained = steady(records)
     times = step_times(records, kills)
     metrics = {
-        "tokens_per_s": len(trained) * tokens_per_group_step / (t1 - t0),
+        "tokens_per_s": (tokens_per_group_step * sum(share_inside(r, close) for r in trained)
+                         / (close - t0)),
         "step_p90_ms": 1e3 * nearest_rank(times, 0.9),
         "setup_s": setup_s,
     }
@@ -81,7 +109,9 @@ def end_to_end(
             stall = max([stall] + [b - a for a, b in zip(commits, commits[1:])])
         metrics["survivor_stall_s"] = stall
     counts = {
+        "window_s": close - t0,
         "interval_s": t1 - t0,
+        "tokens_per_s_to_the_last_step": len(trained) * tokens_per_group_step / (t1 - t0),
         "attempted": len(m),
         "failed": sum(1 for r in m if not r["committed"]),
         "group_steps_trained": len(trained),

@@ -1,4 +1,4 @@
-"""Median wall time of the steps ``step_p90_ms`` takes its tail from (committed,
+"""Median wall time of the steps ``healthy_step_p90_ms`` takes its tail from (committed,
 the slowest group's, a recovery's left out by number): the steady statistic
 beside the tail."""
 

@@ -63,7 +63,7 @@ def main() -> int:
         jax.eval_shape(make, key),
         jax.ShapeDtypeStruct((batch, seq), np.int32, sharding=on_dev)).compile()
     analysis = grad_step.memory_analysis()
-    toks = jax.device_put(model.tokens_for(sizes["vocab_size"], batch, seq, args.seed, 0, 0), on_dev)
+    toks = jax.device_put(model.tokens_for(model.vocab_rows(family, sizes), batch, seq, args.seed, 0, 0), on_dev)
     block = jax.jit(lambda: jnp.zeros((BLOCK,), jnp.uint8), out_shardings=on_dev)
 
     before = dev.memory_stats()

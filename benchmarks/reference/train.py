@@ -15,22 +15,24 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def leaf_norms(tree: Any) -> Dict[str, jax.Array]:
-    """L2 norm of every leaf, stacked per-layer leaves ([L, ...]) layer by
-    layer: ``{"blocks/wq": [L], "embed": [1], ...}``.  Runs under jit."""
+def leaf_norms(tree: Any, stacked: Sequence[str] = ("blocks",)) -> Dict[str, jax.Array]:
+    """L2 norm of every leaf; the leaves of the top-level groups in
+    ``stacked`` (the family's ``STACKED``) are stacked by layer ([L, ...]) and
+    read layer by layer: ``{"blocks/wq": [L], "embed": [1], ...}``.  Runs
+    under jit."""
     out = {}
     for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]:
         name = "/".join(str(getattr(k, "key", k)) for k in path)
         x = x.astype(jnp.float32)
-        if name.startswith("blocks/"):
+        if name.split("/", 1)[0] in stacked:
             out[name] = jnp.sqrt(jnp.sum(x * x, axis=tuple(range(1, x.ndim))))
         else:
             out[name] = jnp.sqrt(jnp.sum(x * x))[None]
     return out
 
 
-def delta_norms(new: Any, old: Any) -> Dict[str, jax.Array]:
-    return leaf_norms(jax.tree_util.tree_map(lambda a, b: a - b, new, old))
+def delta_norms(new: Any, old: Any, stacked: Sequence[str] = ("blocks",)) -> Dict[str, jax.Array]:
+    return leaf_norms(jax.tree_util.tree_map(lambda a, b: a - b, new, old), stacked)
 
 
 def adamw_step(params: Any, grads: Any, mu: Any, nu: Any, count: int,
@@ -58,6 +60,7 @@ def run(
     hp: Dict[str, float],
     devices: Sequence[Any],
     operand_dtype: Optional[str] = None,
+    stacked: Sequence[str] = ("blocks",),
 ) -> Dict[str, Any]:
     """``batches[step][group]`` is that group's [B, T] token array.  Returns
     per-step per-group losses, the norms of step 0's mean gradient and the
@@ -77,8 +80,8 @@ def run(
         lambda p, g, mu, nu, count, scale: adamw_step(
             p, jax.tree_util.tree_map(lambda x: x * scale, g), mu, nu, count, hp),
         static_argnums=(4,), donate_argnums=(0, 2, 3))
-    norms = jax.jit(leaf_norms)
-    dnorms = jax.jit(delta_norms)
+    norms = jax.jit(lambda t: leaf_norms(t, stacked))
+    dnorms = jax.jit(lambda new, old: delta_norms(new, old, stacked))
     zeros = jax.jit(lambda t: jax.tree_util.tree_map(jnp.zeros_like, t),
                     out_shardings=replicated)
 

@@ -42,6 +42,10 @@ def main(argv: "list[str] | None" = None) -> int:
         print(f"refused: {e}", file=sys.stderr, flush=True)
         return 1
     print(json.dumps(result), flush=True)
+    # each number compared beside its limit, as the last lines on standard error
+    for name, row in result["compared"].items():
+        print(f"compared: {name} = {row['value']} limit {row['limit']} "
+              f"{'ok' if row['ok'] else 'FAIL'}", file=sys.stderr, flush=True)
     return 0
 
 

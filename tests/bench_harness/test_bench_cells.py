@@ -11,7 +11,7 @@ import sys
 
 import pytest
 
-from bench_tiny import TINY, WINDOW_S, rehearsal
+from bench_tiny import WINDOW_S, preset, rehearsal
 from benchmarks.harness import files, loop
 
 BENCH = files.load_benchmark_json()
@@ -23,8 +23,12 @@ DEVICE_ONLY = {m["name"] for m in BENCH["per_layer"] if m["source"] == "device_t
 @pytest.mark.parametrize("cell", CELLS)
 def test_result_line(cell):
     result = rehearsal(cell, False)
-    assert set(result) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics", "device", "compared"]
     assert result["correct"] is True
+    # each number compared beside its limit, under the line's last key
+    limits = {**files.load_limits(cell), **preset(cell)["limits"]}
+    assert {k: v["limit"] for k, v in result["compared"].items()} == limits
+    assert all(v["ok"] and v["value"] <= v["limit"] for v in result["compared"].values())
     assert result["attempted"] > 0 and result["failed"] == 0
     assert result["device"]["platform"] == "cpu"
     assert {"platform", "kind", "count", "memory_peak_bytes"} <= set(result["device"])
@@ -77,7 +81,7 @@ def test_a_new_metric_is_an_entry_and_a_reader(monkeypatch):
     monkeypatch.setattr(files, "load_benchmark_json", lambda: bench)
     monkeypatch.setattr(files, "load_layer_metric",
                         lambda name: reader if name == "check_ms" else found(name))
-    got = run_cell(CELLS[0], 8, WINDOW_S, True, platform="cpu", preset=TINY)["metrics"]
+    got = run_cell(CELLS[0], 8, WINDOW_S, True, platform="cpu", preset=preset(CELLS[0]))["metrics"]
     assert got["check_ms"]["unit"] == "ms" and got["check_ms"]["value"] > 0
 
 
@@ -91,10 +95,11 @@ def _flip_one_bit(state):
     import jax
     import jax.numpy as jnp
 
-    leaf = state["params"]["final_norm"]
-    bits = jax.lax.bitcast_convert_type(leaf, jnp.uint32).at[0].add(1)
-    state["params"]["final_norm"] = jax.device_put(
-        jax.lax.bitcast_convert_type(bits, leaf.dtype), leaf.sharding)
+    leaves, tree = jax.tree_util.tree_flatten(state["params"])
+    leaf = leaves[-1]
+    bits = jax.lax.bitcast_convert_type(leaf, jnp.uint32).ravel().at[0].add(1).reshape(leaf.shape)
+    leaves[-1] = jax.device_put(jax.lax.bitcast_convert_type(bits, leaf.dtype), leaf.sharding)
+    state["params"] = jax.tree_util.tree_unflatten(tree, leaves)
 
 
 def test_correct_false_when_healed_state_differs_by_one_bit(monkeypatch):
@@ -102,7 +107,7 @@ def test_correct_false_when_healed_state_differs_by_one_bit(monkeypatch):
 
     cell = next(w["name"] for w in BENCH["workloads"] if files.load_traffic(w["traffic"])["kills"])
     monkeypatch.setattr(loop, "after_heal", _flip_one_bit)
-    result = run_cell(cell, 5, WINDOW_S, False, platform="cpu", preset=TINY)
+    result = run_cell(cell, 5, WINDOW_S, False, platform="cpu", preset=preset(cell))
     assert result["correct"] is False
 
 
@@ -112,7 +117,7 @@ def test_correct_false_when_the_step_returns_its_state_unchanged(monkeypatch):
 
     monkeypatch.setattr(ft.Optimizer, "update",
                         lambda self, params, grads, opt_state: (params, opt_state))
-    result = run_cell(CELLS[0], 6, WINDOW_S, False, platform="cpu", preset=TINY)
+    result = run_cell(CELLS[0], 6, WINDOW_S, False, platform="cpu", preset=preset(CELLS[0]))
     assert result["correct"] is False
 
 
@@ -120,7 +125,7 @@ def test_run_cell_refuses_the_wrong_platform():
     from benchmarks.harness.cell import Refused, run_cell
 
     with pytest.raises(Refused, match="platform"):
-        run_cell(CELLS[0], 1, WINDOW_S, False, preset=TINY)  # expects a tpu
+        run_cell(CELLS[0], 1, WINDOW_S, False, preset=preset(CELLS[0]))  # expects a tpu
 
 
 def test_command_line_refuses_a_cpu():

@@ -18,37 +18,100 @@ def _names(kind, ext=".json"):
                   if f.endswith(ext) and not f.startswith("_"))
 
 
-WIDTH_KEYS = ("hidden_size", "intermediate_size", "head_dim", "num_attention_heads",
-              "num_key_value_heads", "vocab_size")
-
-
 @pytest.mark.parametrize("name", _names("configs"))
 def test_config_file(name):
     cfg = files.load_config(name)
-    entry = next(c for c in BENCH["configs"] if c["name"] == name)
+    entry = files.load_config_entry(name)
     assert entry["file"] == f"benchmarks/configs/{name}.json"
     assert entry["source"] == cfg["source"] and cfg["source"].startswith("https://")
-    assert not set(entry["reduced"]) & set(WIDTH_KEYS), "no width is ever cut"
-    assert not any(k.endswith(("_dim", "_rank", "_layers")) for k in entry["reduced"]), (
-        "both configurations run at full width and depth")
-    assert sorted(d.split(":")[0] for d in cfg["departures"]) == sorted(entry["reduced"]), (
-        "every key that differs from the source says why")
-    for key in ("remat_policy", "attn_impl", "seq_len", "optimizer"):
-        assert key in cfg["assumed"], f"{key} is set by the file and must be listed"
     sizes = model.sizes_of(cfg)
     family = files.load_family(cfg["family"])
     family.check(sizes)
-    assert family.n_params(sizes) == cfg["params"]
+    # no width in `reduced`, every cut with its published value and the chips
+    # that share a layer and above the guide's floors, every departure named,
+    # what the file sets itself under `assumed`, `params` as the family counts
+    files.check_config(cfg, entry["reduced"], family)
+
+
+def _a_family(**over):
+    """A family's side of the floors, in no family's keys: one dense layer
+    leads, then a period of three kinds of layer."""
+    import types
+
+    return types.SimpleNamespace(**{
+        "CUT_KEYS": {"layers": "depth", "experts": "experts_here", "vocab": "rows"},
+        "WIDTH_KEYS": ("routes", "router_out"), "ASSUMED_KEYS": ("kernel",),
+        "layer_pattern": lambda sizes: {"leading_dense": 1, "period": 3},
+        "n_params": lambda sizes: 7, **over})
+
+
+def _a_cut(**over):
+    """A configuration cut to a chip's share, as the guide's section 4 has it."""
+    cfg = {
+        "name": "a-cut", "depth": 5, "experts_here": 8, "rows": 4096, "model_dim": 64,
+        "routes": 2, "norm_eps": 1e-6, "params": 7,
+        "published": {"depth": 28, "experts_here": 64, "rows": 32768},
+        "deployment": {"chips_sharing_a_layer": 8, "how": "experts and vocabulary over 8 chips"},
+        "departures": ["depth: a", "experts_here: b", "rows: c", "norm_eps: d"],
+        "assumed": dict.fromkeys(files.GENERIC_ASSUMED + ("kernel",), 1),
+    }
+    cfg.update(over)
+    return cfg
+
+
+_CUT = ["depth", "experts_here", "rows", "norm_eps"]
+
+
+def test_a_cut_at_the_floors_passes():
+    files.check_config(_a_cut(), _CUT, _a_family())
+
+
+@pytest.mark.parametrize("over,reduced,family,match", [
+    ({"departures": ["depth: a", "model_dim: e"]}, ["depth", "model_dim"], {}, "no width is ever cut"),
+    ({"departures": ["routes: e"]}, ["routes"], {}, "no width is ever cut"),
+    ({"departures": ["kv_rank: e"]}, ["kv_rank"], {}, "no width is ever cut"),
+    ({"departures": ["ffn_hidden: e"]}, ["ffn_hidden"], {}, "no width is ever cut"),
+    ({"depth": 4}, _CUT, {}, "the floor is 4"),
+    ({"depth": 5}, _CUT, {"layer_pattern": lambda sizes: {"leading_dense": 1, "period": 6}},
+     "less than a whole period"),
+    ({"experts_here": 7}, _CUT, {}, "7 experts held"),
+    ({"rows": 32768 // 9}, _CUT, {}, "less than an eighth"),
+    ({"published": {"depth": 28, "rows": 32768}}, _CUT, {}, "states its published value"),
+    ({"deployment": "eight chips"}, _CUT, {}, "over how many chips"),
+    ({"deployment": {"how": "eight chips"}}, _CUT, {}, "over how many chips"),
+    ({"rows": 40000}, _CUT, {}, "is no cut of the published"),
+    ({}, _CUT[:3], {}, "every key that differs from the source says why"),
+    ({"assumed": dict.fromkeys(files.GENERIC_ASSUMED, 1)}, _CUT, {}, "must be under `assumed`"),
+    ({"params": 8}, _CUT, {}, "not what the family counts"),
+], ids=["a-width-by-its-ending", "a-width-the-family-names", "a-rank", "a-hidden-width",
+        "three-layers-after-the-dense", "less-than-a-period", "seven-experts", "a-ninth-of-the-vocabulary",
+        "no-published-value", "no-deployment-object", "no-chips-sharing-a-layer", "a-cut-that-grows",
+        "a-departure-not-named", "an-assumed-key-missing", "params-miscounted"])
+def test_a_configuration_below_the_floors_is_refused(over, reduced, family, match):
+    with pytest.raises(ValueError, match=match):
+        files.check_config(_a_cut(**over), reduced, _a_family(**family))
+
+
+FAMILY_MEMBERS = ("check", "make_weights_fn", "program_init_shapes", "n_params", "make_grad_step",
+                  "flops_per_step", "reference_loss", "layer_pattern")
 
 
 @pytest.mark.parametrize("name", _names("families", ".py"))
 def test_family_file(name):
     """What the harness takes from a family, and nothing of it by name."""
     family = files.load_family(name)
-    for attr in ("check", "make_weights_fn", "n_params", "make_grad_step", "flops_per_step",
-                 "reference_loss"):
+    for attr in FAMILY_MEMBERS:
         assert callable(getattr(family, attr)), attr
+    assert set(family.CUT_KEYS) == {"layers", "experts", "vocab"} and family.CUT_KEYS["vocab"]
+    assert all(isinstance(group, str) for group in family.STACKED)
+    assert isinstance(family.WIDTH_KEYS, tuple) and isinstance(family.ASSUMED_KEYS, tuple)
     assert any(c["family"] == name for c in map(files.load_config, _names("configs")))
+    # its presets for the rehearsals, in its own keys
+    import bench_tiny
+
+    tiny = bench_tiny.of_family(name)
+    assert {"tiny", "wide", "float32_parity", "flops_check"} <= set(tiny)
+    assert {"config", "traffic", "limits"} <= set(tiny["tiny"])
 
 
 @pytest.mark.parametrize("name", _names("traffic"))
