@@ -1,0 +1,33 @@
+"""The chunked delta rule's share of its roofline inside the grad step: the
+least time the chip could take for the operations and bytes the chunkwise
+algorithm needs (the family's ``kda_work``: forward and backward of one layer;
+``harness/peaks.py``) over the device time under the program's scope ``kda``
+(``kda_ms``).  The forward counts twice where the trace shows it recomputed
+under remat (rows under ``rematted_computation``), as the flash kernels' share
+counts its calls.  Plain XLA, no kernel: the share says how far the program's
+many passes over memory are from the algorithm's one."""
+
+from benchmarks.harness import peaks
+
+
+def read(run):
+    family = run.get("family")
+    if not hasattr(family, "kda_work") or not hasattr(family, "scope_rows"):
+        return None
+    rows = family.scope_rows(run, ("kda",))
+    if rows is None:
+        return None
+    spent = sum(op["seconds"] for op in rows)
+    runs = run["trace"]["module_seconds"].get(run["grad_module"])
+    if not runs or not spent:
+        return 0.0  # no device ran it (a rehearsal on the CPU)
+    work = family.kda_work(run["sizes"], run["traffic"]["batch_per_group"], run["traffic"]["seq_len"])
+    layers = sum(1 for n in run["sizes"]["linear_attn_config"]["kda_layers"]
+                 if n <= run["sizes"][family.CUT_KEYS["layers"]])
+    forwards = 2 if any("rematted_computation" in op["op_name"] for op in rows) else 1
+    least = layers * len(runs) * sum(
+        times * peaks.roofline_seconds(run["device_kind"], work[part]["flops"], work[part]["bytes"])
+        for part, times in (("forward", forwards), ("backward", 1)))
+    print(f"kda: {layers} layers, forward x{forwards}; per grad step roofline "
+          f"{1e3 * least / len(runs):.3f} ms, device {1e3 * spent / len(runs):.3f} ms", flush=True)
+    return 100.0 * least / spent

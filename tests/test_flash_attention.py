@@ -226,3 +226,41 @@ class TestFlashBf16:
         out = np.asarray(flash_attention(q, k, v), np.float32)
         scale = np.abs(ref).max() + 1e-9
         assert np.abs(out - ref).max() / scale < 3e-2
+
+
+class TestFlashUnequalWidths:
+    """Queries and keys wider than values (latent attention: 192 against
+    128): forward and every gradient against dense attention."""
+
+    @staticmethod
+    def _qkv(d, dv, h=2, hkv=2, t=256, seed=5):
+        key = jax.random.PRNGKey(seed)
+        q = jax.random.normal(jax.random.fold_in(key, 0), (2, t, h, d), jnp.float32)
+        k = jax.random.normal(jax.random.fold_in(key, 1), (2, t, hkv, d), jnp.float32)
+        v = jax.random.normal(jax.random.fold_in(key, 2), (2, t, hkv, dv), jnp.float32)
+        return q, k, v
+
+    @pytest.mark.parametrize("d,dv,hkv,causal", [
+        (192, 128, 2, True), (192, 128, 1, True), (192, 128, 2, False),
+        (64, 128, 2, True), (128, 64, 2, True),
+    ])
+    def test_forward_and_grads_match_dense(self, d, dv, hkv, causal):
+        q, k, v = self._qkv(d, dv, hkv=hkv)
+        out = flash_attention(q, k, v, causal=causal)
+        assert out.shape == q.shape[:3] + (dv,)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(dense_attention(q, k, v, causal=causal)),
+            atol=2e-5, rtol=1e-5)
+
+        def grads(fn):
+            def loss(q, k, v):
+                o = fn(q, k, v, causal=causal)
+                return (o * jnp.arange(o.size, dtype=o.dtype).reshape(o.shape)).mean()
+
+            return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+        for name, a, b in zip("qkv", grads(flash_attention), grads(dense_attention)):
+            assert a.shape == b.shape
+            top = float(np.abs(np.asarray(b)).max()) + 1e-12
+            np.testing.assert_allclose(np.asarray(a) / top, np.asarray(b) / top,
+                                       atol=1e-5, err_msg=f"d{name}")

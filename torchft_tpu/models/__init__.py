@@ -1,7 +1,9 @@
 """Model families: flagship llama-style transformer + the reference's
-example-scale CNN/MLP (reference train_ddp.py:84-102, train_diloco.py:76-120)."""
+example-scale CNN/MLP (reference train_ddp.py:84-102, train_diloco.py:76-120),
+and a sparse hybrid decoder (``kimi_linear``: delta-rule linear attention beside
+latent attention, a chip's share of sigmoid-routed experts)."""
 
-from torchft_tpu.models import cnn, mlp, transformer
+from torchft_tpu.models import cnn, kimi_linear, mlp, transformer
 from torchft_tpu.models.transformer import (
     TransformerConfig,
     init_params,
@@ -13,6 +15,7 @@ from torchft_tpu.models.transformer import (
 
 __all__ = [
     "cnn",
+    "kimi_linear",
     "mlp",
     "transformer",
     "TransformerConfig",

@@ -13,6 +13,12 @@ E)``; tokens past capacity are dropped (their combine weight is zero, so
 the residual connection passes them through unchanged — standard GShard
 semantics). The load-balance auxiliary loss (Switch/GShard ``E * Σ_e
 fraction_tokens_e * mean_prob_e``) is returned for the trainer to add.
+
+Beside it, a chip's share of a sigmoid-routed, dropless layer with a shared
+expert (``HeldMoEConfig``, ``held_moe_ffn``): the router scores every
+published expert, the layer is told which of them it holds and computes their
+part of the result.  No capacity, no drop, no auxiliary loss, no ``ep`` axis:
+the chips that hold the other experts and their exchange are not in it.
 """
 
 from __future__ import annotations
@@ -204,7 +210,167 @@ def moe_ffn_reference(
     return out.reshape(b, t, d).astype(x.dtype)
 
 
+# ---------------------------------------------------------------------------
+# a chip's share of a sigmoid-routed, dropless expert layer
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class HeldMoEConfig:
+    """An expert layer as one chip of an expert-parallel deployment holds it:
+    the router scores all ``n_routed`` published experts and keeps ``top_k``
+    a token, the layer is told which of them live here (``held``, their
+    published ids) and computes their part of the result, plus the shared
+    expert that every chip computes alike."""
+
+    d_model: int
+    d_expert: int
+    n_routed: int
+    top_k: int
+    held: "Tuple[int, ...]"
+    routed_scale: float = 1.0
+    # rows of the pool the landed assignments are gathered into, over the mean
+    # load of the held experts together; a batch that overflows it takes the
+    # masked path instead: nothing is ever dropped
+    slack: float = 8.0
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+
+def init_held_moe_params(rng: jax.Array, cfg: HeldMoEConfig, n_layers: int) -> Params:
+    """Router over all published experts, the held experts, the shared one;
+    a leading ``[n_layers]`` dim for stacked blocks."""
+    e, f, pd = cfg.d_model, cfg.d_expert, cfg.param_dtype
+    held = len(cfg.held)
+    keys = jax.random.split(rng, 7)
+
+    def dense(key, *shape):
+        return (jax.random.normal(key, (n_layers,) + shape, pd) / np.sqrt(shape[-2])).astype(pd)
+
+    return {
+        "router": dense(keys[0], e, cfg.n_routed),
+        "w_gate": dense(keys[1], held, e, f),
+        "w_up": dense(keys[2], held, e, f),
+        "w_down": dense(keys[3], held, f, e),
+        "shared_gate": dense(keys[4], e, f),
+        "shared_up": dense(keys[5], e, f),
+        "shared_down": dense(keys[6], f, e),
+    }
+
+
+def route_sigmoid(
+    flat: jax.Array, router: jax.Array, cfg: HeldMoEConfig,
+    router_bias: "Optional[jax.Array]" = None,
+) -> "Tuple[jax.Array, jax.Array]":
+    """``[N, d] -> (chosen [N, k] published ids, weights [N, k])``: sigmoid
+    scores in float32 at the highest precision (a near-tie decides where a
+    token goes), the ``top_k`` largest of score + ``router_bias`` (a buffer
+    the balancing rule moves, never the gradient), the chosen scores
+    renormalised to one and scaled."""
+    scores = jax.nn.sigmoid(jnp.matmul(
+        flat.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    ranked = scores if router_bias is None else scores + jax.lax.stop_gradient(router_bias)
+    _, chosen = jax.lax.top_k(ranked, cfg.top_k)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    weights = picked / (picked.sum(axis=-1, keepdims=True) + 1e-20) * cfg.routed_scale
+    return chosen, weights
+
+
+def held_moe_ffn(
+    x: jax.Array, params: Params, cfg: HeldMoEConfig,
+    router_bias: "Optional[jax.Array]" = None,
+) -> "Tuple[jax.Array, Dict[str, jax.Array]]":
+    """``x [B, T, d] -> (y, stats)``: ``y = sum over the chosen experts that
+    live here of w_e SwiGLU_e(x), plus SwiGLU_shared(x)``; a token none of
+    whose experts is here gets the shared expert alone.  No capacity, no
+    drop, no auxiliary loss.  ``stats``: ``assignments`` ``[held]`` (how many
+    of the ``N k`` assignments landed on each held expert) and ``unrouted``
+    (tokens that found none of their experts here).
+
+    Static shapes with the work going by what landed: the assignments that
+    landed here are laid out by expert in a pool of ``slack x`` the mean load
+    (``N k held / n_routed``) rows, the three products of the SwiGLU run as
+    ``lax.ragged_dot`` over the pool's groups (on a TPU a grouped-matmul
+    kernel that visits the rows in use) and the results are added back by
+    token.  A batch so skewed that more lands here than the pool holds takes,
+    under ``lax.cond``, a path that runs every held expert over every token
+    with the weights as a mask."""
+    from torchft_tpu.models.transformer import _swiglu
+
+    b, t, d = x.shape
+    n, k, held = b * t, cfg.top_k, len(cfg.held)
+    act = cfg.dtype
+    flat = x.reshape(n, d)
+    with jax.named_scope("moe.route"):
+        chosen, weights = route_sigmoid(flat, params["router"], cfg, router_bias)
+        slot_of = np.full((cfg.n_routed,), -1, np.int32)
+        slot_of[list(cfg.held)] = np.arange(held, dtype=np.int32)
+        local = jnp.asarray(slot_of)[chosen].reshape(n * k)          # -1: lives elsewhere
+        landed = (local[:, None] == jnp.arange(held)[None, :]).astype(jnp.int32)  # [N k, held]
+        assignments = landed.sum(axis=0)
+        unrouted = n - jnp.any(local.reshape(n, k) >= 0, axis=-1).sum()
+        pool = min(n * min(k, held), -(-int(np.ceil(cfg.slack * n * k * held / cfg.n_routed)) // 8) * 8)
+        # the row of each assignment: its expert's first row (experts in the
+        # order held), then its place among that expert's, by token then choice
+        first = jnp.cumsum(assignments) - assignments
+        place = ((jnp.cumsum(landed, axis=0) - 1 + first[None, :]) * landed).sum(axis=-1)
+        row = jnp.where((local >= 0) & (place < pool), place, pool)
+        token = jnp.arange(n * k, dtype=jnp.int32) // k
+        rows_token = jnp.full((pool,), n, jnp.int32).at[row].set(token, mode="drop")
+        rows_weight = jnp.zeros((pool,), jnp.float32).at[row].set(
+            weights.reshape(n * k), mode="drop")
+
+    with jax.named_scope("moe.experts"):
+        wg, wu, wd = (params[name].astype(act) for name in ("w_gate", "w_up", "w_down"))
+
+        def gathered(_):
+            rows = jnp.concatenate([flat, jnp.zeros((1, d), flat.dtype)])[rows_token]
+            # a TPU's grouped matmul leaves the rows past the last group as
+            # it found them: nothing of them may reach the sum or a gradient
+            used = (jnp.arange(pool) < assignments.sum())[:, None]
+
+            def grouped(lhs, rhs, out_type):
+                return jnp.where(used, jax.lax.ragged_dot(
+                    lhs, rhs, assignments, preferred_element_type=out_type), 0)
+
+            hidden = jax.nn.silu(grouped(rows, wg, act)) * grouped(rows, wu, act)
+            out = grouped(hidden, wd, jnp.float32) * rows_weight[:, None]
+            return jnp.zeros((n + 1, d), jnp.float32).at[rows_token].add(out)[:n]
+
+        def masked(_):
+            gate = jnp.where(
+                local.reshape(n, k, 1) == jnp.arange(held), weights[..., None], 0.0).sum(axis=1)
+
+            @jax.checkpoint
+            def expert(g, u, dn, gate_e):
+                return _swiglu(flat, g, u, dn).astype(jnp.float32) * gate_e[:, None]
+
+            def one(acc, e):
+                return acc + expert(*e), None
+
+            acc, _ = jax.lax.scan(one, jnp.zeros((n, d), jnp.float32), (wg, wu, wd, gate.T))
+            return acc
+
+        # each path under its own checkpoint: what `cond` keeps for the
+        # backward is then the paths' common inputs, not both paths' insides;
+        # and under its own scope: a device trace says which of them ran
+        routed = jax.lax.cond(
+            assignments.sum() <= pool,
+            jax.checkpoint(jax.named_scope("moe.gathered")(gathered)),
+            jax.checkpoint(jax.named_scope("moe.masked")(masked)), None)
+
+    with jax.named_scope("moe.shared"):
+        shared = _swiglu(flat, params["shared_gate"], params["shared_up"], params["shared_down"])
+    y = (routed + shared.astype(jnp.float32)).astype(x.dtype).reshape(b, t, d)
+    return y, {"assignments": assignments, "unrouted": unrouted}
+
+
 __all__ = [
+    "HeldMoEConfig",
+    "init_held_moe_params",
+    "route_sigmoid",
+    "held_moe_ffn",
     "MoEConfig",
     "init_moe_params",
     "moe_param_specs",

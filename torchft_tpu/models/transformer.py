@@ -272,10 +272,19 @@ def shard_params(params: Params, mesh: Mesh, cfg: TransformerConfig) -> Params:
 # ---------------------------------------------------------------------------
 
 
-def _rms_norm(x: jax.Array, w: jax.Array) -> jax.Array:
+def _rms_norm(x: jax.Array, w: jax.Array, eps: float = 1e-6) -> jax.Array:
     x32 = x.astype(jnp.float32)
     var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-    return (x32 * jax.lax.rsqrt(var + 1e-6)).astype(x.dtype) * w.astype(x.dtype)
+    return (x32 * jax.lax.rsqrt(var + eps)).astype(x.dtype) * w.astype(x.dtype)
+
+
+def _swiglu(h: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array) -> jax.Array:
+    """``down(silu(gate h) * up h)`` in the dtype of ``h``: the dense FFN of
+    this model and the dense, shared and routed ones of ``kimi_linear``."""
+    act = h.dtype
+    gate = jax.nn.silu(h @ w_gate.astype(act))
+    up = h @ w_up.astype(act)
+    return (gate * up) @ w_down.astype(act)
 
 
 def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
@@ -470,9 +479,7 @@ def _make_block(
 
             y, aux = moe_ffn(h, p, _moe_cfg(cfg), mesh=mesh)
             return x + y, aux
-        gate = jax.nn.silu(h @ p["w_gate"].astype(act))
-        up = h @ p["w_up"].astype(act)
-        x = x + (gate * up) @ p["w_down"].astype(act)
+        x = x + _swiglu(h, p["w_gate"], p["w_up"], p["w_down"])
         return x, jnp.zeros((), jnp.float32)
 
     return block
@@ -671,6 +678,19 @@ def forward_pipelined(
     return logits
 
 
+def _next_token_nll(logits: jax.Array, tokens: jax.Array) -> jax.Array:
+    """``[B, T, V]`` logits, ``[B, T]`` tokens -> ``[B, T - 1]``: the negative
+    log-likelihood of token ``t + 1`` at position ``t``."""
+    logits = logits[:, :-1]
+    targets = tokens[:, 1:]
+    # fused NLL: logsumexp(logits) - logit[target] == -log_softmax[target]
+    # without materializing the full [B, T, V] log-probability tensor (at
+    # flagship scale that tensor is ~1 GB of f32 HBM write+read per step)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    return lse - picked
+
+
 def loss_fn(
     params: Params,
     tokens: jax.Array,
@@ -680,14 +700,7 @@ def loss_fn(
     """Next-token cross-entropy, mean over all positions but the last.
     MoE configs add the weighted load-balance auxiliary loss."""
     logits, aux = forward(params, tokens, cfg, mesh, return_aux=True)
-    logits = logits[:, :-1]
-    targets = tokens[:, 1:]
-    # fused NLL: logsumexp(logits) - logit[target] == -log_softmax[target]
-    # without materializing the full [B, T, V] log-probability tensor (at
-    # flagship scale that tensor is ~1 GB of f32 HBM write+read per step)
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    loss = (lse - picked).mean()
+    loss = _next_token_nll(logits, tokens).mean()
     if cfg.n_experts:
         loss = loss + cfg.moe_aux_weight * aux
     return loss

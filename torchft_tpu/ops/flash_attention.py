@@ -144,9 +144,9 @@ def _fwd(
     offsets: "Optional[jax.Array]" = None,
 ) -> "Tuple[jax.Array, jax.Array]":
     bh, tq, d = q3.shape
-    tk = k3.shape[1]
-    blk_q = _block_size(tq, d)
-    blk_k = _block_size(tk, d)
+    tk, dv = k3.shape[1], v3.shape[2]  # values may be narrower than q/k
+    blk_q = _block_size(tq, max(d, dv))
+    blk_k = _block_size(tk, max(d, dv))
     if offsets is None:
         offsets = jnp.zeros((2,), jnp.int32)
     grid = (bh, tq // blk_q, tk // blk_k)
@@ -159,10 +159,10 @@ def _fwd(
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, blk_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, blk_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, blk_k, dv), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=(
-            pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, blk_q, dv), lambda b, i, j: (b, i, 0)),
             # row stats as [bh, 1, t]: a (1, 1, blk) block keeps the
             # sublane dim equal to the array's (TPU block-shape rule) and
             # the per-row scalars on lanes — 128x less HBM than
@@ -170,15 +170,18 @@ def _fwd(
             pl.BlockSpec((1, 1, blk_q), lambda b, i, j: (b, 0, i)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((bh, tq, d), q3.dtype),
+            jax.ShapeDtypeStruct((bh, tq, dv), q3.dtype),
             jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32),
         ),
         scratch_shapes=[
-            pltpu.VMEM((blk_q, d), jnp.float32),
+            pltpu.VMEM((blk_q, dv), jnp.float32),
             pltpu.VMEM((blk_q, _LANE), jnp.float32),
             pltpu.VMEM((blk_q, _LANE), jnp.float32),
         ],
         interpret=_interpret(),
+        # the benchmark finds the three kernels in a trace by these names
+        # (benchmarks/families/*.py FLASH_KERNELS)
+        name="_fwd_kernel",
     )(offsets.astype(jnp.int32), q3, k3, v3)
     return o, lse[:, 0]
 
@@ -296,9 +299,9 @@ def _bwd(
     delta: "Optional[jax.Array]" = None,
 ) -> "Tuple[jax.Array, jax.Array, jax.Array]":
     bh, tq, d = q3.shape
-    tk = k3.shape[1]
-    blk = _block_size(tq, d)
-    blk_kk = _block_size(tk, d)
+    tk, d_v = k3.shape[1], v3.shape[2]
+    blk = _block_size(tq, max(d, d_v))
+    blk_kk = _block_size(tk, max(d, d_v))
     n = tq // blk
     nk = tk // blk_kk
     if offsets is None:
@@ -323,24 +326,25 @@ def _bwd(
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, blk, d), lambda b, jj, ii: (b, ii, 0)),     # q
             pl.BlockSpec((1, blk_kk, d), lambda b, jj, ii: (b, jj, 0)),  # k
-            pl.BlockSpec((1, blk_kk, d), lambda b, jj, ii: (b, jj, 0)),  # v
-            pl.BlockSpec((1, blk, d), lambda b, jj, ii: (b, ii, 0)),     # do
+            pl.BlockSpec((1, blk_kk, d_v), lambda b, jj, ii: (b, jj, 0)),  # v
+            pl.BlockSpec((1, blk, d_v), lambda b, jj, ii: (b, ii, 0)),     # do
             pl.BlockSpec((1, 1, blk), lambda b, jj, ii: (b, 0, ii)),  # lse
             pl.BlockSpec((1, 1, blk), lambda b, jj, ii: (b, 0, ii)),  # delta
         ],
         out_specs=(
             pl.BlockSpec((1, blk_kk, d), lambda b, jj, ii: (b, jj, 0)),
-            pl.BlockSpec((1, blk_kk, d), lambda b, jj, ii: (b, jj, 0)),
+            pl.BlockSpec((1, blk_kk, d_v), lambda b, jj, ii: (b, jj, 0)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((bh, tk, d), q3.dtype),
-            jax.ShapeDtypeStruct((bh, tk, d), q3.dtype),
+            jax.ShapeDtypeStruct((bh, tk, d_v), q3.dtype),
         ),
         scratch_shapes=[
             pltpu.VMEM((blk_kk, d), jnp.float32),
-            pltpu.VMEM((blk_kk, d), jnp.float32),
+            pltpu.VMEM((blk_kk, d_v), jnp.float32),
         ],
         interpret=_interpret(),
+        name="_bwd_kv_kernel",
     )(offsets, q3, k3, v3, do3, lse3, delta)
 
     # q kernel grid = (b, i, j): index maps receive (b, q_block, kv_block)
@@ -354,8 +358,8 @@ def _bwd(
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, blk, d), lambda b, ii, jj: (b, ii, 0)),     # q
             pl.BlockSpec((1, blk_kk, d), lambda b, ii, jj: (b, jj, 0)),  # k
-            pl.BlockSpec((1, blk_kk, d), lambda b, ii, jj: (b, jj, 0)),  # v
-            pl.BlockSpec((1, blk, d), lambda b, ii, jj: (b, ii, 0)),     # do
+            pl.BlockSpec((1, blk_kk, d_v), lambda b, ii, jj: (b, jj, 0)),  # v
+            pl.BlockSpec((1, blk, d_v), lambda b, ii, jj: (b, ii, 0)),     # do
             pl.BlockSpec((1, 1, blk), lambda b, ii, jj: (b, 0, ii)),  # lse
             pl.BlockSpec((1, 1, blk), lambda b, ii, jj: (b, 0, ii)),  # delta
         ],
@@ -363,6 +367,7 @@ def _bwd(
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), q3.dtype),
         scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)],
         interpret=_interpret(),
+        name="_bwd_q_kernel",
     )(offsets, q3, k3, v3, do3, lse3, delta)
     return dq, dk, dv
 
@@ -393,12 +398,14 @@ _flash3.defvjp(_flash3_fwd, _flash3_bwd)
 def flash_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True
 ) -> jax.Array:
-    """Tiled fused causal attention, ``[B, T, H, D] -> [B, T, H, D]``.
+    """Tiled fused causal attention, ``[B, T, H, D] -> [B, T, H, Dv]``.
 
     Drop-in for :func:`~torchft_tpu.ops.ring_attention.dense_attention`
     with O(T) memory instead of the O(T^2) score matrix.  GQA K/V with
     fewer heads are broadcast up (the kernel is per-head).  Requires
     ``T % 128 == 0``; other shapes should use ``dense_attention``.
+    ``v`` may have a head width ``Dv`` of its own (latent attention: queries
+    and keys of 192 against values of 128); the scale is ``D ** -0.5``.
     """
     b, t, h, d = q.shape
     if h % k.shape[2] != 0:

@@ -1256,3 +1256,17 @@ HA_REDIRECTS = counter(
     "NOT_LEADER reply from a follower peer",
     (),
 )
+MOE_ASSIGNMENTS = counter(
+    "torchft_moe_assignments_total",
+    "Token-to-expert assignments that landed on an expert this chip holds, "
+    "by layer (its number in the model) and expert (its published id); fed "
+    "from models/kimi_linear.py routing_stats when a caller asks, never "
+    "inside a training step",
+    ("layer", "expert"),
+)
+MOE_TOKENS_UNROUTED = counter(
+    "torchft_moe_tokens_unrouted_total",
+    "Tokens none of whose chosen experts lives on this chip (they get the "
+    "shared expert alone), by layer",
+    ("layer",),
+)
