@@ -693,9 +693,10 @@ def _shutdown(pgs):
 
 
 @pytest.fixture
-def pack_spans(tmp_path):
+def ring_spans(tmp_path):
     """Runs ``fn()`` under an open ``ring`` phase with a file-sink tracer
-    installed; returns the ``ring.pack`` spans' attributes."""
+    installed; returns its result and the spans of ``ring``'s parts, as
+    ``(name, attributes)`` in the order they ended."""
     import json
 
     from torchft_tpu.utils import tracing
@@ -716,9 +717,18 @@ def pack_spans(tmp_path):
             tracing.set_current(None)
             tracing.uninstall_tracer()
         spans = [json.loads(l) for l in path.read_text().splitlines() if l]
-        return out, [
-            s["attributes"] for s in spans if s["name"] == "ring.pack"
-        ]
+        return out, [(s["name"], s["attributes"]) for s in spans]
+
+    return run
+
+
+@pytest.fixture
+def pack_spans(ring_spans):
+    """As ``ring_spans``, the ``ring.pack`` spans' attributes alone."""
+
+    def run(fn):
+        out, spans = ring_spans(fn)
+        return out, [attrs for name, attrs in spans if name == "ring.pack"]
 
     return run
 
@@ -937,4 +947,230 @@ class TestRingContract:
         assert pack["copied"] == leaves[0].nbytes and pack["handed"] == 0
         assert got.flags.c_contiguous and got.shape == (n, 2)
         np.testing.assert_array_equal(got, leaves[0] + leaves[1])
+        _shutdown(pgs)
+
+
+# ---------------------------------------------------------------------------
+# A device leaf held in another order of dimensions leaves the device flat:
+# the ring re-orders nothing on the host, and the result is bit for bit what
+# the host-side path gives
+# ---------------------------------------------------------------------------
+
+
+def _on_device(x, order=None, device=0):
+    """``x`` as a ``jax.Array``, held with its dimensions in ``order``
+    (major to minor) where one is given: what a TPU does of its own accord
+    to a leaf whose last dimension is no multiple of 128.  The CPU backend
+    takes the layout and hands the host copy back in it, as strides."""
+    import jax
+    from jax.experimental.layout import Format, Layout
+
+    where = jax.sharding.SingleDeviceSharding(jax.devices()[device])
+    if order is not None:
+        where = Format(Layout(major_to_minor=order), where)
+    return jax.device_put(x, where)
+
+
+def _relayout_leaves(rank, dtype, held):
+    """One leaf over ``BUCKET_BYTES`` that rings alone, with a size that
+    pads at world sizes 2 and 3, two small ones that share a bucket, and a
+    vector; ``held`` = ``"device-order"`` puts every leaf of two or more
+    dimensions in another order than its shape's."""
+    rng = np.random.default_rng(200 + rank)
+    shapes = [(1025, 1027), (3, 5, 7), (6, 4), (11,)]
+    orders = [(1, 0), (0, 2, 1), (1, 0), None]
+    return [
+        _on_device(
+            rng.standard_normal(shape).astype(np.float32).astype(dtype),
+            order if held == "device-order" else None,
+        )
+        for shape, order in zip(shapes, orders)
+    ]
+
+
+def _spans_of_rank0(pgs, ring_spans, per_rank, op=REDUCE_AVG):
+    """One allreduce of ``per_rank[r]`` on every rank; rank 0 runs under an
+    open ``ring`` and hands back ``(work, result, spans)``, the other ranks
+    run without a phase (their parts are annotations only)."""
+
+    def run(rank, _):
+        def once():
+            work = pgs[rank].allreduce(per_rank[rank], op)
+            return work, work.wait(timeout=30)
+
+        if rank == 0:
+            (work, got), spans = ring_spans(once)
+            return work, got, spans
+        return once()
+
+    return run_parallel(len(pgs), run)[0]
+
+
+def _attr(spans, name, key):
+    return [attrs[key] for n, attrs in spans if n == name]
+
+
+class TestDeviceRelayout:
+    @pytest.mark.parametrize("held", ["default", "device-order"])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("world", [2, 3])
+    def test_result_is_bitwise_the_host_paths(
+        self, store, ring_spans, world, dtype, held
+    ):
+        import ml_dtypes
+
+        dtype = np.dtype(getattr(ml_dtypes, dtype, dtype))
+        pgs = make_group(store, world, f"relay-{world}-{dtype.name}-{held}")
+        leaves = [_relayout_leaves(r, dtype, held) for r in range(world)]
+        # the path before: the leaves' host copies as the device hands them
+        # over (strides and all), re-ordered by the ring on the host
+        hosts = [[np.asarray(x) for x in rank] for rank in leaves]
+        strided = [not h.flags.c_contiguous for h in hosts[0]]
+        assert strided == [held == "device-order"] * 3 + [False]
+        _, want, host_spans = _spans_of_rank0(pgs, ring_spans, hosts)
+        work, got, spans = _spans_of_rank0(pgs, ring_spans, leaves)
+
+        _assert_same_bits(got, want)
+        for g, leaf in zip(got, leaves[0]):
+            assert g.shape == leaf.shape and g.dtype == leaf.dtype
+            assert g.flags.c_contiguous
+        # ring.d2h says what was laid out on the device: the leaves held in
+        # another order, nothing else, and nothing of a host leaf
+        moved = sum(h.nbytes for h, s in zip(hosts[0], strided) if s)
+        assert _attr(spans, "ring.d2h", "relaid") == [moved]
+        assert _attr(host_spans, "ring.d2h", "relaid") == [0]
+        # the leaf that rings alone: a float32 one is the ring's source
+        # where it lies and only the chunk with the padded tail is copied;
+        # one that widens is cast into the buffer whole, as before
+        n = hosts[0][0].size
+        chunk = -(-n // world)
+        tail = n - (n // chunk) * chunk
+        solo = next(a for m, a in spans if m == "ring.pack" and "copied" in a)
+        if dtype == np.float32:
+            assert (solo["copied"], solo["handed"]) == (tail * 4, (n - tail) * 4)
+        else:
+            assert (solo["copied"], solo["handed"]) == (n * 4, 0)
+        # same plan, same bytes over the link
+        assert _attr(spans, "ring.wire", "bytes") == _attr(host_spans, "ring.wire", "bytes")
+        assert work.wire_bytes == sum(_attr(spans, "ring.wire", "bytes"))
+        _shutdown(pgs)
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_alone_the_leaf_is_still_its_own_device_to_host_copy(
+        self, ring_spans, dtype
+    ):
+        import ml_dtypes
+
+        dtype = np.dtype(getattr(ml_dtypes, dtype, dtype))
+        (pg,) = _world(None, 1, "relay-alone")
+        leaf = _on_device(
+            np.arange(6 * 4, dtype=np.float32).reshape(6, 4).astype(dtype), (1, 0)
+        )
+        _, (got,), spans = _spans_of_rank0([pg], ring_spans, [[leaf]], REDUCE_SUM)
+        host = np.asarray(leaf)
+        assert _attr(spans, "ring.d2h", "relaid") == [0]
+        assert _attr(spans, "ring.pack", "copied") == [0]
+        # handed through as it came off the device: the device's order kept
+        assert got.strides == host.strides and not got.flags.c_contiguous
+        assert np.shares_memory(got, host) and not got.flags.writeable
+        _assert_same_bits([got], [host])
+        pg.shutdown()
+
+    @pytest.mark.parametrize("order", ["c", "strided"])
+    def test_a_numpy_leaf_is_never_handed_to_jax(
+        self, store, ring_spans, monkeypatch, order
+    ):
+        from torchft_tpu.parallel import process_group
+
+        def no_jax():
+            raise AssertionError("a host leaf went to the device")
+
+        monkeypatch.setattr(process_group, "_flatten_jit", no_jax)
+        world = 2
+        pgs = make_group(store, world, f"relay-numpy-{order}")
+        n = (1 << 20) + 8
+        data = [
+            np.arange(2 * n, dtype=np.float32).reshape(2, n) * (r + 1)
+            for r in range(world)
+        ]
+        if order == "strided":
+            data = [x.T for x in data]
+        _, (got,), spans = _spans_of_rank0(pgs, ring_spans, [[x] for x in data], REDUCE_SUM)
+        assert _attr(spans, "ring.d2h", "relaid") == [0]
+        # a strided host array is still copied in, in one pass
+        copied = data[0].nbytes if order == "strided" else 0
+        assert _attr(spans, "ring.pack", "copied") == [copied]
+        np.testing.assert_array_equal(got, data[0] + data[1])
+        _shutdown(pgs)
+
+    @pytest.mark.parametrize("world", [2, 3])
+    def test_a_mixed_list_keeps_the_plan_and_the_wire_bytes(
+        self, store, ring_spans, world
+    ):
+        """Device leaves in either order, host leaves, small ones that
+        share a bucket: the buckets and ``Work.wire_bytes`` are those of
+        the same list as host arrays."""
+        import ml_dtypes
+
+        pgs = make_group(store, world, f"relay-mixed-{world}")
+
+        def mixed(rank):
+            f32 = _relayout_leaves(rank, np.dtype(np.float32), "device-order")
+            bf16 = _relayout_leaves(rank, np.dtype(ml_dtypes.bfloat16), "default")
+            host = _contract_leaves(rank)
+            return [f32[1], host[0], bf16[2], f32[0], host[6], f32[2], host[3], bf16[0]]
+
+        leaves = [mixed(r) for r in range(world)]
+        hosts = [[np.asarray(x) for x in rank] for rank in leaves]
+        host_work, want, host_spans = _spans_of_rank0(pgs, ring_spans, hosts, REDUCE_SUM)
+        work, got, spans = _spans_of_rank0(pgs, ring_spans, leaves, REDUCE_SUM)
+        _assert_same_bits(got, want)
+        assert work.wire_bytes == host_work.wire_bytes > 0
+        for name, key in [("ring.wire", "bytes"), ("ring.pack", "leaves"), ("ring.unpack", "leaves")]:
+            assert [a.get(key) for n, a in spans if n == name] == [
+                a.get(key) for n, a in host_spans if n == name
+            ]
+        # only the device leaves held in another order were laid out there
+        assert _attr(spans, "ring.d2h", "relaid") == [
+            sum(hosts[0][i].nbytes for i in (0, 3, 5))
+        ]
+        _shutdown(pgs)
+
+    def test_leaves_on_two_devices_are_laid_out_on_each(self, store, ring_spans):
+        """A list may hold leaves of more than one device (the stages of a
+        pipeline): one program cannot take both, each device runs its own."""
+        world = 2
+        pgs = make_group(store, world, "relay-two-devices")
+        rng = np.random.default_rng(5)
+        leaves = [
+            [
+                _on_device(rng.standard_normal((6, 4)).astype(np.float32), (1, 0), d)
+                for d in (0, 1, 0)
+            ]
+            for _ in range(world)
+        ]
+        hosts = [[np.asarray(x) for x in rank] for rank in leaves]
+        _, want, _ = _spans_of_rank0(pgs, ring_spans, hosts)
+        _, got, spans = _spans_of_rank0(pgs, ring_spans, leaves)
+        _assert_same_bits(got, want)
+        assert _attr(spans, "ring.d2h", "relaid") == [3 * 6 * 4 * 4]
+        _shutdown(pgs)
+
+    def test_ring_buffers_hit_the_pool_from_the_second_step(self, store, ring_spans):
+        from torchft_tpu.utils.bufpool import POOL
+
+        world = 2
+        pgs = make_group(store, world, "relay-pool")
+        POOL.clear()
+        pools = []
+        for step in range(3):
+            leaves = [
+                [_relayout_leaves(r + 10 * step, np.dtype(np.float32), "device-order")[0]]
+                for r in range(world)
+            ]
+            work, got, spans = _spans_of_rank0(pgs, ring_spans, leaves)
+            pools.append(_attr(spans, "ring.pack", "pool"))
+            # the lease ends with the last view of the result
+            del work, got
+        assert pools == [["miss"], ["hit"], ["hit"]]
         _shutdown(pgs)

@@ -107,7 +107,7 @@ PROTOCOL_PHASES = (
 PHASE_PARTS = (
     # ProcessGroupTCP.allreduce, on its worker thread
     "ring.queue",  # submit until the worker picks the op up
-    "ring.d2h",  # wait for the device + copy of the leaves to the host
+    "ring.d2h",  # wait for the device (its relayout of some leaves included) + copy to the host
     "ring.pack",  # bucket concat, pad-in of a widened leaf or a tail, copy of a host leaf
     "ring.wire",  # the exchanges: send + receive + waiting for the peer
     "ring.reduce",  # the in-place ufunc between exchanges
@@ -1656,7 +1656,9 @@ class Manager:
         (``ProcessGroupTCP``; they sum to ``ring`` within the thread
         hand-offs): ``ring.queue`` (submit until the worker picks the op
         up), ``ring.d2h`` (wait for the device + device→host copy of the
-        leaves), ``ring.pack`` (bucket concat, the lease of the ring buffer
+        leaves; ``relaid`` = bytes of leaves laid out flat on the device
+        first, because it held them in another order of dimensions),
+        ``ring.pack`` (bucket concat, the lease of the ring buffer
         and what is copied into it: a leaf that widens, a zero-padded tail;
         at world size 1 the copy of a leaf the caller passed as host
         memory; its attributes say bytes ``copied``, bytes ``handed``

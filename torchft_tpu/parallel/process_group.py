@@ -31,6 +31,7 @@ JAX mesh composition lives in torchft_tpu/parallel/device_mesh.py).
 from __future__ import annotations
 
 import contextlib
+import functools
 import logging
 import os
 import pickle
@@ -115,6 +116,52 @@ def _off_device(x: Any) -> bool:
     copied.  (No import: the Baby worker process stays free of jax.)"""
     jax = sys.modules.get("jax")
     return jax is not None and isinstance(x, jax.Array)
+
+
+def _in_device_order(x: Any) -> bool:
+    """True for a ``jax.Array`` on one device that holds it with its
+    dimensions in another order than its shape's (a TPU does so with a
+    leaf whose last dimension is no multiple of 128): the host copy of
+    such a leaf comes in the device's order, as strides.  The leaf says so
+    itself, ``format.layout.major_to_minor``; one spread over several
+    devices is put together on the host, in C order."""
+    if not _off_device(x) or x.ndim < 2 or len(x.sharding.device_set) != 1:
+        return False
+    order = getattr(x.format.layout, "major_to_minor", None)
+    return order is not None and tuple(order) != tuple(range(x.ndim))
+
+
+@functools.lru_cache(maxsize=None)
+def _flatten_jit() -> Any:
+    def ring_relayout(leaves: "List[Any]") -> "List[Any]":
+        return [x.reshape(-1) for x in leaves]
+
+    # (a device trace shows it under this name, ``jit_ring_relayout``)
+    return sys.modules["jax"].jit(ring_relayout)
+
+
+def _to_host(arrays: "List[Any]", relay: "List[int]") -> "List[np.ndarray]":
+    """The leaves' host arrays.  Those named in ``relay`` (leaves
+    :func:`_in_device_order`) are laid out flat, row-major, on the device
+    first: one jitted program for all of them (one a device, should they
+    differ), at the memory's speed where the host re-orders at under 1 GB/s.
+    Each arrives as a C-contiguous vector of the leaf's dtype, and its
+    flat device copy goes as soon as the host array exists.  The leaves
+    that go as they are held are copied first: their copies need not wait
+    for the program, which queues behind whatever else the device runs
+    (another group's grad step, where two share a chip)."""
+    flat: "Dict[int, Any]" = {}
+    by_device: "Dict[Any, List[int]]" = {}
+    for i in relay:
+        by_device.setdefault(arrays[i].sharding, []).append(i)
+    for idxs in by_device.values():
+        flat.update(zip(idxs, _flatten_jit()([arrays[i] for i in idxs])))
+    out: "List[Any]" = [
+        None if i in flat else _as_numpy(a) for i, a in enumerate(arrays)
+    ]
+    for i in relay:
+        out[i] = _as_numpy(flat.pop(i))
+    return out
 
 
 def _divide(a: np.ndarray, divisor: "Optional[int]") -> np.ndarray:
@@ -1121,15 +1168,30 @@ class ProcessGroupTCP(ProcessGroup):
                 # immediately (the DiLoCo overlap pattern: outer-grad allreduce
                 # rides behind the next fragment's inner steps).
                 deadline = time.monotonic() + deadline_budget
-                with _tracing.phase(".d2h", bytes=nbytes):
-                    np_arrays = [_as_numpy(a) for a in arrays]
+                # A ring re-orders on the host whatever does not arrive in C
+                # order, so such leaves leave the device flat; alone, a leaf
+                # is handed through as it arrives and nothing is done.
+                relay = (
+                    [i for i, a in enumerate(arrays) if _in_device_order(a)]
+                    if self._world > 1
+                    else []
+                )
+                with _tracing.phase(
+                    ".d2h",
+                    bytes=nbytes,
+                    relaid=sum(arrays[i].nbytes for i in relay),
+                ):
+                    np_arrays = _to_host(arrays, relay)
                 by = self._world if op == REDUCE_AVG else divisor
                 if self._world == 1:
                     # the post-failure shrunken-group hot path
                     return _allreduce_alone(
                         np_arrays, [_off_device(a) for a in arrays], by
                     )
-                return self._allreduce_coalesced(np_arrays, op, by, deadline)
+                results = self._allreduce_coalesced(np_arrays, op, by, deadline)
+                for i in relay:  # back in the leaf's own shape: a view
+                    results[i] = results[i].reshape(arrays[i].shape)
+                return results
 
         work = self._submit(run, op="allreduce")
         # Wire accounting on the UNQUANTIZED path too (parity with the
@@ -1282,9 +1344,10 @@ class ProcessGroupTCP(ProcessGroup):
           received``; the rank's own chunk is sent from the source at step
           0 and written by the allgather.  Only what the source does not
           hold as a whole chunk, in the accumulation dtype and in C order,
-          is copied: a leaf that widens (bf16, ints), one that came off
-          the device with its dimensions in another order, and the
-          zero-padded tail;
+          is copied: a leaf that widens (bf16, ints), one the caller
+          passed as strided host memory, and the zero-padded tail (a
+          device leaf held in another order of dimensions arrives flat:
+          :func:`_to_host`);
         - the division (``REDUCE_AVG``, the Manager's participant count)
           is one in-place pass over the buffer, none for a divisor of 1.
         """
@@ -1293,10 +1356,9 @@ class ProcessGroupTCP(ProcessGroup):
         reduce_into = _REDUCE_UFUNCS[op]
         n = array.size
         chunk = -(-n // w)
-        # The source as the ring orders it, where that is a view.  What
-        # comes off a TPU is not always: a leaf whose last dimension is no
-        # multiple of 128 arrives in the device's order of dimensions, as
-        # strides (models' w_down, embed), and is copied in.
+        # The source as the ring orders it, where that is a view: so
+        # arrives what came off a device (``_to_host``); a caller's strided
+        # host array is copied in.
         src = array.reshape(-1) if array.flags.c_contiguous else None
         # chunks read straight from the source; the rest starts at ``lo``
         direct = (
