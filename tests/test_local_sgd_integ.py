@@ -42,6 +42,20 @@ def lighthouse():
     server.shutdown()
 
 
+@pytest.fixture
+def patient_lighthouse():
+    """Waits two seconds, not a tenth, for a live replica that has not yet
+    asked: a replica that joins mid-run asks out of phase with the others,
+    and a quorum that goes without the late one each round (a different
+    one each time) strands whichever is left out of the LAST round below
+    ``min_replicas``, stepping forever."""
+    server = LighthouseServer(
+        min_replicas=2, join_timeout_ms=2000, heartbeat_timeout_ms=1000
+    )
+    yield server
+    server.shutdown()
+
+
 class DiLoCoRunner:
     """Replica running DiLoCo: deterministic inner updates so outer syncs
     are exactly comparable across replicas."""
@@ -272,7 +286,8 @@ class TestDiLoCoInteg:
         assert all(r["manager_state"]["step"] == 8 for r in results)
         assert_params_equal(results)
 
-    def test_diloco_upscale_mid_run(self, lighthouse):
+    def test_diloco_upscale_mid_run(self, patient_lighthouse):
+        lighthouse = patient_lighthouse
         # Third replica joins after the first two have synced a couple of
         # times.  The join is gated on OBSERVED fleet progress (lighthouse
         # ``max_step``), not a wall-clock delay: a fixed sleep assumes the

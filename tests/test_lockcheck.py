@@ -137,7 +137,7 @@ class TestSemantics:
         the stdlib fallback probes acquire(False) while holding, which
         attempt-time edge recording would misread as a same-name
         self-acquisition — a false deadlock alarm on every wait/notify
-        (the ProcessGroupBaby cond pattern)."""
+        (any ``threading.Condition`` built over a checked lock)."""
         inner = lockcheck.lock("sem.cond_probe")
         cond = threading.Condition(inner)
         with cond:

@@ -15,7 +15,9 @@ from torchft_tpu.manager import Manager, WorldSizeMode
 from torchft_tpu.parallel.process_group import (
     ErrorSwallowingProcessGroupWrapper,
     FakeProcessGroupWrapper,
+    REDUCE_SUM,
     ProcessGroupDummy,
+    ProcessGroupTCP,
 )
 
 
@@ -83,6 +85,29 @@ def manager_ctx():
     yield build, client, transport
     for p in patches:
         p.stop()
+
+
+def _start_async_heal(manager, client, transport):
+    """A quorum in which this replica (rank 1) heals from rank 0 at step 7:
+    under the async quorum it is healing, and no participant, until the
+    commit applies the state."""
+    client._quorum.return_value = make_quorum(
+        replica_rank=1,
+        max_step=7,
+        max_replica_rank=None,
+        max_world_size=1,
+        heal=True,
+        recover_src_replica_rank=0,
+        recover_src_manager_address="peer:1",
+    )
+    transport.recv_checkpoint.return_value = {
+        "user": {"default": {"w": 42}},
+        "torchft": {"step": 7, "batches_committed": 70},
+    }
+    with patch("torchft_tpu.manager.ManagerClient") as peer_cls:
+        peer_cls.return_value._checkpoint_metadata.return_value = "http://peer"
+        manager.start_quorum()
+        manager.wait_quorum()
 
 
 class TestManagerHappyPath:
@@ -155,18 +180,27 @@ class TestManagerHappyPath:
         np.testing.assert_allclose(np.asarray(out["s"]), 4.0)
 
 
-class _NoDivisorDummy(ProcessGroupDummy):
-    """A group that takes no divisor, as a subprocess group or a fake."""
+class _AloneTCP(ProcessGroupTCP):
+    """The ring's own group, configured alone (world size 1) whatever the
+    quorum says: the Manager's participant count is then not its size."""
 
-    def _allreduce_mean(self, arrays, divisor):
-        return None
+    def configure(self, store_addr, replica_id, rank, world_size):
+        super().configure("", replica_id, 0, 1)
+
+
+class _RecordingDummy(ProcessGroupDummy):
+    """Keeps what the Manager handed to ``allreduce``."""
+
+    def allreduce(self, arrays, op=REDUCE_SUM, divisor=None):
+        self.sent = list(arrays)
+        return super().allreduce(arrays, op, divisor)
 
 
 class TestManagerAverage:
-    """The average is one division by the participant count: in place by
-    the group that owns the reduced buffer, out of place here for a group
-    that takes no divisor, none at all for a divisor of 1; never a write
-    into memory the caller passed in."""
+    """The average is one division by the participant count, by the group
+    (it owns the reduced buffer), none at all for a divisor of 1; never a
+    write into memory the caller passed in.  The Manager hands leaves over
+    and touches no leaf's memory."""
 
     def _mixed(self):
         import jax.numpy as jnp
@@ -178,7 +212,7 @@ class TestManagerAverage:
             "bf16": np.arange(4).astype(ml_dtypes.bfloat16),
         }
 
-    @pytest.mark.parametrize("pg_kind", ["owner", "no-divisor", "swallowing"])
+    @pytest.mark.parametrize("pg_kind", ["owner", "tcp-alone", "swallowing"])
     @pytest.mark.parametrize("participants", [1, 2, 3])
     def test_matches_numpy_and_never_writes_the_input(
         self, manager_ctx, pg_kind, participants
@@ -186,7 +220,7 @@ class TestManagerAverage:
         build, client, _ = manager_ctx
         pg = {
             "owner": ProcessGroupDummy,
-            "no-divisor": _NoDivisorDummy,
+            "tcp-alone": _AloneTCP,
             "swallowing": lambda: ErrorSwallowingProcessGroupWrapper(
                 ProcessGroupDummy()
             ),
@@ -208,6 +242,42 @@ class TestManagerAverage:
             assert not np.shares_memory(out[key], grads["host"])
             assert np.array(grads[key]).tobytes() == x.tobytes(), key
         assert manager.errored() is None
+        pg.shutdown()
+
+    @pytest.mark.parametrize("leaf", ["fortran-order", "bfloat16", "device"])
+    def test_a_non_participant_sends_zeros_made_from_shapes(
+        self, manager_ctx, leaf
+    ):
+        import jax.numpy as jnp
+        import ml_dtypes
+
+        build, client, transport = manager_ctx
+        pg = _RecordingDummy()
+        manager = build(pg=pg)
+        _start_async_heal(manager, client, transport)
+        assert not manager.is_participating()
+
+        if leaf == "fortran-order":
+            x = np.asfortranarray(np.arange(12, dtype=np.float32).reshape(3, 4) + 1)
+            assert not x.flags.c_contiguous
+        elif leaf == "bfloat16":
+            x = (np.arange(6).reshape(2, 3) + 1).astype(ml_dtypes.bfloat16)
+        else:
+            # a leaf whose conversion raises: nothing may take it off the
+            # device to make zeros of it
+            x = jnp.arange(20, dtype=jnp.float32).reshape(4, 5) + 1
+            x.delete()
+            with pytest.raises(RuntimeError):
+                np.asarray(x)
+        out = manager.allreduce({"g": x}).wait(timeout=10)["g"]
+        assert manager.errored() is None
+        (sent,) = pg.sent
+        for zeros in (sent, out):
+            assert type(zeros) is np.ndarray and zeros.flags.c_contiguous
+            assert zeros.shape == x.shape and zeros.dtype == x.dtype
+            assert not zeros.any()
+        if leaf != "device":
+            assert not np.shares_memory(sent, x) and x.all()
 
     def test_divisor_one_hands_a_device_leaf_through(self, manager_ctx):
         import jax.numpy as jnp
@@ -256,26 +326,9 @@ class TestManagerHealing:
             load_state_dict=lambda sd: loaded.update(sd),
             state_dict=lambda: {"w": 1},
         )
-        client._quorum.return_value = make_quorum(
-            replica_rank=1,
-            max_step=7,
-            max_replica_rank=None,
-            max_world_size=1,
-            heal=True,
-            recover_src_replica_rank=0,
-            recover_src_manager_address="peer:1",
-        )
         client.should_commit.return_value = True
         client._checkpoint_metadata.return_value = "http://peer"
-        transport.recv_checkpoint.return_value = {
-            "user": {"default": {"w": 42}},
-            "torchft": {"step": 7, "batches_committed": 70},
-        }
-
-        with patch("torchft_tpu.manager.ManagerClient") as peer_cls:
-            peer_cls.return_value._checkpoint_metadata.return_value = "http://peer"
-            manager.start_quorum()
-            manager.wait_quorum()
+        _start_async_heal(manager, client, transport)
 
         # healing: not participating this step, contributes zeros
         assert manager._healing

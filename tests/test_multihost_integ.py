@@ -72,7 +72,7 @@ def test_diloco_across_real_process_groups_with_chaos():
 def test_diloco_quantized_wire_across_real_process_groups():
     """The int8 quantized outer sync over REAL process boundaries (the
     reference exercises its quantized allreduce over NCCL ranks;
-    threads/Baby cover the in-process cases): 2 groups x 2 processes,
+    threads cover the in-process cases): 2 groups x 2 processes,
     every outer pseudograd sync rides the int8+rowscale wire through the
     native codec, and all four processes end bitwise identical — the
     quantized allreduce's allgather hop guarantees every rank decodes

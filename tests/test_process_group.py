@@ -768,7 +768,7 @@ class TestRingContract:
         want = _numpy_reduce(data, REDUCE_SUM, divisor)
 
         def run(rank, _):
-            work = pgs[rank]._allreduce_mean(data[rank], divisor)
+            work = pgs[rank].allreduce(data[rank], REDUCE_SUM, divisor=divisor)
             return work.wait(timeout=30)
 
         for rank, got in enumerate(run_parallel(world, run)):
@@ -906,20 +906,9 @@ class TestRingContract:
             inner = FakeProcessGroupWrapper(ProcessGroupDummy())
             inner.report_future_error(RuntimeError("injected"))
             pg = ErrorSwallowingProcessGroupWrapper(inner)
-        (got,) = pg._allreduce_mean([x], 2).wait(timeout=5)
+        (got,) = pg.allreduce([x], divisor=2).wait(timeout=5)
         assert got is x and pg.errored() is not None
         np.testing.assert_array_equal(x, np.arange(6))
-
-    def test_a_group_that_takes_no_divisor_says_so(self):
-        from torchft_tpu.parallel.process_group import (
-            ProcessGroupBabyTCP,
-            ProcessGroupWrapper,
-        )
-
-        assert ProcessGroupBabyTCP()._allreduce_mean([np.ones(2)], 2) is None
-        # and a wrapper passes the answer on
-        wrapped = ProcessGroupWrapper(ProcessGroupBabyTCP())
-        assert wrapped._allreduce_mean([np.ones(2)], 2) is None
 
     def test_a_leaf_in_another_memory_order_is_copied_in_once(
         self, store, pack_spans
@@ -948,6 +937,107 @@ class TestRingContract:
         assert got.flags.c_contiguous and got.shape == (n, 2)
         np.testing.assert_array_equal(got, leaves[0] + leaves[1])
         _shutdown(pgs)
+
+
+# ---------------------------------------------------------------------------
+# One entry point (PR 30): every group reduces AND divides behind
+# ``allreduce(arrays, op, divisor)``, the wrappers included
+# ---------------------------------------------------------------------------
+
+_GROUP_KINDS = [
+    "dummy", "tcp-alone", "tcp-pair", "wrapper(dummy)", "swallowing(dummy)",
+    "fake(dummy)",
+]
+
+
+def _groups_of(kind, store, prefix):
+    """The ranks of one group of ``kind``: two for ``tcp-pair``, else one."""
+    if kind == "tcp-pair":
+        return make_group(store, 2, prefix)
+    if kind == "tcp-alone":
+        return _world(None, 1, prefix)
+    wrap = {
+        "dummy": lambda pg: pg,
+        "wrapper(dummy)": ProcessGroupWrapper,
+        "swallowing(dummy)": ErrorSwallowingProcessGroupWrapper,
+        "fake(dummy)": FakeProcessGroupWrapper,
+    }[kind]
+    return [wrap(ProcessGroupDummy())]
+
+
+def _typed_leaves(rank, dtype):
+    """Small integers (sums exact in every dtype): a size that pads at
+    world size 2, a matrix, and one whose memory is in another order."""
+    rng = np.random.default_rng(300 + rank)
+    return [
+        rng.integers(-40, 40, size=shape).astype(dtype)
+        for shape in [(7,), (3, 5)]
+    ] + [rng.integers(-40, 40, size=(6, 4)).astype(dtype).T]
+
+
+class TestAllreduceContract:
+    @pytest.mark.parametrize("divisor", [None, 3])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int32"])
+    @pytest.mark.parametrize("kind", _GROUP_KINDS)
+    def test_sum_over_divisor_in_the_leafs_dtype(
+        self, store, kind, dtype, divisor
+    ):
+        import ml_dtypes
+
+        dtype = np.dtype(getattr(ml_dtypes, dtype, dtype))
+        pgs = _groups_of(kind, store, f"one-{kind}-{dtype.name}-{divisor}")
+        data = [_typed_leaves(r, dtype) for r in range(len(pgs))]
+        before = [[x.copy() for x in leaves] for leaves in data]
+        want = _numpy_reduce(data, REDUCE_SUM, divisor)
+
+        def run(rank, _):
+            work = pgs[rank].allreduce(data[rank], REDUCE_SUM, divisor=divisor)
+            return work.wait(timeout=30)
+
+        for rank, got in enumerate(run_parallel(len(pgs), run)):
+            _assert_same_bits(got, want)  # value, dtype and shape
+            # the caller's arrays: not written, not part of the result
+            _assert_same_bits(data[rank], before[rank])
+            for g, x in zip(got, data[rank]):
+                assert not np.shares_memory(g, x)
+        _shutdown(pgs)
+
+    def test_managed_group_refuses_a_divisor(self):
+        from unittest.mock import MagicMock
+
+        from torchft_tpu.parallel.process_group import ManagedProcessGroup
+
+        manager = MagicMock()
+        pg = ManagedProcessGroup(manager)
+        x = np.ones(3, np.float32)
+        with pytest.raises(ValueError, match="divisor"):
+            pg.allreduce([x], REDUCE_SUM, divisor=2)
+        manager.allreduce.assert_not_called()
+        # the Manager it routes to averages by the live count itself
+        pg.allreduce([x], REDUCE_AVG)
+        manager.allreduce.assert_called_once_with([x], reduce_op=REDUCE_AVG)
+
+    def test_every_group_takes_the_keyword(self):
+        import inspect
+
+        from torchft_tpu.parallel import process_group
+        from torchft_tpu.parallel.process_group import ProcessGroup
+
+        groups = [
+            cls
+            for _, cls in inspect.getmembers(process_group, inspect.isclass)
+            if issubclass(cls, ProcessGroup) and not inspect.isabstract(cls)
+        ]
+        assert {cls.__name__ for cls in groups} >= {
+            "ProcessGroupDummy", "ProcessGroupTCP", "ProcessGroupWrapper",
+            "ErrorSwallowingProcessGroupWrapper", "FakeProcessGroupWrapper",
+            "ManagedProcessGroup",
+        }
+        for cls in groups:
+            params = inspect.signature(cls.allreduce).parameters
+            assert list(params)[:4] == ["self", "arrays", "op", "divisor"], cls
+            assert params["divisor"].default is None, cls
+        assert not hasattr(ProcessGroup, "_allreduce_mean")
 
 
 # ---------------------------------------------------------------------------

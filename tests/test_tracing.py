@@ -446,9 +446,9 @@ class TestPhase:
         assert sink == {"ring": float(n_threads * n_adds)}
 
     def test_silent_where_jax_is_not_loaded(self, monkeypatch):
-        """The Baby PG's worker process moves host bytes and must not be
-        made to import jax: no annotation where jax is not already in
-        ``sys.modules``, and everything else works."""
+        """A process that only moves host bytes (``process_group`` loads
+        without jax) must not be made to import jax: no annotation where
+        jax is not already in ``sys.modules``, and everything else works."""
         import sys
 
         monkeypatch.setattr(tracing, "_TraceAnnotation", None)

@@ -23,7 +23,6 @@ from torchft_tpu.parallel.process_group import (
     ManagedProcessGroup,
     NotParticipatingError,
     ProcessGroup,
-    ProcessGroupBabyTCP,
     ProcessGroupDummy,
     ProcessGroupTCP,
 )
@@ -60,7 +59,6 @@ __all__ = [
     "Optimizer",
     "OptimizerWrapper",
     "ProcessGroup",
-    "ProcessGroupBabyTCP",
     "ProcessGroupDummy",
     "ProcessGroupTCP",
     "PureDistributedDataParallel",

@@ -1273,33 +1273,21 @@ class Manager:
 
         with self._phase("host_sync"):
             leaves, treedef = jax.tree_util.tree_flatten(value)
-            if should_quantize and self.is_participating():
-                # Leave device arrays on device: the quantized collective runs
-                # the Pallas quantize kernel on-chip (when on TPU) so only the
-                # int8 payload + row scales cross the device→host boundary
-                # (reference wires its Triton kernels the same way,
-                # torchft/collectives.py:297-415).  The device→host hop is then
-                # inside the collective and counted in the ``ring`` phase.
-                # Non-array leaves (Python scalars) still need numpy wrapping
-                # for the dtype checks below.
-                send_leaves: "List[Any]" = [
-                    x if isinstance(x, (np.ndarray, jax.Array)) else np.asarray(x)
-                    for x in leaves
-                ]
-            elif not self.is_participating():
-                send_leaves = [np.zeros_like(np.asarray(x)) for x in leaves]
-            else:
-                # Leaves pass through unmaterialized: the PG converts on its
-                # worker thread, so the device→host sync overlaps whatever the
-                # caller does next instead of blocking this thread (counted in
-                # the ``ring`` phase; the DiLoCo fragment-overlap pattern
-                # depends on this submit being non-blocking).  Non-array leaves
-                # (Python scalars) still need numpy wrapping for the dtype
-                # checks below.
-                send_leaves = [
-                    x if isinstance(x, (np.ndarray, jax.Array)) else np.asarray(x)
-                    for x in leaves
-                ]
+            # An array leaf is handed over as it is, its memory untouched:
+            # the group takes it off the device on its worker thread, so the
+            # device→host sync overlaps whatever the caller does next (counted
+            # in the ``ring`` phase; the DiLoCo fragment-overlap pattern
+            # depends on this submit being non-blocking), and the quantized
+            # collective quantizes on the chip, so only the int8 payload and
+            # row scales cross to the host.  A leaf that is no array (a
+            # Python scalar) is wrapped, for its shape and dtype.
+            send_leaves: "List[Any]" = [
+                x if isinstance(x, (np.ndarray, jax.Array)) else np.asarray(x)
+                for x in leaves
+            ]
+            if not self.is_participating():
+                # a healer's share is zeros, made from the shapes alone
+                send_leaves = [np.zeros(x.shape, x.dtype) for x in send_leaves]
 
         if reduce_op == REDUCE_AVG:
             if not all(_is_floating(x.dtype) for x in send_leaves):
@@ -1320,39 +1308,31 @@ class Manager:
             # parts (PHASE_PARTS ``ring.*``) are timed there, by the PG;
             # ``under`` makes them ring's, in phase_times() and the trace.
             ring = self._phase("ring").begin()
-            # The average is one division by the live participant count.
-            # The group that runs the ring owns the buffer it reduced into
-            # and divides there, in place; one that takes no divisor says
-            # so (None), and the sum is divided here, into new arrays: this
-            # side cannot tell whose memory a result is.
-            divide = reduce_op == REDUCE_AVG and num_participants != 1
+            # The average is the sum over the live participant count, and
+            # the group takes the count: it owns the buffer it reduced into
+            # and scales it there (this side cannot tell whose memory a
+            # result is).
+            divisor = (
+                num_participants
+                if reduce_op == REDUCE_AVG and num_participants != 1
+                else None
+            )
             with tracing.under(ring):
                 if should_quantize:
                     from torchft_tpu.ops.collectives import allreduce_quantized
 
                     work = allreduce_quantized(
                         send_leaves, pg_reduce_op, self._pg,
+                        average_by=divisor,
                         device_quantize=device_quantize,
                     )
                 else:
-                    work = None
-                    if divide:
-                        work = self._pg._allreduce_mean(
-                            send_leaves, num_participants
-                        )
-                        divide = work is None
-                    if work is None:
-                        work = self._pg.allreduce(send_leaves, pg_reduce_op)
+                    work = self._pg.allreduce(
+                        send_leaves, pg_reduce_op, divisor=divisor
+                    )
 
             def _postprocess(reduced: "List[np.ndarray]") -> Any:
                 with tracing.under(ring), tracing.phase(".unpack"):
-                    if divide:
-                        # (bf16 / int is float32 in numpy: cast back, so a
-                        # result has its leaf's dtype whoever divided)
-                        reduced = [
-                            (x / num_participants).astype(x.dtype, copy=False)
-                            for x in reduced
-                        ]
                     return jax.tree_util.tree_unflatten(treedef, reduced)
 
             chained = work.then(_postprocess)

@@ -1336,8 +1336,8 @@ def allreduce_quantized(
     world = pg.size()
     if world <= 1:
         out = [np.array(a) for a in arrays]
-        if op == REDUCE_AVG and average_by:
-            out = [a / average_by for a in out]
+        if average_by:  # as the pipeline does, whatever the op
+            out = [(a / average_by).astype(a.dtype, copy=False) for a in out]
         solo = completed_work(out)
         return _attach_accounting(solo, None, 0, 0, wire_dtype)
     divisor = average_by if average_by is not None else (world if op == REDUCE_AVG else 0)

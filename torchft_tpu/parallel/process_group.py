@@ -17,23 +17,17 @@ properties reproduced here (reference §5 semantics):
   owns only the elastic replica dimension, so membership changes never
   trigger re-jit (zero-fill participation keeps compiled shapes static).
 
-Subprocess isolation: ``ProcessGroupBabyTCP`` runs the real PG in a spawned
-worker process (reference "Baby" variants, torchft/process_group.py:
-1358-2023).  On TPU there is no NCCL-context crash mode to contain, but the
-isolation still buys a *hard* abort — killing the worker cancels a wedged
-collective no matter what state its sockets are in — and shields the
-trainer (and its XLA runtime) from any failure mode of the collective
-stack.  Design divergence from the reference, by intent: no fake
-world-size-1 backend registration (a torch-DeviceMesh-specific trick; the
-JAX mesh composition lives in torchft_tpu/parallel/device_mesh.py).
+Design divergences from the reference, by intent: no subprocess-isolated
+groups (reference torchft/process_group.py:1358-2023: a socket ring aborts
+in-process by closing its sockets, and a chip belongs to one process), and
+no fake world-size-1 backend registration (a torch-DeviceMesh-specific
+trick; the JAX mesh composition lives in torchft_tpu/parallel/device_mesh.py).
 """
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import logging
-import os
 import pickle
 import queue
 import socket
@@ -41,11 +35,10 @@ import struct
 import sys
 import threading
 import time
-import uuid
 import concurrent.futures as concurrent_futures
 from abc import ABC, abstractmethod
 from concurrent.futures import Future
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -58,7 +51,7 @@ from torchft_tpu.utils import lockcheck as _lockcheck
 from torchft_tpu.utils import metrics as _metrics
 from torchft_tpu.utils import tracing as _tracing
 from torchft_tpu.utils.bufpool import POOL as _pool
-from torchft_tpu.utils.env import env_float, env_str
+from torchft_tpu.utils.env import env_float
 
 logger = logging.getLogger(__name__)
 
@@ -113,7 +106,7 @@ def _off_device(x: Any) -> bool:
     """True for a ``jax.Array``: its host array (:func:`_as_numpy`) is
     read-only memory the caller cannot write, so a result may be that array
     itself.  Anything else may be memory the caller still writes and is
-    copied.  (No import: the Baby worker process stays free of jax.)"""
+    copied.  (No import: this module loads without jax.)"""
     jax = sys.modules.get("jax")
     return jax is not None and isinstance(x, jax.Array)
 
@@ -198,8 +191,7 @@ def _allreduce_alone(
 def _check_recv_buffer(out: np.ndarray, shape: Any, dtype: str) -> None:
     """Validate a caller-supplied in-place recv buffer against the wire
     header: shape, dtype, and contiguity must all match (a silent
-    value-cast or reshape would mask a buffer-setup bug).  Shared by the
-    direct wire reader and the Baby PG's in-place emulation."""
+    value-cast or reshape would mask a buffer-setup bug)."""
     if (
         str(out.dtype) != dtype
         or tuple(out.shape) != tuple(shape)
@@ -274,23 +266,23 @@ class ProcessGroup(ABC):
     # -- collectives -------------------------------------------------------
 
     @abstractmethod
-    def allreduce(self, arrays: "List[Any]", op: str = REDUCE_SUM) -> Work:
+    def allreduce(
+        self,
+        arrays: "List[Any]",
+        op: str = REDUCE_SUM,
+        divisor: "Optional[int]" = None,
+    ) -> Work:
         """Resolves to one host array per leaf, in the leaf's shape and
-        dtype.  The result is private to the caller for as long as the
-        caller holds it (or any view of it); it never aliases an
-        ``np.ndarray`` the caller passed in; it may be read-only when it
-        came straight off the device (a ``jax.Array`` leaf at world size
-        1): copy before writing into it."""
-
-    def _allreduce_mean(
-        self, arrays: "List[Any]", divisor: int
-    ) -> "Optional[Work]":
-        """The sum over the group divided by ``divisor`` (the Manager's
-        live participant count, which is not always ``size()``), the
-        division done in place by the group, which owns the buffer it
-        reduced into.  ``None`` says this group does not take a divisor
-        (a subprocess group, a fake): the caller allreduces and divides."""
-        return None
+        dtype: the leaves reduced over the group by ``op`` and, where a
+        ``divisor`` is given, divided by it in the leaf's dtype (the
+        Manager's live participant count, which is not always ``size()``;
+        ``REDUCE_AVG`` is the divisor ``size()``).  The group divides, in
+        place where it owns the buffer it reduced into.  The result is
+        private to the caller for as long as the caller holds it (or any
+        view of it); it never aliases an ``np.ndarray`` the caller passed
+        in, and that array is not written; it may be read-only when it came
+        straight off the device (a ``jax.Array`` leaf at world size 1):
+        copy before writing into it."""
 
     @abstractmethod
     def allgather(self, array: Any) -> Work:
@@ -364,13 +356,12 @@ class ProcessGroupDummy(ProcessGroup):
     def size(self) -> int:
         return self._world
 
-    def allreduce(self, arrays: "List[Any]", op: str = REDUCE_SUM) -> Work:
-        return self._alone(arrays, None)
-
-    def _allreduce_mean(self, arrays: "List[Any]", divisor: int) -> Work:
-        return self._alone(arrays, divisor)
-
-    def _alone(self, arrays: "List[Any]", divisor: "Optional[int]") -> Work:
+    def allreduce(
+        self,
+        arrays: "List[Any]",
+        op: str = REDUCE_SUM,
+        divisor: "Optional[int]" = None,
+    ) -> Work:
         return completed_work(
             _allreduce_alone(
                 [_as_numpy(a) for a in arrays],
@@ -1137,16 +1128,13 @@ class ProcessGroupTCP(ProcessGroup):
 
     # -- collectives -------------------------------------------------------
 
-    def allreduce(self, arrays: "List[Any]", op: str = REDUCE_SUM) -> Work:
-        return self._allreduce(arrays, op, None)
-
-    def _allreduce_mean(self, arrays: "List[Any]", divisor: int) -> Work:
-        return self._allreduce(arrays, REDUCE_SUM, divisor)
-
-    def _allreduce(
-        self, arrays: "List[Any]", op: str, divisor: "Optional[int]"
+    def allreduce(
+        self,
+        arrays: "List[Any]",
+        op: str = REDUCE_SUM,
+        divisor: "Optional[int]" = None,
     ) -> Work:
-        """``divisor``: what the reduced sum is divided by, in place, by
+        """``divisor``: what the reduced result is divided by, in place, by
         the ring that owns the buffer; ``REDUCE_AVG`` is the divisor
         ``size()``."""
         deadline_budget = self._timeout
@@ -1616,19 +1604,18 @@ class ProcessGroupWrapper(ProcessGroup):
     def size(self) -> int:
         return self._pg.size()
 
-    def allreduce(self, arrays: "List[Any]", op: str = REDUCE_SUM) -> Work:
+    def allreduce(
+        self,
+        arrays: "List[Any]",
+        op: str = REDUCE_SUM,
+        divisor: "Optional[int]" = None,
+    ) -> Work:
+        # The fallback hands the caller's own arrays back, undivided and
+        # unwritten: a failed step is never committed.
         return self._wrap(
-            self._pg.allreduce(arrays, op),
+            self._pg.allreduce(arrays, op, divisor),
             lambda: [_as_numpy(a) for a in arrays],
         )
-
-    def _allreduce_mean(
-        self, arrays: "List[Any]", divisor: int
-    ) -> "Optional[Work]":
-        work = self._pg._allreduce_mean(arrays, divisor)
-        if work is None:
-            return None
-        return self._wrap(work, lambda: [_as_numpy(a) for a in arrays])
 
     def allgather(self, array: Any) -> Work:
         return self._wrap(self._pg.allgather(array), lambda: [_as_numpy(array)])
@@ -1798,7 +1785,17 @@ class ManagedProcessGroup(ProcessGroup):
     def size(self) -> int:
         return self._manager.num_participants()
 
-    def allreduce(self, arrays: "List[Any]", op: str = REDUCE_SUM) -> Work:
+    def allreduce(
+        self,
+        arrays: "List[Any]",
+        op: str = REDUCE_SUM,
+        divisor: "Optional[int]" = None,
+    ) -> Work:
+        if divisor is not None:
+            raise ValueError(
+                "ManagedProcessGroup takes no divisor: its Manager averages "
+                "by the live participant count (op=REDUCE_AVG)"
+            )
         # Manager.allreduce takes a pytree; a list of arrays is one.
         return self._manager.allreduce(list(arrays), reduce_op=op)
 
@@ -1819,640 +1816,3 @@ class ManagedProcessGroup(ProcessGroup):
 
     def recv(self, src: int, tag: int = 0, out: "Optional[np.ndarray]" = None) -> Work:
         return failed_work(RuntimeError("ManagedProcessGroup only supports allreduce"))
-
-
-# ---------------------------------------------------------------------------
-# Subprocess-isolated ("Baby") process groups
-# ---------------------------------------------------------------------------
-
-# Arrays >= this cross the parent<->worker boundary as POSIX shared-memory
-# segments instead of pickled pipe bytes: the pipe path costs two full
-# serializations plus 2x the payload in 64 KiB pipe writes per direction
-# (reference streams tensors with backpressure instead of pickling,
-# torchft/process_group.py:1602-1645).
-_SHM_MIN_BYTES = 1 << 20
-
-
-class _ShmRef:
-    """Pickle-tiny stand-in for an array staged in shared memory."""
-
-    __slots__ = ("name", "shape", "dtype")
-
-    def __init__(self, name: str, shape: "Tuple[int, ...]", dtype: str) -> None:
-        self.name = name
-        self.shape = shape
-        self.dtype = dtype
-
-    def __getstate__(self):
-        return (self.name, self.shape, self.dtype)
-
-    def __setstate__(self, state):
-        self.name, self.shape, self.dtype = state
-
-
-def _shm_untrack(shm: Any) -> None:
-    """Drop the resource-tracker claim on a segment.
-
-    Parent and spawned workers share ONE tracker process whose cache is a
-    set of names, and this Python registers on attach as well as create —
-    so cross-process register/unregister pairs can't be balanced per
-    process.  Protocol instead: every create/attach untracks immediately
-    (the set stays empty of our names) and :func:`_shm_unlink_balanced`
-    re-registers just before the final unlink so unlink's internal
-    unregister finds the entry.  Tradeoff: the tracker won't clean our
-    segments if a process dies mid-op — the Baby design's parent survives
-    and does (``_release_shms`` / the view finalizers)."""
-    from multiprocessing import resource_tracker
-
-    try:
-        resource_tracker.unregister(shm._name, "shared_memory")  # noqa: SLF001
-    except Exception:  # noqa: BLE001 - tracker API is version-dependent
-        pass
-
-
-def _shm_unlink_balanced(shm: Any) -> None:
-    """Unlink with tracker bookkeeping balanced (see :func:`_shm_untrack`);
-    safe when another handle already unlinked the name."""
-    from multiprocessing import resource_tracker
-
-    try:
-        resource_tracker.register(shm._name, "shared_memory")  # noqa: SLF001
-    except Exception:  # noqa: BLE001
-        pass
-    try:
-        shm.unlink()  # internal unregister consumes the registration
-    except FileNotFoundError:
-        _shm_untrack(shm)
-
-
-def _finalize_shm_view(shm: Any) -> None:
-    shm.close()
-    _shm_unlink_balanced(shm)
-
-
-class _ShmIn:
-    """A resolved input segment inside the worker: kept open for the op's
-    lifetime and reusable as the (already warm) result buffer."""
-
-    __slots__ = ("ref", "shm", "view", "used")
-
-    def __init__(self, ref: "_ShmRef", shm: Any, view: np.ndarray) -> None:
-        self.ref = ref
-        self.shm = shm
-        self.view = view
-        self.used = False
-
-
-def _shm_stage_value(value: Any, created: "List[Any]") -> Any:
-    """Replace large arrays in ``value`` (an array or list of arrays) with
-    ``_ShmRef``s backed by fresh segments appended to ``created``."""
-    from multiprocessing import shared_memory
-
-    def stage(a: Any) -> Any:
-        if not isinstance(a, np.ndarray) or a.nbytes < _SHM_MIN_BYTES:
-            return a
-        a = np.ascontiguousarray(a)
-        shm = shared_memory.SharedMemory(create=True, size=a.nbytes)
-        _shm_untrack(shm)
-        dst = np.ndarray(a.shape, dtype=a.dtype, buffer=shm.buf)
-        dst[...] = a
-        created.append(shm)
-        return _ShmRef(shm.name, a.shape, str(a.dtype))
-
-    if isinstance(value, list):
-        return [stage(a) for a in value]
-    return stage(value)
-
-
-def _shm_resolve_value(value: Any, opened: "List[_ShmIn]") -> Any:
-    """Inverse of :func:`_shm_stage_value`: materialize ``_ShmRef``s as
-    zero-copy views; the backing segments are appended to ``opened`` and
-    must outlive the views."""
-    from multiprocessing import shared_memory
-
-    def resolve(a: Any) -> Any:
-        if not isinstance(a, _ShmRef):
-            return a
-        shm = shared_memory.SharedMemory(name=a.name)
-        _shm_untrack(shm)  # the parent owns (and unlinks) input segments
-        view = np.ndarray(a.shape, dtype=np.dtype(a.dtype), buffer=shm.buf)
-        opened.append(_ShmIn(a, shm, view))
-        return view
-
-    if isinstance(value, list):
-        return [resolve(a) for a in value]
-    return resolve(value)
-
-
-def _shm_stage_result(value: Any, inputs: "List[_ShmIn]") -> Any:
-    """Worker-side result staging: write each large result array into a
-    matching (shape+dtype) input segment — already-warm pages, no fresh
-    allocation — falling back to a fresh segment.  Small values pickle."""
-    from multiprocessing import shared_memory
-
-    def stage(a: Any) -> Any:
-        if not isinstance(a, np.ndarray) or a.nbytes < _SHM_MIN_BYTES:
-            return a
-        for inp in inputs:
-            if (
-                not inp.used
-                and inp.view.shape == a.shape
-                and inp.view.dtype == a.dtype
-            ):
-                inp.used = True
-                if inp.view is not a and not np.shares_memory(inp.view, a):
-                    inp.view[...] = a
-                return inp.ref
-        shm = shared_memory.SharedMemory(create=True, size=a.nbytes)
-        _shm_untrack(shm)  # ownership passes to the parent (it unlinks)
-        np.ndarray(a.shape, dtype=a.dtype, buffer=shm.buf)[...] = a
-        shm.close()
-        return _ShmRef(shm.name, a.shape, str(a.dtype))
-
-    if isinstance(value, list):
-        return [stage(a) for a in value]
-    return stage(value)
-
-
-def _shm_discard_value(value: Any) -> None:
-    """Reclaim result segments whose message will never be consumed (reader
-    superseded by reconfigure, future already failed): worker-created
-    segments are untracked, so dropping their refs without unlinking would
-    pin the payload in /dev/shm forever."""
-    from multiprocessing import shared_memory
-
-    refs = value if isinstance(value, list) else [value]
-    for a in refs:
-        if not isinstance(a, _ShmRef):
-            continue
-        try:
-            shm = shared_memory.SharedMemory(name=a.name)
-        except FileNotFoundError:
-            continue  # an input-reused segment the parent already unlinked
-        _shm_untrack(shm)
-        shm.close()
-        _shm_unlink_balanced(shm)
-
-
-def _shm_wrap_value(value: Any) -> Any:
-    """Parent-side result decode: materialize each ``_ShmRef`` as a ZERO-
-    COPY view of its segment; a GC finalizer on the array closes (and, for
-    worker-created segments, unlinks) the mapping.  Must run before the
-    parent unlinks the op's input segments (attach needs the name; the
-    mapping survives the unlink)."""
-    import weakref
-
-    from multiprocessing import shared_memory
-
-    def wrap(a: Any) -> Any:
-        if not isinstance(a, _ShmRef):
-            return a
-        shm = shared_memory.SharedMemory(name=a.name)
-        _shm_untrack(shm)
-        arr = np.ndarray(a.shape, dtype=np.dtype(a.dtype), buffer=shm.buf)
-        weakref.finalize(arr, _finalize_shm_view, shm)
-        return arr
-
-    if isinstance(value, list):
-        return [wrap(a) for a in value]
-    return wrap(value)
-
-
-def _baby_worker(
-    pg_cls: type,
-    pipe_conn: Any,
-    store_addr: str,
-    replica_id: str,
-    rank: int,
-    world_size: int,
-    timeout: float,
-) -> None:
-    """Worker-process loop: run the real PG, execute ops from the pipe.
-
-    Protocol (reference worker loop, torchft/process_group.py:1470-1600):
-    parent sends ``(op_id, func_name, args, kwargs)``; worker runs the op,
-    *waits* the resulting Work, and replies ``(op_id, value)`` on success or
-    ``(op_id, exception)`` on failure. ``(op_id, "__shutdown__", ...)``
-    exits the loop. Collectives execute on a small thread pool so an
-    in-flight op cannot block the command loop (and ops on distinct tags can
-    overlap), matching the parent's async Work API.
-    """
-    import concurrent.futures as cf
-
-    pg = pg_cls()
-    pg.set_timeout(timeout)
-    try:
-        pg.configure(store_addr, replica_id, rank, world_size)
-    except Exception as e:  # noqa: BLE001 - shipped to parent
-        try:
-            # bare exception: _MonitoredPipe re-raises it in the parent's
-            # configure with the real root cause intact
-            pipe_conn.send(e)
-        except (BrokenPipeError, OSError):
-            pass
-        return
-    pipe_conn.send((-1, "configured"))
-
-    send_lock = _lockcheck.lock("pg.baby.pipe_send")
-    pool = cf.ThreadPoolExecutor(max_workers=4, thread_name_prefix="baby_op")
-
-    def _send(op_id: int, value: Any) -> None:
-        with send_lock:
-            try:
-                pipe_conn.send((op_id, value))
-            except (BrokenPipeError, OSError):
-                pass
-
-    def _finish(op_id: int, work: Any, opened: "List[_ShmIn]") -> None:
-        try:
-            try:
-                value = (
-                    work.wait(timeout=timeout) if isinstance(work, Work) else work
-                )
-            except Exception as e:  # noqa: BLE001 - shipped to parent
-                _send(op_id, e)
-                return
-            # stage results into the warm input segments where shapes
-            # match (allreduce/broadcast/alltoall), fresh segments
-            # otherwise; the parent owns every segment from here
-            value = _shm_stage_result(value, opened)
-            _send(op_id, value)
-        finally:
-            for inp in opened:
-                inp.shm.close()
-
-    try:
-        while True:
-            try:
-                msg = pipe_conn.recv()
-            except (EOFError, OSError):
-                break
-            op_id, func, args, kwargs = msg
-            if func == "__shutdown__":
-                break
-            # enqueue on THIS thread so ops hit the inner PG in pipe order
-            # (pipelined collectives must match across ranks); only the
-            # wait() moves to the pool so an in-flight op can't block the
-            # command loop.
-            opened: "List[_ShmIn]" = []
-            try:
-                args = [_shm_resolve_value(a, opened) for a in args]
-                work = getattr(pg, func)(*args, **kwargs)
-            except Exception as e:  # noqa: BLE001 - shipped to parent
-                for inp in opened:
-                    inp.shm.close()
-                _send(op_id, e)
-                continue
-            pool.submit(_finish, op_id, work, opened)
-    finally:
-        pool.shutdown(wait=False)
-        try:
-            pg.shutdown()
-        except Exception:  # noqa: BLE001 - worker teardown is best-effort
-            pass
-
-
-_spawn_env_lock = threading.Lock()
-
-
-@contextlib.contextmanager
-def _cpu_only_child_env() -> Any:
-    """Children started inside this block cannot take the accelerator.
-
-    A chip belongs to one process at a time and the parent — the trainer —
-    holds it.  The worker only moves host bytes, but it re-imports the
-    user's ``__main__`` on spawn, and anything there that touches a JAX
-    device would make the child reach for the chip and fail or hang.
-    ``spawn`` hands the child a copy of ``os.environ`` as of ``start()``,
-    so the platform is pinned there; the parent's own (already
-    configured) JAX does not re-read it."""
-    with _spawn_env_lock:
-        prev = env_str("JAX_PLATFORMS")
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        try:
-            yield
-        finally:
-            if prev:
-                os.environ["JAX_PLATFORMS"] = prev
-            else:
-                os.environ.pop("JAX_PLATFORMS", None)
-
-
-class ProcessGroupBaby(ProcessGroup):
-    """Runs the real PG in a spawned subprocess for crash isolation.
-
-    Reference: torchft/process_group.py:1358-1828.  ``configure`` kills any
-    existing worker and spawns a fresh one (subprocess restart *is* the
-    reconfigure); every collective is shipped over a command pipe and
-    returns a Work backed by a future that a reader thread resolves.
-    ``abort()`` kills the worker — the hard-cancel that a wedged socket
-    stack cannot block.
-
-    Workers start via the ``spawn`` method, so (as with any spawning
-    library) the using script must be importable without side effects —
-    guard its entry point with ``if __name__ == "__main__":``.
-    """
-
-    PG_CLASS: type = None  # set by subclasses
-
-    def __init__(self, timeout: float = 60.0, max_active_work: int = 16) -> None:
-        """``max_active_work``: backpressure cap on in-flight ops — each op
-        can hold staged shared-memory payloads, so an unbounded submitter
-        would pin unbounded host memory (reference num_active_work,
-        torchft/process_group.py:1602-1645).  0 disables the cap."""
-        super().__init__(timeout)
-        self._proc: Optional[Any] = None
-        self._pipe: Optional[Any] = None
-        self._rank = -1
-        self._world = -1
-        self._errored_exc: Optional[Exception] = None
-        self._next_op_id = 0
-        self._baby_replica_id = ""
-        self._gen = 0  # bumped per configure; guards against stale readers
-        self._pending: Dict[int, Future] = {}
-        self._pending_shm: "Dict[int, List[Any]]" = {}
-        self._max_active_work = max_active_work
-        self._lock = _lockcheck.lock("pg.baby.state")
-        self._cond = threading.Condition(self._lock)
-        self._reader: Optional[threading.Thread] = None
-
-    def configure(self, store_addr: str, replica_id: str, rank: int, world_size: int) -> None:
-        import multiprocessing as mp
-
-        _faults.check("pg.reconfigure", replica=replica_id)
-        self._kill_worker()
-        self._errored_exc = None
-        self._baby_replica_id = replica_id
-        self._rank = rank
-        self._world = world_size
-
-        ctx = mp.get_context("spawn")
-        parent_conn, child_conn = ctx.Pipe()
-        self._proc = ctx.Process(
-            target=_baby_worker,
-            args=(
-                type(self).PG_CLASS,
-                child_conn,
-                store_addr,
-                replica_id,
-                rank,
-                world_size,
-                self._timeout,
-            ),
-            daemon=True,
-        )
-        with _cpu_only_child_env():
-            self._proc.start()
-        child_conn.close()
-
-        from torchft_tpu.multiprocessing import _MonitoredPipe
-
-        pipe = _MonitoredPipe(parent_conn)
-        with self._lock:
-            self._pipe = pipe
-            self._gen += 1
-            gen = self._gen
-        # first message acks configure; a worker-side configure failure
-        # arrives as a bare exception that _MonitoredPipe re-raises here
-        ack = self._recv_ack(pipe)
-        if ack != (-1, "configured"):
-            self._kill_worker()
-            raise RuntimeError(f"unexpected configure ack from worker: {ack!r}")
-
-        self._reader = threading.Thread(
-            target=self._read_loop,
-            args=(pipe, gen),
-            name="baby_pg_reader",
-            daemon=True,
-        )
-        self._reader.start()
-        _metrics.PG_RECONFIGURES.labels(transport="baby").inc()
-
-    def _recv_ack(self, pipe: Any) -> Any:
-        try:
-            return pipe.recv(timeout=self._timeout)
-        except Exception:
-            self._kill_worker()
-            raise
-
-    def _read_loop(self, pipe: Any, gen: int) -> None:
-        while True:
-            try:
-                op_id, value = pipe.recv(timeout=None)
-            except Exception as e:  # noqa: BLE001 - includes EOF/reset/transport
-                # EOFError (clean close) or ConnectionResetError (SIGKILL)
-                # both mean the worker died; transported exceptions arrive
-                # without an op id and are equally fatal to all pending ops.
-                # The generation check inside _fail_all makes a stale reader
-                # (whose PG was already reconfigured) a no-op.
-                if isinstance(e, (EOFError, OSError)):
-                    self._fail_all(RuntimeError(f"baby PG worker exited: {e!r}"), gen)
-                else:
-                    self._fail_all(
-                        e if isinstance(e, Exception) else RuntimeError(str(e)), gen
-                    )
-                return
-            with self._lock:
-                if gen != self._gen:
-                    # reconfigured under us; results no longer ours — but
-                    # any worker-created result segments still need reaping
-                    _shm_discard_value(value)
-                    return
-                fut = self._pending.pop(op_id, None)
-                in_shms = self._pending_shm.pop(op_id, [])
-                if fut is not None and isinstance(value, Exception):
-                    self._errored_exc = self._errored_exc or value
-                self._cond.notify_all()
-            if fut is None or isinstance(value, Exception):
-                self._release_shms(in_shms)
-                if not isinstance(value, Exception):
-                    _shm_discard_value(value)
-                if fut is not None:
-                    fut.set_exception(value)
-                continue
-            # decode BEFORE unlinking inputs: results may live in reused
-            # input segments (attach needs the name; mappings survive)
-            try:
-                result = _shm_wrap_value(value)
-            except Exception as e:  # noqa: BLE001 - decode failure
-                self._release_shms(in_shms)
-                fut.set_exception(e)
-                continue
-            self._release_shms(in_shms)
-            fut.set_result(result)
-
-    @staticmethod
-    def _release_shms(shms: "List[Any]") -> None:
-        for shm in shms:
-            shm.close()
-            _shm_unlink_balanced(shm)
-
-    def _fail_all(self, exc: Exception, gen: "Optional[int]" = None) -> None:
-        with self._lock:
-            if gen is not None and gen != self._gen:
-                return  # stale reader of a pre-reconfigure worker
-            self._errored_exc = self._errored_exc or exc
-            pending, self._pending = self._pending, {}
-            pending_shm, self._pending_shm = self._pending_shm, {}
-            self._cond.notify_all()
-        for shms in pending_shm.values():
-            self._release_shms(shms)
-        for fut in pending.values():
-            if not fut.done():
-                fut.set_exception(exc)
-
-    def _kill_worker(self) -> None:
-        # claim pipe+proc under the lock: abort() and configure() can race
-        # here, and nulling before close makes the reader thread see a stale
-        # pipe (deliberate teardown), not a worker death. Bumping the
-        # generation here (not just in configure) immediately invalidates
-        # the old reader so it cannot latch an error after a reconfigure
-        # clears the latched state.
-        with self._lock:
-            pipe, self._pipe = self._pipe, None
-            proc, self._proc = self._proc, None
-            self._gen += 1
-        if pipe is not None:
-            try:
-                pipe.close()
-            except OSError:
-                pass
-        if proc is not None:
-            try:
-                proc.terminate()
-                proc.join(timeout=5)
-                if proc.is_alive():
-                    proc.kill()
-                    proc.join(timeout=5)
-            except ValueError:
-                pass  # process never started (configure failed mid-spawn)
-        self._fail_all(_PGAborted("process group aborted"))
-
-    def _submit(self, func: str, *args: Any, **kwargs: Any) -> Work:
-        with self._lock:
-            # backpressure: bound in-flight ops (each may pin staged shm)
-            if self._max_active_work > 0:
-                deadline = time.monotonic() + self._timeout
-                while (
-                    len(self._pending) >= self._max_active_work
-                    and self._errored_exc is None
-                    and self._pipe is not None
-                ):
-                    remaining = deadline - time.monotonic()
-                    if remaining <= 0 or not self._cond.wait(timeout=remaining):
-                        return failed_work(
-                            TimeoutError(
-                                f"{len(self._pending)} ops in flight >= "
-                                f"max_active_work={self._max_active_work} "
-                                f"for {self._timeout}s"
-                            )
-                        )
-            if self._errored_exc is not None:
-                return failed_work(self._errored_exc)
-            if self._pipe is None:
-                return failed_work(RuntimeError("process group not configured"))
-            op_id = self._next_op_id
-            self._next_op_id += 1
-            fut: Future = Future()
-            self._pending[op_id] = fut
-            pipe = self._pipe  # local ref: abort() may null the attribute
-        # stage large payloads outside the lock (memcpy can be tens of ms);
-        # the segments stay alive until the op resolves
-        created: "List[Any]" = []
-        try:
-            args = tuple(_shm_stage_value(a, created) for a in args)
-        except Exception as e:  # noqa: BLE001 - staging failure fails the op
-            self._release_shms(created)
-            with self._lock:
-                self._pending.pop(op_id, None)
-                self._cond.notify_all()
-            return failed_work(e)
-        with self._lock:
-            if op_id in self._pending:
-                self._pending_shm[op_id] = created
-            else:
-                # failed/aborted while staging; nothing will clean these
-                self._release_shms(created)
-                created = []
-        try:
-            pipe.send((op_id, func, args, kwargs))
-        except (BrokenPipeError, OSError) as e:
-            with self._lock:
-                self._pending.pop(op_id, None)
-                shms = self._pending_shm.pop(op_id, [])
-                self._cond.notify_all()
-            self._release_shms(shms)
-            self._errored_exc = self._errored_exc or e
-            return failed_work(e)
-        return Work(fut).with_timeout(self._timeout)
-
-    # -- ProcessGroup API --------------------------------------------------
-
-    def abort(self) -> None:
-        _metrics.PG_ABORTS.labels(transport="baby").inc()
-        _flightrec.record(
-            "pg.abort", status="abort", transport="baby",
-            replica_id=self._baby_replica_id, rank=self._rank,
-            world=self._world,
-        )
-        _flightrec.dump("baby process group aborted", trigger="pg_abort")
-        self._kill_worker()  # latches _PGAborted via _fail_all
-
-    def errored(self) -> Optional[Exception]:
-        return self._errored_exc
-
-    def shutdown(self) -> None:
-        if self._pipe is not None:
-            try:
-                self._pipe.send((-1, "__shutdown__", (), {}))
-            except (BrokenPipeError, OSError):
-                pass
-        self._kill_worker()
-
-    def rank(self) -> int:
-        return self._rank
-
-    def size(self) -> int:
-        return self._world
-
-    def allreduce(self, arrays: "List[Any]", op: str = REDUCE_SUM) -> Work:
-        return self._submit("allreduce", [_as_numpy(a) for a in arrays], op)
-
-    def allgather(self, array: Any) -> Work:
-        return self._submit("allgather", _as_numpy(array))
-
-    def broadcast(self, array: Any, root: int = 0) -> Work:
-        return self._submit("broadcast", _as_numpy(array), root)
-
-    def reduce_scatter(self, array: Any, op: str = REDUCE_SUM) -> Work:
-        return self._submit("reduce_scatter", _as_numpy(array), op)
-
-    def alltoall(self, arrays: "List[Any]") -> Work:
-        return self._submit("alltoall", [_as_numpy(a) for a in arrays])
-
-    def sendrecv(self, array: Any, dst: int, src: int, tag: int = 0) -> Work:
-        return self._submit("sendrecv", _as_numpy(array), dst, src, tag)
-
-    def send(self, array: Any, dst: int, tag: int = 0) -> Work:
-        return self._submit("send", _as_numpy(array), dst, tag)
-
-    def recv(self, src: int, tag: int = 0, out: "Optional[np.ndarray]" = None) -> Work:
-        work = self._submit("recv", src, tag)
-        if out is None:
-            return work
-        # the worker can't share the caller's buffer; emulate in-place by
-        # copying the (possibly shm-backed) result into it — with the same
-        # validation the direct backend's wire reader applies
-        def into(arr: np.ndarray) -> np.ndarray:
-            _check_recv_buffer(out, arr.shape, str(arr.dtype))
-            out[...] = arr
-            return out
-
-        return work.then(into)
-
-
-class ProcessGroupBabyTCP(ProcessGroupBaby):
-    """Subprocess-isolated ProcessGroupTCP (reference ProcessGroupBabyGloo
-    analog, torchft/process_group.py:1883-1923)."""
-
-    PG_CLASS = ProcessGroupTCP

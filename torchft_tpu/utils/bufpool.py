@@ -87,11 +87,11 @@ class BufferPool:
         while isinstance(base.base, np.ndarray) and base.base.nbytes == arr.nbytes:
             base = base.base
         # Only pool arrays that OWN their memory (malloc'd by numpy).  A
-        # view over foreign memory — e.g. the Baby PG's zero-copy
-        # /dev/shm-backed receive buffers, whose close/unlink finalizer
-        # would be pinned for as long as the pool holds the view — must
-        # fall to the GC instead.  This is enforced here, at the seam,
-        # so no recycle call site has to know which PG produced a buffer.
+        # view over foreign memory (an mmap, a device's host copy), whose
+        # owner would be pinned for as long as the pool holds the view,
+        # must fall to the GC instead.  This is enforced here, at the
+        # seam, so no recycle call site has to know where a buffer came
+        # from.
         if base.base is not None:
             return
         key = (base.size, base.dtype.str)

@@ -40,8 +40,7 @@ site                  fires in
                       matrix degrades to older rows, never wedges)
 ``manager.quorum``    ``Manager._async_quorum`` before the quorum RPC
 ``manager.heal``      ``Manager._async_quorum`` heal send/recv branches
-``pg.reconfigure``    ``ProcessGroupTCP.configure`` /
-                      ``ProcessGroupBaby.configure``
+``pg.reconfigure``    ``ProcessGroupTCP.configure``
 ``pg.allreduce``      ``Manager.allreduce`` before collective submission;
                       also per chunk in the quantized pipeline drivers
 ``pg.allreduce.chunk``  quantized pipeline drivers, per chunk
