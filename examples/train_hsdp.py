@@ -122,9 +122,10 @@ def train(replica_id: str, lighthouse_addr: str, devices, args, log=print) -> di
                 jnp.int32,
             )
             loss, grads = grad_fn(state["params"], tokens)
-            avg = manager.allreduce(
-                jax.tree_util.tree_map(np.asarray, grads)
-            ).wait(timeout=30)
+            # the leaves go as they are, sharded inside the group: with
+            # peers the collective gathers them to the host for the ring; a
+            # group alone gets them back as they are, sharding intact
+            avg = manager.allreduce(grads).wait(timeout=30)
             new_params, new_opt, committed = optimizer.step(
                 state["params"],
                 jax.tree_util.tree_map(jnp.asarray, avg),

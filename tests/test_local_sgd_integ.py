@@ -198,6 +198,45 @@ class TestLocalSGDInteg:
         assert_params_equal(results)
 
 
+    @pytest.mark.parametrize("leaves", ["device", "mixed"])
+    def test_local_sgd_alone_hands_set_params_device_arrays(self, leaves):
+        """At world size 1 the average of device parameters is those
+        parameters: ``set_params`` gets them back as ``jax.Array``, and no
+        leaf makes the round trip over the host.  A host leaf beside them
+        comes back as a host copy."""
+        import jax
+        import jax.numpy as jnp
+
+        server = LighthouseServer(min_replicas=1, join_timeout_ms=100)
+        params = {"w": jnp.arange(8, dtype=jnp.float32) / 3, "b": jnp.ones((2, 3))}
+        if leaves == "mixed":
+            params["h"] = np.arange(3, dtype=np.float32)
+        handed = []
+        manager = Manager(
+            pg=ProcessGroupTCP(timeout=10.0), min_replica_size=1,
+            load_state_dict=lambda sd: None, state_dict=lambda: {},
+            replica_id="lsgd_alone", lighthouse_addr=server.address(),
+            group_rank=0, group_world_size=1, use_async_quorum=False,
+            timeout=10.0,
+        )
+        try:
+            lsgd = LocalSGD(manager, lambda: dict(params), handed.append, sync_every=2)
+            for _ in range(4):
+                lsgd.step()
+            assert len(handed) == 2 and manager.current_step() == 2
+            for got in handed:
+                assert set(got) == set(params)
+                for key in ("w", "b"):
+                    assert isinstance(got[key], jax.Array) and got[key] is params[key]
+                if leaves == "mixed":
+                    assert type(got["h"]) is np.ndarray
+                    assert not np.shares_memory(got["h"], params["h"])
+                    np.testing.assert_array_equal(got["h"], params["h"])
+        finally:
+            manager.shutdown()
+            server.shutdown()
+
+
 class TestDiLoCoInteg:
     def test_diloco_healthy_two_fragments(self, lighthouse):
         runners = [

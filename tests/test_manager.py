@@ -290,10 +290,88 @@ class TestManagerAverage:
         manager.start_quorum()
         dev = jnp.arange(1 << 12, dtype=jnp.float32)
         out = manager.allreduce({"g": dev}).wait(timeout=10)["g"]
-        # the device-to-host copy itself: no second array, no division
-        assert isinstance(out, np.ndarray) and not out.flags.writeable
-        assert np.shares_memory(out, np.asarray(dev))
+        # the leaf itself, still on the device: no copy, no division
+        assert out is dev
         np.testing.assert_array_equal(out, np.arange(1 << 12))
+
+    def _alone(self, manager_ctx, pg_kind):
+        build, client, _ = manager_ctx
+        pg = {"owner": ProcessGroupDummy, "tcp-alone": _AloneTCP}[pg_kind]()
+        manager = build(pg=pg, min_replica_size=1)
+        client._quorum.return_value = make_quorum(
+            replica_world_size=1, max_world_size=1
+        )
+        manager.start_quorum()
+        assert manager.num_participants() == 1
+        return manager, pg
+
+    @pytest.mark.parametrize("pg_kind", ["owner", "tcp-alone"])
+    def test_alone_a_device_pytree_comes_back_as_its_leaves(
+        self, manager_ctx, pg_kind
+    ):
+        import jax
+        import jax.numpy as jnp
+
+        manager, pg = self._alone(manager_ctx, pg_kind)
+        key = jax.random.PRNGKey(3)
+        grads = {
+            "w": jax.random.normal(key, (33, 7), jnp.float32),
+            "blocks": [
+                jax.random.normal(key, (5,), jnp.float32).astype(jnp.bfloat16),
+                {"b": jnp.arange(3, dtype=jnp.float32) / 7},
+            ],
+        }
+        before = jax.tree_util.tree_map(lambda x: np.array(x), grads)
+        out = manager.allreduce(grads).wait(timeout=10)
+        assert manager.errored() is None
+        assert jax.tree_util.tree_structure(out) == jax.tree_util.tree_structure(grads)
+        for got, leaf, want in zip(*map(jax.tree_util.tree_leaves, (out, grads, before))):
+            assert isinstance(got, jax.Array) and got is leaf
+            assert got.dtype == want.dtype and got.shape == want.shape
+            assert np.asarray(got).tobytes() == want.tobytes()
+        pg.shutdown()
+
+    @pytest.mark.parametrize("pg_kind", ["owner", "tcp-alone"])
+    def test_alone_a_mixed_pytree_returns_each_kind(self, manager_ctx, pg_kind):
+        """A ``jax.Array`` leaf comes back as itself, an ``np.ndarray`` as a
+        copy the caller may write, a Python scalar as a host array."""
+        import jax
+        import jax.numpy as jnp
+
+        manager, pg = self._alone(manager_ctx, pg_kind)
+        grads = {
+            "dev": jnp.arange(6, dtype=jnp.float32) * 3,
+            "host": np.arange(4, dtype=np.float32) + 1,
+            "scalar": 2.5,
+        }
+        out = manager.allreduce(grads).wait(timeout=10)
+        assert isinstance(out["dev"], jax.Array) and out["dev"] is grads["dev"]
+        assert type(out["host"]) is np.ndarray and out["host"].flags.writeable
+        assert not np.shares_memory(out["host"], grads["host"])
+        out["host"] += 1
+        np.testing.assert_array_equal(grads["host"], np.arange(4) + 1)
+        assert isinstance(out["scalar"], np.ndarray) and out["scalar"] == 2.5
+        pg.shutdown()
+
+    @pytest.mark.parametrize("pg_kind", ["owner", "tcp-alone"])
+    def test_a_lone_steps_phase_delta_still_holds_d2h_and_pack(
+        self, manager_ctx, pg_kind
+    ):
+        """What ``benchmarks/layer_metrics/d2h_ms.py`` and ``ring_host_ms.py``
+        read: a step whose leaves all stayed on the device still reports
+        both parts, each above zero, inside ``ring``."""
+        import jax.numpy as jnp
+
+        manager, pg = self._alone(manager_ctx, pg_kind)
+        before = manager.phase_times()
+        for _ in range(2):
+            manager.allreduce({"g": jnp.ones((64,), jnp.float32)}).wait(timeout=10)
+            now = manager.phase_times()
+            delta = {k: v - before.get(k, 0.0) for k, v in now.items()}
+            before = now
+            assert delta["ring.d2h"] > 0 and delta["ring.pack"] > 0
+            assert delta["ring"] >= delta["ring.d2h"] + delta["ring.pack"]
+        pg.shutdown()
 
     @pytest.mark.parametrize("how", ["latched", "op-fails", "swallowed"])
     def test_errored_pass_through_hands_the_input_back_unwritten(

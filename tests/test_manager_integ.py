@@ -419,11 +419,13 @@ class TestAllreduceReleasesInputs:
                 work = manager.allreduce(grads)
                 avg = work.wait(timeout=10)
                 np.testing.assert_array_equal(avg["w"], np.full(1024, step + 1.0))
-                # at world size 1 the result is the leaf's own host array,
-                # handed through uncopied; on the CPU backend host and
-                # device memory are one, so the result (and only it) is
-                # the leaf's buffer
-                del grads, work, avg
+                # at world size 1 the result is the leaf itself, still on
+                # the device: the result references it, and once the
+                # caller's own names are gone, nothing else does
+                assert avg["w"] is ref()
+                del grads, work
+                assert ref() is not None, "the result is not the input leaf"
+                del avg
                 assert ref() is None, "input leaf still referenced after wait()"
                 assert manager.should_commit()
         finally:

@@ -800,9 +800,11 @@ class TestRingContract:
         _shutdown(pgs)
 
     @pytest.mark.parametrize("kind", ["tcp", "dummy"])
-    def test_device_leaf_at_world_one_is_handed_through(self, kind, pack_spans):
-        """A ``jax.Array`` leaf at world size 1 costs the device-to-host
-        copy and nothing more: no pool allocation, no bytes copied."""
+    def test_device_leaf_at_world_one_is_handed_through(self, kind, ring_spans):
+        """A ``jax.Array`` leaf at world size 1 is the result itself: it
+        never leaves the device, no pool allocation, no bytes copied.  The
+        host leaf beside it is still copied."""
+        import jax
         import jax.numpy as jnp
 
         from torchft_tpu.utils.bufpool import POOL
@@ -819,18 +821,126 @@ class TestRingContract:
         def run():
             return pg.allreduce([dev, host], REDUCE_AVG).wait(timeout=20)
 
-        (got_dev, got_host), packs = pack_spans(run)
+        (got_dev, got_host), spans = ring_spans(run)
         assert (POOL.misses, POOL.hits) == (misses, hits)
-        (pack,) = packs
-        assert pack["copied"] == host.nbytes and pack["handed"] == dev.nbytes
+        assert isinstance(got_dev, jax.Array)
+        assert got_dev.unsafe_buffer_pointer() == dev.unsafe_buffer_pointer()
+        assert got_dev.sharding == dev.sharding
+        # nothing left the device, and the span says so
+        assert _attr(spans, "ring.d2h", "bytes") == [0]
+        assert _attr(spans, "ring.d2h", "kept") == [dev.nbytes]
+        assert _attr(spans, "ring.pack", "copied") == [host.nbytes]
+        assert _attr(spans, "ring.pack", "handed") == [dev.nbytes]
         np.testing.assert_array_equal(got_dev, np.arange(1 << 16))
-        assert not got_dev.flags.writeable  # straight off the device
         # the caller's host leaf is copied: the result is its own memory
-        assert got_host.flags.writeable
+        assert isinstance(got_host, np.ndarray) and got_host.flags.writeable
         assert not np.shares_memory(got_host, host)
         got_host += 1
         np.testing.assert_array_equal(host, np.arange(8))
         pg.shutdown()
+
+    @pytest.mark.parametrize("kind", ["tcp", "dummy"])
+    def test_sharded_leaf_at_world_one_keeps_its_sharding(
+        self, kind, ring_spans, monkeypatch
+    ):
+        """A leaf spread over several devices is not gathered to the host:
+        it comes back as it is, shard for shard."""
+        import jax
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+        from torchft_tpu.parallel import process_group
+
+        def no_host(x):
+            raise AssertionError("a sharded leaf was gathered to the host")
+
+        mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("fsdp", "tp"))
+        rows = NamedSharding(mesh, PartitionSpec("fsdp", "tp"))
+        whole = NamedSharding(mesh, PartitionSpec())
+        x = np.arange(8 * 6, dtype=np.float32).reshape(8, 6)
+        leaves = [jax.device_put(x, rows), jax.device_put(x[0], whole)]
+        (pg,) = _world(None, 1, "sharded") if kind == "tcp" else [ProcessGroupDummy()]
+        monkeypatch.setattr(process_group, "_as_numpy", no_host)
+        got, spans = ring_spans(
+            lambda: pg.allreduce(leaves, REDUCE_AVG).wait(timeout=20)
+        )
+        monkeypatch.undo()
+        for g, leaf in zip(got, leaves):
+            assert isinstance(g, jax.Array) and g.sharding == leaf.sharding
+            assert len(g.addressable_shards) == 4
+            for a, b in zip(g.addressable_shards, leaf.addressable_shards):
+                assert a.device == b.device
+                assert a.data.unsafe_buffer_pointer() == b.data.unsafe_buffer_pointer()
+        np.testing.assert_array_equal(got[0], x)
+        assert _attr(spans, "ring.d2h", "kept") == [x.nbytes + x[0].nbytes]
+        assert _attr(spans, "ring.d2h", "bytes") == [0]
+        pg.shutdown()
+
+    @pytest.mark.parametrize("kind", ["tcp", "dummy"])
+    def test_a_divisor_above_one_at_world_one_still_divides_on_the_host(
+        self, kind, ring_spans
+    ):
+        """No loop passes one to a lone group; the contract holds anyway, on
+        the host path as before: the device leaf leaves the device."""
+        import jax.numpy as jnp
+        import ml_dtypes
+
+        (pg,) = _world(None, 1, "div2") if kind == "tcp" else [ProcessGroupDummy()]
+        dev = jnp.arange(10, dtype=jnp.float32) * 3
+        bf16 = jnp.arange(6, dtype=jnp.float32).astype(jnp.bfloat16)
+        host = np.arange(4, dtype=np.float32) + 1
+        got, spans = ring_spans(
+            lambda: pg.allreduce([dev, bf16, host], REDUCE_SUM, divisor=2).wait(timeout=20)
+        )
+        assert all(type(g) is np.ndarray for g in got)
+        want = [
+            np.arange(10, dtype=np.float32) * 3 / 2,
+            (np.arange(6, dtype=np.float32) / 2).astype(ml_dtypes.bfloat16),
+            (np.arange(4, dtype=np.float32) + 1) / 2,
+        ]
+        _assert_same_bits(got, want)
+        np.testing.assert_array_equal(host, np.arange(4) + 1)
+        assert not np.shares_memory(got[2], host)
+        assert _attr(spans, "ring.d2h", "bytes") == [dev.nbytes + bf16.nbytes]
+        assert _attr(spans, "ring.d2h", "kept") == [0]
+        total = dev.nbytes + bf16.nbytes + host.nbytes
+        assert _attr(spans, "ring.pack", "copied") == [total]
+        pg.shutdown()
+
+    @pytest.mark.parametrize("kind", ["tcp", "dummy"])
+    def test_leaves_kept_counter(self, store, kind):
+        """``torchft_ring_leaves_kept_total``: the device leaves a lone
+        group handed back, under the stable replica id; a host leaf, a
+        divisor above 1 and a group that rings add nothing."""
+        import jax.numpy as jnp
+
+        from torchft_tpu.utils import metrics
+
+        def count(replica_id):
+            return metrics.RING_LEAVES_KEPT.labels(replica_id=replica_id).get()
+
+        name = f"kept-{kind}"
+        if kind == "tcp":
+            pg = ProcessGroupTCP(timeout=20.0)
+        else:
+            pg = ProcessGroupDummy()
+        pg.configure("", f"{name}:incarnation-1", 0, 1)
+        dev = [jnp.ones((3,)), jnp.ones((2, 2)), jnp.zeros((1,))]
+        start = count(name)
+        pg.allreduce(dev + [np.ones(2, np.float32)], REDUCE_AVG).wait(timeout=20)
+        assert count(name) == start + 3
+        pg.allreduce(dev[:2], REDUCE_SUM).wait(timeout=20)
+        assert count(name) == start + 5
+        pg.allreduce(dev, REDUCE_SUM, divisor=2).wait(timeout=20)
+        pg.allreduce([np.ones(2, np.float32)]).wait(timeout=20)
+        assert count(name) == start + 5
+        pg.shutdown()
+        if kind == "tcp":
+            # a pair rings: nothing is kept
+            pgs = make_group(store, 2, "kept-pair")
+            pair = count("rank0")
+            run_parallel(2, lambda r, _: pgs[r].allreduce(dev).wait(timeout=30))
+            assert count("rank0") == pair
+            _shutdown(pgs)
 
     def test_pack_says_what_was_copied_and_whether_the_pool_hit(
         self, store, pack_spans
@@ -1147,23 +1257,36 @@ class TestDeviceRelayout:
 
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
     def test_alone_the_leaf_is_still_its_own_device_to_host_copy(
-        self, ring_spans, dtype
+        self, ring_spans, monkeypatch, dtype
     ):
+        """(The name is the contract before PR 31.)  Alone, the leaf never
+        leaves the device: nothing is laid out, nothing is copied, and it
+        comes back in the order of dimensions the device holds it in."""
+        import jax
         import ml_dtypes
 
+        from torchft_tpu.parallel import process_group
+
+        def no_host(x):
+            raise AssertionError("a leaf left the device")
+
+        monkeypatch.setattr(process_group, "_as_numpy", no_host)
+        monkeypatch.setattr(process_group, "_to_host", no_host)
         dtype = np.dtype(getattr(ml_dtypes, dtype, dtype))
         (pg,) = _world(None, 1, "relay-alone")
         leaf = _on_device(
             np.arange(6 * 4, dtype=np.float32).reshape(6, 4).astype(dtype), (1, 0)
         )
         _, (got,), spans = _spans_of_rank0([pg], ring_spans, [[leaf]], REDUCE_SUM)
-        host = np.asarray(leaf)
         assert _attr(spans, "ring.d2h", "relaid") == [0]
+        assert _attr(spans, "ring.d2h", "bytes") == [0]
+        assert _attr(spans, "ring.d2h", "kept") == [leaf.nbytes]
         assert _attr(spans, "ring.pack", "copied") == [0]
-        # handed through as it came off the device: the device's order kept
-        assert got.strides == host.strides and not got.flags.c_contiguous
-        assert np.shares_memory(got, host) and not got.flags.writeable
-        _assert_same_bits([got], [host])
+        assert _attr(spans, "ring.pack", "handed") == [leaf.nbytes]
+        assert got is leaf and isinstance(got, jax.Array)
+        assert got.format == leaf.format  # the device's order kept
+        monkeypatch.undo()
+        _assert_same_bits([np.asarray(got)], [np.asarray(leaf)])
         pg.shutdown()
 
     @pytest.mark.parametrize("order", ["c", "strided"])

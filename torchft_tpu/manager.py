@@ -1252,13 +1252,18 @@ class Manager:
         with the input (zeroed) value and the error is tracked for
         ``should_commit`` (reference manager.py:385-467).
 
-        The result is host arrays, private to the caller for as long as it
-        holds them: it never aliases an ``np.ndarray`` that was passed in,
-        and nothing writes to it again (a ring buffer returns to the pool
-        only when the last view of the result is gone).  It may be
-        read-only where it came straight off the device (a ``jax.Array``
-        leaf at world size 1 is the device→host copy itself): copy before
-        writing into it.  Only the error path above hands the input back.
+        The result is arrays the caller may not write: host arrays as a
+        rule, private to the caller for as long as it holds them, never an
+        alias of an ``np.ndarray`` that was passed in, and nothing writes
+        to them again (a ring buffer returns to the pool only when the last
+        view of the result is gone).  A ``jax.Array`` leaf may come back as
+        a ``jax.Array``: when the group is alone (world size 1, one
+        participant) the average is the leaf, and it comes back as itself,
+        still on its devices with its sharding, without crossing to the
+        host and back; an ``np.ndarray`` leaf beside it comes back as a
+        copy.  Take ``np.array(x)`` of a leaf before writing into it or
+        calling an ``ndarray``-only method.  Only the error path above
+        hands the input back.
 
         ``device_quantize`` (quantized path only): quantize on-chip with
         the Pallas kernel before the device→host copy; ``None`` = auto
@@ -1331,7 +1336,7 @@ class Manager:
                         send_leaves, pg_reduce_op, divisor=divisor
                     )
 
-            def _postprocess(reduced: "List[np.ndarray]") -> Any:
+            def _postprocess(reduced: "List[Any]") -> Any:
                 with tracing.under(ring), tracing.phase(".unpack"):
                     return jax.tree_util.tree_unflatten(treedef, reduced)
 
@@ -1346,7 +1351,7 @@ class Manager:
             # held in HBM across the next forward/backward.  Nor the raw
             # Work: a future keeps its callbacks, so this callback would
             # close a cycle around the raw result, which at world size 1 is
-            # the leaves' own host arrays.
+            # the device leaves themselves.
             inputs = [(send_leaves, work)]
 
             def _done(f: "concurrent.futures.Future[Any]") -> None:
@@ -1637,12 +1642,15 @@ class Manager:
         hand-offs): ``ring.queue`` (submit until the worker picks the op
         up), ``ring.d2h`` (wait for the device + device→host copy of the
         leaves; ``relaid`` = bytes of leaves laid out flat on the device
-        first, because it held them in another order of dimensions),
+        first, because it held them in another order of dimensions; at
+        world size 1 ``bytes`` = what left the device, 0 for leaves handed
+        back as the ``jax.Array`` they are, and ``kept`` = their bytes),
         ``ring.pack`` (bucket concat, the lease of the ring buffer
         and what is copied into it: a leaf that widens, a zero-padded tail;
         at world size 1 the copy of a leaf the caller passed as host
-        memory; its attributes say bytes ``copied``, bytes ``handed``
-        through uncopied and whether the buffer was a ``pool`` hit),
+        memory, a leaf kept on the device counts as handed; its attributes
+        say bytes ``copied``, bytes ``handed`` through uncopied and whether
+        the buffer was a ``pool`` hit),
         ``ring.wire`` (the 2(w-1) exchanges of each bucket: send + receive
         + waiting for the peer), ``ring.reduce`` (the ufunc between them,
         which is also a chunk's first write into the buffer),

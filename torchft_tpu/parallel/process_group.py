@@ -103,10 +103,11 @@ def _as_numpy(x: Any) -> np.ndarray:
 
 
 def _off_device(x: Any) -> bool:
-    """True for a ``jax.Array``: its host array (:func:`_as_numpy`) is
-    read-only memory the caller cannot write, so a result may be that array
-    itself.  Anything else may be memory the caller still writes and is
-    copied.  (No import: this module loads without jax.)"""
+    """True for a ``jax.Array``: nobody can write it, nor its host array
+    (:func:`_as_numpy`, read-only memory), so a result may be the leaf
+    itself, still on its devices, or that host array.  Anything else may be
+    memory the caller still writes and is copied.  (No import: this module
+    loads without jax.)"""
     jax = sys.modules.get("jax")
     return jax is not None and isinstance(x, jax.Array)
 
@@ -170,22 +171,50 @@ def _divide(a: np.ndarray, divisor: "Optional[int]") -> np.ndarray:
 
 
 def _allreduce_alone(
-    arrays: "List[np.ndarray]", owned: "List[bool]", divisor: "Optional[int]"
-) -> "List[np.ndarray]":
-    """Allreduce at world size 1: the result is the input.  A leaf that
-    came off the device (``owned``) is handed through as it is; one the
-    caller passed as host memory is copied, because the result must not
-    alias what the caller can still write.  ``.pack`` says how many bytes
-    went which way."""
+    arrays: "List[Any]", divisor: "Optional[int]", kept_leaves: Any
+) -> "List[Any]":
+    """Allreduce at world size 1, of the leaves as they were handed in: the
+    one place that decides, for every group that can be alone.
+
+    With nothing to divide by (``divisor`` none or 1) the result is the
+    input, and a ``jax.Array`` leaf comes back as itself: nobody can write
+    it, so it never leaves its devices, whether it lies on one or is sharded
+    over many.  A leaf the caller passed as host memory is copied, because
+    the result must not alias what the caller can still write.  A divisor
+    above 1 (no loop passes one to a lone group) takes the host path: the
+    leaf comes off the device and is divided there, in its dtype.
+
+    ``.d2h`` says how many bytes left the device (``bytes``) and how many
+    stayed on it (``kept``), ``.pack`` how many bytes the host copied or
+    handed through (a leaf that stayed counts as handed); ``kept_leaves``
+    (``torchft_ring_leaves_kept_total``) counts the leaves that stayed."""
     scaled = divisor not in (None, 1)
-    copied = sum(a.nbytes for a, own in zip(arrays, owned) if scaled or not own)
+    device = [_off_device(a) for a in arrays]
+    on_device = sum(int(a.nbytes) for a, dev in zip(arrays, device) if dev)
     with _tracing.phase(
-        ".pack", copied=copied, handed=sum(a.nbytes for a in arrays) - copied
+        ".d2h",
+        bytes=on_device if scaled else 0,
+        kept=0 if scaled else on_device,
+        relaid=0,
     ):
+        if scaled:
+            arrays = [_as_numpy(a) for a in arrays]
+        else:  # a host leaf is its own host array
+            arrays = [a if dev else _as_numpy(a) for a, dev in zip(arrays, device)]
+            kept_leaves.inc(sum(device))
+    sizes = [int(a.nbytes) for a in arrays]
+    copied = sum(n for n, dev in zip(sizes, device) if scaled or not dev)
+    with _tracing.phase(".pack", copied=copied, handed=sum(sizes) - copied):
         return [
-            _divide(a if own else a.copy(), divisor)
-            for a, own in zip(arrays, owned)
+            _divide(a if dev else a.copy(), divisor)
+            for a, dev in zip(arrays, device)
         ]
+
+
+def _stable_replica_id(replica_id: str) -> str:
+    """What precedes the ``:<uuid>`` of an incarnation: the id a group's
+    series are labelled with, as the Manager labels its own."""
+    return replica_id.split(":", 1)[0] or replica_id
 
 
 def _check_recv_buffer(out: np.ndarray, shape: Any, dtype: str) -> None:
@@ -272,17 +301,22 @@ class ProcessGroup(ABC):
         op: str = REDUCE_SUM,
         divisor: "Optional[int]" = None,
     ) -> Work:
-        """Resolves to one host array per leaf, in the leaf's shape and
-        dtype: the leaves reduced over the group by ``op`` and, where a
+        """Resolves to one array per leaf, in the leaf's shape and dtype:
+        the leaves reduced over the group by ``op`` and, where a
         ``divisor`` is given, divided by it in the leaf's dtype (the
         Manager's live participant count, which is not always ``size()``;
         ``REDUCE_AVG`` is the divisor ``size()``).  The group divides, in
         place where it owns the buffer it reduced into.  The result is
-        private to the caller for as long as the caller holds it (or any
-        view of it); it never aliases an ``np.ndarray`` the caller passed
-        in, and that array is not written; it may be read-only when it came
-        straight off the device (a ``jax.Array`` leaf at world size 1):
-        copy before writing into it."""
+        arrays the caller may not write: host arrays as a rule, private to
+        the caller for as long as the caller holds them (or any view of
+        them), never an alias of an ``np.ndarray`` the caller passed in,
+        and that array is not written.  A ``jax.Array`` leaf may come back
+        as a ``jax.Array``: at world size 1 with nothing to divide by the
+        mean over one participant is the leaf, and it comes back as itself,
+        on its devices, sharding intact (:func:`_allreduce_alone`; a
+        divisor above 1 there takes the host path).  Convert
+        (``np.array(x)``) before writing into a result or calling an
+        ``ndarray``-only method on it."""
 
     @abstractmethod
     def allgather(self, array: Any) -> Work:
@@ -338,11 +372,19 @@ class ProcessGroupDummy(ProcessGroup):
         self._rank = rank
         self._world = world
         self._errored: Optional[Exception] = None
+        self._bind_metrics("")
         self.configure_count = 0
 
     def configure(self, store_addr: str, replica_id: str, rank: int, world_size: int) -> None:
         self.configure_count += 1
         self._errored = None
+        self._bind_metrics(replica_id)
+
+    def _bind_metrics(self, replica_id: str) -> None:
+        self._metric_replica_id = _stable_replica_id(replica_id)
+        self._m_leaves_kept = _metrics.RING_LEAVES_KEPT.labels(
+            replica_id=self._metric_replica_id
+        )
 
     def abort(self) -> None:
         self._errored = RuntimeError("aborted")
@@ -362,13 +404,8 @@ class ProcessGroupDummy(ProcessGroup):
         op: str = REDUCE_SUM,
         divisor: "Optional[int]" = None,
     ) -> Work:
-        return completed_work(
-            _allreduce_alone(
-                [_as_numpy(a) for a in arrays],
-                [_off_device(a) for a in arrays],
-                divisor,
-            )
-        )
+        by = self._world if op == REDUCE_AVG else divisor
+        return completed_work(_allreduce_alone(arrays, by, self._m_leaves_kept))
 
     def allgather(self, array: Any) -> Work:
         return completed_work([_as_numpy(array).copy()])
@@ -559,11 +596,11 @@ class ProcessGroupTCP(ProcessGroup):
         )
 
     def _bind_metrics(self) -> None:
-        """``torchft_ring_buffers_total`` children by pool hit, under the
-        stable replica id (what precedes the ``:<uuid>`` of an incarnation,
-        as the Manager labels its series)."""
-        self._metric_replica_id = (
-            self._replica_id.split(":", 1)[0] or self._replica_id
+        """``torchft_ring_buffers_total`` children by pool hit and
+        ``torchft_ring_leaves_kept_total``, under the stable replica id."""
+        self._metric_replica_id = _stable_replica_id(self._replica_id)
+        self._m_leaves_kept = _metrics.RING_LEAVES_KEPT.labels(
+            replica_id=self._metric_replica_id
         )
         self._m_ring_buffers = {
             hit: _metrics.RING_BUFFERS.labels(
@@ -1136,7 +1173,9 @@ class ProcessGroupTCP(ProcessGroup):
     ) -> Work:
         """``divisor``: what the reduced result is divided by, in place, by
         the ring that owns the buffer; ``REDUCE_AVG`` is the divisor
-        ``size()``."""
+        ``size()``.  Alone (world size 1) there is no ring: the op still
+        takes its turn on the worker thread, and what it resolves to is
+        :func:`_allreduce_alone`'s to decide."""
         deadline_budget = self._timeout
         # The ring, opened (manager.PHASE_PARTS ``ring.*``): each part is
         # timed here, where it runs, as a part of the caller's open phase
@@ -1146,9 +1185,14 @@ class ProcessGroupTCP(ProcessGroup):
         nbytes = sum(int(getattr(a, "nbytes", 0)) for a in arrays)
         queued = _tracing.phase(".queue").begin()
 
-        def run() -> List[np.ndarray]:
+        def run() -> "List[Any]":
             queued.end()
             with _tracing.under(whole):
+                by = self._world if op == REDUCE_AVG else divisor
+                if self._world == 1:
+                    # the post-failure shrunken-group hot path: with nothing
+                    # to divide by, a device leaf stays where it is
+                    return _allreduce_alone(arrays, by, self._m_leaves_kept)
                 # device→host materialization happens HERE, on the PG worker:
                 # for jax-array inputs `_as_numpy` blocks on device compute +
                 # transfer, and doing that on the caller thread would stall it
@@ -1157,25 +1201,14 @@ class ProcessGroupTCP(ProcessGroup):
                 # rides behind the next fragment's inner steps).
                 deadline = time.monotonic() + deadline_budget
                 # A ring re-orders on the host whatever does not arrive in C
-                # order, so such leaves leave the device flat; alone, a leaf
-                # is handed through as it arrives and nothing is done.
-                relay = (
-                    [i for i, a in enumerate(arrays) if _in_device_order(a)]
-                    if self._world > 1
-                    else []
-                )
+                # order, so such leaves leave the device flat.
+                relay = [i for i, a in enumerate(arrays) if _in_device_order(a)]
                 with _tracing.phase(
                     ".d2h",
                     bytes=nbytes,
                     relaid=sum(arrays[i].nbytes for i in relay),
                 ):
                     np_arrays = _to_host(arrays, relay)
-                by = self._world if op == REDUCE_AVG else divisor
-                if self._world == 1:
-                    # the post-failure shrunken-group hot path
-                    return _allreduce_alone(
-                        np_arrays, [_off_device(a) for a in arrays], by
-                    )
                 results = self._allreduce_coalesced(np_arrays, op, by, deadline)
                 for i in relay:  # back in the leaf's own shape: a view
                     results[i] = results[i].reshape(arrays[i].shape)

@@ -1176,6 +1176,14 @@ RING_BUFFERS = counter(
     "(miss); hit / (hit + miss) over a step is the ring-buffer reuse share",
     ("replica_id", "result"),
 )
+RING_LEAVES_KEPT = counter(
+    "torchft_ring_leaves_kept_total",
+    "Leaves of a plain allreduce that a group alone (world size 1, nothing "
+    "to divide by) handed back as the jax.Array they came in as, so they "
+    "never left the device (parallel/process_group.py); 0 for a group that "
+    "rings, and for host leaves, which are copied",
+    ("replica_id",),
+)
 LINK_GOODPUT = gauge(
     "torchft_link_goodput_bytes_per_s",
     "Passively measured link goodput by peer host and transfer plane "
