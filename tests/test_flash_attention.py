@@ -264,3 +264,110 @@ class TestFlashUnequalWidths:
             top = float(np.abs(np.asarray(b)).max()) + 1e-12
             np.testing.assert_allclose(np.asarray(a) / top, np.asarray(b) / top,
                                        atol=1e-5, err_msg=f"d{name}")
+
+
+def _masked_softmax(q, k, v, window):
+    """The window's definition, written out: query ``i`` sees key ``j`` iff
+    ``0 <= i - j < window``; grouped queries read key-value head ``h // rep``."""
+    t, d = q.shape[1], q.shape[-1]
+    rep = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+    ahead = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
+    s = jnp.where((ahead >= 0) & (ahead < window), s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+
+
+class TestFlashWindow:
+    """A sliding window: the three kernels walk the band's tiles only, against
+    a plain masked softmax.  The sequences are several tiles long (a window's
+    tile is at most half of it, 128 at the least)."""
+
+    # (T, window): shorter than a tile; not a multiple of the tile (a band of
+    # three 128-tiles); bands of three and four 256-tiles with one wholly
+    # inside, which runs unmasked; one key short of causal; equal to T and
+    # longer (plain causal attention)
+    CASES = [(512, 100), (512, 200), (1024, 512), (1024, 640), (384, 383), (384, 384), (384, 1000)]
+
+    @pytest.mark.parametrize("t,window", CASES)
+    def test_forward_matches_a_masked_softmax(self, t, window):
+        q, k, v = _qkv(t=t, h=4, hkv=2, seed=t + window)
+        out = flash_attention(q, k, v, window=window)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(_masked_softmax(q, k, v, window)), atol=2e-5, rtol=1e-5)
+
+    @pytest.mark.parametrize("t,window", CASES)
+    def test_three_gradients_match_a_masked_softmax(self, t, window):
+        q, k, v = _qkv(t=t, h=4, hkv=1, seed=t + window)  # grouped four to one
+        weight = jax.random.normal(jax.random.PRNGKey(9), q.shape)
+
+        def grads(fn):
+            return jax.grad(lambda q, k, v: (fn(q, k, v) * weight).sum(), argnums=(0, 1, 2))(q, k, v)
+
+        got = grads(lambda q, k, v: flash_attention(q, k, v, window=window))
+        want = grads(lambda q, k, v: _masked_softmax(q, k, v, window))
+        for name, a, b in zip("qkv", got, want):
+            top = float(np.abs(np.asarray(b)).max()) + 1e-12
+            np.testing.assert_allclose(np.asarray(a) / top, np.asarray(b) / top,
+                                       atol=1e-5, err_msg=f"d{name}")
+
+    def test_the_band_is_the_tiles_a_query_tile_can_see(self):
+        from torchft_tpu.ops.flash_attention import _tiles
+
+        # square tiles of half the window, of those that divide the sequence;
+        # the diagonal tile and those back to the tile of key i - window + 1
+        assert _tiles(8192, 8192, 128, 2048) == (1024, 1024, 3)
+        assert _tiles(8192, 8192, 128, 1024) == (512, 512, 3)
+        assert _tiles(512, 512, 64, 1) == (128, 128, 1)
+        assert _tiles(512, 512, 64, 129) == (128, 128, 2) and _tiles(512, 512, 64, 130) == (128, 128, 3)
+        assert _tiles(384, 384, 128, 2048) == (128, 128, 3)  # never more than there are
+        assert _tiles(2048, 1024, 64, None) == (1024, 1024, None)
+        with pytest.raises(ValueError, match="128"):
+            _tiles(100, 100, 64, 64)
+
+    @pytest.mark.parametrize("window,names", [
+        (None, {"_fwd_kernel", "_bwd_kv_kernel", "_bwd_q_kernel"}),
+        (256, {"_fwd_kernel", "_bwd_kv_kernel", "_bwd_q_kernel"}),  # no shorter than the sequence
+        (100, {"_fwd_window_kernel", "_bwd_kv_window_kernel", "_bwd_q_window_kernel"}),
+    ])
+    def test_windowed_calls_carry_names_of_their_own(self, window, names):
+        """A trace tells window from global calls; the window-less call keeps
+        the names every existing cell's roofline share finds its kernels by."""
+        q, k, v = _qkv(t=256)
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda q, k, v: flash_attention(q, k, v, window=window).sum(), argnums=(0, 1, 2)))(q, k, v))
+        import re
+
+        assert set(re.findall(r"name=(_\w+kernel)", text)) == names
+
+    def test_the_window_less_call_is_what_it_was(self):
+        """``window=None`` and a window no shorter than the sequence run the
+        causal kernels: bit for bit the call without the argument."""
+        q, k, v = _qkv(t=256, seed=11)
+
+        def both(**kw):
+            out, grads = jax.value_and_grad(
+                lambda q, k, v: (flash_attention(q, k, v, **kw) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+            return [out, *grads]
+
+        plain = both()
+        for kw in ({"window": None}, {"window": 256}, {"causal": True, "window": 9999}):
+            for a, b in zip(both(**kw), plain):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_a_window_needs_causal_self_attention(self):
+        q, k, v = _qkv(t=128)
+        with pytest.raises(ValueError, match="window"):
+            flash_attention(q, k, v, causal=False, window=64)
+        with pytest.raises(ValueError, match="window"):
+            flash_attention(q, k, v, window=0)
+        with pytest.raises(ValueError, match="window"):
+            dense_attention(q, k, v, causal=False, window=64)
+
+    def test_dense_attention_takes_the_same_window(self):
+        q, k, v = _qkv(t=128, seed=4)
+        np.testing.assert_allclose(
+            np.asarray(dense_attention(q, k, v, window=40)), np.asarray(_masked_softmax(q, k, v, 40)),
+            atol=2e-5, rtol=1e-5)
+        np.testing.assert_array_equal(
+            np.asarray(dense_attention(q, k, v, window=128)), np.asarray(dense_attention(q, k, v)))

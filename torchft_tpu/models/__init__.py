@@ -1,9 +1,11 @@
 """Model families: flagship llama-style transformer + the reference's
 example-scale CNN/MLP (reference train_ddp.py:84-102, train_diloco.py:76-120),
-and a sparse hybrid decoder (``kimi_linear``: delta-rule linear attention beside
-latent attention, a chip's share of sigmoid-routed experts)."""
+a sparse hybrid decoder (``kimi_linear``: delta-rule linear attention beside
+latent attention, a chip's share of sigmoid-routed experts) and a sparse decoder
+with windowed beside global attention (``afmoe``: gated heads, norms on both
+sides of a sub-block, the same expert layer)."""
 
-from torchft_tpu.models import cnn, kimi_linear, mlp, transformer
+from torchft_tpu.models import afmoe, cnn, kimi_linear, mlp, transformer
 from torchft_tpu.models.transformer import (
     TransformerConfig,
     init_params,
@@ -14,6 +16,7 @@ from torchft_tpu.models.transformer import (
 )
 
 __all__ = [
+    "afmoe",
     "cnn",
     "kimi_linear",
     "mlp",

@@ -39,12 +39,13 @@ chips that hold the other experts and layers are not this program's.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from torchft_tpu.models import moe
 from torchft_tpu.models.moe import HeldMoEConfig, held_moe_ffn, init_held_moe_params
 from torchft_tpu.models.transformer import (
     _embed,
@@ -342,44 +343,49 @@ _unstack.defvjp(lambda leaf: (_unstack(leaf), None), lambda _, cts: (jnp.stack(c
 
 
 def _run_layers(
-    x: jax.Array, groups: "Dict[str, Params]", cfg: KimiLinearConfig
+    x: jax.Array, groups: "Dict[str, Params]", kinds: "Sequence[Kind]",
+    make_layer: "Callable[[Kind], Any]",
 ) -> "Tuple[jax.Array, Dict[str, jax.Array]]":
-    """Walks ``layer_plan``; ``groups`` are the stacked parameter groups (the
-    ``moe`` one may carry the ``router_bias`` buffer).  Returns the routing
-    stats of the expert layers stacked in layer order."""
+    """Walks ``layer_plan(kinds)``.  ``groups`` are the stacked parameter
+    groups by the names the kinds use (an expert group may carry the
+    ``router_bias`` buffer); ``make_layer(kind)`` gives ``layer(x, attention
+    params, ffn params) -> (x, routing stats or None)``.  Returns the routing
+    stats of the expert layers stacked in layer order.  (``models/afmoe.py``
+    walks its layers through this too.)"""
+    names = tuple(groups)
     stats: "List[Dict[str, jax.Array]]" = []
 
     def walk(x, pattern, taken):
         """One pass over ``pattern``; ``taken[g]`` holds this pass's layers
         of group ``g`` stacked on the first dimension."""
-        seen = {g: 0 for g in GROUPS}
+        seen = {g: 0 for g in names}
         found = []
         layers = {g: {name: _unstack(leaf) for name, leaf in taken[g].items() if leaf.shape[0]}
-                  for g in GROUPS}
+                  for g in names}
         for kind in pattern:
             pick = [{name: ls[seen[g]] for name, ls in layers[g].items()} for g in kind]
             for g in kind:
                 seen[g] += 1
-            x, st = _make_layer(kind, cfg)(x, *pick)
+            x, st = make_layer(kind)(x, *pick)
             if st is not None:
                 found.append(st)
         stacked = jax.tree_util.tree_map(lambda *s: jnp.stack(s), *found) if found else None
         return x, stacked
 
-    plan = layer_plan(layer_kinds(cfg))
-    bounds = {g: (0,) for g in GROUPS}
+    plan = layer_plan(kinds)
+    bounds = {g: (0,) for g in names}
     for pattern, repeats in plan:
-        for g in GROUPS:
+        for g in names:
             bounds[g] += (bounds[g][-1] + repeats * sum(1 for kind in pattern if g in kind),)
     runs = {g: jax.tree_util.tree_map(lambda leaf, g=g: _cut(leaf, bounds[g]), groups[g])
-            for g in GROUPS}
+            for g in names}
     for at, (pattern, repeats) in enumerate(plan):
         taken = {
             g: jax.tree_util.tree_map(
                 lambda run, c=sum(1 for kind in pattern if g in kind): run[at].reshape(
                     (repeats, c) + run[at].shape[1:]),
                 runs[g], is_leaf=lambda x: isinstance(x, tuple))
-            for g in GROUPS}
+            for g in names}
         if repeats == 1:
             x, st = walk(x, pattern, jax.tree_util.tree_map(lambda leaf: leaf[0], taken))
         else:
@@ -432,7 +438,8 @@ def forward_hidden(
     given)."""
     with jax.named_scope("embed"):
         x = _embed(params, tokens, cfg, sharded=False)
-    return _run_layers(x, _groups(params, router_bias), cfg)
+    return _run_layers(x, _groups(params, router_bias), layer_kinds(cfg),
+                       lambda kind: _make_layer(kind, cfg))
 
 
 def forward(
@@ -484,15 +491,8 @@ def record_routing_stats(stats: "Dict[str, Any]", cfg: KimiLinearConfig) -> None
     ``torchft_moe_assignments_total{layer,expert}`` and
     ``torchft_moe_tokens_unrouted_total{layer}`` (layers by their number in
     the model, experts by their published id)."""
-    from torchft_tpu.utils import metrics
-
     expert_layers = [i + 1 for i, kind in enumerate(layer_kinds(cfg)) if kind[1] == "moe"]
-    assignments, unrouted = np.asarray(stats["assignments"]), np.asarray(stats["unrouted"])
-    for row, layer in enumerate(expert_layers):
-        for slot, expert in enumerate(cfg.held_experts):
-            metrics.MOE_ASSIGNMENTS.labels(layer=str(layer), expert=str(expert)).inc(
-                int(assignments[row, slot]))
-        metrics.MOE_TOKENS_UNROUTED.labels(layer=str(layer)).inc(int(unrouted[row]))
+    moe.record_routing_stats(stats, expert_layers, cfg.held_experts)
 
 
 __all__ = [

@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -366,11 +366,31 @@ def held_moe_ffn(
     return y, {"assignments": assignments, "unrouted": unrouted}
 
 
+def record_routing_stats(
+    stats: "Dict[str, Any]", expert_layers: "Sequence[int]", held: "Sequence[int]",
+) -> None:
+    """Feeds one batch's routing stats (``held_moe_ffn``'s, stacked over a
+    model's expert layers) to the ``utils/metrics`` counters
+    ``torchft_moe_assignments_total{layer,expert}`` and
+    ``torchft_moe_tokens_unrouted_total{layer}``: ``expert_layers`` are the
+    stacked rows' numbers in the model, ``held`` the published ids of the
+    experts held here."""
+    from torchft_tpu.utils import metrics
+
+    assignments, unrouted = np.asarray(stats["assignments"]), np.asarray(stats["unrouted"])
+    for row, layer in enumerate(expert_layers):
+        for slot, expert in enumerate(held):
+            metrics.MOE_ASSIGNMENTS.labels(layer=str(layer), expert=str(expert)).inc(
+                int(assignments[row, slot]))
+        metrics.MOE_TOKENS_UNROUTED.labels(layer=str(layer)).inc(int(unrouted[row]))
+
+
 __all__ = [
     "HeldMoEConfig",
     "init_held_moe_params",
     "route_sigmoid",
     "held_moe_ffn",
+    "record_routing_stats",
     "MoEConfig",
     "init_moe_params",
     "moe_param_specs",

@@ -15,6 +15,13 @@ Standard FlashAttention-2 scheme, fwd + bwd:
   kernel and dQ over K/V blocks in another.
 - causal block skipping: fully-masked tiles are skipped via ``pl.when``
   (half the FLOPs at long T), diagonal tiles masked elementwise.
+- a sliding window (``window=w``: query ``i`` sees key ``j`` iff ``0 <= i - j
+  < w``): the same three kernels on a grid whose last axis walks only the
+  band's ``ceil((w - 1) / blk) + 1`` tiles beside each query (key) tile, so
+  tiles older than the window are neither computed nor fetched; the band's
+  two edges are masked elementwise.  These calls are named
+  ``_fwd_window_kernel`` / ``_bwd_kv_window_kernel`` / ``_bwd_q_window_kernel``
+  in the compiled program, so a trace tells them from the global ones.
 - dtypes: matmuls run in the input dtype (bf16 on TPU) with f32
   accumulation; softmax statistics and accumulators are f32 scratch.
 
@@ -47,16 +54,74 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _block_size(t: int, d: int) -> int:
+def _block_size(t: int, d: int, at_most: int = 1024) -> int:
     """Largest tile that divides ``t`` — bigger tiles amortize the
     per-block softmax bookkeeping.  1024 engages only at head_dim <= 256
     (measured +3% whole-step at the d256 flagship; beyond d256 the
-    q/k/v/acc tiles alone would crowd VMEM)."""
+    q/k/v/acc tiles alone would crowd VMEM).  ``at_most`` (128 or more)
+    caps it."""
     sizes = (1024, 512, 256, 128) if d <= 256 else (512, 256, 128)
     for blk in sizes:
-        if t % blk == 0:
+        if t % blk == 0 and blk <= at_most:
             return blk
     raise ValueError(f"flash attention requires seq len % 128 == 0, got {t}")
+
+
+# A windowed call's tile is at most this share of the window.  A query tile
+# computes ``window + blk`` keys' worth of tiles for ``window`` live keys, so
+# a smaller tile wastes less, but costs more a pair: on a v5e at ``T`` 8192,
+# window 2048, heads of 128 (forward | forward + backward of one layer, ms)
+# tiles of 1024 read 9.0 | 25.5, of 512 13.3 | 29.4, of 256 23.1 | 54.4
+# against the causal call's 14.7 | 45.0.
+_WINDOW_TILES = 2
+
+
+def _tiles(tq: int, tk: int, width: int, window: "Optional[int]"):
+    """``(blk_q, blk_k, band)`` of a call.  Under a window: square tiles of at
+    most the window's ``_WINDOW_TILES``-th, and ``band``, how many key tiles a
+    query tile sees (and how many query tiles see a key tile): the diagonal
+    one and those before it down to the tile of key ``i - window + 1`` for
+    the tile's first query ``i``.  ``band`` is None without a window."""
+    if window is None:
+        return _block_size(tq, width), _block_size(tk, width), None
+    blk = _block_size(tq, width, at_most=max(window // _WINDOW_TILES, 128))
+    return blk, blk, min(tk // blk, -(-(window - 1) // blk) + 1)
+
+
+def _key_tile(i, step, band):
+    """The key tile the inner grid axis is at beside query tile ``i``:
+    ``step`` itself, or the band's, its last the diagonal one; a tile before
+    the sequence's start repeats tile 0, which is fetched once."""
+    return step if band is None else jnp.maximum(i - (band - 1) + step, 0)
+
+
+def _query_tile(j, step, band, n):
+    """The query tile the inner grid axis is at beside key tile ``j``:
+    ``step`` itself, or the band's, its first the diagonal one; a tile past
+    the last of the ``n`` repeats it."""
+    return step if band is None else jnp.minimum(j + step, n - 1)
+
+
+def _visible(rq, rk, window):
+    """The causal mask of query rows ``rq`` on key rows ``rk``, and under a
+    window its older edge."""
+    if window is None:
+        return rq >= rk
+    return jnp.logical_and(rq >= rk, rq - rk < window)
+
+
+def _on_live_tiles(needed, window, ahead, blk, tile):
+    """Runs ``tile(masked)`` where the tile is ``needed``.  Under a window a
+    tile ``ahead()`` tiles before the diagonal that lies wholly inside the
+    band (every ``i - j`` in ``[1, window)``) runs without the mask's compares
+    and selects: of the band's ``w / blk + 1`` tiles only the two at its
+    edges need them."""
+    if window is None:
+        pl.when(needed)(lambda: tile(True))
+        return
+    inside = jnp.logical_and(ahead() >= 1, (ahead() + 1) * blk <= window)
+    pl.when(jnp.logical_and(needed, inside))(lambda: tile(False))
+    pl.when(jnp.logical_and(needed, jnp.logical_not(inside)))(lambda: tile(True))
 
 
 # ---------------------------------------------------------------------------
@@ -66,30 +131,36 @@ def _block_size(t: int, d: int) -> int:
 
 def _fwd_kernel(
     offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s,
-    *, scale, causal, blk_q, blk_k
+    *, scale, causal, blk_q, blk_k, window=None
 ):
     """offs_ref: SMEM int32 [2] = (q_offset, k_offset) GLOBAL positions of
     this call's first query/key row — the ring composition runs the kernel
-    on local chunks whose causal relation depends on the shard offsets."""
+    on local chunks whose causal relation depends on the shard offsets.
+
+    ``window``: the last grid axis walks the band's tiles only (square
+    tiles, no offsets), the last of them the diagonal tile."""
     i = pl.program_id(1)
-    j = pl.program_id(2)
+    step = pl.program_id(2)
     nj = pl.num_programs(2)
+    j = step if window is None else i - (nj - 1) + step
     q_off, k_off = offs_ref[0], offs_ref[1]
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _():
         acc[:] = jnp.zeros_like(acc)
         m_s[:] = jnp.full_like(m_s, _NEG_INF)
         l_s[:] = jnp.zeros_like(l_s)
 
-    # causal: this tile is live unless every key position exceeds every
-    # query position in the block
-    needed = jnp.logical_or(
-        not causal, k_off + j * blk_k <= q_off + i * blk_q + blk_q - 1
-    )
+    if window is None:
+        # causal: this tile is live unless every key position exceeds every
+        # query position in the block
+        needed = jnp.logical_or(
+            not causal, k_off + j * blk_k <= q_off + i * blk_q + blk_q - 1
+        )
+    else:
+        needed = j >= 0  # a tile before the sequence's start
 
-    @pl.when(needed)
-    def _():
+    def tile(masked):
         q = q_ref[0]
         s = jax.lax.dot_general(
             q,
@@ -97,14 +168,14 @@ def _fwd_kernel(
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale  # [blk_q, blk_k]
-        if causal:
+        if causal and masked:
             rq = q_off + i * blk_q + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 0
             )
             rk = k_off + j * blk_k + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1
             )
-            s = jnp.where(rq >= rk, s, _NEG_INF)
+            s = jnp.where(_visible(rq, rk, window), s, _NEG_INF)
         m_prev = m_s[:, :1]
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         # A query row with zero live keys so far has m_new == _NEG_INF, so
@@ -127,7 +198,9 @@ def _fwd_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(j == nj - 1)
+    _on_live_tiles(needed, window, lambda: nj - 1 - step, blk_k, tile)
+
+    @pl.when(step == nj - 1)
     def _():
         l = jnp.maximum(l_s[:, :1], 1e-30)
         o_ref[0] = (acc[:] / l).astype(o_ref.dtype)
@@ -142,24 +215,29 @@ def _fwd(
     scale: float,
     causal: bool,
     offsets: "Optional[jax.Array]" = None,
+    window: "Optional[int]" = None,
 ) -> "Tuple[jax.Array, jax.Array]":
     bh, tq, d = q3.shape
     tk, dv = k3.shape[1], v3.shape[2]  # values may be narrower than q/k
-    blk_q = _block_size(tq, max(d, dv))
-    blk_k = _block_size(tk, max(d, dv))
     if offsets is None:
         offsets = jnp.zeros((2,), jnp.int32)
-    grid = (bh, tq // blk_q, tk // blk_k)
+    blk_q, blk_k, band = _tiles(tq, tk, max(d, dv), window)
+    grid = (bh, tq // blk_q, band or tk // blk_k)
+
+    def kv(b, i, step):
+        return (b, _key_tile(i, step, band), 0)
+
     o, lse = pl.pallas_call(
         functools.partial(
-            _fwd_kernel, scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k
+            _fwd_kernel, scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k,
+            window=window,
         ),
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, blk_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, blk_k, dv), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, blk_k, d), kv),
+            pl.BlockSpec((1, blk_k, dv), kv),
         ],
         out_specs=(
             pl.BlockSpec((1, blk_q, dv), lambda b, i, j: (b, i, 0)),
@@ -181,7 +259,7 @@ def _fwd(
         interpret=_interpret(),
         # the benchmark finds the three kernels in a trace by these names
         # (benchmarks/families/*.py FLASH_KERNELS)
-        name="_fwd_kernel",
+        name="_fwd_kernel" if window is None else "_fwd_window_kernel",
     )(offsets.astype(jnp.int32), q3, k3, v3)
     return o, lse[:, 0]
 
@@ -191,7 +269,7 @@ def _fwd(
 # ---------------------------------------------------------------------------
 
 
-def _recompute_p(q, k, lse_row, scale, causal, q_pos0, k_pos0):
+def _recompute_p(q, k, lse_row, scale, causal, q_pos0, k_pos0, window=None):
     """exp(q·kᵀ·scale − L) with the causal mask — shared by both bwd
     kernels.  lse_row: [1, blk_q] f32 lane vector (reshaped to a column
     here; Mosaic relayout).  q_pos0/k_pos0: GLOBAL position of the first
@@ -204,35 +282,40 @@ def _recompute_p(q, k, lse_row, scale, causal, q_pos0, k_pos0):
     if causal:
         rq = q_pos0 + jax.lax.broadcasted_iota(jnp.int32, p.shape, 0)
         rk = k_pos0 + jax.lax.broadcasted_iota(jnp.int32, p.shape, 1)
-        p = jnp.where(rq >= rk, p, 0.0)
+        p = jnp.where(_visible(rq, rk, window), p, 0.0)
     return p
 
 
 def _bwd_kv_kernel(
     offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal, blk_q, blk_k,
+    window=None,
 ):
     j = pl.program_id(1)  # K/V block (outer)
-    i = pl.program_id(2)  # Q block (inner, accumulated)
+    step = pl.program_id(2)  # Q block (inner, accumulated)
     ni = pl.num_programs(2)
+    # under a window: the band's query tiles, the first the diagonal one
+    i = step if window is None else j + step
     q_off, k_off = offs_ref[0], offs_ref[1]
 
-    @pl.when(i == 0)
+    @pl.when(step == 0)
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    needed = jnp.logical_or(
-        not causal, q_off + i * blk_q + blk_q - 1 >= k_off + j * blk_k
-    )
+    if window is None:
+        needed = jnp.logical_or(
+            not causal, q_off + i * blk_q + blk_q - 1 >= k_off + j * blk_k
+        )
+    else:
+        needed = i < pl.num_programs(1)  # a tile past the sequence's end
 
-    @pl.when(needed)
-    def _():
+    def tile(masked):
         q = q_ref[0]
         do = do_ref[0]
         p = _recompute_p(
-            q, k_ref[0], lse_ref[0], scale, causal,
-            q_off + i * blk_q, k_off + j * blk_k,
+            q, k_ref[0], lse_ref[0], scale, causal and masked,
+            q_off + i * blk_q, k_off + j * blk_k, window,
         )
         pt = p.astype(q.dtype)
         dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
@@ -248,7 +331,9 @@ def _bwd_kv_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(i == ni - 1)
+    _on_live_tiles(needed, window, lambda: step, blk_k, tile)
+
+    @pl.when(step == ni - 1)
     def _():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -256,27 +341,31 @@ def _bwd_kv_kernel(
 
 def _bwd_q_kernel(
     offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-    dq_ref, dq_acc, *, scale, causal, blk_q, blk_k,
+    dq_ref, dq_acc, *, scale, causal, blk_q, blk_k, window=None,
 ):
     i = pl.program_id(1)  # Q block (outer)
-    j = pl.program_id(2)  # K/V block (inner, accumulated)
+    step = pl.program_id(2)  # K/V block (inner, accumulated)
     nj = pl.num_programs(2)
+    # under a window: the band's key tiles, the last the diagonal one
+    j = step if window is None else i - (nj - 1) + step
     q_off, k_off = offs_ref[0], offs_ref[1]
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    needed = jnp.logical_or(
-        not causal, k_off + j * blk_k <= q_off + i * blk_q + blk_q - 1
-    )
+    if window is None:
+        needed = jnp.logical_or(
+            not causal, k_off + j * blk_k <= q_off + i * blk_q + blk_q - 1
+        )
+    else:
+        needed = j >= 0
 
-    @pl.when(needed)
-    def _():
+    def tile(masked):
         q = q_ref[0]
         p = _recompute_p(
-            q, k_ref[0], lse_ref[0], scale, causal,
-            q_off + i * blk_q, k_off + j * blk_k,
+            q, k_ref[0], lse_ref[0], scale, causal and masked,
+            q_off + i * blk_q, k_off + j * blk_k, window,
         )
         dp = jax.lax.dot_general(
             do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
@@ -288,7 +377,9 @@ def _bwd_q_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(j == nj - 1)
+    _on_live_tiles(needed, window, lambda: nj - 1 - step, blk_k, tile)
+
+    @pl.when(step == nj - 1)
     def _():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
@@ -297,13 +388,20 @@ def _bwd(
     q3, k3, v3, o3, lse, do3, scale: float, causal: bool,
     offsets: "Optional[jax.Array]" = None,
     delta: "Optional[jax.Array]" = None,
+    window: "Optional[int]" = None,
 ) -> "Tuple[jax.Array, jax.Array, jax.Array]":
     bh, tq, d = q3.shape
     tk, d_v = k3.shape[1], v3.shape[2]
-    blk = _block_size(tq, max(d, d_v))
-    blk_kk = _block_size(tk, max(d, d_v))
+    blk, blk_kk, band = _tiles(tq, tk, max(d, d_v), window)
     n = tq // blk
     nk = tk // blk_kk
+
+    def q_of(jj, step):
+        return _query_tile(jj, step, band, n)
+
+    def kv_of(ii, step):
+        return _key_tile(ii, step, band)
+
     if offsets is None:
         offsets = jnp.zeros((2,), jnp.int32)
     offsets = offsets.astype(jnp.int32)
@@ -319,17 +417,17 @@ def _bwd(
     dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_kv_kernel, scale=scale, causal=causal, blk_q=blk,
-            blk_k=blk_kk,
+            blk_k=blk_kk, window=window,
         ),
-        grid=(bh, nk, n),
+        grid=(bh, nk, band or n),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, blk, d), lambda b, jj, ii: (b, ii, 0)),     # q
+            pl.BlockSpec((1, blk, d), lambda b, jj, ii: (b, q_of(jj, ii), 0)),     # q
             pl.BlockSpec((1, blk_kk, d), lambda b, jj, ii: (b, jj, 0)),  # k
             pl.BlockSpec((1, blk_kk, d_v), lambda b, jj, ii: (b, jj, 0)),  # v
-            pl.BlockSpec((1, blk, d_v), lambda b, jj, ii: (b, ii, 0)),     # do
-            pl.BlockSpec((1, 1, blk), lambda b, jj, ii: (b, 0, ii)),  # lse
-            pl.BlockSpec((1, 1, blk), lambda b, jj, ii: (b, 0, ii)),  # delta
+            pl.BlockSpec((1, blk, d_v), lambda b, jj, ii: (b, q_of(jj, ii), 0)),     # do
+            pl.BlockSpec((1, 1, blk), lambda b, jj, ii: (b, 0, q_of(jj, ii))),  # lse
+            pl.BlockSpec((1, 1, blk), lambda b, jj, ii: (b, 0, q_of(jj, ii))),  # delta
         ],
         out_specs=(
             pl.BlockSpec((1, blk_kk, d), lambda b, jj, ii: (b, jj, 0)),
@@ -344,21 +442,21 @@ def _bwd(
             pltpu.VMEM((blk_kk, d_v), jnp.float32),
         ],
         interpret=_interpret(),
-        name="_bwd_kv_kernel",
+        name="_bwd_kv_kernel" if window is None else "_bwd_kv_window_kernel",
     )(offsets, q3, k3, v3, do3, lse3, delta)
 
     # q kernel grid = (b, i, j): index maps receive (b, q_block, kv_block)
     dq = pl.pallas_call(
         functools.partial(
             _bwd_q_kernel, scale=scale, causal=causal, blk_q=blk,
-            blk_k=blk_kk,
+            blk_k=blk_kk, window=window,
         ),
-        grid=(bh, n, nk),
+        grid=(bh, n, band or nk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, blk, d), lambda b, ii, jj: (b, ii, 0)),     # q
-            pl.BlockSpec((1, blk_kk, d), lambda b, ii, jj: (b, jj, 0)),  # k
-            pl.BlockSpec((1, blk_kk, d_v), lambda b, ii, jj: (b, jj, 0)),  # v
+            pl.BlockSpec((1, blk_kk, d), lambda b, ii, jj: (b, kv_of(ii, jj), 0)),  # k
+            pl.BlockSpec((1, blk_kk, d_v), lambda b, ii, jj: (b, kv_of(ii, jj), 0)),  # v
             pl.BlockSpec((1, blk, d_v), lambda b, ii, jj: (b, ii, 0)),     # do
             pl.BlockSpec((1, 1, blk), lambda b, ii, jj: (b, 0, ii)),  # lse
             pl.BlockSpec((1, 1, blk), lambda b, ii, jj: (b, 0, ii)),  # delta
@@ -367,7 +465,7 @@ def _bwd(
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), q3.dtype),
         scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)],
         interpret=_interpret(),
-        name="_bwd_q_kernel",
+        name="_bwd_q_kernel" if window is None else "_bwd_q_window_kernel",
     )(offsets, q3, k3, v3, do3, lse3, delta)
     return dq, dk, dv
 
@@ -377,26 +475,27 @@ def _bwd(
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash3(q3, k3, v3, scale, causal):
-    return _fwd(q3, k3, v3, scale, causal)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash3(q3, k3, v3, scale, causal, window):
+    return _fwd(q3, k3, v3, scale, causal, window=window)[0]
 
 
-def _flash3_fwd(q3, k3, v3, scale, causal):
-    o, lse = _fwd(q3, k3, v3, scale, causal)
+def _flash3_fwd(q3, k3, v3, scale, causal, window):
+    o, lse = _fwd(q3, k3, v3, scale, causal, window=window)
     return o, (q3, k3, v3, o, lse)
 
 
-def _flash3_bwd(scale, causal, res, do3):
+def _flash3_bwd(scale, causal, window, res, do3):
     q3, k3, v3, o3, lse = res
-    return _bwd(q3, k3, v3, o3, lse, do3, scale, causal)
+    return _bwd(q3, k3, v3, o3, lse, do3, scale, causal, window=window)
 
 
 _flash3.defvjp(_flash3_fwd, _flash3_bwd)
 
 
 def flash_attention(
-    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True
+    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,
+    window: "Optional[int]" = None,
 ) -> jax.Array:
     """Tiled fused causal attention, ``[B, T, H, D] -> [B, T, H, Dv]``.
 
@@ -406,15 +505,24 @@ def flash_attention(
     ``T % 128 == 0``; other shapes should use ``dense_attention``.
     ``v`` may have a head width ``Dv`` of its own (latent attention: queries
     and keys of 192 against values of 128); the scale is ``D ** -0.5``.
+    ``window``: query ``i`` sees key ``j`` iff ``0 <= i - j < window`` (causal
+    self-attention only); the kernels then walk the band's tiles alone.
     """
     b, t, h, d = q.shape
+    if window is not None:
+        if not causal or window < 1 or k.shape[1] != t:
+            raise ValueError(
+                "a window needs causal self-attention and at least one key"
+            )
+        if window >= t:
+            window = None  # no causal query looks further back than t - 1
     if h % k.shape[2] != 0:
         raise ValueError(
             f"query heads {h} not a multiple of kv heads {k.shape[2]}"
         )
     k, v = _expand_gqa(k, v, h)
     scale = 1.0 / math.sqrt(d)
-    out3 = _flash3(_to3(q), _to3(k), _to3(v), scale, causal)
+    out3 = _flash3(_to3(q), _to3(k), _to3(v), scale, causal, window)
     return _from3(out3, b, h)
 
 

@@ -128,12 +128,14 @@ def ring_attention_local(
 
 
 def dense_attention(
-    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True
+    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,
+    window: "Optional[int]" = None,
 ) -> jax.Array:
     """Plain (single-pass) causal attention over the full sequence,
     ``[B, T, H, D]`` — the cp=1 path; XLA shards it via constraint
     propagation (batch/head parallel). GQA: K/V with fewer heads are
-    broadcast up to the query head count.
+    broadcast up to the query head count.  ``window`` (causal only): query
+    ``i`` sees key ``j`` iff ``0 <= i - j < window``.
 
     Materializes the full ``[B, H, T, T]`` score matrix — O(T^2) HBM.
     Warns once per (B, H, T) at trace time beyond 4k context; use
@@ -171,9 +173,13 @@ def dense_attention(
         )
         / math.sqrt(d)
     )
+    if window is not None and not causal:
+        raise ValueError("a window needs causal attention")
     if causal:
         t = q.shape[1]
         mask = jnp.tril(jnp.ones((t, t), bool))
+        if window is not None:
+            mask = jnp.logical_and(mask, ~jnp.tril(jnp.ones((t, t), bool), -window))
         scores = jnp.where(mask[None, None], scores, _NEG_INF)
     p = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum(

@@ -1268,8 +1268,9 @@ MOE_ASSIGNMENTS = counter(
     "torchft_moe_assignments_total",
     "Token-to-expert assignments that landed on an expert this chip holds, "
     "by layer (its number in the model) and expert (its published id); fed "
-    "from models/kimi_linear.py routing_stats when a caller asks, never "
-    "inside a training step",
+    "from a sparse model's routing_stats (models/kimi_linear.py, "
+    "models/afmoe.py) through models/moe.py record_routing_stats when a "
+    "caller asks, never inside a training step",
     ("layer", "expert"),
 )
 MOE_TOKENS_UNROUTED = counter(
