@@ -1387,3 +1387,246 @@ class TestDeviceRelayout:
             del work, got
         assert pools == [["miss"], ["hit"], ["hit"]]
         _shutdown(pgs)
+
+
+class _SlowLeaf:
+    """A device leaf's stand-in: it can start its own host copy
+    (``copy_to_host_async``, which notes the call in ``log``), and handing
+    the host array over (``__array__``) waits until ``release`` is set, as
+    ``np.asarray`` of a ``jax.Array`` waits for a copy still under way."""
+
+    def __init__(self, name, value, log, release=None):
+        self.name, self.value, self.log, self.release = name, value, log, release
+        self.shape, self.dtype = value.shape, value.dtype
+        self.size, self.nbytes, self.ndim = value.size, value.nbytes, value.ndim
+
+    def copy_to_host_async(self):
+        self.log.append(("started", self.name))
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append(("asked", self.name))
+        if self.release is not None:
+            assert self.release.wait(timeout=30), f"{self.name} never released"
+        return self.value
+
+
+_BIG = (1 << 20) + 3  # elements: over BUCKET_BYTES, pads at world size 2
+
+
+def _pipeline_leaves(rank, log, release):
+    """Four leaves over ``BUCKET_BYTES``, alike, that ring alone and two
+    small ones that share a bucket ACROSS the second large one: the list
+    holds ``big0, a, big1, b, big2, last``, the plan rings ``big0 | a + b
+    | big1 | big2 | last``.  The last leaf's copy waits for ``release``."""
+    shapes = {"big0": _BIG, "a": 5, "big1": _BIG, "b": (2, 3), "big2": _BIG, "last": _BIG}
+    return [
+        _SlowLeaf(
+            name,
+            np.full(shape, rank + 1.0 + k, np.float32),
+            log,
+            release if name == "last" else None,
+        )
+        for k, (name, shape) in enumerate(shapes.items())
+    ]
+
+
+def _wait_for(logs, reached):
+    """Poll until ``reached()``; the logs are the message when it never is."""
+    deadline = time.monotonic() + 30
+    while not reached():
+        assert time.monotonic() < deadline, logs
+        time.sleep(0.01)
+
+
+def _prefetched(replica_id):
+    from torchft_tpu.utils import metrics
+
+    return sum(
+        metrics.RING_LEAVES_PREFETCHED.labels(
+            replica_id=replica_id, result=result
+        ).get()
+        for result in ("ready", "waited")
+    )
+
+
+class TestLinkAheadOfTheRing:
+    """At world size > 1 the device leaves' host copies are started ahead
+    of the ring, in the order the plan rings the buckets, and each bucket
+    waits only for its own leaves."""
+
+    @pytest.mark.parametrize("world", [2, 3])
+    def test_a_mixed_list_is_bitwise_each_leaf_rung_alone(
+        self, store, ring_spans, world
+    ):
+        """Large float32 leaves, a bfloat16 leaf that widens, small leaves
+        that share a bucket across the large ones, leaves the device holds
+        in another order of dimensions, an ``np.ndarray`` leaf: what every
+        rank gets is what ringing each leaf by itself gives (values whose
+        sums are exact, so that a chunk's order of additions cannot
+        tell)."""
+        import ml_dtypes
+
+        pgs = make_group(store, world, f"ahead-{world}")
+
+        def mixed(rank):
+            rng = np.random.default_rng(300 + rank)
+
+            def ints(shape, dtype=np.float32):
+                return rng.integers(-40, 40, size=shape).astype(dtype)
+
+            return [
+                _on_device(ints((1025, 1027))),
+                _on_device(ints(7)),
+                _on_device(ints((1 << 20) + 5, ml_dtypes.bfloat16)),
+                _on_device(ints((6, 4)), (1, 0)),
+                ints((1 << 20) + 3),  # the caller's host memory
+                _on_device(ints((3, 5, 7)), (0, 2, 1)),
+                _on_device(ints((1027, 1025)), (1, 0)),
+                _on_device(ints(11)),
+            ]
+
+        leaves = [mixed(r) for r in range(world)]
+        device_leaves = sum(not isinstance(x, np.ndarray) for x in leaves[0])
+        before = [_prefetched(f"rank{r}") for r in range(world)]
+
+        def alone(rank, _):
+            return [
+                pgs[rank].allreduce([np.asarray(x)], REDUCE_AVG).wait(timeout=30)[0]
+                for x in leaves[rank]
+            ]
+
+        want = run_parallel(world, alone)
+        assert [_prefetched(f"rank{r}") for r in range(world)] == before
+
+        def together(rank, _):
+            def once():
+                return pgs[rank].allreduce(leaves[rank], REDUCE_AVG).wait(timeout=30)
+
+            return ring_spans(once) if rank == 0 else (once(), None)
+
+        got = run_parallel(world, together)
+        for rank in range(world):
+            _assert_same_bits(got[rank][0], want[rank])
+            _assert_same_bits(got[rank][0], want[0])
+            # every device leaf was sent ahead, the host leaf was not
+            assert _prefetched(f"rank{rank}") == before[rank] + device_leaves
+        spans = got[0][1]
+        (nbytes,), (overlapped,) = (
+            _attr(spans, "ring.d2h", key) for key in ("bytes", "overlapped")
+        )
+        assert nbytes == sum(x.nbytes for x in leaves[0])
+        assert 0 <= overlapped <= nbytes - leaves[0][4].nbytes
+        _shutdown(pgs)
+
+    def test_a_bucket_rings_while_a_later_copy_is_still_under_way(self, store):
+        """The copies are started in the plan's order, not the list's
+        (``b`` before ``big1``), each before its bucket's turn and no
+        further ahead than the plan's largest bucket (one large leaf here),
+        and four buckets have rung on both ranks while the last leaf's copy
+        is still under way."""
+        world = 2
+        pgs = make_group(store, world, "ahead-pipeline")
+        release = threading.Event()
+        logs = [[] for _ in range(world)]
+        leaves = [_pipeline_leaves(r, logs[r], release) for r in range(world)]
+        for rank, pg in enumerate(pgs):
+            ring_one = pg._allreduce_one
+
+            def rung(array, *args, _one=ring_one, _log=logs[rank]):
+                out = _one(array, *args)
+                _log.append(("rung", array.size))
+                return out
+
+            pg._allreduce_one = rung
+        works = [pgs[r].allreduce(leaves[r], REDUCE_SUM) for r in range(world)]
+        _wait_for(logs, lambda: all(("asked", "last") in log for log in logs))
+        for log in logs:
+            assert log == [
+                ("started", "big0"), ("started", "a"), ("started", "b"),
+                ("started", "big1"), ("asked", "big0"), ("rung", _BIG),
+                ("asked", "a"), ("asked", "b"), ("rung", 11),
+                ("started", "big2"), ("asked", "big1"), ("rung", _BIG),
+                ("started", "last"), ("asked", "big2"), ("rung", _BIG),
+                ("asked", "last"),
+            ]
+        assert not any(w.done() for w in works)
+        release.set()
+        for work in works:
+            got = work.wait(timeout=30)
+            for k, (g, leaf) in enumerate(zip(got, leaves[0])):
+                assert g.shape == leaf.shape
+                np.testing.assert_array_equal(
+                    g, np.full(leaf.shape, 3.0 + 2 * k, np.float32)
+                )
+        assert [log[-1] for log in logs] == [("rung", _BIG)] * world
+        _shutdown(pgs)
+
+    def test_an_abort_on_the_wire_leaves_no_thread_and_no_lease(self, store):
+        """Rank 1 never gets bucket 1's leaves, so rank 0 waits for it on
+        bucket 1's wire: an abort there resolves rank 0's ``Work`` with the
+        error; the copies started for the later buckets are dropped with
+        their arrays, no thread outlives the op, and bucket 0's ring buffer
+        goes back to the pool with the failed op."""
+        import gc
+
+        from torchft_tpu.utils.bufpool import POOL
+
+        gc.collect()
+        threads, leased = set(threading.enumerate()), POOL.leased_bytes
+        world = 2
+        pgs = make_group(store, world, "ahead-abort")
+        stuck = threading.Event()
+        logs = [[] for _ in range(world)]
+        leaves = [_pipeline_leaves(r, logs[r], None) for r in range(world)]
+        leaves[1][1].release = stuck  # "a", of bucket 1, on rank 1
+        works = [pgs[r].allreduce(leaves[r], REDUCE_SUM) for r in range(world)]
+        _wait_for(
+            logs, lambda: ("asked", "b") in logs[0] and ("asked", "a") in logs[1]
+        )
+        time.sleep(0.05)  # rank 0 is inside bucket 1's first exchange
+        pgs[0].abort()
+        with pytest.raises(Exception):
+            works[0].wait(timeout=30)
+        assert pgs[0].errored() is not None
+        # the later buckets: no leaf asked for, and the copies beyond the
+        # one bucket that was sent ahead never started, on either rank
+        for name in ("big2", "last"):
+            assert ("started", name) not in logs[0] + logs[1]
+        assert ("asked", "big1") not in logs[0] + logs[1]
+        stuck.set()
+        with pytest.raises(Exception):
+            works[1].wait(timeout=30)
+        _shutdown(pgs)
+        # a failed op's traceback holds its frames, and they the buffer
+        del works, pgs
+        gc.collect()
+        deadline = time.monotonic() + 10
+        while set(threading.enumerate()) - threads and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert not set(threading.enumerate()) - threads
+        assert POOL.leased_bytes == leased
+
+    @pytest.mark.parametrize("kind", ["tcp", "dummy"])
+    def test_alone_no_copy_is_started(self, kind, ring_spans):
+        """World size 1 returns from ``_allreduce_alone`` before any of
+        this: a leaf that could start its copy is not asked to, a
+        ``jax.Array`` stays on its device (``kept`` as before)."""
+        import jax
+        import jax.numpy as jnp
+
+        (pg,) = _world(None, 1, "ahead-alone") if kind == "tcp" else [ProcessGroupDummy()]
+        log = []
+        dev = jnp.arange(12, dtype=jnp.float32).reshape(3, 4)
+        slow = _SlowLeaf("s", np.arange(4, dtype=np.float32), log)
+        before = _prefetched("rank0")
+        got, spans = ring_spans(
+            lambda: pg.allreduce([dev, slow], REDUCE_AVG).wait(timeout=20)
+        )
+        assert ("started", "s") not in log
+        assert got[0] is dev and isinstance(got[0], jax.Array)
+        np.testing.assert_array_equal(got[1], slow.value)
+        assert _attr(spans, "ring.d2h", "kept") == [dev.nbytes]
+        assert _attr(spans, "ring.d2h", "bytes") == [0]
+        assert "overlapped" not in dict(spans)["ring.d2h"]
+        assert _prefetched("rank0") == before
+        pg.shutdown()

@@ -357,6 +357,43 @@ class TestPhase:
         recs = [r for r in flightrecorder.snapshot() if r.get("kind") == "phase"]
         assert [r["op"] for r in recs] == ["heal_decode"]
 
+    def test_a_lapped_part_among_its_siblings_and_a_count_made_on_the_way(
+        self, span_file
+    ):
+        """``ring.d2h`` as the PG worker times it: stretches between the
+        other parts of ``ring`` (a wait a bucket), one span whose
+        ``seconds`` are the stretches alone, so the parts still add up to
+        the whole; ``overlapped`` is counted while the stretches run and
+        the span carries what it read at the end."""
+        path, _ = span_file
+        sink = {}
+        with tracing.phase("ring", sink, step=7):
+            d2h = tracing.phase(".d2h", bytes=12, overlapped=0)
+            for bucket in range(3):
+                with d2h.lap():
+                    time.sleep(0.004)
+                    d2h.attrs["overlapped"] += 4 if bucket else 0
+                with tracing.phase(".wire", bytes=4):
+                    time.sleep(0.006)
+            assert d2h.end() == sink["ring.d2h"]
+        tracing.uninstall_tracer()
+        by = {}
+        for s in _spans(path):
+            by.setdefault(s["name"], []).append(s)
+        (span,), (ring,) = by["ring.d2h"], by["ring"]
+        assert span["parent_span_id"] == ring["span_id"]
+        assert span["attributes"] == {
+            "step": 7, "bytes": 12, "overlapped": 8,
+            "seconds": pytest.approx(sink["ring.d2h"]),
+        }
+        # the span runs from the first stretch to the last, across two
+        # exchanges; what is booked is the stretches
+        wall = (span["end_ns"] - span["start_ns"]) / 1e9
+        assert 0.012 <= sink["ring.d2h"] < 0.024 <= wall
+        assert len(by["ring.wire"]) == 3
+        parts = sink["ring.d2h"] + sink["ring.wire"]
+        assert parts <= sink["ring"] and parts >= 0.9 * sink["ring"]
+
     def test_exclude_books_less_and_span_keeps_its_ends(self, span_file):
         path, _ = span_file
         sink = {}

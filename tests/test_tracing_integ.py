@@ -306,6 +306,17 @@ class TestRingOpened:
         rings = [s for s in spans if s["name"] == "ring"]
         wires = [s for s in parts if s["name"] == "ring.wire"]
         assert len(wires) == 2 * len(rings)
+        # ring.d2h is one span a ring over its stretches (the start of the
+        # copies, then a wait a bucket): it carries the seconds it booked,
+        # and host leaves are their own host arrays, so nothing was sent
+        # ahead of the ring
+        d2h = [s for s in parts if s["name"] == "ring.d2h"]
+        assert len(d2h) == len(rings)
+        for s in d2h:
+            attrs = s["attributes"]
+            assert attrs["bytes"] == 4 * (self.N + 32)
+            assert attrs["overlapped"] == 0 and attrs["relaid"] == 0
+            assert 0 < attrs["seconds"] <= (s["end_ns"] - s["start_ns"]) / 1e9 + 1e-6
 
 
 class TestChaosTrace:

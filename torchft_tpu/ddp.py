@@ -35,7 +35,10 @@ class DistributedDataParallel:
     def allreduce_gradients(self, grads: Any) -> Work:
         """Average a gradient pytree over the live quorum (single fused op —
         bandwidth-optimal for the ring; the reference's bucket hook exists to
-        overlap with backward, which JAX expresses via async dispatch)."""
+        overlap with backward, which JAX expresses via async dispatch).  One
+        op to the caller; beneath it the device link is not fused with the
+        ring: the group rings bucket i while the leaves of the next
+        buckets are still crossing to the host."""
         return self._manager.allreduce(grads, should_quantize=self._should_quantize)
 
     def wrap_grad_fn(

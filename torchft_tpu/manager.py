@@ -107,7 +107,7 @@ PROTOCOL_PHASES = (
 PHASE_PARTS = (
     # ProcessGroupTCP.allreduce, on its worker thread
     "ring.queue",  # submit until the worker picks the op up
-    "ring.d2h",  # wait for the device (its relayout of some leaves included) + copy to the host
+    "ring.d2h",  # held by the device→host leg: the relayout's dispatch, then a bucket's starts and its wait
     "ring.pack",  # bucket concat, pad-in of a widened leaf or a tail, copy of a host leaf
     "ring.wire",  # the exchanges: send + receive + waiting for the peer
     "ring.reduce",  # the in-place ufunc between exchanges
@@ -1640,10 +1640,14 @@ class Manager:
         ``ring`` is opened into its parts on the PG worker thread
         (``ProcessGroupTCP``; they sum to ``ring`` within the thread
         hand-offs): ``ring.queue`` (submit until the worker picks the op
-        up), ``ring.d2h`` (wait for the device + device→host copy of the
-        leaves; ``relaid`` = bytes of leaves laid out flat on the device
-        first, because it held them in another order of dimensions; at
-        world size 1 ``bytes`` = what left the device, 0 for leaves handed
+        up), ``ring.d2h`` (the time the worker is held by the
+        device→host leg: at world size > 1 the device leaves' copies are
+        started ahead of the ring, in the order the buckets ring, and a
+        bucket waits only for its own leaves, so it is the sum of those
+        waits and not the copies' length; ``overlapped`` = bytes whose copy was complete
+        when their bucket asked; ``relaid`` = bytes of leaves laid out flat
+        on the device first, because it held them in another order of
+        dimensions; at world size 1 ``bytes`` = what left the device, 0 for leaves handed
         back as the ``jax.Array`` they are, and ``kept`` = their bytes),
         ``ring.pack`` (bucket concat, the lease of the ring buffer
         and what is copied into it: a leaf that widens, a zero-padded tail;

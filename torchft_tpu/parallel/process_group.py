@@ -27,6 +27,7 @@ trick; the JAX mesh composition lives in torchft_tpu/parallel/device_mesh.py).
 from __future__ import annotations
 
 import functools
+import itertools
 import logging
 import pickle
 import queue
@@ -134,28 +135,52 @@ def _flatten_jit() -> Any:
     return sys.modules["jax"].jit(ring_relayout)
 
 
-def _to_host(arrays: "List[Any]", relay: "List[int]") -> "List[np.ndarray]":
-    """The leaves' host arrays.  Those named in ``relay`` (leaves
-    :func:`_in_device_order`) are laid out flat, row-major, on the device
-    first: one jitted program for all of them (one a device, should they
-    differ), at the memory's speed where the host re-orders at under 1 GB/s.
-    Each arrives as a C-contiguous vector of the leaf's dtype, and its
-    flat device copy goes as soon as the host array exists.  The leaves
-    that go as they are held are copied first: their copies need not wait
-    for the program, which queues behind whatever else the device runs
-    (another group's grad step, where two share a chip)."""
-    flat: "Dict[int, Any]" = {}
+# A copy that is complete hands its host array over in tens of microseconds;
+# one still under way holds the asker for what is left of it.  Below this
+# wait the leaf counts as having been on the host when its bucket asked
+# (``ring.d2h``'s ``overlapped``, ``torchft_ring_leaves_prefetched_total``).
+_COPY_READY_S = 1e-3
+
+
+def _plan_leaf(a: Any) -> "Tuple[np.dtype, int]":
+    """A leaf as the bucket plan sees it, ``(accumulation dtype, element
+    count)``: from its shape and dtype alone, so a device leaf stays
+    unmaterialized."""
+    if not hasattr(a, "dtype") or not hasattr(a, "size"):
+        a = np.asarray(a)
+    return _accumulation_dtype(np.dtype(a.dtype)), int(a.size)
+
+
+def _to_host(arrays: "List[Any]", relay: "List[int]") -> "List[Any]":
+    """What each leaf's host array is made from (:func:`_as_numpy`): the
+    leaf itself, or for those named in ``relay`` (leaves
+    :func:`_in_device_order`) a copy laid out flat, row-major, on the device:
+    one jitted program for all of them (one a device, should they differ),
+    at the memory's speed where the host re-orders at under 1 GB/s.  Such a
+    leaf arrives as a C-contiguous vector of its dtype.  The program is only
+    dispatched here, and queues behind whatever else the device runs
+    (another group's grad step, where two share a chip); nothing is waited
+    for and nothing is copied yet (:func:`_send_to_host`)."""
+    sources = list(arrays)
     by_device: "Dict[Any, List[int]]" = {}
     for i in relay:
         by_device.setdefault(arrays[i].sharding, []).append(i)
     for idxs in by_device.values():
-        flat.update(zip(idxs, _flatten_jit()([arrays[i] for i in idxs])))
-    out: "List[Any]" = [
-        None if i in flat else _as_numpy(a) for i, a in enumerate(arrays)
-    ]
-    for i in relay:
-        out[i] = _as_numpy(flat.pop(i))
-    return out
+        for i, flat in zip(idxs, _flatten_jit()([arrays[i] for i in idxs])):
+            sources[i] = flat
+    return sources
+
+
+def _send_to_host(sources: "List[Any]", idxs: "List[int]") -> None:
+    """Starts the host copy of whatever can start its own
+    (``copy_to_host_async``: a ``jax.Array``, one copy a shard where it is
+    spread over devices) and waits for none: :func:`_as_numpy` is then a
+    wait for a copy already under way.  An ``np.ndarray`` leaf is its own
+    host array and is not touched."""
+    for i in idxs:
+        start = getattr(sources[i], "copy_to_host_async", None)
+        if start is not None:
+            start()
 
 
 def _divide(a: np.ndarray, divisor: "Optional[int]") -> np.ndarray:
@@ -596,12 +621,21 @@ class ProcessGroupTCP(ProcessGroup):
         )
 
     def _bind_metrics(self) -> None:
-        """``torchft_ring_buffers_total`` children by pool hit and
-        ``torchft_ring_leaves_kept_total``, under the stable replica id."""
+        """``torchft_ring_buffers_total`` children by pool hit,
+        ``torchft_ring_leaves_prefetched_total`` children by whether the
+        copy was ready, and ``torchft_ring_leaves_kept_total``, under the
+        stable replica id."""
         self._metric_replica_id = _stable_replica_id(self._replica_id)
         self._m_leaves_kept = _metrics.RING_LEAVES_KEPT.labels(
             replica_id=self._metric_replica_id
         )
+        self._m_leaves_prefetched = {
+            ready: _metrics.RING_LEAVES_PREFETCHED.labels(
+                replica_id=self._metric_replica_id,
+                result="ready" if ready else "waited",
+            )
+            for ready in (True, False)
+        }
         self._m_ring_buffers = {
             hit: _metrics.RING_BUFFERS.labels(
                 replica_id=self._metric_replica_id,
@@ -1182,7 +1216,6 @@ class ProcessGroupTCP(ProcessGroup):
         # (the Manager's ``ring``), which is carried to the worker thread:
         # the seconds land in its sink, the spans are its children.
         whole = _tracing.open_phase()
-        nbytes = sum(int(getattr(a, "nbytes", 0)) for a in arrays)
         queued = _tracing.phase(".queue").begin()
 
         def run() -> "List[Any]":
@@ -1193,26 +1226,14 @@ class ProcessGroupTCP(ProcessGroup):
                     # the post-failure shrunken-group hot path: with nothing
                     # to divide by, a device leaf stays where it is
                     return _allreduce_alone(arrays, by, self._m_leaves_kept)
-                # device→host materialization happens HERE, on the PG worker:
-                # for jax-array inputs `_as_numpy` blocks on device compute +
-                # transfer, and doing that on the caller thread would stall it
-                # for the whole sync instead of letting the submit return
-                # immediately (the DiLoCo overlap pattern: outer-grad allreduce
-                # rides behind the next fragment's inner steps).
+                # The device→host leg is STARTED here, on the PG worker, and
+                # waited for bucket by bucket (`_allreduce_coalesced`): on
+                # the caller thread even the waiting would stall it for the
+                # whole sync instead of letting the submit return immediately
+                # (the DiLoCo overlap pattern: outer-grad allreduce rides
+                # behind the next fragment's inner steps).
                 deadline = time.monotonic() + deadline_budget
-                # A ring re-orders on the host whatever does not arrive in C
-                # order, so such leaves leave the device flat.
-                relay = [i for i, a in enumerate(arrays) if _in_device_order(a)]
-                with _tracing.phase(
-                    ".d2h",
-                    bytes=nbytes,
-                    relaid=sum(arrays[i].nbytes for i in relay),
-                ):
-                    np_arrays = _to_host(arrays, relay)
-                results = self._allreduce_coalesced(np_arrays, op, by, deadline)
-                for i in relay:  # back in the leaf's own shape: a view
-                    results[i] = results[i].reshape(arrays[i].shape)
-                return results
+                return self._allreduce_coalesced(arrays, op, by, deadline)
 
         work = self._submit(run, op="allreduce")
         # Wire accounting on the UNQUANTIZED path too (parity with the
@@ -1220,14 +1241,9 @@ class ProcessGroupTCP(ProcessGroup):
         # compare f32 vs int8 traffic honestly): per-rank ring egress from
         # the same bucket plan the reduce will use, computed synchronously
         # from shapes/dtypes — device arrays stay unmaterialized.
-        def _leaf(a: Any) -> "Tuple[np.dtype, int]":
-            if not hasattr(a, "dtype") or not hasattr(a, "size"):
-                a = np.asarray(a)
-            return _accumulation_dtype(np.dtype(a.dtype)), int(a.size)
-
         try:
             work.wire_bytes = self._ring_wire_bytes(
-                [_leaf(a) for a in arrays], self._world
+                [_plan_leaf(a) for a in arrays], self._world
             )
             work.unquantized_wire_bytes = work.wire_bytes
         except Exception:  # noqa: BLE001 - accounting must not fail the op
@@ -1289,12 +1305,13 @@ class ProcessGroupTCP(ProcessGroup):
 
     def _allreduce_coalesced(
         self,
-        arrays: "List[np.ndarray]",
+        arrays: "List[Any]",
         op: str,
         divisor: "Optional[int]",
         deadline: float,
     ) -> "List[np.ndarray]":
-        """Bucketized allreduce of a gradient pytree's leaves.
+        """Bucketized allreduce of a gradient pytree's leaves, the device
+        link running ahead of the ring.
 
         A gradient pytree is many small leaves; ringing each one costs
         2*(w-1) latency-bound exchanges per leaf. Same-accumulation-dtype
@@ -1303,22 +1320,72 @@ class ProcessGroupTCP(ProcessGroup):
         TORCHFT_USE_BUCKETIZATION, local_sgd.py:29); oversized leaves ring
         solo on the zero-copy path. Order-preserving.
 
-        Timed as the parts ``.pack|wire|reduce|unpack`` of the open phase:
-        one span per part and bucket, never one per exchange.
+        The plan comes first, from shapes and dtypes alone.  The device
+        leaves' host copies are started in the plan's order and none is
+        waited for before its bucket's turn (:func:`_send_to_host`): while
+        bucket i is on the wire the leaves of i+1.. cross the link on the
+        runtime's transfer thread.  The link shares itself out among the
+        copies under way (five started together: the first is there after
+        78 ms where alone it takes 32), so only as many are started as keep
+        the bytes on their way behind the bucket now taken at the plan's
+        largest bucket: one bucket ahead where leaves are alike, several
+        where small buckets come before a large one.  The buckets ring in
+        the plan's order on every rank, whatever arrived first.  Should a
+        bucket fail, the copies under way are dropped with their arrays:
+        nothing waits for them.
+
+        Timed as the parts ``.d2h|pack|wire|reduce|unpack`` of the open
+        phase: one span per part and bucket, never one per exchange;
+        ``.d2h`` is one span over its stretches, those in which this thread
+        is held by the link (the relayout's dispatch, then a bucket's
+        starts and its wait).  Its ``overlapped`` are the bytes of the
+        leaves sent ahead that were on the host when their bucket asked
+        (``torchft_ring_leaves_prefetched_total`` counts the leaves).
         """
-        if len(arrays) <= 1:
-            return [
-                self._allreduce_one(a, op, divisor, deadline) for a in arrays
-            ]
-        buckets = self._plan_buckets(
-            [(_accumulation_dtype(a.dtype), a.size) for a in arrays]
+        buckets = self._plan_buckets([_plan_leaf(a) for a in arrays])
+        sizes = [
+            sum(int(getattr(arrays[i], "nbytes", 0)) for i in idxs)
+            for _, idxs, _ in buckets
+        ]
+        window = max(sizes, default=0)
+        before = [0, *itertools.accumulate(sizes)]  # bytes of the buckets < k
+        # A ring re-orders on the host whatever does not arrive in C order,
+        # so such leaves leave the device flat.
+        relay = [i for i, a in enumerate(arrays) if _in_device_order(a)]
+        d2h = _tracing.phase(
+            ".d2h",
+            bytes=before[-1],
+            relaid=sum(arrays[i].nbytes for i in relay),
+            overlapped=0,
         )
+        with d2h.lap():
+            sources = _to_host(arrays, relay)
+
+        def host_array(i: int) -> np.ndarray:
+            source, sources[i] = sources[i], None  # a flat copy goes here
+            if not hasattr(source, "copy_to_host_async"):
+                return _as_numpy(source)
+            waited = time.perf_counter()
+            a = _as_numpy(source)
+            ready = time.perf_counter() - waited < _COPY_READY_S
+            self._m_leaves_prefetched[ready].inc()
+            if ready:
+                d2h.attrs["overlapped"] += a.nbytes
+            return a
+
         results: "List[Optional[np.ndarray]]" = [None] * len(arrays)
-        for acc_dtype, idxs, _ in buckets:
+        sent = 0  # buckets whose copies are started
+        for b, (acc_dtype, idxs, _) in enumerate(buckets):
+            with d2h.lap():
+                while sent < len(buckets) and (
+                    sent <= b or before[sent] - before[b + 1] < window
+                ):
+                    _send_to_host(sources, buckets[sent][1])
+                    sent += 1
+                host = [host_array(i) for i in idxs]
             if len(idxs) == 1:
-                i = idxs[0]
-                results[i] = self._allreduce_one(
-                    arrays[i], op, divisor, deadline
+                results[idxs[0]] = self._allreduce_one(
+                    host[0], op, divisor, deadline
                 )
                 continue
             # cast leaves individually: mixed input dtypes sharing one acc
@@ -1326,24 +1393,23 @@ class ProcessGroupTCP(ProcessGroup):
             with _tracing.phase(".pack", leaves=len(idxs)):
                 flat = np.concatenate(
                     [
-                        np.ascontiguousarray(arrays[i])
+                        np.ascontiguousarray(a)
                         .ravel()
                         .astype(acc_dtype, copy=False)
-                        for i in idxs
+                        for a in host
                     ]
                 )
             reduced = self._allreduce_one(flat, op, divisor, deadline)
             with _tracing.phase(".unpack", leaves=len(idxs)):
                 off = 0
-                for i in idxs:
-                    n = arrays[i].size
-                    results[i] = (
-                        reduced[off : off + n]
-                        .astype(arrays[i].dtype, copy=False)
-                        .reshape(arrays[i].shape)
+                for i, a in zip(idxs, host):
+                    results[i] = reduced[off : off + a.size].astype(
+                        a.dtype, copy=False
                     )
-                    off += n
-        return results  # type: ignore[return-value]
+                    off += a.size
+        d2h.end()
+        # in the leaf's own shape (a flat copy's result too): a view
+        return [r.reshape(np.shape(a)) for r, a in zip(results, arrays)]
 
     def _allreduce_one(
         self,

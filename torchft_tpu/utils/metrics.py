@@ -1184,6 +1184,16 @@ RING_LEAVES_KEPT = counter(
     "rings, and for host leaves, which are copied",
     ("replica_id",),
 )
+RING_LEAVES_PREFETCHED = counter(
+    "torchft_ring_leaves_prefetched_total",
+    "Device leaves of a plain allreduce at world size > 1 whose host copy "
+    "ProcessGroupTCP started ahead of its bucket's turn (parallel/process_group.py), "
+    "by whether the copy was complete when the leaf's bucket asked for it "
+    "(ready: the link ran ahead of the ring) or the ring had to wait for "
+    "what was left of it (waited); ready / (ready + waited) over a step is "
+    "the share of leaves whose device-to-host leg hid behind the wire",
+    ("replica_id", "result"),
+)
 LINK_GOODPUT = gauge(
     "torchft_link_goodput_bytes_per_s",
     "Passively measured link goodput by peer host and transfer plane "
