@@ -114,12 +114,17 @@ def test_the_router_bias_moves_the_choice_and_not_the_weights():
 
 # ---- (e) latent attention through the flash kernels --------------------------
 
-def test_latent_attention_through_the_flash_kernels_is_dense_attention():
-    """Queries and keys of 192 against values of 128, the kernels interpreted."""
+@pytest.mark.parametrize("over", [{}, {"q_lora_rank": 48, "mla_use_nope": False}],
+                         ids=["as-published", "a-query-latent-and-rotary"])
+def test_latent_attention_through_the_flash_kernels_is_dense_attention(over):
+    """Queries and keys of 192 against values of 128, the kernels interpreted;
+    as published (a full-rank query, no rotary) and with the two keys the
+    shared function (``models/mla.py``) reads set the other way."""
     cfg = dataclasses.replace(
         TINY, d_model=64, n_heads=2, kv_lora_rank=32, qk_nope_head_dim=128, qk_rope_head_dim=64,
-        v_head_dim=128, n_layers=1, kda_layers=(), full_attn_layers=(1,))
+        v_head_dim=128, n_layers=1, kda_layers=(), full_attn_layers=(1,), **over)
     params = kl.init_params(jax.random.PRNGKey(0), cfg)
+    assert ("wq" in params["mla"]) == (not over) and ("q_b" in params["mla"]) == bool(over)
     p = jax.tree_util.tree_map(lambda w: w[0], params["mla"])
     h = jax.random.normal(jax.random.PRNGKey(1), (2, 128, 64))
 
@@ -202,6 +207,55 @@ def test_model_in_float32_is_the_plain_reference(cfg):
         np.testing.assert_allclose(
             np.asarray(g), np.asarray(r), rtol=2e-3, atol=2e-4 * float(np.abs(np.asarray(r)).max()),
             err_msg=jax.tree_util.keystr(path))
+
+
+def _parent_mla_attention(h, p, cfg):
+    """``_mla_attention`` as it stood in this file's model before the
+    function moved to ``models/mla.py`` (PR 34), word for word."""
+    from torchft_tpu.models.transformer import _rms_norm
+    from torchft_tpu.ops.ring_attention import dense_attention
+
+    b, t, _ = h.shape
+    nh, act = cfg.n_heads, cfg.dtype
+    nope, rope, dv, rank = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
+                            cfg.kv_lora_rank)
+    with jax.named_scope("mla"):
+        q = (h @ p["wq"].astype(act)).reshape(b, t, nh, nope + rope)
+        kv_a = h @ p["kv_a"].astype(act)
+        latent = _rms_norm(kv_a[..., :rank], p["kv_norm"], cfg.rms_norm_eps)
+        k_pe = jnp.broadcast_to(kv_a[..., None, rank:], (b, t, nh, rope))
+        kv = (latent @ p["kv_b"].astype(act)).reshape(b, t, nh, nope + dv)
+        k = jnp.concatenate([kv[..., :nope], k_pe], axis=-1)
+        v = kv[..., nope:]
+        o = dense_attention(q, k, v, causal=True)
+        return o.reshape(b, t, nh * dv) @ p["wo"].astype(act)
+
+
+def test_the_lifted_latent_attention_leaves_this_model_bit_for_bit():
+    """The latent attention now lives in ``models/mla.py`` and serves two
+    families.  On this file's seeded tiny preset the loss and every gradient
+    are the parent commit's to the last bit (recorded there, on this CPU
+    backend), and the step traces to the program the parent's function
+    traces to, operation for operation."""
+    import hashlib
+
+    params = kl.init_params(jax.random.PRNGKey(5), TINY)
+    tokens = jax.random.randint(jax.random.PRNGKey(6), (2, 96), 0, TINY.vocab_size)
+    loss, grads = kl.make_grad_step(TINY)(params, tokens)
+    assert float(loss).hex() == "0x1.5e285a0000000p+2"
+    digest = hashlib.sha256()
+    for leaf in jax.tree_util.tree_leaves(grads):
+        digest.update(np.asarray(leaf).tobytes())
+    assert digest.hexdigest() == "855598f6b69608efbda03ddd97f11e0580395dfaeaa1ec2801f80ab5b4a04028"
+    sums = {name: float(jnp.sum(g)).hex() for name, g in grads["mla"].items()}
+    assert sums == {"attn_norm": "-0x1.2538180000000p-5", "kv_a": "0x1.6663c80000000p-3",
+                    "kv_b": "-0x1.fc60780000000p-5", "kv_norm": "0x1.9b534a0000000p-8",
+                    "wo": "0x1.d1fd7a0000000p-6", "wq": "-0x1.d267ea0000000p-5"}
+    # the same operations in the same order, whatever machine this runs on
+    p = jax.tree_util.tree_map(lambda w: w[0], params["mla"])
+    h = jax.random.normal(jax.random.PRNGKey(1), (2, 96, 32))
+    assert str(jax.make_jaxpr(lambda h, p: kl._mla_attention(h, p, TINY))(h, p)) == str(
+        jax.make_jaxpr(lambda h, p: _parent_mla_attention(h, p, TINY))(h, p))
 
 
 def test_logits_and_loss_agree():

@@ -1,11 +1,14 @@
 """Model families: flagship llama-style transformer + the reference's
 example-scale CNN/MLP (reference train_ddp.py:84-102, train_diloco.py:76-120),
 a sparse hybrid decoder (``kimi_linear``: delta-rule linear attention beside
-latent attention, a chip's share of sigmoid-routed experts) and a sparse decoder
+latent attention, a chip's share of sigmoid-routed experts), a sparse decoder
 with windowed beside global attention (``afmoe``: gated heads, norms on both
-sides of a sub-block, the same expert layer)."""
+sides of a sub-block, the same expert layer) and a sparse decoder with latent
+attention in every layer and a multi-token-prediction module (``joyai``: a
+query latent and a rotary part through ``mla``, the function ``kimi_linear``
+runs too; a loss over two prediction depths; the same expert layer)."""
 
-from torchft_tpu.models import afmoe, cnn, kimi_linear, mlp, transformer
+from torchft_tpu.models import afmoe, cnn, joyai, kimi_linear, mla, mlp, transformer
 from torchft_tpu.models.transformer import (
     TransformerConfig,
     init_params,
@@ -18,7 +21,9 @@ from torchft_tpu.models.transformer import (
 __all__ = [
     "afmoe",
     "cnn",
+    "joyai",
     "kimi_linear",
+    "mla",
     "mlp",
     "transformer",
     "TransformerConfig",

@@ -14,11 +14,14 @@ attention and which FFN a layer has comes from the configuration's lists
   write strength ``beta = sigmoid(W_b x)`` per head; the delta-rule state in
   chunks (``ops/kda.py``); output ``W_o (RMSNorm_head(o) * sigmoid(W_gb W_ga
   x))``.
-- **MLA**: ``q = W_q x`` with heads of ``nope + rope`` (no rotary is applied:
-  NoPE); ``[c; k_pe] = W_kva x``, ``c = RMSNorm(c)``, ``[k_nope; v] = W_kvb
-  c`` per head, ``k = [k_nope; k_pe]`` with ``k_pe`` shared by the heads;
-  causal softmax at ``(nope + rope) ** -0.5`` through the flash kernels
-  (queries and keys of 192 against values of 128).
+- **MLA**: ``models/mla.py`` ``mla_attention``, the latent attention
+  ``models/joyai.py`` runs too, as this model's configuration has it:
+  ``q = W_q x`` with heads of ``nope + rope`` (``q_lora_rank`` null: no
+  latent under the query; ``mla_use_nope``: no rotary is applied);
+  ``[c; k_pe] = W_kva x``, ``c = RMSNorm(c)``, ``[k_nope; v] = W_kvb c`` per
+  head, ``k = [k_nope; k_pe]`` with ``k_pe`` shared by the heads; causal
+  softmax at ``(nope + rope) ** -0.5`` through the flash kernels (queries and
+  keys of 192 against values of 128).
 - **Experts**: ``models/moe.py`` ``held_moe_ffn``: the chip's share of a
   sigmoid-routed, dropless layer, told which published experts it holds.
 
@@ -46,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from torchft_tpu.models import moe
+from torchft_tpu.models.mla import MLAConfig, mla_attention
 from torchft_tpu.models.moe import HeldMoEConfig, held_moe_ffn, init_held_moe_params
 from torchft_tpu.models.transformer import (
     _embed,
@@ -55,7 +59,6 @@ from torchft_tpu.models.transformer import (
     _swiglu,
 )
 from torchft_tpu.ops.kda import kda_chunked
-from torchft_tpu.ops.ring_attention import dense_attention
 
 Params = Dict[str, Any]
 Kind = Tuple[str, str]  # (attention, ffn) of one layer
@@ -78,11 +81,14 @@ class KimiLinearConfig:
     conv_kernel: int = 4
     kda_gate_rank: int = 128   # the width between W_fa / W_fb and W_ga / W_gb
     kda_chunk: int = 64
-    # MLA
+    # MLA; as published: no latent under the query, no rotary on its 64
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    q_lora_rank: Optional[int] = None
+    mla_use_nope: bool = True
+    rope_theta: float = 10000.0
     # FFNs
     d_ff: int = 9216
     d_expert: int = 1024
@@ -102,6 +108,15 @@ class KimiLinearConfig:
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    def mla(self) -> MLAConfig:
+        return MLAConfig(
+            d_model=self.d_model, n_heads=self.n_heads, kv_lora_rank=self.kv_lora_rank,
+            qk_nope_head_dim=self.qk_nope_head_dim, qk_rope_head_dim=self.qk_rope_head_dim,
+            v_head_dim=self.v_head_dim, q_lora_rank=self.q_lora_rank,
+            rope_theta=None if self.mla_use_nope else self.rope_theta,
+            rms_norm_eps=self.rms_norm_eps, dtype=self.dtype, param_dtype=self.param_dtype,
+            attn_impl=self.attn_impl)
 
     def moe(self) -> HeldMoEConfig:
         return HeldMoEConfig(
@@ -193,9 +208,13 @@ def init_params(rng: jax.Array, cfg: KimiLinearConfig) -> Params:
         "o_norm": jnp.ones((lk, dh), pd),
         "wo": dense(lk, d, e),
     }
+    if cfg.q_lora_rank is None:
+        query = {"wq": dense(lm, e, cfg.n_heads * cfg.qk_head_dim)}
+    else:
+        query = {"q_a": dense(lm, e, cfg.q_lora_rank), "q_norm": jnp.ones((lm, cfg.q_lora_rank), pd),
+                 "q_b": dense(lm, cfg.q_lora_rank, cfg.n_heads * cfg.qk_head_dim)}
     mla = {
-        "attn_norm": jnp.ones((lm, e), pd),
-        "wq": dense(lm, e, cfg.n_heads * cfg.qk_head_dim),
+        "attn_norm": jnp.ones((lm, e), pd), **query,
         "kv_a": dense(lm, e, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
         "kv_norm": jnp.ones((lm, cfg.kv_lora_rank), pd),
         "kv_b": dense(lm, cfg.kv_lora_rank, cfg.n_heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
@@ -278,27 +297,7 @@ def _kda_attention(h: jax.Array, p: Params, cfg: KimiLinearConfig) -> jax.Array:
 
 
 def _mla_attention(h: jax.Array, p: Params, cfg: KimiLinearConfig) -> jax.Array:
-    b, t, _ = h.shape
-    nh, act = cfg.n_heads, cfg.dtype
-    nope, rope, dv, rank = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
-                            cfg.kv_lora_rank)
-    with jax.named_scope("mla"):
-        q = (h @ p["wq"].astype(act)).reshape(b, t, nh, nope + rope)
-        kv_a = h @ p["kv_a"].astype(act)
-        latent = _rms_norm(kv_a[..., :rank], p["kv_norm"], cfg.rms_norm_eps)
-        k_pe = jnp.broadcast_to(kv_a[..., None, rank:], (b, t, nh, rope))
-        kv = (latent @ p["kv_b"].astype(act)).reshape(b, t, nh, nope + dv)
-        k = jnp.concatenate([kv[..., :nope], k_pe], axis=-1)
-        v = kv[..., nope:]
-        if cfg.attn_impl == "flash":
-            from torchft_tpu.ops.flash_attention import flash_attention
-
-            o = flash_attention(q, k, v, causal=True)
-        elif cfg.attn_impl == "dense":
-            o = dense_attention(q, k, v, causal=True)
-        else:
-            raise ValueError(f"unknown attn_impl {cfg.attn_impl!r}; expected 'flash' or 'dense'")
-        return o.reshape(b, t, nh * dv) @ p["wo"].astype(act)
+    return mla_attention(h, p, cfg.mla())
 
 
 def _make_layer(kind: Kind, cfg: KimiLinearConfig):

@@ -1279,7 +1279,8 @@ MOE_ASSIGNMENTS = counter(
     "Token-to-expert assignments that landed on an expert this chip holds, "
     "by layer (its number in the model) and expert (its published id); fed "
     "from a sparse model's routing_stats (models/kimi_linear.py, "
-    "models/afmoe.py) through models/moe.py record_routing_stats when a "
+    "models/afmoe.py, models/joyai.py) through models/moe.py "
+    "record_routing_stats when a "
     "caller asks, never inside a training step",
     ("layer", "expert"),
 )
@@ -1288,4 +1289,13 @@ MOE_TOKENS_UNROUTED = counter(
     "Tokens none of whose chosen experts lives on this chip (they get the "
     "shared expert alone), by layer",
     ("layer",),
+)
+LOSS_DEPTH = gauge(
+    "torchft_loss_depth",
+    "Most recent loss of each prediction depth of a model trained with a "
+    "multi-token-prediction loss (depth 0: the next token's, 1: the "
+    "module's, the token after next); fed from models/joyai.py's jitted "
+    "make_loss_parts through record_loss_parts when a caller asks, never "
+    "inside a training step",
+    ("replica_id", "depth"),
 )
