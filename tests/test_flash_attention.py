@@ -371,3 +371,111 @@ class TestFlashWindow:
             atol=2e-5, rtol=1e-5)
         np.testing.assert_array_equal(
             np.asarray(dense_attention(q, k, v, window=128)), np.asarray(dense_attention(q, k, v)))
+
+
+class TestFlashKeptResults:
+    """The forward rule names the kernel's two results (``FLASH_OUT_NAME``,
+    ``FLASH_LSE_NAME``): a ``jax.checkpoint`` that saves those names runs the
+    forward kernel once where one that keeps nothing runs it twice, to the
+    same bits; without such a checkpoint a name is the identity."""
+
+    # causal at heads of 64, windowed, values narrower than keys (192 against
+    # 128), not causal, grouped heads of 16
+    CASES = {
+        "causal-64": (dict(d=64), {}),
+        "window": (dict(d=64, t=512), {"window": 100}),
+        "values-128-keys-192": (dict(d=192, dv=128), {}),
+        "not-causal": (dict(d=64), {"causal": False}),
+        "values-16": (dict(d=24, dv=16, h=2, hkv=1), {}),
+    }
+
+    @staticmethod
+    def _qkv(d, dv=None, t=256, h=4, hkv=2, seed=3):
+        key = jax.random.PRNGKey(seed)
+        q = jax.random.normal(jax.random.fold_in(key, 0), (2, t, h, d), jnp.float32)
+        k = jax.random.normal(jax.random.fold_in(key, 1), (2, t, hkv, d), jnp.float32)
+        v = jax.random.normal(jax.random.fold_in(key, 2), (2, t, hkv, dv or d), jnp.float32)
+        return q, k, v
+
+    @staticmethod
+    def _value_and_grads(remat, kw):
+        def loss(q, k, v):
+            o = remat(lambda q, k, v: flash_attention(q, k, v, **kw))(q, k, v)
+            return (o * jnp.arange(o.size, dtype=o.dtype).reshape(o.shape)).mean()
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))
+
+    @staticmethod
+    def _keeping(fn):
+        from torchft_tpu.ops.flash_attention import FLASH_LSE_NAME, FLASH_OUT_NAME
+
+        return jax.checkpoint(fn, policy=jax.checkpoint_policies.save_only_these_names(
+            FLASH_OUT_NAME, FLASH_LSE_NAME))
+
+    @pytest.mark.parametrize("case", CASES)
+    def test_a_checkpoint_that_saves_the_names_runs_the_forward_once(self, case):
+        import re
+
+        shapes, kw = self.CASES[case]
+        q, k, v = self._qkv(**shapes)
+        forward = "_fwd_window_kernel" if "window" in kw else "_fwd_kernel"
+
+        def calls(remat):
+            text = str(jax.make_jaxpr(self._value_and_grads(remat, kw))(q, k, v))
+            return sorted(re.findall(r"name=(_\w+kernel)", text))
+
+        kept, nothing = calls(self._keeping), calls(jax.checkpoint)
+        assert kept.count(forward) == 1 and nothing.count(forward) == 2
+        assert [n for n in kept if n != forward] == [n for n in nothing if n != forward]
+        assert len(kept) == 3
+
+    @pytest.mark.parametrize("case", CASES)
+    def test_kept_and_recomputed_are_the_same_bits(self, case):
+        shapes, kw = self.CASES[case]
+        q, k, v = self._qkv(**shapes)
+        plain = self._value_and_grads(lambda fn: fn, kw)(q, k, v)
+        for remat in (self._keeping, jax.checkpoint):
+            got = self._value_and_grads(remat, kw)(q, k, v)
+            for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(plain)):
+                assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+    @pytest.mark.parametrize("case", CASES)
+    def test_the_names_tag_the_output_in_the_models_rows_and_the_logsumexp(self, case):
+        from torchft_tpu.ops.flash_attention import FLASH_LSE_NAME, FLASH_OUT_NAME
+
+        shapes, kw = self.CASES[case]
+        q, k, v = self._qkv(**shapes)
+        b, t, h, _ = q.shape
+        named = {}
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "name":
+                    named[eqn.params["name"]] = eqn.outvars[0].aval
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+        walk(jax.make_jaxpr(self._value_and_grads(lambda fn: fn, kw))(q, k, v).jaxpr)
+        assert set(named) == {FLASH_OUT_NAME, FLASH_LSE_NAME}
+        assert named[FLASH_OUT_NAME].shape == (b, t, h * v.shape[-1])
+        assert named[FLASH_LSE_NAME].shape == (b * h, t) and named[FLASH_LSE_NAME].dtype == jnp.float32
+        # the primal call, which no gradient is taken of, names nothing
+        named.clear()
+        walk(jax.make_jaxpr(lambda q, k, v: flash_attention(q, k, v, **kw))(q, k, v).jaxpr)
+        assert named == {}
+
+    def test_the_ring_composition_names_nothing(self):
+        """``ring_flash_local`` calls the kernel itself and combines chunks'
+        results: there is no single ``o`` of a layer to keep."""
+        from jax.sharding import Mesh, PartitionSpec as P
+
+        from torchft_tpu.ops.flash_attention import ring_flash_local
+
+        q, k, v = _qkv(t=256)
+        mesh = Mesh(np.array(jax.devices()[:2]), ("cp",))
+        spec = P(None, "cp", None, None)
+        fn = jax.shard_map(
+            lambda q, k, v: ring_flash_local(q, k, v, "cp", True), mesh=mesh,
+            in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)
+        text = str(jax.make_jaxpr(jax.grad(lambda q, k, v: fn(q, k, v).sum(), argnums=(0, 1, 2)))(q, k, v))
+        assert "flash_attn_" not in text and "_fwd_kernel" in text

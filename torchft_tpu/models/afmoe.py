@@ -49,7 +49,7 @@ import numpy as np
 from torchft_tpu.models import moe
 from torchft_tpu.models.kimi_linear import Kind, _head_nll, _logits, _run_layers
 from torchft_tpu.models.moe import HeldMoEConfig, held_moe_ffn, init_held_moe_params
-from torchft_tpu.models.transformer import _remat, _rms_norm, _rope, _swiglu
+from torchft_tpu.models.transformer import _grad_step, _remat, _rms_norm, _rope, _swiglu
 from torchft_tpu.ops.ring_attention import dense_attention
 
 Params = Dict[str, Any]
@@ -82,6 +82,10 @@ class AfmoeConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = True
+    # as ``TransformerConfig.remat_policy``: "full" keeps a layer's input and,
+    # of a layer through the flash kernels, the forward kernel's two results
+    # (B*T*H*Dv activations + B*H*T float32 a layer) so that the backward
+    # does not run that kernel again; "dots" keeps matrix products
     remat_policy: str = "full"
     # "flash" (ops/flash_attention.py; T % 128 == 0) or "dense"
     attn_impl: str = "flash"
@@ -230,10 +234,7 @@ def make_grad_step(cfg: AfmoeConfig, router_bias: "Optional[jax.Array]" = None):
     """A jitted ``(params, tokens) -> (loss, grads)`` step, the FT-DDP shape
     of ``models/transformer.py`` ``make_grad_step``."""
 
-    def step(params, tokens):
-        return jax.value_and_grad(loss_fn)(params, tokens, cfg, router_bias)
-
-    return jax.jit(step)
+    return jax.jit(_grad_step(lambda p, t: loss_fn(p, t, cfg, router_bias), cfg))
 
 
 def make_routing_stats(cfg: AfmoeConfig, router_bias: "Optional[jax.Array]" = None):

@@ -53,6 +53,7 @@ from torchft_tpu.models.mla import MLAConfig, mla_attention
 from torchft_tpu.models.moe import HeldMoEConfig, held_moe_ffn, init_held_moe_params
 from torchft_tpu.models.transformer import (
     _embed,
+    _grad_step,
     _next_token_nll,
     _remat,
     _rms_norm,
@@ -101,6 +102,10 @@ class KimiLinearConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = True
+    # as ``TransformerConfig.remat_policy``: "full" keeps a layer's input and,
+    # of a layer through the flash kernels, the forward kernel's two results
+    # (B*T*H*Dv activations + B*H*T float32 a layer) so that the backward
+    # does not run that kernel again; "dots" keeps matrix products
     remat_policy: str = "full"
     # "flash" (ops/flash_attention.py; T % 128 == 0) or "dense"
     attn_impl: str = "flash"
@@ -466,10 +471,7 @@ def make_grad_step(cfg: KimiLinearConfig, router_bias: "Optional[jax.Array]" = N
     """A jitted ``(params, tokens) -> (loss, grads)`` step, the FT-DDP shape
     of ``models/transformer.py`` ``make_grad_step``."""
 
-    def step(params, tokens):
-        return jax.value_and_grad(loss_fn)(params, tokens, cfg, router_bias)
-
-    return jax.jit(step)
+    return jax.jit(_grad_step(lambda p, t: loss_fn(p, t, cfg, router_bias), cfg))
 
 
 def make_routing_stats(cfg: KimiLinearConfig, router_bias: "Optional[jax.Array]" = None):

@@ -59,11 +59,17 @@ class TransformerConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = True
-    # remat granularity: "full" recomputes the whole block in the backward
-    # (min memory); "dots" saves matmul outputs and recomputes only the
-    # cheap elementwise ops (jax.checkpoint_policies.dots_saveable —
-    # trades ~260 MB/layer of bf16 activations for skipping the
-    # FLOP-heavy recompute; measured faster whenever it fits in HBM).
+    # remat granularity: "full" recomputes the block in the backward except
+    # the flash kernel's forward call, whose two results it keeps from the
+    # forward pass: a layer's B*T*H*Dv activations (2 B each in bfloat16)
+    # and B*H*T float32 of logsumexp on top of the block's input (0.03 GB a
+    # layer at 360m B8), so the backward does not run the forward kernel a
+    # second time; a job at its memory limit should reckon that.  A block
+    # without the flash kernel (dense, ring, ulysses) keeps its input alone.
+    # "dots" saves matmul outputs and recomputes only the cheap elementwise
+    # ops (jax.checkpoint_policies.dots_saveable — trades ~260 MB/layer of
+    # bf16 activations for skipping the FLOP-heavy recompute; measured
+    # faster whenever it fits in HBM).
     remat_policy: str = "full"
     # "auto"    = TPU-first resolution per call site: flash when the
     #             sequence is lane-aligned (T % 128 == 0) and unsharded,
@@ -486,7 +492,12 @@ def _make_block(
 
 
 def _remat(fn, cfg: TransformerConfig):
-    """Apply cfg's rematerialization policy to a block function."""
+    """Apply cfg's rematerialization policy to a block function.
+
+    ``"full"`` recomputes the block in the backward except the flash kernel's
+    forward call: its two results, which ``ops/flash_attention.py`` names, are
+    kept from the forward pass (a layer's ``B T H Dv`` x 2 B + ``B H T`` x 4 B).
+    A block without that kernel has no such name and keeps nothing."""
     if cfg.remat_policy == "dots":
         return jax.checkpoint(
             fn, policy=jax.checkpoint_policies.dots_saveable
@@ -495,8 +506,53 @@ def _remat(fn, cfg: TransformerConfig):
         raise ValueError(
             f"unknown remat_policy {cfg.remat_policy!r}; expected 'full' or 'dots'"
         )
-    return jax.checkpoint(fn)
+    from torchft_tpu.ops.flash_attention import FLASH_LSE_NAME, FLASH_OUT_NAME
 
+    return jax.checkpoint(
+        fn,
+        policy=jax.checkpoint_policies.save_only_these_names(
+            FLASH_OUT_NAME, FLASH_LSE_NAME
+        ),
+    )
+
+
+def _kept_bytes(jaxpr, layers: int = 1) -> int:
+    """Bytes of the flash kernel's named results in a gradient's jaxpr outside
+    its rematerialized parts, a scanned one counted once a layer: what the
+    ``"full"`` policy of :func:`_remat` keeps from the forward pass."""
+    from torchft_tpu.ops.flash_attention import FLASH_LSE_NAME, FLASH_OUT_NAME
+
+    total = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "name":
+            if eqn.params["name"] in (FLASH_OUT_NAME, FLASH_LSE_NAME):
+                aval = eqn.outvars[0].aval
+                total += layers * aval.size * aval.dtype.itemsize
+        elif eqn.primitive.name != "checkpoint":
+            inner = layers * eqn.params.get("length", 1)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                total += _kept_bytes(sub, inner)
+    return total
+
+
+def _grad_step(loss, cfg):
+    """The body of a family's jitted ``step(params, tokens) -> (loss, grads)``.
+    Tracing it sets the gauge ``torchft_remat_kept_bytes`` from the one trace
+    the step is built from (the inner ``jit`` is inlined into the caller's:
+    it is there to hand out its jaxpr).  A name outside a checkpoint is kept
+    only where ``"full"`` asked for it, so the other settings read 0."""
+    from torchft_tpu.utils import metrics
+
+    grad = jax.jit(jax.value_and_grad(loss), inline=True)
+
+    def step(params, tokens):
+        kept = 0
+        if cfg.remat and cfg.remat_policy == "full":
+            kept = _kept_bytes(grad.trace(params, tokens).jaxpr.jaxpr)
+        metrics.REMAT_KEPT_BYTES.set(kept)
+        return grad(params, tokens)
+
+    return step
 
 
 def forward(
@@ -749,9 +805,7 @@ def make_grad_step(
     across replica groups over DCN, then `apply_updates` runs (reference
     ddp.py:47-79 comm-hook factored the same way)."""
 
-    def step(params, tokens):
-        return jax.value_and_grad(loss_fn)(params, tokens, cfg, mesh)
-
+    step = _grad_step(lambda p, t: loss_fn(p, t, cfg, mesh), cfg)
     if mesh is None:
         return jax.jit(step)
     pspecs = param_specs(cfg, mesh)

@@ -43,11 +43,20 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 _LANE = 128
+
+# The names ``_flash_fwd`` gives the forward kernel's two results.  A
+# ``jax.checkpoint`` whose policy saves these names (``models/transformer.py``
+# ``_remat`` under ``"full"``) keeps ``o`` and ``lse`` from the forward pass, so
+# its backward does not run the forward kernel a second time.  Without such a
+# policy a name is the identity.
+FLASH_OUT_NAME = "flash_attn_out"
+FLASH_LSE_NAME = "flash_attn_lse"
 
 
 def _interpret() -> bool:
@@ -476,21 +485,42 @@ def _bwd(
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash3(q3, k3, v3, scale, causal, window):
-    return _fwd(q3, k3, v3, scale, causal, window=window)[0]
+def _flash(q, k, v, scale, causal, window):
+    """``[B, T, H, D]`` x 2, ``[B, T, H, Dv]`` -> ``[B, T, H, Dv]``; ``k`` and
+    ``v`` carry the query's heads."""
+    b, _, h, _ = q.shape
+    o3, _ = _fwd(_to3(q), _to3(k), _to3(v), scale, causal, window=window)
+    return _from3(o3, b, h)
 
 
-def _flash3_fwd(q3, k3, v3, scale, causal, window):
-    o, lse = _fwd(q3, k3, v3, scale, causal, window=window)
-    return o, (q3, k3, v3, o, lse)
+def _flash_fwd(q, k, v, scale, causal, window):
+    b, t, h, _ = q.shape
+    o3, lse = _fwd(_to3(q), _to3(k), _to3(v), scale, causal, window=window)
+    # ``o`` is named as the rows a block reads it in, ``[B, T, H Dv]``: the
+    # forward pass lays those out anyway, and a stack of the kernel's own
+    # ``[bh, T, 64]`` would be padded to 128 lanes and hold twice its bytes.
+    # The primal result is read from the named rows, so that a checkpoint
+    # which saves the name needs no second ``o``.
+    o = checkpoint_name(_from3(o3, b, h).reshape(b, t, -1), FLASH_OUT_NAME)
+    lse = checkpoint_name(lse, FLASH_LSE_NAME)
+    return o.reshape(b, t, h, -1), (q, k, v, o, lse)
 
 
-def _flash3_bwd(scale, causal, window, res, do3):
-    q3, k3, v3, o3, lse = res
-    return _bwd(q3, k3, v3, o3, lse, do3, scale, causal, window=window)
+def _flash_bwd(scale, causal, window, res, do):
+    q, k, v, o, lse = res
+    b, t, h, _ = q.shape
+    # delta_i = rowsum(dO * O), from the rows as they were kept
+    delta = jnp.sum(
+        do.astype(jnp.float32) * o.reshape(do.shape).astype(jnp.float32), axis=-1
+    ).transpose(0, 2, 1).reshape(b * h, t)
+    dq, dk, dv = _bwd(
+        _to3(q), _to3(k), _to3(v), None, lse, _to3(do), scale, causal,
+        delta=delta, window=window,
+    )
+    return _from3(dq, b, h), _from3(dk, b, h), _from3(dv, b, h)
 
 
-_flash3.defvjp(_flash3_fwd, _flash3_bwd)
+_flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(
@@ -522,8 +552,7 @@ def flash_attention(
         )
     k, v = _expand_gqa(k, v, h)
     scale = 1.0 / math.sqrt(d)
-    out3 = _flash3(_to3(q), _to3(k), _to3(v), scale, causal, window)
-    return _from3(out3, b, h)
+    return _flash(q, k, v, scale, causal, window)
 
 
 __all__ = ["flash_attention"]

@@ -1299,3 +1299,13 @@ LOSS_DEPTH = gauge(
     "inside a training step",
     ("replica_id", "depth"),
 )
+REMAT_KEPT_BYTES = gauge(
+    "torchft_remat_kept_bytes",
+    "Bytes of the flash kernel's forward results (the attention output and "
+    "the rows' logsumexp, all layers) that one grad step keeps from its "
+    "forward pass under remat_policy 'full', so that its backward does not "
+    "run the forward kernel again; read off the traced program when a "
+    "model's make_grad_step is built, 0 where no flash call lies under a "
+    "checkpoint",
+    (),
+)
