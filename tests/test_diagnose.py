@@ -6,6 +6,7 @@ dead replica before eviction)."""
 
 import json
 import threading
+import time
 import urllib.request
 
 import numpy as np
@@ -650,8 +651,12 @@ class TestTraceLedger:
         from torchft_tpu.manager import PHASE_PARTS, PROTOCOL_PHASES
 
         whole = {name: 1.0 + i for i, name in enumerate(PROTOCOL_PHASES)}
-        opened = dict(whole, **{part: 0.25 for part in PHASE_PARTS})
+        plain = [p for p in PHASE_PARTS if p not in diagnose.PART_CATEGORY]
+        opened = dict(whole, **{part: 0.25 for part in plain})
         assert diagnose.ledger_categories(opened) == diagnose.ledger_categories(whole)
+        # a refining part moves seconds between categories and adds none
+        opened.update({part: 0.25 for part in diagnose.PART_CATEGORY})
+        assert set(diagnose.PART_CATEGORY) <= set(PHASE_PARTS)
         assert sum(diagnose.ledger_categories(opened).values()) == pytest.approx(
             sum(whole.values())
         )
@@ -681,6 +686,88 @@ class TestTraceLedger:
         assert with_parts["categories"] == without["categories"]
         assert without["categories"] == {"codec": 0.25, "wire": 0.65}
 
+    def test_the_rings_waits_move_from_wire_to_straggler_wait(self):
+        """ISSUE 38 (e): ``ring.wire.arrive`` + ``ring.wire.wait`` refine
+        ``ring``: the PG worker blocked on a peer is straggler-wait, not
+        wire.  Nothing is counted twice, and without the parts the phase
+        reads as before."""
+        cats = diagnose.ledger_categories(
+            {"ring": 1.0, "ring.wire.arrive": 0.3, "ring.wire.wait": 0.1}
+        )
+        assert cats == {
+            "wire": pytest.approx(0.6), "straggler-wait": pytest.approx(0.4)
+        }
+        assert diagnose.ledger_categories({"ring": 1.0}) == {"wire": 1.0}
+        # the other parts of the wire, and of the ring, move nothing
+        assert diagnose.ledger_categories(
+            {"ring": 1.0, "ring.wire": 0.9, "ring.wire.recv": 0.5,
+             "ring.wire.send": 0.1, "ring.d2h": 0.1}
+        ) == {"wire": 1.0}
+        # a part takes at most its phase's seconds, and none of a phase
+        # that is not there
+        assert diagnose.ledger_categories(
+            {"ring": 0.2, "ring.wire.arrive": 0.3, "commit": 0.1}
+        ) == {
+            "wire": pytest.approx(0.0), "straggler-wait": pytest.approx(0.2),
+            "protocol": 0.1,
+        }
+        assert diagnose.ledger_categories({"ring.wire.arrive": 0.3}) == {}
+        assert diagnose.dominant_contributor(
+            {"ring": 1.0, "ring.wire.arrive": 0.6, "commit": 0.3}
+        ) == "straggler-wait"
+        assert diagnose.dominant_contributor({"ring": 1.0, "commit": 0.3}) == "wire"
+
+    def test_trace_ledger_finds_the_waits_below_ring_wire(self):
+        """In a span file the two parts are grandchildren of ``ring``
+        (round -> ring -> ring.wire -> the part): the ledger files them
+        under their round, books the lapped one by its ``seconds``, and a
+        quantized pipeline, which replaces ``ring``, leaves them nothing
+        to refine."""
+        T = "d" * 32
+        root, ring, wire = ("r" + c + "0" * 14 for c in "abc")
+
+        def spans(replica, late):
+            rid = f"rep_{replica}"
+            ids = [x.replace("r", replica, 1) for x in (root, ring, wire)]
+            return [
+                _span("quorum_round", T, ids[0], None, 0, 1000,
+                      replica_id=rid, step=5, quorum_id=2),
+                _span("commit", T, replica + "c" + "0" * 14, ids[0], 900, 1000),
+                _span("ring", T, ids[1], ids[0], 0, 900, replica_id=rid),
+                _span("ring.wire", T, ids[2], ids[1], 100, 900),
+                _span("ring.wire.arrive", T, replica + "d" + "0" * 14, ids[2],
+                      100, 100 + late),
+                # lapped: the span runs first stretch to last, books 50 ms
+                _span("ring.wire.wait", T, replica + "e" + "0" * 14, ids[2],
+                      100 + late, 900, seconds=0.05),
+                _span("ring.wire.recv", T, replica + "f" + "0" * 14, ids[2],
+                      100 + late, 900, seconds=0.2),
+            ]
+
+        both = spans("a", 600) + spans("b", 0)
+        step = diagnose.analyze_trace(both)["steps"][0]
+        waited, late = step["replicas"]["rep_a"], step["replicas"]["rep_b"]
+        assert waited["categories"] == {
+            "protocol": pytest.approx(0.1), "wire": pytest.approx(0.25),
+            "straggler-wait": pytest.approx(0.65),
+        }
+        assert waited["dominant"] == "straggler-wait"
+        assert late["categories"]["wire"] == pytest.approx(0.85)
+        assert late["dominant"] == "wire"
+        # without the parts the same trace reads as before
+        plain = [s for s in both if not s["name"].startswith("ring.wire.")]
+        for info in diagnose.analyze_trace(plain)["steps"][0]["replicas"].values():
+            assert info["categories"]["wire"] == pytest.approx(0.9)
+            assert "straggler-wait" not in info["categories"]
+        # quant.pipeline replaces ring: nothing is left to move
+        quant = both + [
+            _span("quant.pipeline", T, "q" * 16, "aa" + "0" * 14, 0, 900,
+                  codec_s=0.3, wire_s=0.5)
+        ]
+        cats = diagnose.analyze_trace(quant)["steps"][0]["replicas"]["rep_a"]["categories"]
+        assert cats["wire"] == pytest.approx(0.5)
+        assert cats.get("straggler-wait", 0.0) == 0.0
+
     def test_bench_vocabulary_matches(self):
         """bench.py's per-leg dominant field uses this module's mapping —
         pin the vocabulary so the tail stays joinable with the ledger."""
@@ -693,3 +780,91 @@ class TestTraceLedger:
         assert diagnose.dominant_contributor({}) is None
         for cat in diagnose.PHASE_CATEGORY.values():
             assert cat in diagnose.LEDGER_CATEGORIES
+
+
+class TestRingStragglerTrace:
+    """ISSUE 38, acceptance: with an async quorum a slow replica group is
+    not waited for at the quorum but inside the first exchange of the
+    ring.  A live two-replica trace in which one replica sleeps 0.2 s
+    before its allreduce: ``torchft-diagnose --trace`` names
+    ``straggler-wait``, not ``wire``, for the replica that waited, and the
+    counter that holds the same seconds is in ``/metrics``."""
+
+    STEPS = 3
+
+    def _replica(self, rid, addr, out):
+        params = {"w": np.zeros(1024, dtype=np.float32)}
+        manager = Manager(
+            pg=ProcessGroupTCP(timeout=20.0),
+            min_replica_size=2,
+            load_state_dict=lambda sd: params.update(sd),
+            state_dict=lambda: dict(params),
+            lighthouse_addr=addr,
+            replica_id=f"replica_{rid}",
+            group_rank=0,
+            group_world_size=1,
+            use_async_quorum=True,
+            timeout=20.0,
+            quorum_timeout=20.0,
+            init_sync=False,
+        )
+        try:
+            while manager.current_step() < self.STEPS:
+                manager.start_quorum()
+                if rid == 1:
+                    time.sleep(0.2)  # its grad step, on a slower chip
+                manager.allreduce({"w": np.ones(1024, np.float32)}).wait(timeout=30)
+                manager.should_commit()
+            out[rid] = manager.phase_times()
+        finally:
+            manager.shutdown()
+
+    def test_a_late_replica_is_its_peers_straggler_wait(
+        self, tmp_path, monkeypatch, capsys
+    ):
+        from torchft_tpu.utils import metrics, tracing
+
+        path = tmp_path / "trace.jsonl"
+        monkeypatch.setenv("TORCHFT_TRACE_FILE", str(path))
+        monkeypatch.delenv("TORCHFT_USE_OTEL", raising=False)
+        tracing.uninstall_tracer()
+        assert tracing.maybe_install_from_env() is not None
+        lighthouse = LighthouseServer(
+            min_replicas=2, join_timeout_ms=100, heartbeat_timeout_ms=1000
+        )
+        out = {}
+        try:
+            threads = [
+                threading.Thread(target=self._replica, args=(rid, lighthouse.address(), out))
+                for rid in range(2)
+            ]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+            assert not any(t.is_alive() for t in threads) and set(out) == {0, 1}
+        finally:
+            tracing.uninstall_tracer()
+            lighthouse.shutdown()
+        # the replica that went first waited in the ring, by name
+        assert out[0]["ring.wire.arrive"] >= 0.15 * self.STEPS
+        assert out[1]["ring.wire.arrive"] < 0.1
+
+        assert diagnose.main(["--trace", str(path), "--json"]) == 0
+        ledger = json.loads(capsys.readouterr().out)["trace_ledger"]
+        steps = [s for s in ledger["steps"] if "replica_0" in str(s["replicas"])]
+        assert len(steps) == self.STEPS
+        for s in steps:
+            (waited,) = [v for k, v in s["replicas"].items() if k.startswith("replica_0")]
+            assert waited["dominant"] == "straggler-wait", (s["step"], waited)
+            assert waited["categories"]["straggler-wait"] >= 0.15
+            assert waited["categories"]["straggler-wait"] > waited["categories"]["wire"]
+        # the same seconds, where an operator scrapes them
+        fams = parse_text_exposition(metrics.REGISTRY.render())
+        samples = fams["torchft_ring_peer_wait_seconds_total"]["samples"]
+        by_kind = {
+            dict(labels)["kind"]: v for (_, labels), v in samples.items()
+            if dict(labels).get("replica_id") == "replica_0"
+        }
+        assert set(by_kind) == {"arrive", "wait"}
+        assert by_kind["arrive"] >= 0.15 * self.STEPS

@@ -1630,3 +1630,223 @@ class TestLinkAheadOfTheRing:
         assert "overlapped" not in dict(spans)["ring.d2h"]
         assert _prefetched("rank0") == before
         pg.shutdown()
+
+
+def _ring_phases(pgs, leaves_of, before=None, rounds=1):
+    """Each rank's allreduce of ``leaves_of(rank)`` on a thread of its own,
+    under an open ``ring`` phase: the seconds of ``ring`` and its parts by
+    rank, and when each rank called ``allreduce``.  ``before(rank, round)``
+    runs on the rank's thread first (a rank that comes late)."""
+    from torchft_tpu.utils import tracing
+
+    sinks = [{} for _ in pgs]
+    entered = [[] for _ in pgs]
+
+    def op(rank, pg):
+        for n in range(rounds):
+            leaves = leaves_of(rank)
+            if before is not None:
+                before(rank, n)
+            ring = tracing.phase("ring", sinks[rank]).begin()
+            with tracing.under(ring):
+                entered[rank].append(time.perf_counter())
+                work = pg.allreduce(leaves, REDUCE_SUM)
+            work.wait(timeout=20)
+            ring.end()
+
+    run_parallel(len(pgs), op, pgs)
+    return sinks, entered
+
+
+_WIRE_PARTS = tuple("ring.wire." + p for p in ("arrive", "wait", "recv", "send"))
+_RING_PARTS = tuple("ring." + p for p in ("queue", "d2h", "pack", "wire", "reduce", "unpack"))
+
+
+def _peer_wait(replica_id):
+    from torchft_tpu.utils import metrics
+
+    return {
+        kind: metrics.RING_PEER_WAIT.labels(replica_id=replica_id, kind=kind).get()
+        for kind in ("arrive", "wait")
+    }
+
+
+class TestWireOpened:
+    """ISSUE 38: ``ring.wire`` opened on the PG worker thread, at the lines
+    where an exchange blocks it, into ``arrive`` (the op's first exchange:
+    the previous rank had not reached the ring), ``wait`` (a later one: it
+    is late with a chunk), ``recv`` (the bytes) and ``send`` (the send's
+    tail).  Every wait of these tests has a time limit of its own; the
+    tolerances are on differences a loaded host moves together."""
+
+    def test_a_rank_that_comes_late_is_its_successors_arrive(self, store):
+        """(a) rank 1 calls 0.2 s after rank 0: rank 0's first exchange
+        waits that long for rank 1's first byte, rank 1 finds rank 0's
+        waiting.  Held against the skew the threads really had."""
+        pgs = make_group(store, 2, "wire-late")
+        tiny = lambda rank: [np.full(64, rank + 1.0, np.float32)]
+        sinks, entered = _ring_phases(
+            pgs, tiny, before=lambda rank, n: time.sleep(0.2 * rank)
+        )
+        skew = entered[1][0] - entered[0][0]
+        assert 0.15 < skew < 5.0
+        assert sinks[0]["ring.wire.arrive"] == pytest.approx(skew, abs=0.03)
+        assert sinks[1]["ring.wire.arrive"] < 0.03
+        # and it is all of rank 0's wire, not the bytes' or the send's
+        assert sinks[0]["ring.wire.arrive"] >= 0.9 * sinks[0]["ring.wire"]
+        for part in ("ring.wire.wait", "ring.wire.recv", "ring.wire.send"):
+            assert sinks[0][part] < 0.03
+        _shutdown(pgs)
+
+    @pytest.mark.parametrize("world", [2, 3])
+    def test_the_four_parts_add_up_to_wire_and_the_six_to_ring(self, store, world):
+        """(b) at a size where an exchange takes milliseconds, over three
+        ops: what lies between the parts is bookkeeping."""
+        pgs = make_group(store, world, f"wire-sum{world}")
+        big = lambda rank: [
+            np.full(6_000_000, rank + 1.0, np.float32) for _ in range(2)
+        ] + [np.ones(8, np.float32), np.ones(3, np.float32)]
+        sinks, _ = _ring_phases(pgs, big, rounds=3)
+        for s in sinks:
+            assert set(_WIRE_PARTS) | set(_RING_PARTS) <= set(s)
+            inside = sum(s[p] for p in _WIRE_PARTS)
+            assert inside <= s["ring.wire"]
+            assert inside == pytest.approx(s["ring.wire"], rel=0.02)
+            opened = sum(s[p] for p in _RING_PARTS)
+            assert opened <= s["ring"]
+            assert opened >= 0.9 * s["ring"]
+        _shutdown(pgs)
+
+    def test_a_peer_that_stalls_between_buckets_is_wait_not_arrive(self, store):
+        """(c) rank 1 reaches the ring on time and is then held 0.2 s
+        before its second bucket (a leaf whose host copy takes that long,
+        booked as its ``ring.d2h``): rank 0 waits as long in a later
+        exchange of the op."""
+
+        class Held(_SlowLeaf):
+            def __array__(self, dtype=None, copy=None):
+                time.sleep(0.2)
+                return super().__array__(dtype, copy)
+
+        pgs = make_group(store, 2, "wire-stall")
+        n = ProcessGroupTCP.BUCKET_BYTES // 4  # a leaf that rings alone
+        log = []
+
+        def leaves_of(rank):
+            second = np.full(n, 2.0, np.float32)
+            return [
+                np.full(n, 1.0, np.float32),
+                Held("held", second, log) if rank else second,
+            ]
+
+        sinks, _ = _ring_phases(pgs, leaves_of)
+        assert ("asked", "held") in log
+        stall = sinks[1]["ring.d2h"]
+        assert 0.19 < stall < 5.0
+        assert sinks[0]["ring.wire.wait"] == pytest.approx(stall, abs=0.05)
+        assert sinks[0]["ring.wire.arrive"] < 0.05
+        assert sinks[1]["ring.wire.wait"] < 0.05
+        _shutdown(pgs)
+
+    def test_in_a_longer_ring_only_the_stragglers_successor_reads_arrive(self, store):
+        """The first exchange depends on nothing the previous rank
+        received, every later one does: with rank 2 of 3 late, rank 0
+        (next after it) waits in ``arrive``, rank 1 gets rank 0's first
+        chunk at once and waits for the late rank's share in ``wait``."""
+        pgs = make_group(store, 3, "wire-long")
+        tiny = lambda rank: [np.full(64, rank + 1.0, np.float32)]
+        sinks, entered = _ring_phases(
+            pgs, tiny, before=lambda rank, n: time.sleep(0.2 * (rank == 2))
+        )
+        skew = entered[2][0] - max(entered[0][0], entered[1][0])
+        assert sinks[0]["ring.wire.arrive"] == pytest.approx(skew, abs=0.04)
+        assert sinks[1]["ring.wire.arrive"] < 0.04
+        assert sinks[1]["ring.wire.wait"] == pytest.approx(skew, abs=0.04)
+        assert sinks[2]["ring.wire.arrive"] + sinks[2]["ring.wire.wait"] < 0.04
+        _shutdown(pgs)
+
+    @pytest.mark.parametrize("kind", ["tcp", "dummy"])
+    def test_alone_there_is_no_wire(self, kind, ring_spans):
+        """(d) world size 1 opens none of it: the keys of a lone group's
+        ``phases`` are the parent's."""
+        (pg,) = _world(None, 1, "wire-alone") if kind == "tcp" else [ProcessGroupDummy()]
+        before = _peer_wait("rank0")
+        _, spans = ring_spans(
+            lambda: pg.allreduce([np.ones(100, np.float32)], REDUCE_AVG).wait(timeout=20)
+        )
+        names = {name for name, _ in spans}
+        assert names and not {n for n in names if n.startswith("ring.wire")}
+        assert _peer_wait("rank0") == before
+        pg.shutdown()
+
+    def test_the_counter_moves_by_the_two_waits_seconds(self, store):
+        """(f) ``torchft_ring_peer_wait_seconds_total{kind}`` is incremented
+        where the parts end, by what they booked."""
+        pgs = make_group(store, 2, "wire-counter")
+        before = [_peer_wait(f"rank{r}") for r in range(2)]
+        tiny = lambda rank: [np.full(64, 1.0, np.float32), np.ones(3, np.float64)]
+        sinks, _ = _ring_phases(
+            pgs, tiny, before=lambda rank, n: time.sleep(0.05 * rank), rounds=2
+        )
+        for rank, s in enumerate(sinks):
+            after = _peer_wait(f"rank{rank}")
+            for kind in ("arrive", "wait"):
+                assert after[kind] - before[rank][kind] == pytest.approx(
+                    s["ring.wire." + kind], abs=1e-9
+                )
+        assert sinks[0]["ring.wire.arrive"] >= 0.08  # two ops, 0.05 s each
+        _shutdown(pgs)
+
+    def test_a_peer_that_never_comes_is_booked_when_the_ring_fails(self, store):
+        """Rank 1 never calls: rank 0's ring fails at its deadline, and what
+        it waited until then is its ``arrive``, in the sink and in the
+        counter, like a wait that ended well."""
+        from torchft_tpu.utils import tracing
+
+        pgs = make_group(store, 2, "wire-never", timeout=1.0)
+        before = _peer_wait("rank0")
+        sink = {}
+        ring = tracing.phase("ring", sink).begin()
+        with tracing.under(ring):
+            work = pgs[0].allreduce([np.ones(64, np.float32)], REDUCE_SUM)
+        with pytest.raises(Exception):
+            work.wait(timeout=20)
+        ring.end(ok=False)
+        assert 0.9 < sink["ring.wire.arrive"] < 10.0
+        assert sink["ring.wire.arrive"] >= 0.9 * sink["ring.wire"]
+        # the send was handed over; a part no stretch of which began books nothing
+        assert "ring.wire.send" in sink
+        assert "ring.wire.wait" not in sink and "ring.wire.recv" not in sink
+        after = _peer_wait("rank0")
+        assert after["arrive"] - before["arrive"] == pytest.approx(
+            sink["ring.wire.arrive"], abs=1e-9
+        )
+        assert after["wait"] == before["wait"]
+        _shutdown(pgs)
+
+    def test_other_collectives_read_the_same_bytes_and_open_nothing(
+        self, store, ring_spans
+    ):
+        """``allgather`` and ``send`` / ``recv`` go through the one
+        ``_recv_msg``: same results, and no part of a wire they do not
+        have."""
+        pgs = make_group(store, 2, "wire-others")
+
+        def both():
+            def op(rank, pg):
+                got = pg.allgather(np.array([rank, 7 * rank])).wait(timeout=20)
+                if rank == 0:
+                    pg.send(np.arange(5, dtype=np.float32), 1).wait(timeout=20)
+                else:
+                    got.append(pg.recv(0).wait(timeout=20))
+                return got
+
+            return run_parallel(2, op, pgs)
+
+        results, spans = ring_spans(both)
+        for got in results:
+            np.testing.assert_array_equal(got[0], [0, 0])
+            np.testing.assert_array_equal(got[1], [1, 7])
+        np.testing.assert_array_equal(results[1][2], np.arange(5, dtype=np.float32))
+        assert not [name for name, _ in spans if "wire" in name]
+        _shutdown(pgs)

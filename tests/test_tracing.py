@@ -394,6 +394,74 @@ class TestPhase:
         parts = sink["ring.d2h"] + sink["ring.wire"]
         assert parts <= sink["ring"] and parts >= 0.9 * sink["ring"]
 
+    def test_a_part_has_parts_the_same_way(self, span_file):
+        """ISSUE 38, ``ring.wire`` as the PG worker opens it: inside
+        ``.wire`` a plain ``.arrive`` once and lapped ``.wait`` / ``.recv``
+        over the exchanges, the reduce between them excluded from the
+        wire and from all of them.  The names go through the dot rule, the
+        spans hang off the wire's, the seconds land in ring's sink and add
+        up to the wire."""
+        path, _ = span_file
+        sink, seen = {}, []
+        with tracing.phase("ring", sink, step=7, observe=lambda n, s: seen.append(n)):
+            reduce = tracing.phase(".reduce")
+            with tracing.phase(".wire", bytes=64) as wire:
+                arrive = tracing.phase(".arrive")
+                wait, recv = tracing.phase(".wait"), tracing.phase(".recv")
+                assert arrive.name == "ring.wire.arrive" and arrive.sink is sink
+                for exchange in range(3):
+                    with (wait.lap() if exchange else arrive):
+                        time.sleep(0.004)
+                        assert tracing.open_phase().name.startswith("ring.wire.")
+                    assert tracing.open_phase() is wire
+                    with recv.lap():
+                        time.sleep(0.002)
+                    with reduce.lap():
+                        time.sleep(0.003)
+                wire.exclude(reduce.end())
+            assert wait.end() == sink["ring.wire.wait"]
+            assert recv.end() == sink["ring.wire.recv"]
+            assert tracing.phase(".send").end() == 0.0  # no lap: no record
+        tracing.uninstall_tracer()
+        by = {s["name"]: s for s in _spans(path)}
+        assert set(by) == set(sink) == {
+            "ring", "ring.reduce", "ring.wire", "ring.wire.arrive",
+            "ring.wire.wait", "ring.wire.recv",
+        }
+        assert all(tracing.is_part(n) for n in sink if n != "ring")
+        assert seen == ["ring"]  # the histogram takes phases alone
+        for name in ("ring.wire.arrive", "ring.wire.wait", "ring.wire.recv"):
+            part = by[name]
+            assert part["parent_span_id"] == by["ring.wire"]["span_id"]
+            assert by["ring.wire"]["start_ns"] <= part["start_ns"]
+            assert part["end_ns"] <= by["ring.wire"]["end_ns"] + 1000
+            assert part["attributes"]["step"] == 7
+            # the lapped ones carry what they booked, the plain one its wall
+            assert ("seconds" in part["attributes"]) == (name != "ring.wire.arrive")
+        assert sink["ring.wire.arrive"] >= 0.004
+        assert sink["ring.wire.wait"] >= 0.008 and sink["ring.wire.recv"] >= 0.006
+        inside = sum(sink["ring.wire." + p] for p in ("arrive", "wait", "recv"))
+        assert inside <= sink["ring.wire"]
+        assert inside == pytest.approx(sink["ring.wire"], rel=0.1)
+        assert sink["ring.wire"] + sink["ring.reduce"] <= sink["ring"]
+
+    def test_a_part_of_an_orphan_part_is_only_the_annotation(self, span_file):
+        """A bare ``pg.allreduce`` (no ``ring`` open): ``.wire`` is only
+        the annotation, and so is what is opened inside it; the seconds
+        are still there for the counter."""
+        path, _ = span_file
+        with tracing.phase(".wire") as wire:
+            with tracing.phase(".arrive") as arrive:
+                time.sleep(0.002)
+            wait = tracing.phase(".wait")
+            with wait.lap():
+                time.sleep(0.002)
+            assert wait.end() >= 0.002
+        assert (wire.name, arrive.name, wait.name) == ("wire", "wire.arrive", "wire.wait")
+        assert arrive.sink is None and arrive.seconds >= 0.002
+        tracing.uninstall_tracer()
+        assert not path.exists()
+
     def test_exclude_books_less_and_span_keeps_its_ends(self, span_file):
         path, _ = span_file
         sink = {}
@@ -528,6 +596,15 @@ class TestPhase:
             with tracing.phase("ring", sink, step=3):
                 with tracing.phase(".d2h", bytes=12):
                     step(x).block_until_ready()
+                with tracing.phase(".wire"):
+                    # once an op and plain, so an annotation; the lapped
+                    # parts beside it are none
+                    with tracing.phase(".arrive"):
+                        time.sleep(0.002)
+                    wait = tracing.phase(".wait")
+                    with wait.lap():
+                        pass
+                    wait.end()
         (pb,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
         found = {}
         for plane in ProfileData.from_file(pb).planes:
@@ -537,12 +614,85 @@ class TestPhase:
                 for ev in line.events:
                     if ev.name.startswith("torchft."):
                         found[ev.name] = (ev.start_ns, ev.duration_ns, dict(ev.stats))
-        assert set(found) == {"torchft.ring", "torchft.ring.d2h"}
+        assert set(found) == {
+            "torchft.ring", "torchft.ring.d2h", "torchft.ring.wire",
+            "torchft.ring.wire.arrive",
+        }
+        a0, ad, _ = found["torchft.ring.wire.arrive"]
+        v0, vd, _ = found["torchft.ring.wire"]
+        assert v0 <= a0 and a0 + ad <= v0 + vd and ad >= 2e6
         w0, wd, wstats = found["torchft.ring"]
         p0, pd, pstats = found["torchft.ring.d2h"]
         assert w0 <= p0 and p0 + pd <= w0 + wd
         assert wstats["step"] == 3 and pstats["bytes"] == 12
         assert pd / 1e9 == pytest.approx(sink["ring.d2h"], rel=0.5, abs=2e-3)
+
+
+class TestWirePartsVocabulary:
+    """ISSUE 38 (g): the four parts of ``ring.wire`` are in
+    ``manager.PHASE_PARTS`` and the ``span-vocab`` lint, run against the
+    tree's own tuples, takes a part of a part by its last component and
+    keeps refusing a name outside them."""
+
+    PARTS = tuple("ring.wire." + p for p in ("arrive", "wait", "recv", "send"))
+
+    def _findings(self, tmp_path, body):
+        import inspect
+        import textwrap
+
+        from torchft_tpu import manager
+        from torchft_tpu.analysis import PASSES, Project, run_passes
+
+        files = {
+            "pkg/manager.py": inspect.getsource(manager),
+            "pkg/mod.py": "from torchft_tpu.utils import tracing\n\n"
+            + textwrap.dedent(body),
+        }
+        paths = []
+        for rel, src in files.items():
+            path = tmp_path / rel
+            path.parent.mkdir(parents=True, exist_ok=True)
+            path.write_text(src)
+            paths.append(str(path))
+        (tmp_path / "docs").mkdir()
+        lint = next(p for p in PASSES if p.id == "span-vocab")
+        results = run_passes(
+            [lint], Project(str(tmp_path), paths), baseline_dir=str(tmp_path / "nb")
+        )
+        return [f for r in results for f in r.findings if f.file.endswith("mod.py")]
+
+    def test_the_four_names_are_parts(self):
+        from torchft_tpu.manager import PHASE_PARTS
+
+        assert set(self.PARTS) <= set(PHASE_PARTS)
+        assert "ring.wire" in PHASE_PARTS
+        assert all(tracing.is_part(p) for p in self.PARTS)
+
+    def test_span_vocab_accepts_them(self, tmp_path):
+        assert not self._findings(
+            tmp_path,
+            """
+            def ring():
+                with tracing.phase(".wire"):
+                    with tracing.phase(".arrive"):
+                        pass
+                    wait = tracing.phase(".wait")
+                    recv, send = tracing.phase(".recv"), tracing.phase(".send")
+            """,
+        )
+
+    @pytest.mark.parametrize("name", [".bogus", "ring.wire.bogus"])
+    def test_span_vocab_refuses_a_name_outside_the_tuples(self, tmp_path, name):
+        findings = self._findings(
+            tmp_path,
+            f"""
+            def ring():
+                with tracing.phase(".wire"), tracing.phase({name!r}):
+                    pass
+            """,
+        )
+        assert [f.code for f in findings] == ["unknown-span-name"]
+        assert findings[0].symbol == name
 
 
 class TestDisabledPathBudget:

@@ -279,10 +279,16 @@ class TestRingOpened:
             ]
             results = [f.result(timeout=120) for f in futs]
         tracing.uninstall_tracer()
-        ring_parts = {p for p in PHASE_PARTS if p.startswith("ring.")}
+        named = {p for p in PHASE_PARTS if p.startswith("ring.")}
+        # ring's own parts, and the parts of one of them (ISSUE 38)
+        wire_parts = {p for p in named if p.startswith("ring.wire.")}
+        ring_parts = named - wire_parts
+        assert wire_parts == {
+            "ring.wire." + p for p in ("arrive", "wait", "recv", "send")
+        }
         for res in results:
             phases = res["phases"]
-            assert ring_parts <= set(phases), sorted(phases)
+            assert named <= set(phases), sorted(phases)
             # the histogram's ``phase`` label takes phases, never a part
             assert "ring" in res["histogram"]
             assert not {k for k in res["histogram"] if tracing.is_part(k)}
@@ -290,6 +296,11 @@ class TestRingOpened:
             assert phases["ring"] > 0.03, phases  # tens of ms and more
             assert opened <= phases["ring"]
             assert opened >= 0.9 * phases["ring"], (opened, phases["ring"])
+            # and the same one level down, where an exchange blocks (how
+            # closely, without a tracer's exports inside the wall, is
+            # tests/test_process_group.py's to say)
+            inside = sum(phases[p] for p in wire_parts)
+            assert 0.5 * phases["ring.wire"] <= inside <= phases["ring.wire"]
         # in the trace every part is a child of a ring span and lies in it
         spans = _load_spans(trace_file)
         by_id = {s["span_id"]: s for s in spans}
@@ -306,6 +317,23 @@ class TestRingOpened:
         rings = [s for s in spans if s["name"] == "ring"]
         wires = [s for s in parts if s["name"] == "ring.wire"]
         assert len(wires) == 2 * len(rings)
+        # the same holds inside ring.wire: a span a bucket for each part
+        # that accumulates, carrying the seconds it booked, and one
+        # ``arrive`` a ring, a plain span in its first bucket's wire
+        inside = [s for s in spans if s["name"] in wire_parts]
+        for s in inside:
+            whole = by_id[s["parent_span_id"]]
+            assert whole["name"] == "ring.wire"
+            assert whole["start_ns"] <= s["start_ns"]
+            assert s["end_ns"] <= whole["end_ns"] + 1000  # ns: int rounding
+        count = {p: sum(s["name"] == p for s in inside) for p in wire_parts}
+        assert count["ring.wire.arrive"] == len(rings)
+        for p in ("wait", "recv", "send"):
+            assert count["ring.wire." + p] == len(wires)
+        assert all(
+            ("seconds" in s["attributes"]) == (s["name"] != "ring.wire.arrive")
+            for s in inside
+        )
         # ring.d2h is one span a ring over its stretches (the start of the
         # copies, then a wait a bucket): it carries the seconds it booked,
         # and host leaves are their own host arrays, so nothing was sent

@@ -382,10 +382,12 @@ class TestPhaseVocabulary:
         assert full <= set(mgr.PHASE_PARTS)
         assert all(any(p.endswith(r) for p in mgr.PHASE_PARTS) for r in relative)
         # every part is timed somewhere, under its full name or its last
-        # component, and its whole is a top-level phase
+        # component, and its whole is a top-level phase or a part of one
+        # (``ring.wire.arrive`` lies in ``ring.wire``, that in ``ring``)
         for part in mgr.PHASE_PARTS:
             whole, _, last = part.rpartition(".")
-            assert whole in PROTOCOL_PHASES
+            assert whole in PROTOCOL_PHASES or whole in mgr.PHASE_PARTS
+            assert part.partition(".")[0] in PROTOCOL_PHASES
             assert part in full or "." + last in relative, part
 
 

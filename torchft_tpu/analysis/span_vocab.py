@@ -12,7 +12,8 @@ each ``phase(...)`` / ``_phase(...)`` call site must name a literal from
 (``.hash``: a part of whatever phase is open, the one way a part is
 opened), which must be the last component of some entry of
 ``manager.PHASE_PARTS`` (a second tuple beside the phases: ``ring.d2h``
-is contained in ``ring``).  The profiler
+is contained in ``ring``, and ``ring.wire.arrive``, a part of a part, in
+``ring.wire``).  The profiler
 annotation of a phase is ``torchft.<name>``; a ``TraceAnnotation`` literal
 under the ``torchft`` prefix anywhere else must be one of those too (no
 second naming scheme).  Every raw ``export_span`` call site must name its
@@ -373,7 +374,8 @@ def _run_on_project(files: "Dict[str, str]") -> "List[Finding]":
 
 _MANAGER_SRC = (
     'PROTOCOL_PHASES = ("quorum_rpc", "ring", "commit", "heal_send")\n'
-    'PHASE_PARTS = ("ring.d2h", "heal_send.hash")\n'
+    'PHASE_PARTS = ("ring.d2h", "ring.wire", "ring.wire.arrive", '
+    '"heal_send.hash")\n'
 )
 
 _GOOD_SRC = """
@@ -386,6 +388,8 @@ def _phase(name, **attrs):
 def step(tracer, sink):
     with _phase("ring"):
         with tracing.phase(".d2h", bytes=1):
+            pass
+        with tracing.phase(".wire"), tracing.phase(".arrive"):
             pass
     with _phase("heal_send"), tracing.phase(".hash"):
         pass

@@ -147,6 +147,16 @@ PHASE_CATEGORY = {
     "layout_commit": "protocol",
 }
 
+#: a part whose seconds are of another category than its phase's: inside
+#: the ring, the PG worker blocked on a peer that had not reached the ring
+#: yet, or was late with a chunk (with an async quorum a slow group is
+#: waited for here and not at the quorum).  Such a part REFINES its phase:
+#: its seconds move from the phase's category to its own.
+PART_CATEGORY = {
+    "ring.wire.arrive": "straggler-wait",
+    "ring.wire.wait": "straggler-wait",
+}
+
 #: the ledger's full category vocabulary, in render order
 LEDGER_CATEGORIES = ("compute", "codec", "wire", "protocol", "straggler-wait")
 
@@ -156,10 +166,12 @@ def ledger_categories(phase_times: "Dict[str, Any]") -> "Dict[str, float]":
     a timeline bucket's ``phase_ms``) into ledger categories.  Unknown
     phase names count as ``protocol`` (they are protocol bookkeeping by
     construction — every traced phase is in ``manager.PROTOCOL_PHASES``).
-    A name with a dot is a part of the phase before the dot
-    (``manager.PHASE_PARTS``) and is skipped: its seconds are in its
-    whole already."""
+    A name with a dot is a part of the phase before the first dot
+    (``manager.PHASE_PARTS``): its seconds are in its whole already, so it
+    adds nothing; a part of :data:`PART_CATEGORY` moves its seconds, at
+    most its phase's, out of the phase's category into its own."""
     out: "Dict[str, float]" = {}
+    left: "Dict[str, float]" = {}  # of a phase, for its parts to take from
     for name, dur in phase_times.items():
         if "." in name:
             continue
@@ -167,8 +179,19 @@ def ledger_categories(phase_times: "Dict[str, Any]") -> "Dict[str, float]":
             v = float(dur)
         except (TypeError, ValueError):
             continue
+        left[name] = v
         cat = PHASE_CATEGORY.get(name, "protocol")
         out[cat] = out.get(cat, 0.0) + v
+    for part, cat in PART_CATEGORY.items():
+        whole = part.partition(".")[0]
+        try:
+            v = min(float(phase_times.get(part) or 0.0), left.get(whole, 0.0))
+        except (TypeError, ValueError):
+            continue
+        if v > 0.0:
+            left[whole] -= v
+            out[PHASE_CATEGORY.get(whole, "protocol")] -= v
+            out[cat] = out.get(cat, 0.0) + v
     return out
 
 
@@ -921,6 +944,11 @@ def analyze_trace(spans: "List[Dict[str, Any]]") -> "Dict[str, Any]":
     - ``quant.pipeline``'s ``codec_s``/``wire_s`` attributes, which
       REPLACE the ``ring`` phase when present (ring wraps the pipeline —
       counting both would double-bill the wire);
+    - the ring's ``ring.wire.arrive`` / ``ring.wire.wait`` parts
+      (:data:`PART_CATEGORY`), which REFINE ``ring``: the seconds its
+      worker was blocked on a peer that had not reached the ring, or was
+      late with a chunk, move from wire to straggler-wait (a trace without
+      the parts reads as before);
     - the lighthouse's ``rpc.quorum`` server span, which REFINES
       straggler-wait (it measures exactly the block-until-quorum-forms
       wait; the ``quorum_wait`` phase then only contributes any excess).
@@ -946,10 +974,21 @@ def analyze_trace(spans: "List[Dict[str, Any]]") -> "Dict[str, Any]":
         quorum_id = (roots[0].get("attributes") or {}).get("quorum_id")
         root_ids = {s.get("span_id"): s for s in roots}
         children: "Dict[str, List[Dict[str, Any]]]" = defaultdict(list)
+        by_id = {s.get("span_id"): s for s in sp}
         for s in sp:
             parent = s.get("parent_span_id")
             if parent in root_ids and s.get("name") != "quorum_round":
                 children[parent].append(s)
+            elif s.get("name") in PART_CATEGORY:
+                # a refining part lies below its phase's span (round ->
+                # ring -> ring.wire -> the part): file it under its round
+                up = by_id.get(parent)
+                for _ in range(3):
+                    if up is None or up.get("span_id") in root_ids:
+                        break
+                    up = by_id.get(up.get("parent_span_id"))
+                if up is not None and up.get("span_id") in root_ids:
+                    children[up["span_id"]].append(s)
 
         replicas: "Dict[str, Dict[str, Any]]" = {}
         for root in roots:
@@ -981,7 +1020,7 @@ def analyze_trace(spans: "List[Dict[str, Any]]") -> "Dict[str, Any]":
                 if not c.get("ok", True):
                     info["ok"] = False
                     info["failed_span"] = info["failed_span"] or name
-                if name in PHASE_CATEGORY:
+                if name in PHASE_CATEGORY or name in PART_CATEGORY:
                     phase_sums[name] = phase_sums.get(name, 0.0) + _span_dur_s(c)
                 elif name == "quant.pipeline":
                     quant_seen = True

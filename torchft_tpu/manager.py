@@ -109,7 +109,11 @@ PHASE_PARTS = (
     "ring.queue",  # submit until the worker picks the op up
     "ring.d2h",  # held by the device→host leg: the relayout's dispatch, then a bucket's starts and its wait
     "ring.pack",  # bucket concat, pad-in of a widened leaf or a tail, copy of a host leaf
-    "ring.wire",  # the exchanges: send + receive + waiting for the peer
+    "ring.wire",  # the wall of a bucket's 2(w-1) exchanges less the reduces between them; its four parts:
+    "ring.wire.arrive",  # first exchange of the op: until the previous rank's first byte, i.e. until it reached the ring
+    "ring.wire.wait",  # every later exchange: until the peer's next message starts (it is in the ring, late with a chunk)
+    "ring.wire.recv",  # a message's header and payload coming in
+    "ring.wire.send",  # handing the send to the sender thread, then what is left of it once the receive has returned
     "ring.reduce",  # the in-place ufunc between exchanges
     "ring.unpack",  # the division in place, cast back, split, unflatten
     # fragments.iter_heal_fragments, per fragment, under whoever called
@@ -1625,8 +1629,9 @@ class Manager:
         ``torchft_quorum_duration_seconds`` histogram, which the phases
         (not their parts) also feed.
 
-        **A key with a dot is contained in the key before the dot**
-        (``ring.d2h`` is part of ``ring``): sum the keys without a dot, or
+        **A key with a dot is contained in the key before its last dot**
+        (``ring.d2h`` is part of ``ring``, ``ring.wire.arrive`` of
+        ``ring.wire``): sum the keys without a dot, or
         a part counts against its whole (``tracing.is_part``).
 
         Caller-thread keys: ``quorum_wait`` (blocked waiting for the async

@@ -26,6 +26,7 @@ trick; the JAX mesh composition lives in torchft_tpu/parallel/device_mesh.py).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import itertools
 import logging
@@ -60,6 +61,10 @@ REDUCE_SUM = "sum"
 REDUCE_AVG = "avg"
 REDUCE_MAX = "max"
 REDUCE_MIN = "min"
+
+# What times the stretches of an exchange where no ring is open on it
+# (allgather, send / recv, the PG heal transport): nothing.
+_UNTIMED = contextlib.nullcontext()
 
 # in-place reduction ufuncs for ring steps (AVG divides at the end)
 _REDUCE_UFUNCS: Dict[str, Any] = {
@@ -623,7 +628,8 @@ class ProcessGroupTCP(ProcessGroup):
     def _bind_metrics(self) -> None:
         """``torchft_ring_buffers_total`` children by pool hit,
         ``torchft_ring_leaves_prefetched_total`` children by whether the
-        copy was ready, and ``torchft_ring_leaves_kept_total``, under the
+        copy was ready, ``torchft_ring_peer_wait_seconds_total`` children by
+        which wait it was, and ``torchft_ring_leaves_kept_total``, under the
         stable replica id."""
         self._metric_replica_id = _stable_replica_id(self._replica_id)
         self._m_leaves_kept = _metrics.RING_LEAVES_KEPT.labels(
@@ -642,6 +648,12 @@ class ProcessGroupTCP(ProcessGroup):
                 result="hit" if hit else "miss",
             )
             for hit in (True, False)
+        }
+        self._m_peer_wait = {
+            kind: _metrics.RING_PEER_WAIT.labels(
+                replica_id=self._metric_replica_id, kind=kind
+            )
+            for kind in ("arrive", "wait")
         }
 
     def set_bandwidth(self, gbps: "Optional[float]") -> None:
@@ -1109,50 +1121,60 @@ class ProcessGroupTCP(ProcessGroup):
         tag: int,
         deadline: float,
         out: "Optional[np.ndarray]" = None,
+        head: Any = _UNTIMED,
+        body: Any = _UNTIMED,
     ) -> np.ndarray:
         """Receive one tagged array; ``out`` receives in place (zero-alloc
         fast path for ring steps — reference pg_transport in-place recv
-        analog, torchft/checkpointing/pg_transport.py:230-300)."""
+        analog, torchft/checkpointing/pg_transport.py:230-300).
+
+        The two places a receive blocks are entered as ``head`` (until the
+        message's first 8 bytes are here: the wait for the peer) and
+        ``body`` (the rest of it: the bytes); a ring hands in the parts of
+        its ``ring.wire``, everyone else nothing."""
         peer = self._peer(src)
-        # record the blocked-on peer BEFORE the header read: a wedged recv
-        # (peer never sends) hangs right here, and that is exactly the state
-        # the flight recorder must capture
-        self._flight_io(recv_peer=src, recv_tag=tag, deadline_mono=deadline)
-        hlen, nbytes = struct.unpack(
-            ">II", self._read_exact_sock(peer.sock, 8, deadline)
-        )
-        header = pickle.loads(self._read_exact_sock(peer.sock, hlen, deadline))
-        if header["tag"] != tag:
-            raise RuntimeError(
-                f"collective tag mismatch: expected {tag}, got {header['tag']}"
+        with head:
+            # record the blocked-on peer BEFORE the header read: a wedged
+            # recv (peer never sends) hangs right here, and that is exactly
+            # the state the flight recorder must capture
+            self._flight_io(recv_peer=src, recv_tag=tag, deadline_mono=deadline)
+            lengths = self._read_exact_sock(peer.sock, 8, deadline)
+        with body:
+            hlen, nbytes = struct.unpack(">II", lengths)
+            header = pickle.loads(
+                self._read_exact_sock(peer.sock, hlen, deadline)
             )
-        if out is None:
-            # Pool-backed receive: repeated collective shapes (ring chunks,
-            # the quantized pipeline's per-chunk wire buffers) re-take the
-            # SAME pages their consumers gave back, so steady-state receive
-            # allocation — and its mmap page-fault bill — is zero.  Buffers
-            # that escape to callers simply never return to the pool (take
-            # falls back to np.empty on a miss), same contract as before.
-            out = _pool.take(header["shape"], np.dtype(header["dtype"]))
-            if out.nbytes != nbytes:
+            if header["tag"] != tag:
                 raise RuntimeError(
-                    f"collective payload size mismatch: header says {nbytes},"
-                    f" shape/dtype imply {out.nbytes}"
+                    f"collective tag mismatch: expected {tag}, got {header['tag']}"
                 )
-        else:
-            _check_recv_buffer(out, header["shape"], header["dtype"])
-            if out.nbytes != nbytes:
-                raise RuntimeError(
-                    f"collective payload size mismatch: header says {nbytes},"
-                    f" shape/dtype imply {out.nbytes}"
+            if out is None:
+                # Pool-backed receive: repeated collective shapes (ring chunks,
+                # the quantized pipeline's per-chunk wire buffers) re-take the
+                # SAME pages their consumers gave back, so steady-state receive
+                # allocation — and its mmap page-fault bill — is zero.  Buffers
+                # that escape to callers simply never return to the pool (take
+                # falls back to np.empty on a miss), same contract as before.
+                out = _pool.take(header["shape"], np.dtype(header["dtype"]))
+                if out.nbytes != nbytes:
+                    raise RuntimeError(
+                        f"collective payload size mismatch: header says {nbytes},"
+                        f" shape/dtype imply {out.nbytes}"
+                    )
+            else:
+                _check_recv_buffer(out, header["shape"], header["dtype"])
+                if out.nbytes != nbytes:
+                    raise RuntimeError(
+                        f"collective payload size mismatch: header says {nbytes},"
+                        f" shape/dtype imply {out.nbytes}"
+                    )
+            self._flight_io(recv_bytes=nbytes)
+            if nbytes:
+                # uint8 view for ml_dtypes compat (see _send_msg)
+                self._read_into_sock(
+                    peer.sock, memoryview(out.reshape(-1).view(np.uint8)), deadline
                 )
-        self._flight_io(recv_bytes=nbytes)
-        if nbytes:
-            # uint8 view for ml_dtypes compat (see _send_msg)
-            self._read_into_sock(
-                peer.sock, memoryview(out.reshape(-1).view(np.uint8)), deadline
-            )
-        return out
+            return out
 
     def _exchange(
         self,
@@ -1163,36 +1185,50 @@ class ProcessGroupTCP(ProcessGroup):
         recv_tag: int,
         deadline: float,
         recv_out: "Optional[np.ndarray]" = None,
+        head: Any = _UNTIMED,
+        body: Any = _UNTIMED,
+        tail: Any = _UNTIMED,
     ) -> np.ndarray:
         """Simultaneous send+recv without deadlocking on full TCP buffers.
 
         Ring steps send and receive concurrently; pushing the send to the
         persistent sender thread keeps both directions draining even when
         payloads exceed socket buffer sizes.
+
+        The stretches of the calling thread's wall are entered as ``head``
+        and ``body`` (:meth:`_recv_msg`) and, twice, ``tail``: what the
+        send costs this thread, handing it over and then what is left of
+        it once the receive has returned.  The sender thread is timed by
+        none of them: a send that overlaps the receive is no part of the
+        caller's wall.
         """
         sender = self._sender
         if sender is None:
             raise _PGAborted("process group not configured/running")
-        send_fut = sender.submit(
-            self._send_msg, send_dst, send_tag, send_array, deadline
-        )
+        with tail:
+            send_fut = sender.submit(
+                self._send_msg, send_dst, send_tag, send_array, deadline
+            )
         send_err: "Optional[BaseException]" = None
         try:
-            received = self._recv_msg(recv_src, recv_tag, deadline, out=recv_out)
+            received = self._recv_msg(
+                recv_src, recv_tag, deadline, out=recv_out, head=head, body=body
+            )
         finally:
             # always reap the send: the socket stream must never be left
             # mid-write when the next step starts (a recv error still
             # propagates; it takes precedence over any send error)
-            try:
-                send_fut.result(
-                    timeout=max(deadline - time.monotonic(), 0.001) + 1.0
-                )
-            except concurrent_futures.TimeoutError:
-                send_err = TimeoutError(
-                    "collective send did not complete by deadline"
-                )
-            except BaseException as e:  # noqa: BLE001 - re-raised below
-                send_err = e
+            with tail:
+                try:
+                    send_fut.result(
+                        timeout=max(deadline - time.monotonic(), 0.001) + 1.0
+                    )
+                except concurrent_futures.TimeoutError:
+                    send_err = TimeoutError(
+                        "collective send did not complete by deadline"
+                    )
+                except BaseException as e:  # noqa: BLE001 - re-raised below
+                    send_err = e
         if send_err is not None:
             raise send_err
         return received
@@ -1385,7 +1421,7 @@ class ProcessGroupTCP(ProcessGroup):
                 host = [host_array(i) for i in idxs]
             if len(idxs) == 1:
                 results[idxs[0]] = self._allreduce_one(
-                    host[0], op, divisor, deadline
+                    host[0], op, divisor, deadline, b == 0
                 )
                 continue
             # cast leaves individually: mixed input dtypes sharing one acc
@@ -1399,7 +1435,7 @@ class ProcessGroupTCP(ProcessGroup):
                         for a in host
                     ]
                 )
-            reduced = self._allreduce_one(flat, op, divisor, deadline)
+            reduced = self._allreduce_one(flat, op, divisor, deadline, b == 0)
             with _tracing.phase(".unpack", leaves=len(idxs)):
                 off = 0
                 for i, a in zip(idxs, host):
@@ -1417,10 +1453,11 @@ class ProcessGroupTCP(ProcessGroup):
         op: str,
         divisor: "Optional[int]",
         deadline: float,
+        first: bool = False,
     ) -> np.ndarray:
-        """One ring over one buffer.  A gradient byte is written on the
-        host only by an operation that changes it, and only into memory
-        that is already faulted:
+        """One ring over one buffer, the op's ``first`` or a later one.  A
+        gradient byte is written on the host only by an operation that
+        changes it, and only into memory that is already faulted:
 
         - the buffer is leased from the pool (``utils/bufpool.py``): the
           result is a view of it, and its memory comes back when the caller
@@ -1483,10 +1520,30 @@ class ProcessGroupTCP(ProcessGroup):
         # ring.wire is the wall of the 2(w-1) exchanges less the reduces
         # between them; ring.reduce accumulates over its w-1 stretches
         reduce = _tracing.phase(".reduce")
+        # ring.wire, opened where an exchange blocks this thread.  The wait
+        # for a message's first bytes is the peer's: in the op's first
+        # exchange, which depends on nothing the previous rank received, it
+        # is how much later than this rank that one reached the ring
+        # (``.arrive``, once an op: a plain phase, so also an annotation
+        # beside the device trace); in every later one the peer is in the
+        # ring and late with this chunk (``.wait``).  ``.recv`` is the
+        # message's bytes coming in, ``.send`` what this rank's send costs
+        # this thread: handing it to the sender, then what is left of it
+        # once the receive has returned.  The last three accumulate, like
+        # ``.reduce``: one span a part and bucket.  They are made as the
+        # wire's before its wall starts and booked after it has ended, so
+        # that the wall holds the exchanges and little else.
+        wire = _tracing.phase(
+            ".wire", bytes=2 * (w - 1) * chunk * acc_dtype.itemsize
+        )
+        with _tracing.under(wire):
+            arrive = _tracing.phase(".arrive") if first else None
+            wait = _tracing.phase(".wait")
+            recv = _tracing.phase(".recv")
+            send = _tracing.phase(".send")
+        head, body, tail = wait.lap(), recv.lap(), send.lap()
         try:
-            with _tracing.phase(
-                ".wire", bytes=2 * (w - 1) * chunk * acc_dtype.itemsize
-            ) as wire:
+            with wire:
                 # ring reduce-scatter: after w-1 steps, chunk (r+1)%w is
                 # fully reduced
                 for step in range(w - 1):
@@ -1496,6 +1553,8 @@ class ProcessGroupTCP(ProcessGroup):
                         nxt, 100 + step,
                         chunks[send_idx] if step else own[send_idx],
                         prv, 100 + step, deadline, recv_out=scratch,
+                        head=head if step or arrive is None else arrive,
+                        body=body, tail=tail,
                     )
                     with reduce.lap():
                         reduce_into(
@@ -1509,9 +1568,17 @@ class ProcessGroupTCP(ProcessGroup):
                     self._exchange(
                         nxt, 200 + step, chunks[send_idx], prv, 200 + step,
                         deadline, recv_out=chunks[recv_idx],
+                        head=head, body=body, tail=tail,
                     )
                 wire.exclude(reduce.end())
         finally:
+            # of a ring that failed too: how long it waited for a peer that
+            # never came is what its operator asks first
+            recv.end()
+            send.end()
+            self._m_peer_wait["wait"].inc(wait.end())
+            if arrive is not None:
+                self._m_peer_wait["arrive"].inc(arrive.seconds)
             _pool.give(scratch)
         with _tracing.phase(".unpack", bytes=array.nbytes):
             # a leaf that widened is cast back into a new array, and the

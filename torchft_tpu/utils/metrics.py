@@ -1194,6 +1194,20 @@ RING_LEAVES_PREFETCHED = counter(
     "the share of leaves whose device-to-host leg hid behind the wire",
     ("replica_id", "result"),
 )
+RING_PEER_WAIT = counter(
+    "torchft_ring_peer_wait_seconds_total",
+    "Seconds the PG worker of a plain allreduce at world size > 1 was blocked "
+    "on the previous rank's next message before its first byte came "
+    "(parallel/process_group.py, the parts ring.wire.arrive / ring.wire.wait): "
+    "arrive in the op's first exchange, which is how much later than this "
+    "group that rank reached the ring (its step, its device-to-host leg, its "
+    "heal), wait in every later exchange (the peer is in the ring and late "
+    "with a chunk).  Over a fleet the group whose arrive rate is lowest is "
+    "the one the others wait for; arrive a step against "
+    "torchft_quorum_duration_seconds{phase=\"ring\"} is the share of the ring "
+    "no change to the wire can touch",
+    ("replica_id", "kind"),
+)
 LINK_GOODPUT = gauge(
     "torchft_link_goodput_bytes_per_s",
     "Passively measured link goodput by peer host and transfer plane "

@@ -360,8 +360,9 @@ def add_seconds(sink: "MutableMapping[str, float]", name: str, seconds: float) -
 
 def is_part(name: str) -> bool:
     """The one rule for nesting: a name with a dot is a part, contained in
-    the phase named before the dot.  Whatever SUMS phases takes the names
-    that are not parts, so no part is counted against its whole."""
+    the name before its last dot (``ring.wire.arrive`` in ``ring.wire``,
+    that in ``ring``).  Whatever SUMS phases takes the names that are not
+    parts, so no part is counted against its whole."""
     return "." in name
 
 
@@ -441,8 +442,9 @@ class phase:
 
     A name that starts with a dot is a part of whatever phase is open on
     this thread: ``.hash`` inside ``heal_send`` is ``heal_send.hash``, in
-    the same sink, its span a child of ``heal_send``'s; with no phase open
-    it is only the annotation.  :class:`under` carries a whole to another
+    the same sink, its span a child of ``heal_send``'s, and a part has
+    parts the same way (``.arrive`` inside ``ring.wire``); with no phase
+    open it is only the annotation.  :class:`under` carries a whole to another
     thread (``ring`` to the PG worker).  A part stays out of the flight
     ring and the histogram, which count phases.  Any phase takes the
     attributes and ``observe`` of the phase it is opened inside.
@@ -479,6 +481,9 @@ class phase:
         if whole is not None:
             name = whole.name + name
             sink = whole.sink if sink is None else sink
+            # a part of a part (``.arrive`` inside ``.wire``) of no phase
+            # is, like it, only the annotation
+            self._recorded = whole._recorded
         elif name.startswith("."):
             name, self._recorded = name[1:], False
         if outer is not None:
