@@ -373,6 +373,128 @@ class TestFlashWindow:
             np.asarray(dense_attention(q, k, v, window=128)), np.asarray(dense_attention(q, k, v)))
 
 
+class TestFlashTilesByPlace:
+    """A causal call without offsets does a tile's work by its place: tiles
+    under the diagonal unmasked and unguarded, the tiles the mask cuts (the
+    diagonal one, a window's older edge) in blocks that leave out the
+    sub-blocks with no live pair, nothing above the diagonal.  Grids with
+    tiles of all three kinds, against dense attention."""
+
+    @staticmethod
+    def _tiles_of(monkeypatch, tile, sub):
+        """Tiles of at most ``tile`` (the test's ``at_most``) cut into blocks
+        of ``sub``, so that a short sequence has a grid of several tiles."""
+        from torchft_tpu.ops import flash_attention as fa
+
+        block_size = fa._block_size
+        monkeypatch.setattr(
+            fa, "_block_size", lambda t, d, at_most=1024: block_size(t, d, min(at_most, tile)))
+        if sub is not None:
+            monkeypatch.setattr(fa, "_sub_block", lambda kernel, blk, d, dv: min(sub, blk))
+
+    @staticmethod
+    def _qkv(t, d, dv, h, hkv, seed=7):
+        key = jax.random.PRNGKey(seed)
+        q = jax.random.normal(jax.random.fold_in(key, 0), (1, t, h, d), jnp.float32)
+        k = jax.random.normal(jax.random.fold_in(key, 1), (1, t, hkv, d), jnp.float32)
+        v = jax.random.normal(jax.random.fold_in(key, 2), (1, t, hkv, dv), jnp.float32)
+        return q, k, v
+
+    @staticmethod
+    def _check(q, k, v, reference, **kw):
+        weight = jax.random.normal(jax.random.PRNGKey(9), q.shape[:3] + v.shape[3:])
+
+        def both(fn):
+            out, grads = jax.value_and_grad(
+                lambda q, k, v: (fn(q, k, v) * weight).sum(), argnums=(0, 1, 2))(q, k, v)
+            return [fn(q, k, v), *grads]
+
+        got, want = both(lambda q, k, v: flash_attention(q, k, v, **kw)), both(reference)
+        for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+            top = float(np.abs(np.asarray(b)).max()) + 1e-12
+            np.testing.assert_allclose(np.asarray(a) / top, np.asarray(b) / top,
+                                       atol=1e-5, err_msg=name)
+
+    # heads of 64 with grouped keys and values, 192 against 128; four tiles of
+    # 128 a side: six under the diagonal, four on it, six above
+    @pytest.mark.parametrize("sub", [32, 64, 128, None], ids=lambda s: f"blocks-{s}")
+    @pytest.mark.parametrize("d,dv,h,hkv", [(64, 64, 4, 2), (192, 128, 2, 2)])
+    def test_forward_and_three_gradients_match_dense(self, monkeypatch, d, dv, h, hkv, sub):
+        self._tiles_of(monkeypatch, 128, sub)
+        q, k, v = self._qkv(512, d, dv, h, hkv)
+        self._check(q, k, v, dense_attention)
+
+    def test_one_head_at_2048_on_tiles_of_1024(self):
+        """The flagship cell's grid, 2 x 2, as the rule cuts it."""
+        from torchft_tpu.ops.flash_attention import tile_kinds
+
+        kinds = tile_kinds(2048, 2048, 64, 64)
+        assert (kinds["under"], kinds["diagonal"], kinds["above"]) == (1, 2, 1)
+        q, k, v = self._qkv(2048, 64, 64, 1, 1)
+        self._check(q, k, v, dense_attention)
+
+    # a window of two tiles (one tile on the older edge, cut like the
+    # diagonal), one that ends inside a tile (two tiles on the edge), one
+    # shorter than a tile (the diagonal tile holds both edges)
+    @pytest.mark.parametrize("window", [256, 200, 100])
+    @pytest.mark.parametrize("sub", [32, 128])
+    def test_a_windows_edges_are_cut_the_same_way(self, monkeypatch, window, sub):
+        self._tiles_of(monkeypatch, 128, sub)
+        q, k, v = self._qkv(768, 32, 32, 2, 1)
+        self._check(q, k, v, lambda q, k, v: _masked_softmax(q, k, v, window), window=window)
+
+    @pytest.mark.parametrize("d,exact", [(64, True), (256, True), (16, True), (128, False), (192, False)])
+    def test_the_scale_is_exact_at_heads_of_a_power_of_four(self, d, exact):
+        from torchft_tpu.ops.flash_attention import _exact_scale
+
+        assert _exact_scale(1.0 / np.sqrt(d)) == exact
+
+    @pytest.mark.parametrize("sub", [32, 128])
+    def test_folding_the_scale_into_the_queries_changes_no_bit(self, monkeypatch, sub):
+        """Heads of 64: ``scale`` is 2^-3, so the kernels multiply the query
+        rows and the accumulators in place of the pairs; the output and the
+        three gradients are the bits of the same kernels with the scale left
+        on the pairs."""
+        from torchft_tpu.ops import flash_attention as fa
+
+        self._tiles_of(monkeypatch, 128, sub)
+        q, k, v = self._qkv(512, 64, 64, 4, 2)
+
+        def both():
+            out, grads = jax.value_and_grad(
+                lambda q, k, v: (flash_attention(q, k, v) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+            return [flash_attention(q, k, v), out, *grads]
+
+        assert fa._exact_scale(1.0 / np.sqrt(64))
+        folded = both()
+        monkeypatch.setattr(fa, "_exact_scale", lambda scale: False)
+        on_the_pairs = both()
+        for a, b in zip(folded, on_the_pairs):
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+    @pytest.mark.parametrize("k_off", [64, 128, 4096])
+    def test_a_chunk_before_every_key_is_zeros_and_finite_gradients(self, k_off):
+        """With offsets a row can have no live key (the ring composition):
+        that path keeps the empty-row guard.  Rows before the first key read
+        0 with ``lse`` ~ -inf, and the backward gives them and the keys they
+        never saw gradients of 0, none of them NaN."""
+        from torchft_tpu.ops.flash_attention import _bwd, _fwd, _to3
+
+        q, k, v = _qkv(t=128, hkv=4)
+        scale = 1.0 / np.sqrt(q.shape[-1])
+        offs = jnp.array([0, k_off], jnp.int32)
+        o, lse = _fwd(_to3(q), _to3(k), _to3(v), scale, True, offs)
+        dead = min(k_off, 128)
+        np.testing.assert_array_equal(np.asarray(o)[:, :dead], 0.0)
+        assert np.all(np.asarray(lse)[:, :dead] < -1e20)
+        do = jnp.ones_like(o)
+        dq, dk, dv = _bwd(_to3(q), _to3(k), _to3(v), o, lse, do, scale, True, offs)
+        for g in (dq, dk, dv):
+            assert np.all(np.isfinite(np.asarray(g)))
+        np.testing.assert_array_equal(np.asarray(dq)[:, :dead], 0.0)
+        assert (k_off >= 128) == (not np.asarray(dk).any())
+
+
 class TestFlashKeptResults:
     """The forward rule names the kernel's two results (``FLASH_OUT_NAME``,
     ``FLASH_LSE_NAME``): a ``jax.checkpoint`` that saves those names runs the
@@ -441,12 +563,14 @@ class TestFlashKeptResults:
 
     @pytest.mark.parametrize("case", CASES)
     def test_the_names_tag_the_output_in_the_models_rows_and_the_logsumexp(self, case):
-        from torchft_tpu.ops.flash_attention import FLASH_LSE_NAME, FLASH_OUT_NAME
+        from torchft_tpu.ops.flash_attention import FLASH_CALL_NAME, FLASH_LSE_NAME, FLASH_OUT_NAME
 
         shapes, kw = self.CASES[case]
         q, k, v = self._qkv(**shapes)
         b, t, h, _ = q.shape
         named = {}
+        # the call's shapes, a third name on the logsumexp that no policy saves
+        call = f"{FLASH_CALL_NAME}:{b * h}:{t}:{t}:{q.shape[-1]}:{v.shape[-1]}:{kw.get('window', 0)}"
 
         def walk(jaxpr):
             for eqn in jaxpr.eqns:
@@ -456,7 +580,7 @@ class TestFlashKeptResults:
                     walk(sub)
 
         walk(jax.make_jaxpr(self._value_and_grads(lambda fn: fn, kw))(q, k, v).jaxpr)
-        assert set(named) == {FLASH_OUT_NAME, FLASH_LSE_NAME}
+        assert set(named) == {FLASH_OUT_NAME, FLASH_LSE_NAME, call}
         assert named[FLASH_OUT_NAME].shape == (b, t, h * v.shape[-1])
         assert named[FLASH_LSE_NAME].shape == (b * h, t) and named[FLASH_LSE_NAME].dtype == jnp.float32
         # the primal call, which no gradient is taken of, names nothing

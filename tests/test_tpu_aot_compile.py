@@ -72,6 +72,39 @@ def test_flash_attention_compiles(one_chip, monkeypatch, batch, direction):
     assert hlo.count('custom_call_target="tpu_custom_call"') == kernels
 
 
+# the benchmark's cells: batch x heads, T, head widths, window.  Every kind of
+# tile and every block size the rule cuts them in compiles for the chip.
+CELLS = {
+    "smollm2-360m": (120, 2048, 64, 64, None),
+    "kimi-linear": (128, 4096, 192, 128, None),
+    "joyai": (64, 8192, 192, 128, None),
+    "trinity-global": (64, 8192, 128, 128, None),
+    "trinity-window": (64, 8192, 128, 128, 2048),
+}
+
+
+@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_attention_compiles_at_the_cells_shapes(one_chip, monkeypatch, cell, direction):
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    bh, t, d, dv, window = CELLS[cell]
+    scale = 1.0 / math.sqrt(d)
+    qk, v = ((bh, t, d), jnp.bfloat16), ((bh, t, dv), jnp.bfloat16)
+    row = ((bh, t), jnp.float32)
+    if direction == "fwd":
+        hlo = _compile(
+            lambda q, k, v: fa._fwd(q, k, v, scale, True, window=window),
+            qk, qk, v, sharding=one_chip,
+        )
+    else:
+        hlo = _compile(
+            lambda q, k, v, lse, do, delta: fa._bwd(
+                q, k, v, None, lse, do, scale, True, delta=delta, window=window),
+            qk, qk, v, row, v, row, sharding=one_chip,
+        )
+    assert hlo.count('custom_call_target="tpu_custom_call"') == (1 if direction == "fwd" else 2)
+
+
 @pytest.mark.parametrize("kernel", ["quantize", "dequantize", "reduce"])
 def test_int8_codec_kernel_compiles(one_chip, kernel):
     rows, cols = FRAG_ROWS, FRAG_COLS

@@ -516,40 +516,60 @@ def _remat(fn, cfg: TransformerConfig):
     )
 
 
-def _kept_bytes(jaxpr, layers: int = 1) -> int:
-    """Bytes of the flash kernel's named results in a gradient's jaxpr outside
-    its rematerialized parts, a scanned one counted once a layer: what the
-    ``"full"`` policy of :func:`_remat` keeps from the forward pass."""
-    from torchft_tpu.ops.flash_attention import FLASH_LSE_NAME, FLASH_OUT_NAME
-
-    total = 0
+def _named(jaxpr, layers: int = 1):
+    """``(name, value's aval, times a step)`` of every ``checkpoint_name`` in a
+    gradient's jaxpr outside its rematerialized parts, a scanned one counted
+    once a layer."""
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "name":
-            if eqn.params["name"] in (FLASH_OUT_NAME, FLASH_LSE_NAME):
-                aval = eqn.outvars[0].aval
-                total += layers * aval.size * aval.dtype.itemsize
+            yield eqn.params["name"], eqn.outvars[0].aval, layers
         elif eqn.primitive.name != "checkpoint":
             inner = layers * eqn.params.get("length", 1)
             for sub in jax.core.jaxprs_in_params(eqn.params):
-                total += _kept_bytes(sub, inner)
+                yield from _named(sub, inner)
+
+
+def _kept_bytes(jaxpr) -> int:
+    """Bytes of the flash kernel's named results in a gradient's jaxpr: what
+    the ``"full"`` policy of :func:`_remat` keeps from the forward pass."""
+    from torchft_tpu.ops.flash_attention import FLASH_LSE_NAME, FLASH_OUT_NAME
+
+    return sum(
+        times * aval.size * aval.dtype.itemsize
+        for name, aval, times in _named(jaxpr)
+        if name in (FLASH_OUT_NAME, FLASH_LSE_NAME)
+    )
+
+
+def _flash_tiles(jaxpr) -> "Dict[str, int]":
+    """A grad step's flash tiles by kind (``ops/flash_attention.py``
+    ``tile_kinds``), summed over its calls, heads and layers."""
+    from torchft_tpu.ops.flash_attention import TILE_KINDS, call_tiles
+
+    total = dict.fromkeys(TILE_KINDS, 0)
+    for name, _, times in _named(jaxpr):
+        for kind, n in (call_tiles(name) or {}).items():
+            total[kind] += times * n
     return total
 
 
 def _grad_step(loss, cfg):
     """The body of a family's jitted ``step(params, tokens) -> (loss, grads)``.
-    Tracing it sets the gauge ``torchft_remat_kept_bytes`` from the one trace
-    the step is built from (the inner ``jit`` is inlined into the caller's:
-    it is there to hand out its jaxpr).  A name outside a checkpoint is kept
-    only where ``"full"`` asked for it, so the other settings read 0."""
+    Tracing it sets the gauges ``torchft_remat_kept_bytes`` and
+    ``torchft_flash_tiles{kind}`` from the one trace the step is built from
+    (the inner ``jit`` is inlined into the caller's: it is there to hand out
+    its jaxpr).  A name outside a checkpoint is kept only where ``"full"``
+    asked for it, so the other settings read 0 kept bytes."""
     from torchft_tpu.utils import metrics
 
     grad = jax.jit(jax.value_and_grad(loss), inline=True)
 
     def step(params, tokens):
-        kept = 0
-        if cfg.remat and cfg.remat_policy == "full":
-            kept = _kept_bytes(grad.trace(params, tokens).jaxpr.jaxpr)
-        metrics.REMAT_KEPT_BYTES.set(kept)
+        jaxpr = grad.trace(params, tokens).jaxpr.jaxpr
+        full = cfg.remat and cfg.remat_policy == "full"
+        metrics.REMAT_KEPT_BYTES.set(_kept_bytes(jaxpr) if full else 0)
+        for kind, n in _flash_tiles(jaxpr).items():
+            metrics.FLASH_TILES.labels(kind=kind).set(n)
         return grad(params, tokens)
 
     return step

@@ -13,13 +13,32 @@ Standard FlashAttention-2 scheme, fwd + bwd:
 - backward: recomputes p = exp(q·kᵀ·scale − L) per tile from the saved L
   (no stored probabilities), accumulating dK/dV over Q blocks in one
   kernel and dQ over K/V blocks in another.
-- causal block skipping: fully-masked tiles are skipped via ``pl.when``
-  (half the FLOPs at long T), diagonal tiles masked elementwise.
+- a causal tile does only the work the mask and the row leave it.  In a
+  call without offsets on square tiles (every model's) that follows from the
+  tile's place in the grid (:func:`_by_place`; :func:`tile_kinds` counts a
+  head's tiles by kind, the gauge ``torchft_flash_tiles{kind}`` a grad
+  step's).  A tile *under* the diagonal runs without the mask's iotas,
+  compare and select and without the forward's empty-row guard (its rows
+  have seen key 0).  A tile the mask *cuts* (the diagonal one; under a
+  window also the band's older edge) is computed in blocks of rows (of keys
+  in the key-value backward; :func:`_sub_block` sizes them by the head's
+  widths), each against the stretch of keys that holds a live pair of it,
+  under a mask of local indices: the sub-blocks wholly above the diagonal
+  are never formed; the guard stays only on a piece in which a row can have
+  no live key (a window's older edge).  A tile *above* the diagonal is
+  skipped via ``pl.when`` and its index maps repeat the diagonal tile, so
+  nothing is fetched for it.  Where the scale is a power of two (heads of 64
+  and 256) it multiplies the query rows and the query gradient's
+  accumulator in place of the pairs, which changes no bit.  A call with
+  offsets (the ring composition) or ``causal=False`` is decided at run time:
+  the mask by positions on every tile its gate lets through and, in the
+  forward, the guard.
 - a sliding window (``window=w``: query ``i`` sees key ``j`` iff ``0 <= i - j
   < w``): the same three kernels on a grid whose last axis walks only the
   band's ``ceil((w - 1) / blk) + 1`` tiles beside each query (key) tile, so
   tiles older than the window are neither computed nor fetched; the band's
-  two edges are masked elementwise.  These calls are named
+  two edges are cut as above, the tiles between them run unmasked.  These
+  calls are named
   ``_fwd_window_kernel`` / ``_bwd_kv_window_kernel`` / ``_bwd_q_window_kernel``
   in the compiled program, so a trace tells them from the global ones.
 - dtypes: matmuls run in the input dtype (bf16 on TPU) with f32
@@ -57,6 +76,11 @@ _LANE = 128
 # policy a name is the identity.
 FLASH_OUT_NAME = "flash_attn_out"
 FLASH_LSE_NAME = "flash_attn_lse"
+# ``_flash_fwd`` also writes the call's shapes into the traced program, as a
+# name that no policy saves: ``<this>:bh:tq:tk:d:dv:window``, which
+# :func:`call_tiles` reads back (``models/transformer.py`` ``_grad_step`` sums
+# them into the gauge ``torchft_flash_tiles{kind}``).
+FLASH_CALL_NAME = "flash_attn_call"
 
 
 def _interpret() -> bool:
@@ -97,18 +121,26 @@ def _tiles(tq: int, tk: int, width: int, window: "Optional[int]"):
     return blk, blk, min(tk // blk, -(-(window - 1) // blk) + 1)
 
 
-def _key_tile(i, step, band):
+def _key_tile(i, step, band, by_place=False):
     """The key tile the inner grid axis is at beside query tile ``i``:
     ``step`` itself, or the band's, its last the diagonal one; a tile before
-    the sequence's start repeats tile 0, which is fetched once."""
-    return step if band is None else jnp.maximum(i - (band - 1) + step, 0)
+    the sequence's start repeats tile 0, which is fetched once.  ``by_place``
+    (no band): a step above the diagonal repeats the diagonal tile, so
+    nothing is fetched for it."""
+    if band is None:
+        return jnp.minimum(step, i) if by_place else step
+    return jnp.maximum(i - (band - 1) + step, 0)
 
 
-def _query_tile(j, step, band, n):
+def _query_tile(j, step, band, n, by_place=False):
     """The query tile the inner grid axis is at beside key tile ``j``:
     ``step`` itself, or the band's, its first the diagonal one; a tile past
-    the last of the ``n`` repeats it."""
-    return step if band is None else jnp.minimum(j + step, n - 1)
+    the last of the ``n`` repeats it.  ``by_place`` (no band): a step above
+    the diagonal fetches the diagonal tile ahead of its turn (the last tile
+    where the keys outnumber the queries)."""
+    if band is None:
+        return jnp.minimum(jnp.maximum(step, j), n - 1) if by_place else step
+    return jnp.minimum(j + step, n - 1)
 
 
 def _visible(rq, rk, window):
@@ -119,18 +151,212 @@ def _visible(rq, rk, window):
     return jnp.logical_and(rq >= rk, rq - rk < window)
 
 
-def _on_live_tiles(needed, window, ahead, blk, tile):
-    """Runs ``tile(masked)`` where the tile is ``needed``.  Under a window a
-    tile ``ahead()`` tiles before the diagonal that lies wholly inside the
-    band (every ``i - j`` in ``[1, window)``) runs without the mask's compares
-    and selects: of the band's ``w / blk + 1`` tiles only the two at its
-    edges need them."""
+def _live(shape, ahead, window, by_key=False):
+    """The mask of a piece of scores whose first query lies ``ahead`` rows
+    after its first key; ``by_key``: keys down the rows, queries along the
+    lanes."""
+    rq = ahead + jax.lax.broadcasted_iota(jnp.int32, shape, 1 if by_key else 0)
+    rk = jax.lax.broadcasted_iota(jnp.int32, shape, 0 if by_key else 1)
+    return _visible(rq, rk, window)
+
+
+def _exact_scale(scale: float) -> bool:
+    """Whether a product with ``scale`` rounds nothing in any float type: a
+    power of two (heads of 64 and 256).  Then ``(q * scale) @ k`` is
+    ``(q @ k) * scale`` bit for bit, and the scale leaves the pairs."""
+    return math.frexp(scale)[0] == 0.5
+
+
+# ---------------------------------------------------------------------------
+# what a tile computes
+# ---------------------------------------------------------------------------
+
+# A piece of a tile is ``(rows, cols, ahead)``: slices of the tile's queries
+# and keys and, where the mask cuts the piece, how many rows its first query
+# lies after its first key (None where every pair is live).
+_WHOLE = ((slice(None), slice(None), None),)
+
+
+def _by_place(causal, offsets_given, blk_q, blk_k) -> bool:
+    """Whether what a tile needs follows from its place in the grid alone:
+    causal attention on square tiles and no offsets, windowed or not (every
+    model's call; the ring composition passes offsets and is decided at run
+    time)."""
+    return bool(causal) and not offsets_given and blk_q == blk_k
+
+
+def _cut_tiles(band, blk, window):
+    """How many tiles before the diagonal the tiles lie that the mask cuts:
+    the diagonal one (0) and, under a window, those on the band's older
+    edge.  Every other live tile holds live pairs alone."""
     if window is None:
-        pl.when(needed)(lambda: tile(True))
+        return (0,)
+    return tuple(
+        a for a in range(band) if not (a >= 1 and (a + 1) * blk <= window)
+    )
+
+
+def _sub_block(kernel: str, blk: int, d: int, dv: int) -> int:
+    """The rows (keys, in the key-value backward) of the blocks a tile that
+    the mask cuts is computed in; ``blk`` leaves it whole under its mask.
+    Blocks of ``blk / n`` spare ``(n - 1) / 2n`` of the tile's pairs.
+
+    The two backward kernels gain what the pairs say at every width, and a
+    quarter tile reads best or within 2 % of it.  The forward pays for each
+    block a row maximum, a row sum and a rescale of its own, which do not
+    shrink with the keys: at heads of 64 and 128 that is more than the pairs
+    spare and the tile stays whole; wider heads put more matrix work on a
+    pair and halves win.  On a v5e (PR 40; ms a call of one layer, bfloat16,
+    the kernel alone with ~0.5 ms of dispatch in each reading; ``parent`` =
+    PR 38's kernels, the backward's two in one reading):
+
+      heads, T, batch x heads   kernel  parent   whole    512     256     128
+      64 / 64, 2048, 120        fwd      2.788   2.226   2.453   2.509     -
+                                bwd_kv   5.519   3.050   2.726   2.578   2.529
+                                bwd_q      "     2.403   2.163   2.039   2.034
+      192 / 128, 4096, 128      fwd     11.054   9.022   8.605   8.683     -
+                                bwd_kv  26.785  13.726  12.689  12.165     -
+                                bwd_q      "    12.680  11.807  11.427     -
+      192 / 128, 8192, 64       fwd     18.429  14.532  14.124  14.194     -
+                                bwd_kv  45.463  22.636  21.590  21.061     -
+                                bwd_q      "    20.367  19.498  19.087     -
+      128 / 128, 8192, 64       fwd     13.365   9.863  10.316  10.430     -
+                                bwd_kv  29.470  13.923  13.222  12.940     -
+                                bwd_q      "    11.303  10.790  10.530     -
+      the same, window 2048     fwd      7.640   6.242   6.717   6.731     -
+                                bwd_kv  15.598   8.240   7.006   6.524     -
+                                bwd_q      "     6.044   5.124   4.703     -
+
+    Two thirds of the live tiles are cut ones at ``T`` 2048, four of ten at
+    4096, eight of thirty-six at 8192, two of three in a band: the share
+    sizes the gain and never turned its sign, so the rule reads the widths
+    and the tile alone."""
+    if kernel == "fwd":
+        return max(blk // 2, _LANE) if max(d, dv) > 128 else blk
+    return max(blk // 4, _LANE)
+
+
+def _pieces(blk, sub, ahead, window, by_key=False):
+    """The pieces of a ``blk`` x ``blk`` tile whose first query lies ``ahead``
+    rows after its first key (the diagonal tile: 0), cut into blocks of
+    ``sub`` queries (keys if ``by_key``): each block against the stretch of
+    keys (queries) that holds a live pair of it, under the mask where the
+    stretch holds a dead one too.  Sub-blocks of ``sub`` x ``sub`` wholly
+    above the diagonal or older than the window fall outside every stretch:
+    of a diagonal tile's ``n`` x ``n`` they are ``n (n - 1) / 2``."""
+    n = blk // sub
+    top = math.inf if window is None else window
+
+    def dead_masked(r, c):  # of query block r on key block c
+        first = ahead + (r - c) * sub  # its first query after its first key
+        lo, hi = first - (sub - 1), first + (sub - 1)
+        return (hi < 0 or lo >= top), not (lo >= 0 and hi < top)
+
+    pieces = []
+    for s in range(n):
+        kinds = [dead_masked(o, s) if by_key else dead_masked(s, o) for o in range(n)]
+        live = [o for o, (dead, _) in enumerate(kinds) if not dead]
+        if not live:
+            continue
+        block, stretch = (s * sub, (s + 1) * sub), (live[0] * sub, (live[-1] + 1) * sub)
+        rows, cols = (stretch, block) if by_key else (block, stretch)
+        masked = any(kinds[o][1] for o in live)
+        pieces.append((
+            slice(*rows), slice(*cols), ahead + rows[0] - cols[0] if masked else None,
+        ))
+    return tuple(pieces)
+
+
+def _can_be_empty(piece, window) -> bool:
+    """Whether a query row of a piece of :func:`_pieces` may have no live key
+    in it: its first row's newest key or its last row's oldest lies outside."""
+    rows, cols, ahead = piece
+    if ahead is None:
+        return False
+    last_row, last_col = rows.stop - rows.start - 1, cols.stop - cols.start - 1
+    return ahead < 0 or (window is not None and ahead + last_row - window >= last_col)
+
+
+def _on_live_tiles(i, j, in_grid, offs_ref, causal, blk, cut, pieces_of, piece):
+    """Runs ``piece(rows, cols, ahead)`` on the pieces of query tile ``i``
+    against key tile ``j`` (``blk = (blk_q, blk_k)``) that hold a live pair.
+    By place (``cut`` given: how many tiles before the diagonal the tiles lie
+    that the mask cuts): those in ``pieces_of(a)``, every other tile under the
+    diagonal whole and unmasked, nothing above it or outside the grid
+    (``in_grid``: a band's step before the sequence's start or past its end).
+    Else, at run time from the call's offsets: the whole tile, masked by
+    positions if causal, unless every key lies after every query."""
+
+    def run(pieces):
+        for p in pieces:
+            piece(*p)
+
+    if cut is None:
+        # GLOBAL positions of the tile's first query and key
+        first_q, first_k = offs_ref[0] + i * blk[0], offs_ref[1] + j * blk[1]
+        live = jnp.logical_or(not causal, first_k <= first_q + blk[0] - 1)
+        ahead = first_q - first_k if causal else None
+        pl.when(live)(lambda: run(((slice(None), slice(None), ahead),)))
         return
-    inside = jnp.logical_and(ahead() >= 1, (ahead() + 1) * blk <= window)
-    pl.when(jnp.logical_and(needed, inside))(lambda: tile(False))
-    pl.when(jnp.logical_and(needed, jnp.logical_not(inside)))(lambda: tile(True))
+    whole = jnp.logical_and(in_grid, i >= j)
+    for a in cut:
+        here = jnp.logical_and(in_grid, i - j == a)
+        pl.when(here)(lambda a=a: run(pieces_of(a)))
+        whole = jnp.logical_and(whole, i - j != a)
+    pl.when(whole)(lambda: run(_WHOLE))
+
+
+TILE_KINDS = ("under", "diagonal", "above", "general", "sub_computed", "sub_skipped")
+
+
+def tile_kinds(tq, tk, d, dv, window=None, offsets_given=False):
+    """A head's tiles by kind in a causal call: ``under`` the diagonal (every
+    pair live: no mask, no guard), ``diagonal`` (cut by the mask: the
+    diagonal tile and, under a window, the band's older edge), ``above``
+    (grid steps with no live pair: skipped, nothing fetched), ``general``
+    (decided at run time: offsets, or tiles that are not square); and of
+    the cut tiles' sub-blocks, summed over the three kernels (each cuts by
+    its own :func:`_sub_block`), those a kernel computes and those it skips."""
+    blk_q, blk_k, band = _tiles(tq, tk, max(d, dv), window)
+    nq, nk = tq // blk_q, tk // blk_k
+    kinds = dict.fromkeys(TILE_KINDS, 0)
+    if not _by_place(True, offsets_given, blk_q, blk_k):
+        kinds["general"] = nq * nk
+        return kinds
+    def sub_blocks(a):  # of a cut tile, the three kernels': (computed, all)
+        done = every = 0
+        for kernel in ("fwd", "bwd_kv", "bwd_q"):
+            sub = _sub_block(kernel, blk_q, d, dv)
+            done += sum(
+                (rows.stop - rows.start) * (cols.stop - cols.start)
+                for rows, cols, _ in _pieces(blk_q, sub, a * blk_q, window)
+            ) // (sub * sub)
+            every += (blk_q // sub) ** 2
+        return done, every
+
+    cut = {a: sub_blocks(a) for a in _cut_tiles(band, blk_q, window)}
+    for i in range(nq):
+        for a in range(i - nk + 1, i + 1) if band is None else range(band):
+            if a < 0 or i - a < 0:
+                kinds["above"] += 1
+            elif a in cut:
+                kinds["diagonal"] += 1
+                kinds["sub_computed"] += cut[a][0]
+                kinds["sub_skipped"] += cut[a][1] - cut[a][0]
+            else:
+                kinds["under"] += 1
+    return kinds
+
+
+def call_tiles(name: str) -> "Optional[dict]":
+    """The tiles by kind (:func:`tile_kinds` times the heads) of the call whose
+    shapes ``_flash_fwd`` wrote into ``name``; None for any other name."""
+    tag, *shapes = name.split(":")
+    if tag != FLASH_CALL_NAME:
+        return None
+    bh, tq, tk, d, dv, window = map(int, shapes)
+    kinds = tile_kinds(tq, tk, d, dv, window or None)
+    return {kind: bh * n for kind, n in kinds.items()}
 
 
 # ---------------------------------------------------------------------------
@@ -140,19 +366,23 @@ def _on_live_tiles(needed, window, ahead, blk, tile):
 
 def _fwd_kernel(
     offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s,
-    *, scale, causal, blk_q, blk_k, window=None
+    *, scale, causal, blk_q, blk_k, window=None, cut=None, sub=None, fold=False,
 ):
     """offs_ref: SMEM int32 [2] = (q_offset, k_offset) GLOBAL positions of
     this call's first query/key row — the ring composition runs the kernel
     on local chunks whose causal relation depends on the shard offsets.
 
     ``window``: the last grid axis walks the band's tiles only (square
-    tiles, no offsets), the last of them the diagonal tile."""
+    tiles, no offsets), the last of them the diagonal tile.
+
+    ``cut`` (:func:`_cut_tiles`; None: not :func:`_by_place`): what a tile
+    needs follows from ``i`` and ``j``; ``sub``: the blocks a cut tile is
+    computed in.  ``fold``: the scale
+    is exact and goes onto the query rows, not the scores."""
     i = pl.program_id(1)
     step = pl.program_id(2)
     nj = pl.num_programs(2)
     j = step if window is None else i - (nj - 1) + step
-    q_off, k_off = offs_ref[0], offs_ref[1]
 
     @pl.when(step == 0)
     def _():
@@ -160,54 +390,56 @@ def _fwd_kernel(
         m_s[:] = jnp.full_like(m_s, _NEG_INF)
         l_s[:] = jnp.zeros_like(l_s)
 
-    if window is None:
-        # causal: this tile is live unless every key position exceeds every
-        # query position in the block
-        needed = jnp.logical_or(
-            not causal, k_off + j * blk_k <= q_off + i * blk_q + blk_q - 1
-        )
-    else:
-        needed = j >= 0  # a tile before the sequence's start
-
-    def tile(masked):
-        q = q_ref[0]
+    def update(rows, cols, ahead):
+        """One online-softmax step of the tile's query ``rows`` over its key
+        ``cols``."""
+        # whether a row may have seen no key yet: decided at run time (offsets),
+        # or a piece of a window's older edge
+        guard = ahead is not None and (
+            cut is None or _can_be_empty((rows, cols, ahead), window))
+        q = q_ref[0, rows]
+        if fold:
+            q = q * scale
         s = jax.lax.dot_general(
             q,
-            k_ref[0],
+            k_ref[0, cols],
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale  # [blk_q, blk_k]
-        if causal and masked:
-            rq = q_off + i * blk_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0
-            )
-            rk = k_off + j * blk_k + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1
-            )
-            s = jnp.where(_visible(rq, rk, window), s, _NEG_INF)
-        m_prev = m_s[:, :1]
+        )  # [rows, cols]
+        if not fold:
+            s = s * scale
+        if ahead is not None:
+            s = jnp.where(_live(s.shape, ahead, window), s, _NEG_INF)
+        m_prev = m_s[rows, :1]
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        # A query row with zero live keys so far has m_new == _NEG_INF, so
-        # s - m_new == 0 for every MASKED entry and p would be 1 — O would
-        # become a garbage mean of V.  Zero p for such rows instead: l
-        # stays 0, O resolves to 0 and lse to ~-inf, so callers passing
-        # offsets (ring chunks where q precedes every k) get an exact
-        # zero-weight chunk rather than relying on the combiner's
-        # exp-underflow to hide it.
-        p = jnp.where(m_new > _NEG_INF / 2, jnp.exp(s - m_new), 0.0)
+        p = jnp.exp(s - m_new)
+        if guard:
+            # A query row with zero live keys so far has m_new == _NEG_INF, so
+            # s - m_new == 0 for every MASKED entry and p would be 1 — O would
+            # become a garbage mean of V.  Zero p for such rows instead: l
+            # stays 0, O resolves to 0 and lse to ~-inf, so callers passing
+            # offsets (ring chunks where q precedes every k) get an exact
+            # zero-weight chunk rather than relying on the combiner's
+            # exp-underflow to hide it.  By place only a window's older edge
+            # can hold such a row: elsewhere a row has seen key 0 or its own.
+            p = jnp.where(m_new > _NEG_INF / 2, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_s[:] = jnp.broadcast_to(
-            l_s[:, :1] * corr + p.sum(axis=1, keepdims=True), l_s.shape
+        lanes = (p.shape[0], _LANE)
+        l_s[rows] = jnp.broadcast_to(
+            l_s[rows, :1] * corr + p.sum(axis=1, keepdims=True), lanes
         )
-        m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
-        acc[:] = acc[:] * corr + jax.lax.dot_general(
+        m_s[rows] = jnp.broadcast_to(m_new, lanes)
+        acc[rows] = acc[rows] * corr + jax.lax.dot_general(
             p.astype(q.dtype),
-            v_ref[0],
+            v_ref[0, cols],
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    _on_live_tiles(needed, window, lambda: nj - 1 - step, blk_k, tile)
+    _on_live_tiles(
+        i, j, j >= 0, offs_ref, causal, (blk_q, blk_k), cut,
+        lambda a: _pieces(blk_q, sub, a * blk_q, window), update,
+    )
 
     @pl.when(step == nj - 1)
     def _():
@@ -228,18 +460,20 @@ def _fwd(
 ) -> "Tuple[jax.Array, jax.Array]":
     bh, tq, d = q3.shape
     tk, dv = k3.shape[1], v3.shape[2]  # values may be narrower than q/k
+    blk_q, blk_k, band = _tiles(tq, tk, max(d, dv), window)
+    by_place = _by_place(causal, offsets is not None, blk_q, blk_k)
     if offsets is None:
         offsets = jnp.zeros((2,), jnp.int32)
-    blk_q, blk_k, band = _tiles(tq, tk, max(d, dv), window)
     grid = (bh, tq // blk_q, band or tk // blk_k)
 
     def kv(b, i, step):
-        return (b, _key_tile(i, step, band), 0)
+        return (b, _key_tile(i, step, band, by_place), 0)
 
     o, lse = pl.pallas_call(
         functools.partial(
             _fwd_kernel, scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k,
-            window=window,
+            window=window, cut=_cut_tiles(band, blk_q, window) if by_place else None,
+            sub=_sub_block("fwd", blk_q, d, dv), fold=_exact_scale(scale),
         ),
         grid=grid,
         in_specs=[
@@ -278,69 +512,63 @@ def _fwd(
 # ---------------------------------------------------------------------------
 
 
-def _recompute_p(q, k, lse_row, scale, causal, q_pos0, k_pos0, window=None):
-    """exp(q·kᵀ·scale − L) with the causal mask — shared by both bwd
-    kernels.  lse_row: [1, blk_q] f32 lane vector (reshaped to a column
-    here; Mosaic relayout).  q_pos0/k_pos0: GLOBAL position of the first
-    row of each block."""
-    lse_col = lse_row.reshape(-1, 1)  # lane vector -> column
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale
-    p = jnp.exp(s - lse_col)
-    if causal:
-        rq = q_pos0 + jax.lax.broadcasted_iota(jnp.int32, p.shape, 0)
-        rk = k_pos0 + jax.lax.broadcasted_iota(jnp.int32, p.shape, 1)
-        p = jnp.where(_visible(rq, rk, window), p, 0.0)
-    return p
-
-
 def _bwd_kv_kernel(
     offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal, blk_q, blk_k,
-    window=None,
+    window=None, cut=None, sub=None, fold=False,
 ):
+    """Keys down the rows, queries along the lanes: the scores are formed as
+    ``k qᵀ``, so ``pᵀ`` and ``dsᵀ`` stand as the two products into ``dv`` and
+    ``dk`` take them, and the rows' ``lse`` and ``delta`` are used as the lane
+    vectors they arrive as."""
     j = pl.program_id(1)  # K/V block (outer)
     step = pl.program_id(2)  # Q block (inner, accumulated)
     ni = pl.num_programs(2)
     # under a window: the band's query tiles, the first the diagonal one
     i = step if window is None else j + step
-    q_off, k_off = offs_ref[0], offs_ref[1]
 
     @pl.when(step == 0)
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    if window is None:
-        needed = jnp.logical_or(
-            not causal, q_off + i * blk_q + blk_q - 1 >= k_off + j * blk_k
-        )
-    else:
-        needed = i < pl.num_programs(1)  # a tile past the sequence's end
-
-    def tile(masked):
-        q = q_ref[0]
-        do = do_ref[0]
-        p = _recompute_p(
-            q, k_ref[0], lse_ref[0], scale, causal and masked,
-            q_off + i * blk_q, k_off + j * blk_k, window,
-        )
-        pt = p.astype(q.dtype)
-        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
-            pt, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dp = jax.lax.dot_general(
-            do, v_ref[0], (((1,), (1,)), ((), ())),
+    def piece(rows, cols, ahead):
+        """The tile's key ``cols`` against its query ``rows``."""
+        q = q_ref[0, rows]
+        if fold:
+            q = q * scale  # exact: the scores' scale and ds's in one
+        do = do_ref[0, rows]
+        st = jax.lax.dot_general(
+            k_ref[0, cols], q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [cols, rows]
+        if not fold:
+            st = st * scale
+        pt = jnp.exp(st - lse_ref[0, :, rows])
+        if ahead is not None:
+            pt = jnp.where(_live(pt.shape, ahead, window, by_key=True), pt, 0.0)
+        dv_acc[cols] = dv_acc[cols] + jax.lax.dot_general(
+            pt.astype(q.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - delta_ref[0].reshape(-1, 1)) * scale
-        dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+        dpt = jax.lax.dot_general(
+            v_ref[0, cols], do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dst = pt * (dpt - delta_ref[0, :, rows])
+        if not fold:
+            dst = dst * scale
+        dk_acc[cols] = dk_acc[cols] + jax.lax.dot_general(
+            dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    _on_live_tiles(needed, window, lambda: step, blk_k, tile)
+    # under a window a band's step may lie past the sequence's end
+    in_grid = window is None or i < pl.num_programs(1)
+    _on_live_tiles(
+        i, j, in_grid, offs_ref, causal, (blk_q, blk_k), cut,
+        lambda a: _pieces(blk_k, sub, a * blk_k, window, by_key=True), piece,
+    )
 
     @pl.when(step == ni - 1)
     def _():
@@ -351,46 +579,55 @@ def _bwd_kv_kernel(
 def _bwd_q_kernel(
     offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref, dq_acc, *, scale, causal, blk_q, blk_k, window=None,
+    cut=None, sub=None, fold=False,
 ):
     i = pl.program_id(1)  # Q block (outer)
     step = pl.program_id(2)  # K/V block (inner, accumulated)
     nj = pl.num_programs(2)
     # under a window: the band's key tiles, the last the diagonal one
     j = step if window is None else i - (nj - 1) + step
-    q_off, k_off = offs_ref[0], offs_ref[1]
 
     @pl.when(step == 0)
     def _():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    if window is None:
-        needed = jnp.logical_or(
-            not causal, k_off + j * blk_k <= q_off + i * blk_q + blk_q - 1
+    def piece(rows, cols, ahead):
+        """The tile's query ``rows`` against its key ``cols``; with ``fold``
+        ``ds`` goes without the scale, which ``dq_acc`` takes at write-out."""
+        q = q_ref[0, rows]
+        if fold:
+            q = q * scale
+        s = jax.lax.dot_general(
+            q, k_ref[0, cols], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
-    else:
-        needed = j >= 0
-
-    def tile(masked):
-        q = q_ref[0]
-        p = _recompute_p(
-            q, k_ref[0], lse_ref[0], scale, causal and masked,
-            q_off + i * blk_q, k_off + j * blk_k, window,
-        )
+        if not fold:
+            s = s * scale
+        # lse, delta: [1, rows] lane vectors -> columns (Mosaic relayout)
+        p = jnp.exp(s - lse_ref[0, :, rows].reshape(-1, 1))
+        if ahead is not None:
+            p = jnp.where(_live(p.shape, ahead, window), p, 0.0)
         dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
+            do_ref[0, rows], v_ref[0, cols], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - delta_ref[0].reshape(-1, 1)) * scale
-        dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
-            ds.astype(q.dtype), k_ref[0], (((1,), (0,)), ((), ())),
+        ds = p * (dp - delta_ref[0, :, rows].reshape(-1, 1))
+        if not fold:
+            ds = ds * scale
+        dq_acc[rows] = dq_acc[rows] + jax.lax.dot_general(
+            ds.astype(q.dtype), k_ref[0, cols], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    _on_live_tiles(needed, window, lambda: nj - 1 - step, blk_k, tile)
+    _on_live_tiles(
+        i, j, j >= 0, offs_ref, causal, (blk_q, blk_k), cut,
+        lambda a: _pieces(blk_q, sub, a * blk_q, window), piece,
+    )
 
     @pl.when(step == nj - 1)
     def _():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        dq = dq_acc[:] * scale if fold else dq_acc[:]
+        dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
 def _bwd(
@@ -399,36 +636,52 @@ def _bwd(
     delta: "Optional[jax.Array]" = None,
     window: "Optional[int]" = None,
 ) -> "Tuple[jax.Array, jax.Array, jax.Array]":
-    bh, tq, d = q3.shape
-    tk, d_v = k3.shape[1], v3.shape[2]
-    blk, blk_kk, band = _tiles(tq, tk, max(d, d_v), window)
-    n = tq // blk
-    nk = tk // blk_kk
-
-    def q_of(jj, step):
-        return _query_tile(jj, step, band, n)
-
-    def kv_of(ii, step):
-        return _key_tile(ii, step, band)
-
-    if offsets is None:
-        offsets = jnp.zeros((2,), jnp.int32)
-    offsets = offsets.astype(jnp.int32)
     if delta is None:
         # delta_i = rowsum(dO * O): tiny elementwise pass, plain XLA
         delta = jnp.sum(
             do3.astype(jnp.float32) * o3.astype(jnp.float32), axis=-1
         )
-    delta = delta[:, None, :]  # [bh, 1, t]
-    lse3 = lse[:, None, :]
+    args = (q3, k3, v3, lse, do3, delta, scale, causal, offsets, window)
+    dk, dv = _bwd_kv(*args)
+    return _bwd_q(*args), dk, dv
 
-    # kv kernel grid = (b, j, i): index maps receive (b, kv_block, q_block)
-    dk, dv = pl.pallas_call(
+
+def _bwd_operands(q3, k3, v3, lse, do3, delta, scale, causal, offsets, window):
+    """What the two backward calls share: the tiles, the kernels' static
+    arguments and their operands (row statistics as ``[bh, 1, t]``)."""
+    d, d_v = q3.shape[2], v3.shape[2]
+    blk, blk_kk, band = _tiles(q3.shape[1], k3.shape[1], max(d, d_v), window)
+    by_place = _by_place(causal, offsets is not None, blk, blk_kk)
+    static = dict(
+        scale=scale, causal=causal, blk_q=blk, blk_k=blk_kk, window=window,
+        cut=_cut_tiles(band, blk, window) if by_place else None,
+        fold=_exact_scale(scale),
+    )
+    if offsets is None:
+        offsets = jnp.zeros((2,), jnp.int32)
+    operands = (
+        offsets.astype(jnp.int32), q3, k3, v3, do3, lse[:, None, :], delta[:, None, :],
+    )
+    return blk, blk_kk, band, static, operands
+
+
+def _bwd_kv(q3, k3, v3, lse, do3, delta, scale, causal, offsets, window):
+    bh, tq, d = q3.shape
+    tk, d_v = k3.shape[1], v3.shape[2]
+    blk, blk_kk, band, static, operands = _bwd_operands(
+        q3, k3, v3, lse, do3, delta, scale, causal, offsets, window
+    )
+    n = tq // blk
+
+    def q_of(jj, step):
+        return _query_tile(jj, step, band, n, static["cut"] is not None)
+
+    # grid = (b, j, i): index maps receive (b, kv_block, q_block)
+    return pl.pallas_call(
         functools.partial(
-            _bwd_kv_kernel, scale=scale, causal=causal, blk_q=blk,
-            blk_k=blk_kk, window=window,
+            _bwd_kv_kernel, sub=_sub_block("bwd_kv", blk_kk, d, d_v), **static
         ),
-        grid=(bh, nk, band or n),
+        grid=(bh, tk // blk_kk, band or n),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, blk, d), lambda b, jj, ii: (b, q_of(jj, ii), 0)),     # q
@@ -452,15 +705,25 @@ def _bwd(
         ],
         interpret=_interpret(),
         name="_bwd_kv_kernel" if window is None else "_bwd_kv_window_kernel",
-    )(offsets, q3, k3, v3, do3, lse3, delta)
+    )(*operands)
 
-    # q kernel grid = (b, i, j): index maps receive (b, q_block, kv_block)
-    dq = pl.pallas_call(
+
+def _bwd_q(q3, k3, v3, lse, do3, delta, scale, causal, offsets, window):
+    bh, tq, d = q3.shape
+    tk, d_v = k3.shape[1], v3.shape[2]
+    blk, blk_kk, band, static, operands = _bwd_operands(
+        q3, k3, v3, lse, do3, delta, scale, causal, offsets, window
+    )
+
+    def kv_of(ii, step):
+        return _key_tile(ii, step, band, static["cut"] is not None)
+
+    # grid = (b, i, j): index maps receive (b, q_block, kv_block)
+    return pl.pallas_call(
         functools.partial(
-            _bwd_q_kernel, scale=scale, causal=causal, blk_q=blk,
-            blk_k=blk_kk, window=window,
+            _bwd_q_kernel, sub=_sub_block("bwd_q", blk, d, d_v), **static
         ),
-        grid=(bh, n, band or nk),
+        grid=(bh, tq // blk, band or tk // blk_kk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, blk, d), lambda b, ii, jj: (b, ii, 0)),     # q
@@ -475,8 +738,7 @@ def _bwd(
         scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)],
         interpret=_interpret(),
         name="_bwd_q_kernel" if window is None else "_bwd_q_window_kernel",
-    )(offsets, q3, k3, v3, do3, lse3, delta)
-    return dq, dk, dv
+    )(*operands)
 
 
 # ---------------------------------------------------------------------------
@@ -502,6 +764,8 @@ def _flash_fwd(q, k, v, scale, causal, window):
     # The primal result is read from the named rows, so that a checkpoint
     # which saves the name needs no second ``o``.
     o = checkpoint_name(_from3(o3, b, h).reshape(b, t, -1), FLASH_OUT_NAME)
+    shapes = (b * h, t, k.shape[1], q.shape[3], v.shape[3], window or 0)
+    lse = checkpoint_name(lse, ":".join(map(str, (FLASH_CALL_NAME, *shapes))))
     lse = checkpoint_name(lse, FLASH_LSE_NAME)
     return o.reshape(b, t, h, -1), (q, k, v, o, lse)
 
@@ -555,7 +819,7 @@ def flash_attention(
     return _flash(q, k, v, scale, causal, window)
 
 
-__all__ = ["flash_attention"]
+__all__ = ["flash_attention", "tile_kinds"]
 
 
 # ---------------------------------------------------------------------------

@@ -1323,3 +1323,17 @@ REMAT_KEPT_BYTES = gauge(
     "checkpoint",
     (),
 )
+FLASH_TILES = gauge(
+    "torchft_flash_tiles",
+    "Tiles of one grad step's causal flash-attention calls by what the "
+    "kernels do with them, summed over calls, heads and layers (the forward "
+    "grid; each backward kernel walks the same tiles): under = every pair "
+    "live, no mask and no empty-row guard; diagonal = cut by the mask (the "
+    "diagonal tile, a window's older edge); above = no live pair, skipped "
+    "and not fetched; general = decided at run time (offsets, tiles not "
+    "square); sub_computed / sub_skipped = the cut tiles' sub-blocks the "
+    "three kernels compute and leave out; a pure function of the calls' "
+    "shapes, read off the traced program when a model's make_grad_step is "
+    "built",
+    ("kind",),
+)
