@@ -167,3 +167,92 @@ class TestTransformerMoE:
             lambda p, t: tfm.loss_fn(p, t, cfg, mesh=mesh)
         )(sharded, tok_sharded)
         np.testing.assert_allclose(float(loss), float(ref), rtol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# a chip's share of a sigmoid-routed layer: the shared expert and the
+# renormalisation's epsilon are fields whose defaults are the three families'
+# ---------------------------------------------------------------------------
+
+
+class TestHeldMoEFields:
+    CFG = dict(d_model=32, d_expert=16, n_routed=16, top_k=4, held=(0, 1, 2, 3), routed_scale=2.5,
+               dtype=jnp.float32)
+
+    @staticmethod
+    def _digest(*arrays):
+        import hashlib
+
+        digest = hashlib.sha256()
+        for a in arrays:
+            digest.update(np.asarray(a).tobytes())
+        return digest.hexdigest()
+
+    def _setup(self, **over):
+        from torchft_tpu.models import moe
+
+        cfg = moe.HeldMoEConfig(**{**self.CFG, **over})
+        params = moe.init_held_moe_params(jax.random.PRNGKey(3), cfg, 2)
+        x = jax.random.normal(jax.random.PRNGKey(4), (2, 24, 32))
+        return moe, cfg, params, jax.tree_util.tree_map(lambda w: w[0], params), x
+
+    def test_the_defaults_are_the_parents_tree_output_and_gradient_bit_for_bit(self):
+        """Recorded on the parent commit (before ``shared`` and ``renorm_eps``
+        were fields), on this CPU backend: the tree the initialiser makes, the
+        layer's output and stats, every leaf's gradient."""
+        moe, cfg, params, p0, x = self._setup()
+        assert (cfg.shared, cfg.renorm_eps) == (True, 1e-20)
+        assert sorted(params) == ["router", "shared_down", "shared_gate", "shared_up", "w_down", "w_gate", "w_up"]
+        assert self._digest(*(params[n] for n in sorted(params))) == (
+            "338d32b33d47e84abe205368ddff37cef1823a9bc5c1116bcb4fb6b72333af60")
+        y, stats = jax.jit(lambda x, p: moe.held_moe_ffn(x, p, cfg))(x, p0)
+        assert int(stats["unrouted"]) == 15
+        assert self._digest(y, stats["assignments"]) == (
+            "02908dbc940dfaf4e39517c6d0d2e45bf629cbbf71859cc4868798fadf32bfe1")
+        g = jax.jit(jax.grad(lambda p: (moe.held_moe_ffn(x, p, cfg)[0] ** 2).sum()))(p0)
+        assert self._digest(*(g[n] for n in sorted(g))) == (
+            "40e2cb59f172078e7b2c6cd12e6eb1b339133e3e91b9dbfef34ae785d9109d6a")
+
+    def test_without_a_shared_expert_the_tree_has_no_such_leaf_and_the_routed_leaves_are_the_same(self):
+        moe, _, with_shared, _, _ = self._setup()
+        _, cfg, params, _, _ = self._setup(shared=False)
+        assert sorted(params) == ["router", "w_down", "w_gate", "w_up"]
+        for name, leaf in params.items():
+            np.testing.assert_array_equal(np.asarray(leaf), np.asarray(with_shared[name]))
+
+    def test_without_a_shared_expert_the_output_is_the_routed_part_alone(self):
+        """``y(shared) - SwiGLU_shared(x) == y(no shared)``; a token with no
+        expert here gets exactly zero, and no ``moe.shared`` scope is opened."""
+        moe, cfg, _, p0, x = self._setup()
+        flat = x.reshape(-1, 32)
+        shared = ((jax.nn.silu(flat @ p0["shared_gate"]) * (flat @ p0["shared_up"]))
+                  @ p0["shared_down"]).reshape(x.shape)
+        whole, _ = moe.held_moe_ffn(x, p0, cfg)
+        bare_cfg = moe.HeldMoEConfig(**{**self.CFG, "shared": False})
+        bare_p = {n: w for n, w in p0.items() if not n.startswith("shared")}
+        routed, stats = jax.jit(lambda x, p: moe.held_moe_ffn(x, p, bare_cfg))(x, bare_p)
+        np.testing.assert_allclose(np.asarray(routed), np.asarray(whole - shared), rtol=1e-5, atol=1e-6)
+        chosen, _ = moe.route_sigmoid(flat, p0["router"], bare_cfg)
+        nowhere = np.asarray((chosen >= 4).all(axis=-1))
+        assert int(nowhere.sum()) == int(stats["unrouted"]) == 15
+        assert np.all(np.asarray(routed).reshape(-1, 32)[nowhere] == 0.0)
+        assert np.all(np.abs(np.asarray(routed).reshape(-1, 32)[~nowhere]).max(axis=-1) > 0)
+        text = jax.jit(lambda x, p: moe.held_moe_ffn(x, p, bare_cfg)).lower(x, bare_p).as_text(debug_info=True)
+        assert "moe.experts" in text and "moe.shared" not in text
+        assert "moe.shared" in jax.jit(lambda x, p: moe.held_moe_ffn(x, p, cfg)).lower(x, p0).as_text(debug_info=True)
+
+    @pytest.mark.parametrize("eps", [1e-20, 1e-6, 0.5])
+    def test_the_epsilon_reaches_the_weights(self, eps):
+        """``w = s[chosen] / (sum s[chosen] + eps) * scale``: the weights' sum
+        a token is ``scale * S / (S + eps)``."""
+        moe, _, _, p0, x = self._setup()
+        cfg = moe.HeldMoEConfig(**{**self.CFG, "renorm_eps": eps})
+        flat = x.reshape(-1, 32)
+        chosen, weights = moe.route_sigmoid(flat, p0["router"], cfg)
+        scores = jax.nn.sigmoid(flat @ p0["router"])
+        total = jnp.take_along_axis(scores, chosen, axis=-1).sum(-1)
+        np.testing.assert_allclose(np.asarray(weights.sum(-1)), np.asarray(2.5 * total / (total + eps)), rtol=1e-5)
+        if eps == 0.5:
+            same, base = moe.route_sigmoid(flat, p0["router"], moe.HeldMoEConfig(**self.CFG))
+            np.testing.assert_array_equal(np.asarray(same), np.asarray(chosen))
+            assert float(jnp.abs(base - weights).max()) > 0.05, "the choice is the same, the weights are not"

@@ -6,9 +6,12 @@ with windowed beside global attention (``afmoe``: gated heads, norms on both
 sides of a sub-block, the same expert layer) and a sparse decoder with latent
 attention in every layer and a multi-token-prediction module (``joyai``: a
 query latent and a rotary part through ``mla``, the function ``kimi_linear``
-runs too; a loss over two prediction depths; the same expert layer)."""
+runs too; a loss over two prediction depths; the same expert layer) and a
+sparse decoder whose token mixer is a gated short convolution in three layers
+of four (``lfm2``: grouped-query attention in the fourth, the same expert
+layer without its shared expert, a head tied to the embedding)."""
 
-from torchft_tpu.models import afmoe, cnn, joyai, kimi_linear, mla, mlp, transformer
+from torchft_tpu.models import afmoe, cnn, joyai, kimi_linear, lfm2, mla, mlp, transformer
 from torchft_tpu.models.transformer import (
     TransformerConfig,
     init_params,
@@ -23,6 +26,7 @@ __all__ = [
     "cnn",
     "joyai",
     "kimi_linear",
+    "lfm2",
     "mla",
     "mlp",
     "transformer",

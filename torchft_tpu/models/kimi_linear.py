@@ -245,14 +245,20 @@ def init_params(rng: jax.Array, cfg: KimiLinearConfig) -> Params:
 # ---------------------------------------------------------------------------
 
 
-def _short_conv(x: jax.Array, w: jax.Array) -> jax.Array:
-    """Depthwise causal convolution then SiLU: ``x [B, T, D]``, ``w [D, K]``,
-    ``y_t = sum_i w[:, i] x_{t - K + 1 + i}``."""
+def _causal_conv(x: jax.Array, w: jax.Array) -> jax.Array:
+    """Depthwise causal convolution: ``x [B, T, D]``, ``w [D, K]``, ``y_t =
+    sum_i w[:, i] x_{t - K + 1 + i}`` with ``x_{<0} = 0``, the taps as shifted
+    multiply-adds accumulated in float32 (the result's type): no ``[B, T, D,
+    K]`` array.  (``models/lfm2.py``'s mixer runs this too.)"""
     taps = w.shape[-1]
     t = x.shape[1]
     padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
-    y = sum(padded[:, i:i + t] * w[:, i].astype(jnp.float32) for i in range(taps))
-    return jax.nn.silu(y).astype(x.dtype)
+    return sum(padded[:, i:i + t] * w[:, i].astype(jnp.float32) for i in range(taps))
+
+
+def _short_conv(x: jax.Array, w: jax.Array) -> jax.Array:
+    """``_causal_conv`` then SiLU, in the type of ``x``."""
+    return jax.nn.silu(_causal_conv(x, w)).astype(x.dtype)
 
 
 def _kda_attention(h: jax.Array, p: Params, cfg: KimiLinearConfig) -> jax.Array:
@@ -417,14 +423,16 @@ def _logits(params: Params, x: jax.Array, cfg: KimiLinearConfig) -> jax.Array:
                       preferred_element_type=jnp.float32)
 
 
-def _head_nll(params: Params, x: jax.Array, tokens: jax.Array, cfg: KimiLinearConfig) -> jax.Array:
+def _head_nll(params: Params, x: jax.Array, tokens: jax.Array, cfg: Any,
+              logits: "Callable[..., jax.Array]" = _logits) -> jax.Array:
     """The summed next-token loss, a row of the batch at a time under
     ``jax.checkpoint``: the float32 logits of one row live at once, not the
-    batch's."""
+    batch's.  ``logits(params, x, cfg)``: the model's final norm and head (a
+    tied head is ``models/lfm2.py``'s)."""
 
     def row(acc, xs):
         x_row, tok_row = xs
-        return acc + _next_token_nll(_logits(params, x_row[None], cfg), tok_row[None]).sum(), None
+        return acc + _next_token_nll(logits(params, x_row[None], cfg), tok_row[None]).sum(), None
 
     with jax.named_scope("head"):
         total, _ = jax.lax.scan(jax.checkpoint(row), jnp.zeros((), jnp.float32), (x, tokens))

@@ -221,7 +221,9 @@ class HeldMoEConfig:
     the router scores all ``n_routed`` published experts and keeps ``top_k``
     a token, the layer is told which of them live here (``held``, their
     published ids) and computes their part of the result, plus the shared
-    expert that every chip computes alike."""
+    expert that every chip computes alike (``shared``: a model without one
+    has no ``shared_*`` leaves, and a token none of whose experts lives here
+    gets nothing from the layer)."""
 
     d_model: int
     d_expert: int
@@ -233,13 +235,16 @@ class HeldMoEConfig:
     # load of the held experts together; a batch that overflows it takes the
     # masked path instead: nothing is ever dropped
     slack: float = 8.0
+    shared: bool = True
+    # added to the chosen scores' sum before they are renormalised to one
+    renorm_eps: float = 1e-20
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
 
 
 def init_held_moe_params(rng: jax.Array, cfg: HeldMoEConfig, n_layers: int) -> Params:
-    """Router over all published experts, the held experts, the shared one;
-    a leading ``[n_layers]`` dim for stacked blocks."""
+    """Router over all published experts, the held experts, the shared one
+    where the model has one; a leading ``[n_layers]`` dim for stacked blocks."""
     e, f, pd = cfg.d_model, cfg.d_expert, cfg.param_dtype
     held = len(cfg.held)
     keys = jax.random.split(rng, 7)
@@ -247,15 +252,16 @@ def init_held_moe_params(rng: jax.Array, cfg: HeldMoEConfig, n_layers: int) -> P
     def dense(key, *shape):
         return (jax.random.normal(key, (n_layers,) + shape, pd) / np.sqrt(shape[-2])).astype(pd)
 
-    return {
+    params = {
         "router": dense(keys[0], e, cfg.n_routed),
         "w_gate": dense(keys[1], held, e, f),
         "w_up": dense(keys[2], held, e, f),
         "w_down": dense(keys[3], held, f, e),
-        "shared_gate": dense(keys[4], e, f),
-        "shared_up": dense(keys[5], e, f),
-        "shared_down": dense(keys[6], f, e),
     }
+    if cfg.shared:
+        params.update(shared_gate=dense(keys[4], e, f), shared_up=dense(keys[5], e, f),
+                      shared_down=dense(keys[6], f, e))
+    return params
 
 
 def route_sigmoid(
@@ -266,14 +272,14 @@ def route_sigmoid(
     scores in float32 at the highest precision (a near-tie decides where a
     token goes), the ``top_k`` largest of score + ``router_bias`` (a buffer
     the balancing rule moves, never the gradient), the chosen scores
-    renormalised to one and scaled."""
+    renormalised to one (their sum + ``renorm_eps``) and scaled."""
     scores = jax.nn.sigmoid(jnp.matmul(
         flat.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     ranked = scores if router_bias is None else scores + jax.lax.stop_gradient(router_bias)
     _, chosen = jax.lax.top_k(ranked, cfg.top_k)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
-    weights = picked / (picked.sum(axis=-1, keepdims=True) + 1e-20) * cfg.routed_scale
+    weights = picked / (picked.sum(axis=-1, keepdims=True) + cfg.renorm_eps) * cfg.routed_scale
     return chosen, weights
 
 
@@ -283,7 +289,8 @@ def held_moe_ffn(
 ) -> "Tuple[jax.Array, Dict[str, jax.Array]]":
     """``x [B, T, d] -> (y, stats)``: ``y = sum over the chosen experts that
     live here of w_e SwiGLU_e(x), plus SwiGLU_shared(x)``; a token none of
-    whose experts is here gets the shared expert alone.  No capacity, no
+    whose experts is here gets the shared expert alone, and exactly zero
+    where the model has none (``cfg.shared`` false).  No capacity, no
     drop, no auxiliary loss.  ``stats``: ``assignments`` ``[held]`` (how many
     of the ``N k`` assignments landed on each held expert) and ``unrouted``
     (tokens that found none of their experts here).
@@ -360,9 +367,11 @@ def held_moe_ffn(
             jax.checkpoint(jax.named_scope("moe.gathered")(gathered)),
             jax.checkpoint(jax.named_scope("moe.masked")(masked)), None)
 
-    with jax.named_scope("moe.shared"):
-        shared = _swiglu(flat, params["shared_gate"], params["shared_up"], params["shared_down"])
-    y = (routed + shared.astype(jnp.float32)).astype(x.dtype).reshape(b, t, d)
+    if cfg.shared:
+        with jax.named_scope("moe.shared"):
+            shared = _swiglu(flat, params["shared_gate"], params["shared_up"], params["shared_down"])
+        routed = routed + shared.astype(jnp.float32)
+    y = routed.astype(x.dtype).reshape(b, t, d)
     return y, {"assignments": assignments, "unrouted": unrouted}
 
 
