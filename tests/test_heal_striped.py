@@ -191,6 +191,18 @@ class TestStripedHeal:
 
     def test_dead_source_from_start_fails_over(self, sources):
         state, transports = sources
+        # Six tiny fragments and six stripe workers, started one after the
+        # other: on a loaded host the primary's two drain the queue before
+        # the dead source's have started, and then nothing ever fails over
+        # (36 of 40 runs beside 12 busy processes, none with a fetch sent
+        # to the dead address).  Stretch every fetch, as the mid-heal kill
+        # above does, so that every worker holds a fragment before any
+        # fetch completes: the dead source's two MUST fail over.
+        faults.FAULTS.configure(
+            [FaultRule(site="transport.heal.frag", action="delay",
+                       delay=0.3, times=100)],
+            seed=0,
+        )
         dead = HTTPTransport(timeout=5.0)
         dead_addr = dead.metadata()
         dead.shutdown()
@@ -358,8 +370,13 @@ class TestHealStagingLifecycle:
             name, raw, digest = next(frag_iter)
             t.stage_streamed_part(9, f"frag:{name}", raw)
 
-            hbuf = frags.fetch_raw(t.metadata(), 9, "frag_header", 2.0,
+            t0 = time.monotonic()
+            hbuf = frags.fetch_raw(t.metadata(), 9, "frag_header", 10.0,
                                    role="heal")
+            # at once, not when the budget runs out: the native data plane
+            # holds no header and would park the request till the version
+            # completes, so the header is never asked of it
+            assert time.monotonic() - t0 < 2.0
             got_header = frags.decode_manifest(hbuf)
             assert got_header["fragments"] == ["0", "1", "2", "3"]
             assert "digests" not in got_header
@@ -425,6 +442,274 @@ class TestHealStagingLifecycle:
             assert mine == manifest["digests"]
         finally:
             t.shutdown()
+
+
+    @pytest.mark.parametrize(
+        "case",
+        [
+            "float32", "bfloat16", "zero_d", "empty", "non_contiguous",
+            "jax_array", "python_leaf", "more_fragments_than_leaves",
+        ],
+    )
+    def test_streamed_digest_is_the_wire_bytes_digest(self, case):
+        """The healer hashes its state in place: per fragment, the digest
+        streamed through ``serialization.prepare``'s writer is that of the
+        bytes ``serialize`` would have built, and the one a source stages."""
+        import hashlib
+
+        import jax
+        import jax.numpy as jnp
+
+        from torchft_tpu.checkpointing import serialization as ser
+
+        rng = np.random.default_rng(3)
+        w = rng.standard_normal((6, 40)).astype(np.float32)
+        state = {f"w{i}": w * (i + 1) for i in range(5)}
+        fragments = 3
+        if case == "bfloat16":
+            state["w1"] = np.asarray(jnp.asarray(w, jnp.bfloat16))
+            assert state["w1"].dtype.name == "bfloat16"
+        elif case == "zero_d":
+            state["w1"] = np.float32(2.5) * np.ones((), np.float32)
+            assert state["w1"].shape == ()
+        elif case == "empty":
+            state["w1"] = np.empty((0, 7), np.float32)
+        elif case == "non_contiguous":
+            state["w1"] = w.T  # a view in the other order of dimensions
+            state["w2"] = w[:, ::3]
+            assert not state["w1"].flags.c_contiguous
+        elif case == "jax_array":
+            state["w1"] = jnp.asarray(w)
+            state["w3"] = jnp.asarray(w, jnp.bfloat16)
+            assert isinstance(state["w1"], jax.Array)
+        elif case == "python_leaf":
+            state.update(step=5, name="replica", lr=1e-3)
+        elif case == "more_fragments_than_leaves":
+            fragments = 9
+
+        n, mine = frags.local_fragment_digests(state, fragments)
+        leaves = jax.tree_util.tree_flatten(state)[0]
+        assert n == len(leaves)
+        names = frags.heal_fragment_names(n, fragments)
+        assert list(mine) == names and len(names) == min(fragments, n)
+        for name in names:
+            frag = {
+                str(slot): (
+                    np.asarray(leaves[slot])
+                    if isinstance(leaves[slot], jax.Array)
+                    else leaves[slot]
+                )
+                for slot in frags.fragment_slots(name, n, len(names))
+            }
+            assert mine[name] == hashlib.sha256(ser.serialize(frag)).hexdigest()
+        t = HTTPTransport(timeout=5.0)
+        try:
+            manifest = t.send_checkpoint_streamed(
+                [1], 2, state, timeout=5.0, fragments=fragments
+            )
+            assert mine == manifest["digests"]
+        finally:
+            t.shutdown()
+
+
+def _stage_by_hand(
+    t, step, state, fragments, delay, header_fragments=None, manifest=True
+):
+    """A source's streamed staging, call by call: the header at once, every
+    fragment, and the digest manifest ``delay`` seconds later.
+    ``header_fragments``: the header shows another layout than the manifest
+    then confirms.  ``manifest=False``: the source never gets that far."""
+    header, frag_iter = frags.iter_heal_fragments(state, fragments)
+    shown = header
+    if header_fragments is not None:
+        shown = frags.iter_heal_fragments(state, header_fragments)[0]
+    t.begin_streamed_checkpoint(
+        step, {f"frag:{frags.HEADER_FRAG}": dict(shown, version=step)}
+    )
+    digests = {}
+    for name, raw, digest in frag_iter:
+        t.stage_streamed_part(step, f"frag:{name}", raw)
+        digests[name] = digest
+    time.sleep(delay)
+    if not manifest:
+        return
+    t.stage_streamed_part(
+        step, f"frag:{frags.MANIFEST_FRAG}",
+        dict(header, version=step, digests=digests,
+             created_ns=time.time_ns()),
+    )
+    t.finish_streamed_checkpoint(step)
+
+
+def _call_threads():
+    """The live threads but an HTTP server's own (the sources are servers
+    in this process: one daemon thread a connection kept alive)."""
+    return {
+        t for t in threading.enumerate()
+        if "process_request_thread" not in t.name
+        and "serve_forever" not in t.name
+    }
+
+
+class TestDigestsDuringTheWait:
+    """ISSUE 42: in delta mode the healer takes the layout from the header,
+    which a source stages before it encodes anything, and hashes its own
+    state on a thread of its own while it long-polls for the manifest.
+    ``heal_diff`` is what the digests still cost once the manifest is in,
+    ``heal_diff.hidden`` the digest work that had ended by then."""
+
+    DELAY = 1.5
+    FRAGMENTS = 4
+
+    @staticmethod
+    def big_state(seed: int = 11) -> dict:
+        rng = np.random.default_rng(seed)
+        return {
+            "user": {
+                f"w{i}": rng.standard_normal(500_000).astype(np.float32)
+                for i in range(8)
+            },
+            "torchft": {"step": 5, "batches_committed": 10},
+        }
+
+    def heal(self, state, local, stage_kw=None, timeout=20.0, **recv_kw):
+        src = HTTPTransport(timeout=10.0)
+        healer = HTTPTransport(timeout=10.0)
+        before = _call_threads()
+        stager = threading.Thread(
+            target=_stage_by_hand,
+            args=(src, 5, state, self.FRAGMENTS, self.DELAY),
+            kwargs=stage_kw or {},
+        )
+        stager.start()
+        try:
+            return healer.recv_checkpoint_striped(
+                [src.metadata()], 5, timeout=timeout,
+                local_state_fn=lambda: local, **recv_kw,
+            )
+        finally:
+            stager.join(timeout=30.0)
+            assert not stager.is_alive()
+            # no thread of the call outlives it, however it ended
+            left = _call_threads() - before - {stager}
+            assert not left, sorted(t.name for t in left)
+            healer.shutdown()
+            src.shutdown()
+
+    @pytest.mark.parametrize("differing", [0, 1])
+    def test_digests_run_in_the_shadow_of_the_wait(self, differing, monkeypatch):
+        # digests that take a third of the wait at least: they end inside
+        # it only if they began with the header, not with the manifest
+        real = frags.local_fragment_digests
+
+        def slow(state_dict, fragments):
+            time.sleep(self.DELAY / 3)
+            return real(state_dict, fragments)
+
+        monkeypatch.setattr(frags, "local_fragment_digests", slow)
+        state = self.big_state()
+        local = clone_state(state)
+        if differing:
+            local["user"]["w3"][:] = -1.0
+        got, info = self.heal(state, local, delta=True)
+        assert_state_equal(got, state)
+        assert info["mode"] == "delta"
+        # w3's fragment alone crosses the wire, or nothing does
+        assert info["changed"] == differing
+        assert (info["wire_bytes"] > 0) == bool(differing)
+        if differing:
+            assert info["wire_bytes"] < sum(
+                v.nbytes for v in state["user"].values()
+            ) / 2
+        parts, phases = info["parts"], info["phases"]
+        work = parts["heal_diff.snapshot"] + parts["heal_diff.hash"]
+        assert "heal_diff.encode" not in parts
+        # the mechanism engaged whole: the work was over when the manifest
+        # came, and the recovery paid a small share of the wait for it
+        assert info["hidden"] == parts["heal_diff.hidden"]
+        assert 0 < info["hidden"] == pytest.approx(work)
+        assert phases["heal_diff"] < 0.1 * self.DELAY
+        assert phases["heal_manifest"] >= 0.9 * self.DELAY
+        assert parts["heal_manifest.wait"] <= phases["heal_manifest"]
+
+    def test_legacy_source_has_no_header_and_hides_nothing(self):
+        state = self.big_state()
+        src = HTTPTransport(timeout=5.0)
+        healer = HTTPTransport(timeout=5.0)
+        before = _call_threads()
+        try:
+            src.send_checkpoint([1], 5, state, timeout=5.0)
+            got, info = healer.recv_checkpoint_striped(
+                [src.metadata()], 5, timeout=10.0,
+                local_state_fn=lambda: clone_state(state), delta=True,
+            )
+            assert not _call_threads() - before
+        finally:
+            healer.shutdown()
+            src.shutdown()
+        assert info["mode"] == "legacy" and info["hidden"] == 0.0
+        assert info["phases"] == {}
+        assert_state_equal(got, state)
+
+    def test_full_mode_and_no_local_state_hide_nothing(self):
+        state = self.big_state()
+        got, info = self.heal(state, clone_state(state), delta=False)
+        assert_state_equal(got, state)
+        assert info["mode"] == "full"
+        assert info["hidden"] == info["parts"]["heal_diff.hidden"] == 0.0
+        assert "heal_diff.hash" not in info["parts"]
+
+    def test_manifest_of_another_layout_recomputes(self):
+        """The manifest defines truth: early digests of a layout it does
+        not confirm are thrown away and taken again, as before."""
+        state = self.big_state()
+        local = clone_state(state)
+        local["user"]["w3"][:] = -1.0
+        got, info = self.heal(
+            state, local, stage_kw={"header_fragments": 3}, delta=True
+        )
+        assert_state_equal(got, state)
+        assert info["mode"] == "delta"
+        assert info["fragments"] == self.FRAGMENTS and info["changed"] == 1
+        assert info["hidden"] == info["parts"]["heal_diff.hidden"] == 0.0
+        # the digests were taken again after the manifest, inside heal_diff
+        assert info["phases"]["heal_diff"] > 0
+
+    def test_an_error_in_the_digests_surfaces(self, monkeypatch):
+        def broken(state_dict, fragments):
+            raise RuntimeError("digest worker exploded")
+
+        monkeypatch.setattr(frags, "local_fragment_digests", broken)
+        state = self.big_state()
+        with pytest.raises(RuntimeError, match="digest worker exploded"):
+            self.heal(state, clone_state(state), delta=True)
+
+    def test_primary_dying_during_the_wait_fails_the_call(self):
+        """As before this PR: the call raises (the Manager reports the
+        error and the next quorum assigns a source).  New: the digests
+        begun during the wait are joined, not left behind."""
+        state = self.big_state()
+        src = HTTPTransport(timeout=10.0)
+        healer = HTTPTransport(timeout=10.0)
+        before = _call_threads()
+        _stage_by_hand(src, 5, state, self.FRAGMENTS, 0.0, manifest=False)
+        killer = threading.Timer(0.3, src.shutdown)
+        killer.start()
+        t0 = time.monotonic()
+        try:
+            with pytest.raises((OSError, TimeoutError)):
+                healer.recv_checkpoint_striped(
+                    [src.metadata()], 5, timeout=2.0,
+                    local_state_fn=lambda: clone_state(state), delta=True,
+                )
+            assert time.monotonic() - t0 < 10.0
+            killer.join(timeout=10.0)
+            left = _call_threads() - before - {killer}
+            assert not left, sorted(t.name for t in left)
+        finally:
+            killer.cancel()
+            healer.shutdown()
+            src.shutdown()
 
 
 class TestStripedHealInteg:
@@ -582,8 +867,12 @@ class TestHealOpened:
         # -- the healer: the new incarnation of replica 1 ----------------
         healer = out[1][-1]
         assert 0 < healer["heal_manifest.wait"] <= healer["heal_manifest"]
-        diff = sum(healer[f"heal_diff.{p}"] for p in ("snapshot", "encode", "hash"))
-        assert 0 < diff <= healer["heal_diff"]
+        # the healer's digests: hashed in place (nothing is encoded), begun
+        # during the wait, so heal_diff is what they still cost after it
+        digest_work = healer["heal_diff.snapshot"] + healer["heal_diff.hash"]
+        assert "heal_diff.encode" not in healer
+        assert 0 <= healer["heal_diff.hidden"] <= digest_work
+        assert 0 < digest_work and 0 < healer["heal_diff"]
         assert healer["heal_apply"] >= 0.01  # the user's load, timed at last
         assert healer["heal_wire"] > 0 and healer["heal_recv"] >= 0
         assert "heal_send" not in healer

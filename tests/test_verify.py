@@ -361,34 +361,42 @@ class TestPhaseVocabulary:
         from torchft_tpu.checkpointing import fragments, http_transport
         from torchft_tpu.parallel import process_group
 
-        timed = set()
+        timed, counted = set(), set()
         for mod in (mgr, process_group, http_transport, fragments):
             tree = ast.parse(inspect.getsource(mod))
             for node in ast.walk(tree):
-                if (
+                if not (
                     isinstance(node, ast.Call)
                     and isinstance(node.func, ast.Attribute)
-                    and node.func.attr in ("_phase", "phase")
-                    and node.args
-                    and isinstance(node.args[0], ast.Constant)
                 ):
-                    timed.add(node.args[0].value)
+                    continue
+                # the name: a phase's first argument, add_seconds' second
+                at = {"_phase": 0, "phase": 0, "add_seconds": 1}.get(node.func.attr)
+                if (
+                    at is None
+                    or len(node.args) <= at
+                    or not isinstance(node.args[at], ast.Constant)
+                ):
+                    continue
+                # add_seconds: seconds put into a phase's sink beside its
+                # timed parts (``heal_diff.hidden``), a part with no span
+                (counted if at else timed).add(node.args[at].value)
         # no hand-rolled timing beside the primitive
         assert "_record_phase" not in inspect.getsource(mgr)
         top = {n for n in timed if "." not in n}
         assert top == set(PROTOCOL_PHASES)
         full = {n for n in timed if "." in n and not n.startswith(".")}
         relative = {n for n in timed if n.startswith(".")}
-        assert full <= set(mgr.PHASE_PARTS)
+        assert full | counted <= set(mgr.PHASE_PARTS)
         assert all(any(p.endswith(r) for p in mgr.PHASE_PARTS) for r in relative)
-        # every part is timed somewhere, under its full name or its last
-        # component, and its whole is a top-level phase or a part of one
+        # every part is timed (or counted) somewhere, under its full name or
+        # its last component, and its whole is a top-level phase or a part of one
         # (``ring.wire.arrive`` lies in ``ring.wire``, that in ``ring``)
         for part in mgr.PHASE_PARTS:
             whole, _, last = part.rpartition(".")
             assert whole in PROTOCOL_PHASES or whole in mgr.PHASE_PARTS
             assert part.partition(".")[0] in PROTOCOL_PHASES
-            assert part in full or "." + last in relative, part
+            assert part in full | counted or "." + last in relative, part
 
 
 class TestVerifyCli:

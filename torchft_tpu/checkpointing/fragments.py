@@ -58,6 +58,7 @@ import http.client
 import io
 import threading
 import time
+import types
 import urllib.error
 from collections import deque
 from concurrent.futures import Future, ThreadPoolExecutor
@@ -415,8 +416,9 @@ def iter_heal_fragments(
     hash, which is what lets the streamed staging overlap a healer's
     fetch of fragment *i* with the encode of fragment *i+1*.  Each of
     the three is timed as a part (``.snapshot``, ``.encode``, ``.hash``)
-    of whatever phase the consumer has open: ``heal_send`` on a source,
-    ``heal_diff`` on a healer hashing its own state.
+    of whatever phase the consumer has open: ``heal_send`` on a source.
+    (A healer wants its own state's digests and no bytes:
+    :func:`local_fragment_digests`.)
 
     Heal fragments are always ``f32`` wire (bitwise — a healed replica
     must converge exactly), leaf slots split round-robin like
@@ -519,14 +521,46 @@ def stage_heal_checkpoint(
 def local_fragment_digests(
     state_dict: Any, fragments: int
 ) -> "Tuple[int, Dict[str, str]]":
-    """Encode ``state_dict`` locally (no staging, no wire) into the heal
-    fragment layout and return ``(num_leaves, {name: sha256})`` — the
-    delta-heal diff base: a rejoiner whose fragment hashes to the same
+    """Hash ``state_dict`` IN PLACE (no wire bytes built, no staging) into
+    the heal fragment layout and return ``(num_leaves, {name: sha256})`` —
+    the delta-heal diff base: a rejoiner whose fragment hashes to the same
     digest as the source's already holds those bytes bitwise and skips
-    their wire entirely."""
-    _header, frag_iter = iter_heal_fragments(state_dict, fragments)
-    digests = {name: digest for name, _raw, digest in frag_iter}
-    return int(_header["num_leaves"]), digests
+    their wire entirely.
+
+    Per fragment: the same host snapshot of the device leaves as
+    :func:`iter_heal_fragments` takes, then ``serialization.prepare``'s
+    writer streams the 8-byte length, the pickled header and the leaves'
+    buffers, in wire order, into ``sha256.update``: byte for byte the
+    digest of ``sha256(ser.serialize(frag))``, with nothing allocated
+    beyond the snapshot.  Each fragment is timed as the parts
+    ``.snapshot`` and ``.hash`` of whatever phase the caller has open
+    (``heal_diff``).  One fragment after another on the caller's thread,
+    though both parts release the interpreter's lock: a healer runs this
+    beside its sources' encode, and three or four threads of it slowed
+    the sources by 0.2-1.0 s on a v5e's host, to end sooner inside a wait
+    that hides one thread's work whole (PERF.md section 6, PR 42)."""
+    import jax
+
+    leaves = jax.tree_util.tree_flatten(state_dict)[0]
+    names = heal_fragment_names(len(leaves), fragments)
+    digests: "Dict[str, str]" = {}
+    for name in names:
+        with _tracing.phase(".snapshot", fragment=name):
+            frag = {
+                str(slot): (
+                    np.asarray(leaves[slot])
+                    if isinstance(leaves[slot], jax.Array)
+                    else leaves[slot]
+                )
+                for slot in fragment_slots(name, len(leaves), len(names))
+            }
+        sha = hashlib.sha256()
+        with _tracing.phase(".hash", fragment=name) as p_hash:
+            p_hash.attrs["bytes"], writer = ser.prepare(frag)
+            # all the writer asks of its sink is ``write``
+            writer(types.SimpleNamespace(write=sha.update))
+        digests[name] = sha.hexdigest()
+    return len(leaves), digests
 
 
 def maybe_decode_heal_doc(doc: Any) -> Any:
@@ -834,7 +868,12 @@ def _raw_data_plane(
         poll_ms = int(min(max(timeout * 1000 - 150, 0), 5000))
         if poll_ms > 0:
             headers = {"X-TFT-Poll-Ms": str(poll_ms)}
-        if _fragdata.enabled():
+        # Not the header: a control part, never mirrored to the native
+        # server (``HTTPTransport._native_stage``), which takes a name it
+        # does not hold in a streaming version for a fragment still to
+        # land and parks the request, 503 after 503, until the version is
+        # complete — the one resource staged to be read BEFORE that.
+        if _fragdata.enabled() and resource != f"frag_{HEADER_FRAG}":
             got = _fragdata.fetch_native(base, version, resource, timeout)
             if got is not None:
                 buf, sha_hex, first_byte_s = got

@@ -26,7 +26,7 @@ import threading
 import time
 import urllib.error
 import urllib.request
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import Future, ThreadPoolExecutor
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Callable, List, Optional
 
@@ -831,6 +831,14 @@ class HTTPTransport(CheckpointTransport[Any]):
           ONLY the fragments whose digest moved — rejoin wire scales
           with the update delta, not model size.  Every fetched
           fragment verifies against the primary digest on receipt.
+          The manifest is staged last, after the source's whole encode,
+          so the healer takes the layout from the header (staged first)
+          and hashes its state on a thread of its own DURING that
+          long-poll; when the manifest is in it joins the digests and
+          diffs.  The manifest still defines truth: early digests of a
+          layout it does not confirm are thrown away and taken again.
+          The thread is joined before the call returns or raises, and
+          an error in it is raised here.
         - **full**: fetch the digest-less header first (served before
           the source has encoded anything), stripe ALL fragments while
           the source is still encoding, then verify the recorded
@@ -842,16 +850,26 @@ class HTTPTransport(CheckpointTransport[Any]):
 
         Returns ``(state_dict, info)`` where ``info`` carries the phase
         split (``phases``: ``heal_manifest``/``heal_diff``/``heal_wire``/
-        ``heal_decode``; ``parts``: what lies inside them,
-        ``heal_manifest.wait``, ``heal_diff.snapshot|encode|hash`` and
-        ``heal_decode.fragment``),
-        mode, fragment counts and wire bytes.  Each is a ``tracing.phase``
+        ``heal_decode``, disjoint stretches of this thread's wall time;
+        ``parts``: what lies inside them, ``heal_manifest.wait`` and
+        ``heal_decode.fragment``, and the digests' work where it runs,
+        ``heal_diff.snapshot|hash``, which in delta mode begins before
+        ``heal_diff`` does: ``heal_diff`` is the stretch from the
+        manifest's arrival until the digests are complete and diffed,
+        what they still cost the recovery), mode, ``hidden`` (also the
+        part ``heal_diff.hidden``: the seconds of digest work that had
+        ended when the manifest arrived; near the work's seconds when the
+        wait hid all of it, 0 for a legacy source, full mode, or a layout
+        the manifest did not confirm), fragment counts and wire bytes.
+        Each is a ``tracing.phase``
         timed here, when it happens; the Manager folds the seconds into
         ``phase_times()`` and emits no span of its own for them.  Falls
         back to the legacy single-source whole-document fetch when the
         primary's staged document has no fragments (mixed-config
         fleet)."""
         import urllib.error as _uerr
+
+        import jax
 
         from torchft_tpu.checkpointing import fragments as frags
         from torchft_tpu.ops.codec_pool import merged_seconds
@@ -867,43 +885,75 @@ class HTTPTransport(CheckpointTransport[Any]):
         # them back apart, by the dot
         timed: "dict[str, float]" = {}
         info: "dict[str, Any]" = {"sources": len(sources)}
+        # ``digester``: the one thread the healer's own digests begin on
+        # while this one long-polls for the manifest; joined when the
+        # block is left, however it is left
         with _flightrec.track(
             "checkpoint.http.recv", step=step, src_rank=0,
             sources=len(sources),
-        ) as op:
+        ) as op, ThreadPoolExecutor(
+            1, thread_name_prefix="tft_heal_digest"
+        ) as digester:
             local_state, into = self._build_into_map(local_state_fn)
             use_delta = (
                 delta
                 if delta is not None
                 else env_bool("TORCHFT_HEAL_DELTA", True)
             ) and local_state is not None
+            local_leaves = (
+                jax.tree_util.tree_flatten(local_state)[0] if use_delta else []
+            )
+            # opened when the manifest is in; its parts (the digests'
+            # ``.snapshot`` and ``.hash``) begin under it before that
+            p_diff = _tracing.phase("heal_diff", timed)
+            caller_ctx = _tracing.get_current()
 
-            # -- manifest phase: the primary defines truth.  Delta needs
-            # the digests (staged last — waits out the source's encode);
-            # full mode starts from the digest-less header (staged
-            # first) so the stripe overlaps the source's encode.
-            want = frags.MANIFEST_FRAG if use_delta else frags.HEADER_FRAG
+            def _digests(fragments: int) -> "dict[str, str]":
+                _tracing.set_current(caller_ctx)
+                with _tracing.under(p_diff):
+                    return frags.local_fragment_digests(
+                        local_state, fragments
+                    )[1]
+
+            def _fetch_manifest(which: str) -> "dict[str, Any]":
+                # long-poll and retries while the source has not staged
+                # it: the healer waiting for the source
+                with _tracing.phase(".wait"):
+                    buf = frags.fetch_raw(
+                        primary, step, f"frag_{which}",
+                        timeout=max(deadline - time.monotonic(), 0.001),
+                        role="heal",
+                    )
+                try:
+                    return frags.decode_manifest(buf)
+                finally:
+                    POOL.give(buf)
+
+            # -- manifest phase: the primary defines truth.  The
+            # digest-less header is staged first, before the source has
+            # encoded anything: full mode stripes from it while the
+            # source encodes.  Delta needs the digests, staged last, and
+            # long-polls for them through the source's whole encode: it
+            # takes the layout from the header first and hashes its own
+            # state into it meanwhile.
+            early: "Optional[Future]" = None
+            manifest: "Optional[dict[str, Any]]" = None
             with _tracing.phase("heal_manifest", timed) as p_manifest:
                 try:
-                    # long-poll and retries while the source has not
-                    # staged it: the healer waiting for the source
-                    with _tracing.phase(".wait"):
-                        mbuf = frags.fetch_raw(
-                            primary, step, f"frag_{want}",
-                            timeout=max(deadline - time.monotonic(), 0.001),
-                            role="heal",
-                        )
+                    header = _fetch_manifest(frags.HEADER_FRAG)
+                    if use_delta:
+                        if len(local_leaves) == int(header["num_leaves"]):
+                            early = digester.submit(
+                                _digests, len(header["fragments"])
+                            )
+                        manifest = _fetch_manifest(frags.MANIFEST_FRAG)
+                    else:
+                        manifest = header
                 except _uerr.HTTPError as e:
                     if e.code != 404:
                         raise
-                    mbuf = None
                     p_manifest.cancel()  # a legacy source: no split
-                else:
-                    try:
-                        manifest = frags.decode_manifest(mbuf)
-                    finally:
-                        POOL.give(mbuf)
-            if mbuf is None:
+            if manifest is None:
                 # Source staged a legacy whole-document snapshot (mixed
                 # config): take the classic path against the primary.
                 result = self._recv_checkpoint(
@@ -911,7 +961,7 @@ class HTTPTransport(CheckpointTransport[Any]):
                     max(deadline - time.monotonic(), 0.001),
                 )
                 op.update(mode="legacy")
-                info.update(mode="legacy", phases={})
+                info.update(mode="legacy", hidden=0.0, phases={})
                 return frags.maybe_decode_heal_doc(result), info
 
             names = [str(n) for n in manifest["fragments"]]
@@ -931,30 +981,42 @@ class HTTPTransport(CheckpointTransport[Any]):
                                    step=step)
                 )
 
-            # -- diff phase: hash the local state into the source's
+            # -- diff phase: the local state's digests in the source's
             # fragment layout; identical digests need no wire at all.
-            with _tracing.phase("heal_diff", timed):
+            # What is timed is what the digests still cost the recovery:
+            # from the manifest's arrival until they are complete.
+            hidden = 0.0
+            with p_diff:
                 changed = list(names)
                 leaves: "dict[int, Any]" = {}
-                if use_delta:
-                    import jax
-
-                    local_leaves = jax.tree_util.tree_flatten(local_state)[0]
-                    if len(local_leaves) == num_leaves:
-                        _n, mine = frags.local_fragment_digests(
-                            local_state, len(names)
+                if use_delta and len(local_leaves) == num_leaves:
+                    if early is not None and all(
+                        header[k] == manifest[k]
+                        for k in ("fragments", "num_leaves")
+                    ):
+                        # the digest work done in the shadow of the wait:
+                        # the parts that had ended when the manifest came
+                        hidden = sum(
+                            timed.get(k, 0.0)
+                            for k in ("heal_diff.snapshot", "heal_diff.hash")
                         )
-                        src_digests = manifest.get("digests") or {}
-                        changed = [
-                            n for n in names
-                            if src_digests.get(n) != mine.get(n)
-                        ]
-                        for name in names:
-                            if name not in changed:
-                                for slot in frags.fragment_slots(
-                                    name, num_leaves, len(names)
-                                ):
-                                    leaves[slot] = local_leaves[slot]
+                        mine = early.result()
+                    else:
+                        # no early digests, or of a layout the manifest
+                        # does not confirm: the manifest defines truth
+                        mine = _digests(len(names))
+                    src_digests = manifest.get("digests") or {}
+                    changed = [
+                        n for n in names
+                        if src_digests.get(n) != mine.get(n)
+                    ]
+                    for name in names:
+                        if name not in changed:
+                            for slot in frags.fragment_slots(
+                                name, num_leaves, len(names)
+                            ):
+                                leaves[slot] = local_leaves[slot]
+            _tracing.add_seconds(timed, "heal_diff.hidden", hidden)
             mode = "delta" if use_delta else "full"
 
             # -- wire + decode: striped fetch across every source,
@@ -1104,6 +1166,7 @@ class HTTPTransport(CheckpointTransport[Any]):
                 )
             info.update(
                 mode=mode,
+                hidden=hidden,
                 fragments=len(names),
                 changed=len(changed),
                 wire_bytes=wire_bytes,

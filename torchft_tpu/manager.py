@@ -116,14 +116,18 @@ PHASE_PARTS = (
     "ring.wire.send",  # handing the send to the sender thread, then what is left of it once the receive has returned
     "ring.reduce",  # the in-place ufunc between exchanges
     "ring.unpack",  # the division in place, cast back, split, unflatten
-    # fragments.iter_heal_fragments, per fragment, under whoever called
+    # fragments.iter_heal_fragments, per fragment, on the source
     "heal_send.snapshot",  # device leaves to host numpy
     "heal_send.encode",  # serialization.serialize
     "heal_send.hash",  # sha256
     "heal_send.stage",  # stage_streamed_part, the native mirror included
+    # fragments.local_fragment_digests, per fragment, on the transport's
+    # digest thread: begun while the healer waits for the manifest, under a
+    # heal_diff that opens when the manifest is in (so the parts may
+    # outweigh their whole)
     "heal_diff.snapshot",
-    "heal_diff.encode",
-    "heal_diff.hash",
+    "heal_diff.hash",  # serialization.prepare's writer into sha256.update: no bytes built
+    "heal_diff.hidden",  # no span: of those two, the seconds ended when the manifest came
     # inside fetch_raw: long-poll until the source staged the manifest
     "heal_manifest.wait",
     # one per fragment decoded; heal_decode is their busy sum
@@ -1676,8 +1680,13 @@ class Manager:
         ``.encode`` serialize, ``.hash`` sha256, ``.stage`` hand-over to
         the transport), ``heal_manifest`` (fetch of the primary's manifest;
         ``heal_manifest.wait`` is the long-poll inside it while the source
-        is still encoding), ``heal_diff`` (hashing the local state into the
-        source's layout: ``heal_diff.snapshot|encode|hash``), ``heal_wire``
+        is still encoding), ``heal_diff`` (what the healer's digests of its
+        own state, in the source's layout, still cost once the manifest is
+        in: they begin during the wait, from the header's layout, on a thread
+        of their own, where ``heal_diff.snapshot|hash`` time the work per
+        fragment; ``heal_diff.hidden`` is how much of that work had ended
+        when the manifest came, 0 where it could not begin early),
+        ``heal_wire``
         (the striped fetch loop's wall less decode), ``heal_decode`` (busy
         seconds of fragment decode, one ``heal_decode.fragment`` each),
         ``heal_recv`` (what those four leave of the receive: metadata RPC,
