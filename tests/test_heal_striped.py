@@ -795,9 +795,14 @@ class TestHealOpened:
                 quorum_timeout=30.0,
                 init_sync=False,
             )
+            # a replica the others finished without can never reach a quorum
+            # of two again: it fails here instead of asking for one forever
+            alone_by = time.monotonic() + 60.0
             try:
                 while manager.current_step() < steps:
                     step = manager.current_step()
+                    if time.monotonic() > alone_by:
+                        raise TimeoutError(f"replica {rid} still at step {step}")
                     faults.check("train.step", replica=f"replica_{rid}", step=step)
                     manager.start_quorum()
                     grads = {k: np.full_like(v, step + 1.0) for k, v in params.items()}

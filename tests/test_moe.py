@@ -256,3 +256,139 @@ class TestHeldMoEFields:
             same, base = moe.route_sigmoid(flat, p0["router"], moe.HeldMoEConfig(**self.CFG))
             np.testing.assert_array_equal(np.asarray(same), np.asarray(chosen))
             assert float(jnp.abs(base - weights).max()) > 0.05, "the choice is the same, the weights are not"
+
+
+# ---------------------------------------------------------------------------
+# the router's score is a field whose default is the four families'; a share
+# may hold more experts than a token chooses
+# ---------------------------------------------------------------------------
+
+
+class TestHeldMoEScore:
+    CFG, _setup = TestHeldMoEFields.CFG, TestHeldMoEFields._setup
+    WIDE = dict(d_model=32, d_expert=16, n_routed=32, top_k=8, held=tuple(range(16)), shared=False,
+                renorm_eps=0.0, score="softmax", dtype=jnp.float32)
+
+    @staticmethod
+    def _text_digest(jaxpr):
+        import hashlib
+
+        return hashlib.sha256(str(jaxpr).encode()).hexdigest()
+
+    def test_the_default_score_is_the_parents_program_jaxpr_for_jaxpr(self):
+        """Recorded on the parent commit (before ``score`` was a field and the
+        placement a function of its own): the layer's jaxpr forward, with a
+        ``router_bias``, and its gradient's.  The output's and the gradient's
+        recorded digests are ``TestHeldMoEFields``'s."""
+        moe, cfg, _, p0, x = self._setup()
+        assert cfg.score == "sigmoid"
+        assert self._text_digest(jax.make_jaxpr(lambda x, p: moe.held_moe_ffn(x, p, cfg))(x, p0)) == (
+            "eacbb3746136df42c39bc4fd4a7832e8de3d3bfc299a1d956f26004c130d52cd")
+        bias = jnp.zeros((16,))
+        assert self._text_digest(jax.make_jaxpr(
+            lambda x, p: moe.held_moe_ffn(x, p, cfg, router_bias=bias))(x, p0)) == (
+            "47319df017d6bcd50f3f29781a084a55f0a05dd362ab42235912504b4b854385")
+        assert self._text_digest(jax.make_jaxpr(jax.grad(
+            lambda p: (moe.held_moe_ffn(x, p, cfg)[0] ** 2).sum()))(p0)) == (
+            "327534181c757e03da54e1de459de6ea817306e87ba6eb7953146824b9782525")
+
+    def test_softmax_weights_sum_to_one_and_are_a_softmax_over_the_chosen_logits(self):
+        """``p[chosen] / sum p[chosen]`` with ``p`` over all 16: the
+        normaliser over all cancels, what is left is a softmax over the chosen
+        logits alone; the choice is the largest logits."""
+        moe, _, _, p0, x = self._setup()
+        cfg = moe.HeldMoEConfig(**{**self.CFG, "routed_scale": 1.0, "renorm_eps": 0.0, "score": "softmax"})
+        flat = x.reshape(-1, 32)
+        chosen, weights = moe.route_softmax(flat, p0["router"], cfg)
+        np.testing.assert_allclose(np.asarray(weights.sum(-1)), 1.0, rtol=1e-6)
+        logits = flat @ p0["router"]
+        _, by_logit = jax.lax.top_k(logits, 4)
+        np.testing.assert_array_equal(np.asarray(chosen), np.asarray(by_logit))
+        alone = jax.nn.softmax(jnp.take_along_axis(logits, chosen, axis=-1), axis=-1)
+        np.testing.assert_allclose(np.asarray(weights), np.asarray(alone), rtol=2e-5)
+        # and not the sigmoid's: the same router gives other weights
+        _, other = moe.route_sigmoid(flat, p0["router"], cfg)
+        assert float(jnp.abs(other - weights).max()) > 0.01
+        scaled = moe.route_softmax(flat, p0["router"], moe.HeldMoEConfig(**{**self.CFG, "score": "softmax"}))[1]
+        np.testing.assert_allclose(np.asarray(scaled.sum(-1)), 2.5, rtol=1e-6)
+
+    def test_the_layer_picks_its_router_by_the_field(self):
+        moe, _, _, p0, x = self._setup()
+        sig = moe.HeldMoEConfig(**self.CFG)
+        soft = moe.HeldMoEConfig(**{**self.CFG, "score": "softmax"})
+        y_sig, _ = moe.held_moe_ffn(x, p0, sig)
+        y_soft, _ = moe.held_moe_ffn(x, p0, soft)
+        assert float(jnp.abs(y_sig - y_soft).max()) > 1e-3
+        text = jax.jit(lambda x, p: moe.held_moe_ffn(x, p, soft)).lower(x, p0).as_text(debug_info=True)
+        for scope in ("moe.route", "moe.route.score", "moe.route.place", "moe.experts"):
+            assert scope in text, scope
+        with pytest.raises(ValueError, match="router_bias"):
+            moe.held_moe_ffn(x, p0, soft, router_bias=jnp.zeros((16,)))
+        with pytest.raises(ValueError, match="score"):
+            moe.held_moe_ffn(x, p0, moe.HeldMoEConfig(**{**self.CFG, "score": "topk"}))
+
+    @pytest.mark.parametrize("slack,pool", [(8.0, 384), (1.0, 192), (0.5, 96), (0.26, 56)])
+    def test_the_pool_where_more_experts_are_held_than_a_token_chooses(self, slack, pool):
+        """16 of 32 held, 8 a token, 48 tokens: the mean load is 192 rows;
+        the cap is every assignment of the batch (48 x 8 = 384), where with
+        ``held <= top_k`` it was ``N held``."""
+        from torchft_tpu.models import moe
+
+        cfg = moe.HeldMoEConfig(**{**self.WIDE, "slack": slack})
+        assert moe.pool_rows(cfg, 48) == pool
+        assert moe.pool_rows(moe.HeldMoEConfig(**{**self.WIDE, "held": (0, 1, 2, 3), "slack": 100.0}), 48) == 48 * 4
+
+    def test_sixteen_held_of_eight_chosen_fill_the_pools_rows_in_expert_order(self):
+        """Every landed assignment has one row; an expert's rows follow the
+        previous expert's, by token then choice; a token lands up to eight
+        times; the rest of the pool names the row of zeros at weight 0."""
+        from torchft_tpu.models import moe
+
+        cfg = moe.HeldMoEConfig(**{**self.WIDE, "slack": 2.0})
+        params = moe.init_held_moe_params(jax.random.PRNGKey(3), cfg, 1)
+        flat = jax.random.normal(jax.random.PRNGKey(4), (48, 32))
+        chosen, weights = moe.route_softmax(flat, params["router"][0], cfg)
+        local, assignments, unrouted, rows_token, rows_weight = map(
+            np.asarray, moe.place_assignments(chosen, weights, cfg))
+        chosen, weights = np.asarray(chosen), np.asarray(weights)
+        assert rows_token.shape == rows_weight.shape == (384,)
+        want_token, want_weight = [], []
+        for e in range(16):
+            for token in range(48):
+                for choice in range(8):
+                    if chosen[token, choice] == e:
+                        want_token.append(token)
+                        want_weight.append(weights[token, choice])
+        landed = len(want_token)
+        assert landed == int(assignments.sum()) == int((local >= 0).sum()) > 48, "a token lands more than once"
+        assert [int((chosen == e).sum()) for e in range(16)] == assignments.tolist()
+        np.testing.assert_array_equal(rows_token[:landed], want_token)
+        np.testing.assert_array_equal(rows_weight[:landed], np.asarray(want_weight, np.float32))
+        assert np.all(rows_token[landed:] == 48) and np.all(rows_weight[landed:] == 0.0)
+        assert int(unrouted) == int((chosen >= 16).all(-1).sum())
+        assert max(np.bincount(rows_token[:landed])) > 1
+
+    @pytest.mark.parametrize("slack", [2.0, 1.0, 0.5, 0.05])
+    def test_a_batch_over_the_pool_takes_the_masked_path_to_the_same_output(self, slack, monkeypatch):
+        """The gathered path while what landed fits ``pool_rows``, the masked
+        one from the first assignment over: which ran is the ``cond``'s
+        predicate; both give the uncut arithmetic."""
+        from torchft_tpu.models import moe
+
+        cfg = moe.HeldMoEConfig(**{**self.WIDE, "slack": slack})
+        p0 = jax.tree_util.tree_map(lambda w: w[0], moe.init_held_moe_params(jax.random.PRNGKey(3), cfg, 1))
+        x = jax.random.normal(jax.random.PRNGKey(4), (2, 24, 32))
+        taken, real = [], jax.lax.cond
+        monkeypatch.setattr(jax.lax, "cond", lambda pred, *rest: (taken.append(bool(pred)), real(pred, *rest))[1])
+        y, stats = moe.held_moe_ffn(x, p0, cfg)
+        landed = int(stats["assignments"].sum())
+        assert taken == [landed <= moe.pool_rows(cfg, 48)]
+        if slack in (2.0, 0.05):  # the cap holds every assignment; 16 rows hold next to none
+            assert taken[0] == (slack == 2.0)
+        flat = x.reshape(-1, 32)
+        chosen, weights = moe.route_softmax(flat, p0["router"], cfg)
+        want = jnp.zeros_like(flat)
+        for e in range(16):
+            glu = (jax.nn.silu(flat @ p0["w_gate"][e]) * (flat @ p0["w_up"][e])) @ p0["w_down"][e]
+            want = want + jnp.where(chosen == e, weights, 0.0).sum(-1, keepdims=True) * glu
+        np.testing.assert_allclose(np.asarray(y).reshape(-1, 32), np.asarray(want), rtol=2e-4, atol=2e-5)

@@ -9,9 +9,13 @@ query latent and a rotary part through ``mla``, the function ``kimi_linear``
 runs too; a loss over two prediction depths; the same expert layer) and a
 sparse decoder whose token mixer is a gated short convolution in three layers
 of four (``lfm2``: grouped-query attention in the fourth, the same expert
-layer without its shared expert, a head tied to the embedding)."""
+layer without its shared expert, a head tied to the embedding) and a sparse
+decoder with experts in every layer (``mellum``: windowed beside global
+attention with a rotary table for each kind, the global one YaRN-scaled; the
+same expert layer routed by a softmax over all published experts, holding
+more of them than a token chooses, no shared expert)."""
 
-from torchft_tpu.models import afmoe, cnn, joyai, kimi_linear, lfm2, mla, mlp, transformer
+from torchft_tpu.models import afmoe, cnn, joyai, kimi_linear, lfm2, mellum, mla, mlp, transformer
 from torchft_tpu.models.transformer import (
     TransformerConfig,
     init_params,
@@ -27,6 +31,7 @@ __all__ = [
     "joyai",
     "kimi_linear",
     "lfm2",
+    "mellum",
     "mla",
     "mlp",
     "transformer",

@@ -14,11 +14,12 @@ the residual connection passes them through unchanged — standard GShard
 semantics). The load-balance auxiliary loss (Switch/GShard ``E * Σ_e
 fraction_tokens_e * mean_prob_e``) is returned for the trainer to add.
 
-Beside it, a chip's share of a sigmoid-routed, dropless layer with a shared
-expert (``HeldMoEConfig``, ``held_moe_ffn``): the router scores every
-published expert, the layer is told which of them it holds and computes their
-part of the result.  No capacity, no drop, no auxiliary loss, no ``ep`` axis:
-the chips that hold the other experts and their exchange are not in it.
+Beside it, a chip's share of a dropless layer, sigmoid- or softmax-routed,
+with or without a shared expert (``HeldMoEConfig``, ``held_moe_ffn``): the
+router scores every published expert, the layer is told which of them it
+holds and computes their part of the result.  No capacity, no drop, no
+auxiliary loss, no ``ep`` axis: the chips that hold the other experts and
+their exchange are not in it.
 """
 
 from __future__ import annotations
@@ -211,7 +212,7 @@ def moe_ffn_reference(
 
 
 # ---------------------------------------------------------------------------
-# a chip's share of a sigmoid-routed, dropless expert layer
+# a chip's share of a dropless expert layer
 # ---------------------------------------------------------------------------
 
 
@@ -223,7 +224,9 @@ class HeldMoEConfig:
     published ids) and computes their part of the result, plus the shared
     expert that every chip computes alike (``shared``: a model without one
     has no ``shared_*`` leaves, and a token none of whose experts lives here
-    gets nothing from the layer)."""
+    gets nothing from the layer).  ``score`` is how the router's logits become
+    scores: ``"sigmoid"`` (each expert alone) or ``"softmax"`` (all
+    ``n_routed`` against each other)."""
 
     d_model: int
     d_expert: int
@@ -238,6 +241,7 @@ class HeldMoEConfig:
     shared: bool = True
     # added to the chosen scores' sum before they are renormalised to one
     renorm_eps: float = 1e-20
+    score: str = "sigmoid"
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
 
@@ -264,6 +268,22 @@ def init_held_moe_params(rng: jax.Array, cfg: HeldMoEConfig, n_layers: int) -> P
     return params
 
 
+def _router_logits(flat: jax.Array, router: jax.Array) -> jax.Array:
+    """``[N, d] -> [N, n_routed]`` in float32 at the highest precision: a
+    near-tie decides where a token goes."""
+    return jnp.matmul(flat.astype(jnp.float32), router.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def _choose(scores: jax.Array, ranked: jax.Array, cfg: HeldMoEConfig) -> "Tuple[jax.Array, jax.Array]":
+    """The ``top_k`` largest of ``ranked``, and their ``scores`` renormalised
+    to one (their sum + ``renorm_eps``) and scaled."""
+    _, chosen = jax.lax.top_k(ranked, cfg.top_k)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    weights = picked / (picked.sum(axis=-1, keepdims=True) + cfg.renorm_eps) * cfg.routed_scale
+    return chosen, weights
+
+
 def route_sigmoid(
     flat: jax.Array, router: jax.Array, cfg: HeldMoEConfig,
     router_bias: "Optional[jax.Array]" = None,
@@ -273,14 +293,60 @@ def route_sigmoid(
     token goes), the ``top_k`` largest of score + ``router_bias`` (a buffer
     the balancing rule moves, never the gradient), the chosen scores
     renormalised to one (their sum + ``renorm_eps``) and scaled."""
-    scores = jax.nn.sigmoid(jnp.matmul(
-        flat.astype(jnp.float32), router.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
+    scores = jax.nn.sigmoid(_router_logits(flat, router))
     ranked = scores if router_bias is None else scores + jax.lax.stop_gradient(router_bias)
-    _, chosen = jax.lax.top_k(ranked, cfg.top_k)
-    picked = jnp.take_along_axis(scores, chosen, axis=-1)
-    weights = picked / (picked.sum(axis=-1, keepdims=True) + cfg.renorm_eps) * cfg.routed_scale
-    return chosen, weights
+    return _choose(scores, ranked, cfg)
+
+
+def route_softmax(
+    flat: jax.Array, router: jax.Array, cfg: HeldMoEConfig,
+) -> "Tuple[jax.Array, jax.Array]":
+    """As ``route_sigmoid`` with the scores a softmax over all ``n_routed``
+    published experts, those that live elsewhere too: the ``top_k`` largest
+    probabilities, renormalised to one over the chosen (which is a softmax
+    over the chosen logits alone) and scaled.  No bias."""
+    scores = jax.nn.softmax(_router_logits(flat, router), axis=-1)
+    return _choose(scores, scores, cfg)
+
+
+def pool_rows(cfg: HeldMoEConfig, n: int) -> int:
+    """Rows of the pool ``n`` tokens' landed assignments are gathered into:
+    ``slack x`` the mean load of the held experts together (``n k held /
+    n_routed``), up to the next multiple of 8, and never more than can land:
+    a token's choices are distinct, so it lands here at most ``min(k, held)``
+    times.  Where more experts are held than a token chooses that cap is the
+    batch's every assignment, ``n k``."""
+    k, held = cfg.top_k, len(cfg.held)
+    return min(n * min(k, held), -(-int(np.ceil(cfg.slack * n * k * held / cfg.n_routed)) // 8) * 8)
+
+
+def place_assignments(
+    chosen: jax.Array, weights: jax.Array, cfg: HeldMoEConfig,
+) -> "Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]":
+    """Where each of the ``N k`` assignments goes: ``(local [N k] the held
+    slot or -1, assignments [held], unrouted, rows_token [pool], rows_weight
+    [pool])``.  The pool's rows are in expert order (the order held), an
+    expert's by token then choice; a row no assignment fills names token ``N``
+    (a row of zeros) at weight 0; an assignment past the pool's end is in no
+    row (the layer then takes the masked path)."""
+    n, k = chosen.shape
+    held, pool = len(cfg.held), pool_rows(cfg, n)
+    slot_of = np.full((cfg.n_routed,), -1, np.int32)
+    slot_of[list(cfg.held)] = np.arange(held, dtype=np.int32)
+    local = jnp.asarray(slot_of)[chosen].reshape(n * k)          # -1: lives elsewhere
+    landed = (local[:, None] == jnp.arange(held)[None, :]).astype(jnp.int32)  # [N k, held]
+    assignments = landed.sum(axis=0)
+    unrouted = n - jnp.any(local.reshape(n, k) >= 0, axis=-1).sum()
+    # the row of each assignment: its expert's first row (experts in the
+    # order held), then its place among that expert's, by token then choice
+    first = jnp.cumsum(assignments) - assignments
+    place = ((jnp.cumsum(landed, axis=0) - 1 + first[None, :]) * landed).sum(axis=-1)
+    row = jnp.where((local >= 0) & (place < pool), place, pool)
+    token = jnp.arange(n * k, dtype=jnp.int32) // k
+    rows_token = jnp.full((pool,), n, jnp.int32).at[row].set(token, mode="drop")
+    rows_weight = jnp.zeros((pool,), jnp.float32).at[row].set(
+        weights.reshape(n * k), mode="drop")
+    return local, assignments, unrouted, rows_token, rows_weight
 
 
 def held_moe_ffn(
@@ -293,40 +359,37 @@ def held_moe_ffn(
     where the model has none (``cfg.shared`` false).  No capacity, no
     drop, no auxiliary loss.  ``stats``: ``assignments`` ``[held]`` (how many
     of the ``N k`` assignments landed on each held expert) and ``unrouted``
-    (tokens that found none of their experts here).
+    (tokens that found none of their experts here).  ``cfg.score`` picks the
+    router (``route_sigmoid`` | ``route_softmax``; a softmax takes no
+    ``router_bias``).
 
     Static shapes with the work going by what landed: the assignments that
-    landed here are laid out by expert in a pool of ``slack x`` the mean load
-    (``N k held / n_routed``) rows, the three products of the SwiGLU run as
-    ``lax.ragged_dot`` over the pool's groups (on a TPU a grouped-matmul
-    kernel that visits the rows in use) and the results are added back by
-    token.  A batch so skewed that more lands here than the pool holds takes,
-    under ``lax.cond``, a path that runs every held expert over every token
-    with the weights as a mask."""
+    landed here are laid out by expert in a pool (``pool_rows``: ``slack x``
+    the mean load, never more than can land; ``place_assignments``), the
+    three products of the SwiGLU run as ``lax.ragged_dot`` over the pool's
+    groups (on a TPU a grouped-matmul kernel that visits the rows in use) and
+    the results are added back by token.  A batch so skewed that more lands
+    here than the pool holds takes, under ``lax.cond``, a path that runs every
+    held expert over every token with the weights as a mask."""
     from torchft_tpu.models.transformer import _swiglu
 
     b, t, d = x.shape
     n, k, held = b * t, cfg.top_k, len(cfg.held)
     act = cfg.dtype
     flat = x.reshape(n, d)
+    if cfg.score not in ("sigmoid", "softmax"):
+        raise ValueError(f"unknown score {cfg.score!r}; expected 'sigmoid' or 'softmax'")
+    if cfg.score == "softmax" and router_bias is not None:
+        raise ValueError("a softmax router takes no router_bias")
     with jax.named_scope("moe.route"):
-        chosen, weights = route_sigmoid(flat, params["router"], cfg, router_bias)
-        slot_of = np.full((cfg.n_routed,), -1, np.int32)
-        slot_of[list(cfg.held)] = np.arange(held, dtype=np.int32)
-        local = jnp.asarray(slot_of)[chosen].reshape(n * k)          # -1: lives elsewhere
-        landed = (local[:, None] == jnp.arange(held)[None, :]).astype(jnp.int32)  # [N k, held]
-        assignments = landed.sum(axis=0)
-        unrouted = n - jnp.any(local.reshape(n, k) >= 0, axis=-1).sum()
-        pool = min(n * min(k, held), -(-int(np.ceil(cfg.slack * n * k * held / cfg.n_routed)) // 8) * 8)
-        # the row of each assignment: its expert's first row (experts in the
-        # order held), then its place among that expert's, by token then choice
-        first = jnp.cumsum(assignments) - assignments
-        place = ((jnp.cumsum(landed, axis=0) - 1 + first[None, :]) * landed).sum(axis=-1)
-        row = jnp.where((local >= 0) & (place < pool), place, pool)
-        token = jnp.arange(n * k, dtype=jnp.int32) // k
-        rows_token = jnp.full((pool,), n, jnp.int32).at[row].set(token, mode="drop")
-        rows_weight = jnp.zeros((pool,), jnp.float32).at[row].set(
-            weights.reshape(n * k), mode="drop")
+        with jax.named_scope("moe.route.score"):
+            if cfg.score == "softmax":
+                chosen, weights = route_softmax(flat, params["router"], cfg)
+            else:
+                chosen, weights = route_sigmoid(flat, params["router"], cfg, router_bias)
+        with jax.named_scope("moe.route.place"):
+            local, assignments, unrouted, rows_token, rows_weight = place_assignments(chosen, weights, cfg)
+    pool = rows_token.shape[0]
 
     with jax.named_scope("moe.experts"):
         wg, wu, wd = (params[name].astype(act) for name in ("w_gate", "w_up", "w_down"))
@@ -398,6 +461,9 @@ __all__ = [
     "HeldMoEConfig",
     "init_held_moe_params",
     "route_sigmoid",
+    "route_softmax",
+    "pool_rows",
+    "place_assignments",
     "held_moe_ffn",
     "record_routing_stats",
     "MoEConfig",

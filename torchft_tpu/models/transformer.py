@@ -297,9 +297,16 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """Rotary embedding; x [B, T, H, D], positions [T] (global)."""
     d = x.shape[-1]
     freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [T, D/2]
+    return _rotate(x, positions[:, None].astype(jnp.float32) * freqs[None, :])
+
+
+def _rotate(x: jax.Array, angles: jax.Array, scale: float = 1.0) -> jax.Array:
+    """The halves of ``x`` [B, T, H, D] turned by ``angles`` [T, D/2]; a
+    scaling rule's factor (``models/mellum.py``) multiplies cos and sin."""
     cos = jnp.cos(angles)[None, :, None, :]
     sin = jnp.sin(angles)[None, :, None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
