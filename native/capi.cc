@@ -323,6 +323,29 @@ int tft_frag_stage(int64_t h, int64_t step, const char* resource,
   return s->stage(step, resource, data, static_cast<size_t>(len));
 }
 
+// Staging without the copy (FragServer::reserve / commit / release): a
+// pooled buffer lent for (step, resource), written by the caller, made
+// the staged fragment where it lies, and let go of when the caller's
+// last view of it is gone.  reserve returns NULL on an unknown step.
+uint8_t* tft_frag_reserve(int64_t h, int64_t step, const char* resource,
+                          int64_t len) {
+  tft::FragServer* s = find_frag(h);
+  if (s == nullptr || resource == nullptr || len < 0) return nullptr;
+  return s->reserve(step, resource, static_cast<size_t>(len));
+}
+
+int tft_frag_commit(int64_t h, int64_t step, const char* resource,
+                    const uint8_t* ptr, int64_t len) {
+  tft::FragServer* s = find_frag(h);
+  if (s == nullptr || resource == nullptr || len < 0) return -1;
+  return s->commit(step, resource, ptr, static_cast<size_t>(len));
+}
+
+int tft_frag_release(int64_t h, const uint8_t* ptr) {
+  tft::FragServer* s = find_frag(h);
+  return s == nullptr ? -1 : s->release(ptr);
+}
+
 int tft_frag_finish(int64_t h, int64_t step) {
   tft::FragServer* s = find_frag(h);
   return s == nullptr ? -1 : s->finish(step);
@@ -383,6 +406,17 @@ int tft_sha256_hex(const uint8_t* data, int64_t len, char* out65) {
   if ((data == nullptr && len > 0) || len < 0 || out65 == nullptr) return -1;
   tft::sha256_hex(data, static_cast<size_t>(len), out65);
   return 0;
+}
+
+// `rows` rows of a matrix that memory holds column by column, written row
+// by row (fragserver.h copy_transposed): a heal source re-orders a leaf the
+// device held the other way round straight into its serving buffer.
+int tft_copy_transposed(uint8_t* dst, const uint8_t* src, int64_t rows,
+                        int64_t cols, int64_t src_rows, int64_t itemsize) {
+  if (dst == nullptr || src == nullptr || rows < 0 || cols < 0 ||
+      src_rows < rows)
+    return -1;
+  return tft::copy_transposed(dst, src, rows, cols, src_rows, itemsize);
 }
 
 // Pure quorum-result math, exposed for unit tests: input/output JSON.

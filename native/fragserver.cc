@@ -424,7 +424,6 @@ constexpr int64_t kLongPollMs = 250;      // cut-through park window
 constexpr int64_t kLongPollCapMs = 5000;  // X-TFT-Poll-Ms request cap
 constexpr int64_t kServeTimeoutMs = 60000;
 constexpr size_t kPoolPerSizeCap = 64;    // recycled buffers kept per size
-
 }  // namespace
 
 void sha256_hex(const uint8_t* data, size_t len, char* out_hex65) {
@@ -440,6 +439,61 @@ void sha256_hex(const uint8_t* data, size_t len, char* out_hex65) {
   out_hex65[64] = '\0';
 }
 
+// ---- re-ordering copy ----------------------------------------------------
+// A TPU hands the host a leaf whose last dimension is no multiple of 128
+// with its last two dimensions the other way round; the wire wants rows.
+// numpy re-orders element by element down a stride of the whole column
+// (0.5 GB/s on a v5e's host, into a temporary); here a tile of kTileRows
+// x kTileCols elements is read along the columns' own memory and written
+// along the rows', both sides' lines reused while they are in L1 (few
+// columns a tile: their addresses lie a column apart and share a cache
+// set).
+
+namespace {
+
+template <typename T>
+void transpose_tiles(T* dst, const T* src, int64_t rows, int64_t cols,
+                     int64_t src_rows) {
+  constexpr int64_t kTileRows = 64, kTileCols = 8;
+  for (int64_t r0 = 0; r0 < rows; r0 += kTileRows) {
+    const int64_t r1 = std::min(r0 + kTileRows, rows);
+    for (int64_t c0 = 0; c0 < cols; c0 += kTileCols) {
+      const int64_t c1 = std::min(c0 + kTileCols, cols);
+      for (int64_t r = r0; r < r1; ++r)
+        for (int64_t c = c0; c < c1; ++c)
+          dst[r * cols + c] = src[c * src_rows + r];
+    }
+  }
+}
+
+}  // namespace
+
+int copy_transposed(uint8_t* dst, const uint8_t* src, int64_t rows,
+                    int64_t cols, int64_t src_rows, int64_t itemsize) {
+  switch (itemsize) {
+    case 1:
+      transpose_tiles(dst, src, rows, cols, src_rows);
+      return 0;
+    case 2:
+      transpose_tiles(reinterpret_cast<uint16_t*>(dst),
+                      reinterpret_cast<const uint16_t*>(src), rows, cols,
+                      src_rows);
+      return 0;
+    case 4:
+      transpose_tiles(reinterpret_cast<uint32_t*>(dst),
+                      reinterpret_cast<const uint32_t*>(src), rows, cols,
+                      src_rows);
+      return 0;
+    case 8:
+      transpose_tiles(reinterpret_cast<uint64_t*>(dst),
+                      reinterpret_cast<const uint64_t*>(src), rows, cols,
+                      src_rows);
+      return 0;
+    default:
+      return -1;
+  }
+}
+
 // ---- server --------------------------------------------------------------
 
 FragServer::FragServer(const std::string& bind_host, int port)
@@ -452,6 +506,10 @@ FragServer::~FragServer() {
   // RpcServer::shutdown is CAS-idempotent so an explicit earlier call
   // (tft_server_shutdown) makes this a no-op.
   shutdown();
+  // A buffer still lent has a Python view over it that may yet be read
+  // (a serve of the Python plane in flight at teardown): its memory is
+  // left allocated rather than pulled from under the reader.
+  for (auto& kv : lent_) (void)kv.second.buf->data.release();
 }
 
 Json FragServer::handle(const std::string& method, const Json&, int64_t) {
@@ -472,7 +530,9 @@ std::shared_ptr<FragBuf> FragServer::pool_take(size_t len) {
     it->second.pop_back();
     ++counters_.pool_hits;
   } else {
-    buf->data.resize(len);
+    // uninitialized: a fresh buffer's pages are touched once, by the
+    // write that fills it, not by a zero-fill first
+    buf->data.reset(new uint8_t[len]);
     ++counters_.pool_misses;
   }
   buf->len = len;
@@ -481,16 +541,29 @@ std::shared_ptr<FragBuf> FragServer::pool_take(size_t len) {
 
 void FragServer::pool_give_locked(FragBuf& buf) {
   // caller holds mu_
-  if (buf.data.empty()) return;
-  auto& slot = pool_[buf.data.size()];
+  if (!buf.data) return;
+  auto& slot = pool_[buf.len];
   if (slot.size() < kPoolPerSizeCap) slot.push_back(std::move(buf.data));
-  buf.data.clear();
+  buf.data.reset();
   buf.len = 0;
 }
 
 void FragServer::deref(const std::shared_ptr<FragBuf>& buf) {
   std::lock_guard<std::mutex> g(mu_);
   if (--buf->refs == 0 && buf->retired) pool_give_locked(*buf);
+}
+
+void FragServer::publish_locked(Version& version, const std::string& resource,
+                                const std::shared_ptr<FragBuf>& buf) {
+  // caller holds mu_
+  auto& slot = version.frags[resource];
+  if (slot) {
+    // restage of the same resource: retire the old buffer
+    slot->retired = true;
+    if (slot->refs == 0) pool_give_locked(*slot);
+  }
+  slot = buf;
+  cv_.notify_all();
 }
 
 int FragServer::begin(int64_t step) {
@@ -510,7 +583,7 @@ int FragServer::stage(int64_t step, const std::string& resource,
   }
   // The one copy in the plane: Python's staged buffer -> the pooled
   // registered buffer, outside the lock so concurrent stagers overlap.
-  if (len > 0) memcpy(buf->data.data(), data, len);
+  if (len > 0) memcpy(buf->data.get(), data, len);
   std::lock_guard<std::mutex> g(mu_);
   auto it = versions_.find(step);
   if (it == versions_.end()) {
@@ -518,15 +591,53 @@ int FragServer::stage(int64_t step, const std::string& resource,
     pool_give_locked(*buf);
     return -1;
   }
-  auto& slot = it->second.frags[resource];
-  if (slot) {
-    // restage of the same resource: retire the old buffer
-    slot->retired = true;
-    if (slot->refs == 0) pool_give_locked(*slot);
-  }
-  slot = buf;
+  publish_locked(it->second, resource, buf);
   counters_.stage_copy_bytes += static_cast<int64_t>(len);
-  cv_.notify_all();
+  return 0;
+}
+
+uint8_t* FragServer::reserve(int64_t step, const std::string& resource,
+                             size_t len) {
+  std::lock_guard<std::mutex> g(mu_);
+  if (versions_.find(step) == versions_.end()) return nullptr;
+  auto buf = pool_take(len);
+  // in no version yet, so a zombie by the rule above: letting go of the
+  // lend alone recycles it, until a commit puts it in one
+  buf->refs = 1;
+  buf->retired = true;
+  uint8_t* ptr = buf->data.get();
+  lent_[ptr] = Lend{buf, step, resource};
+  return ptr;
+}
+
+int FragServer::commit(int64_t step, const std::string& resource,
+                       const uint8_t* ptr, size_t len) {
+  std::lock_guard<std::mutex> g(mu_);
+  auto lit = lent_.find(ptr);
+  // `retired` on a lend: in no version now (never committed, or replaced
+  // there since); one that is being served is not published twice
+  if (lit == lent_.end() || lit->second.step != step ||
+      lit->second.resource != resource || lit->second.buf->len != len ||
+      !lit->second.buf->retired)
+    return -1;
+  auto it = versions_.find(step);
+  if (it == versions_.end()) return -1;  // retired while it was written
+  lit->second.buf->retired = false;
+  publish_locked(it->second, resource, lit->second.buf);
+  counters_.stage_inplace_bytes += static_cast<int64_t>(len);
+  return 0;
+}
+
+int FragServer::release(const uint8_t* ptr) {
+  std::shared_ptr<FragBuf> buf;
+  {
+    std::lock_guard<std::mutex> g(mu_);
+    auto lit = lent_.find(ptr);
+    if (lit == lent_.end()) return -1;
+    buf = std::move(lit->second.buf);
+    lent_.erase(lit);
+  }
+  deref(buf);
   return 0;
 }
 
@@ -565,6 +676,7 @@ Json FragServer::counters_json() const {
   out["pool_hits"] = c.pool_hits;
   out["pool_misses"] = c.pool_misses;
   out["stage_copy_bytes"] = c.stage_copy_bytes;
+  out["stage_inplace_bytes"] = c.stage_inplace_bytes;
   out["serve_copies"] = c.serve_copies;
   out["serve_bytes"] = c.serve_bytes;
   out["serves"] = c.serves;
@@ -620,7 +732,7 @@ bool FragServer::serve_frag(int fd, const std::shared_ptr<FragBuf>& buf) {
                          "Connection: keep-alive\r\n\r\n",
                          buf->len);
   bool ok = sendv_all(fd, hdr, static_cast<size_t>(hdr_len),
-                      buf->data.data(), buf->len,
+                      buf->data.get(), buf->len,
                       now_ms() + kServeTimeoutMs);
   {
     std::lock_guard<std::mutex> g(mu_);

@@ -4,8 +4,10 @@
 // restore — used to cross the Python HTTP handlers byte by byte.  This
 // server owns ONLY the data plane: Python stages raw wire-byte fragment
 // payloads down at stage time (one copy into a pooled registered
-// buffer), and every subsequent serve is a writev straight out of that
-// buffer — zero user-space copies steady-state, no GIL anywhere.
+// buffer; none where the payload was written into a buffer RESERVED here
+// and is then COMMITTED in place), and every subsequent serve is a
+// writev straight out of that buffer — zero user-space copies
+// steady-state, no GIL anywhere.
 // Python keeps all control: plans, manifests, digests-of-record,
 // staging lifecycle, version advertisement.
 //
@@ -33,12 +35,14 @@
 namespace tft {
 
 // One staged fragment payload in a pool-recycled buffer.  `refs` counts
-// in-flight serves (guarded by the server mutex); a retire that lands
-// while a serve holds a ref marks the buffer zombie and the LAST deref
-// recycles it — retire never blocks on the wire.
+// in-flight serves and, for a buffer lent to Python (reserve), the lend
+// (guarded by the server mutex); a retire that lands while anything holds
+// a ref marks the buffer zombie and the LAST deref recycles it — retire
+// never blocks on the wire.  The memory is never zero-filled: nothing
+// reads a buffer before it was written whole.
 struct FragBuf {
-  std::vector<uint8_t> data;  // capacity-pooled backing store
-  size_t len = 0;             // staged payload length (<= data.size())
+  std::unique_ptr<uint8_t[]> data;  // capacity-pooled backing store
+  size_t len = 0;                   // its capacity = the payload's length
   int refs = 0;
   bool retired = false;
 };
@@ -47,6 +51,7 @@ struct FragCounters {
   int64_t pool_hits = 0;
   int64_t pool_misses = 0;
   int64_t stage_copy_bytes = 0;  // the ONE copy: Python buffer -> pool
+  int64_t stage_inplace_bytes = 0;  // committed where written: no copy
   int64_t serve_copies = 0;      // must stay 0: serve is pure writev
   int64_t serve_bytes = 0;
   int64_t serves = 0;
@@ -72,6 +77,21 @@ class FragServer : public RpcServer {
   int finish(int64_t step);
   int retire(int64_t step);
 
+  // Staging without the copy.  `reserve` lends a pooled buffer of `len`
+  // bytes for (step, resource): not in the version yet, so readers of it
+  // stay parked (never partial bytes); nullptr on an unknown step.  The
+  // caller writes the payload into it and `commit`s: the buffer becomes
+  // the staged fragment and parked readers wake.  commit returns -1 when
+  // `ptr` is no lend made for exactly (step, resource, len), is the
+  // staged fragment already, or the step was retired meanwhile (nothing
+  // published).  The lend is one more
+  // reference, held until `release(ptr)`: a retired buffer returns to the
+  // pool only when the lender AND every in-flight serve have let go.
+  uint8_t* reserve(int64_t step, const std::string& resource, size_t len);
+  int commit(int64_t step, const std::string& resource, const uint8_t* ptr,
+             size_t len);
+  int release(const uint8_t* ptr);
+
   FragCounters counters() const;
   Json counters_json() const;
 
@@ -93,8 +113,16 @@ class FragServer : public RpcServer {
     std::map<std::string, std::shared_ptr<FragBuf>> frags;  // by resource
   };
 
+  struct Lend {
+    std::shared_ptr<FragBuf> buf;
+    int64_t step;
+    std::string resource;
+  };
+
   std::shared_ptr<FragBuf> pool_take(size_t len);
   void pool_give_locked(FragBuf& buf);
+  void publish_locked(Version& version, const std::string& resource,
+                      const std::shared_ptr<FragBuf>& buf);
   void deref(const std::shared_ptr<FragBuf>& buf);
   bool reply_simple(int fd, int status, const std::string& body);
   bool serve_frag(int fd, const std::shared_ptr<FragBuf>& buf);
@@ -105,7 +133,8 @@ class FragServer : public RpcServer {
   // Free-list keyed by exact capacity: fragment sizes repeat across
   // publishes, so steady-state stage traffic is all pool hits (the
   // bufpool miss-flat idiom, natively).
-  std::map<size_t, std::vector<std::vector<uint8_t>>> pool_;
+  std::map<size_t, std::vector<std::unique_ptr<uint8_t[]>>> pool_;
+  std::map<const uint8_t*, Lend> lent_;  // buffers Python holds a view of
   FragCounters counters_;
   // injection state (guarded by mu_)
   int inject_mode_ = 0;  // 0 off, 1 drop, 2 delay
@@ -136,5 +165,13 @@ const std::string& frag_client_error();
 
 // Streaming SHA-256 over one buffer, lowercase hex into out[64] + NUL.
 void sha256_hex(const uint8_t* data, size_t len, char* out_hex65);
+
+// dst[r * cols + c] = src[c * src_rows + r] for r < rows, in elements of
+// `itemsize` bytes (1, 2, 4 or 8; -1 otherwise): `rows` rows of a
+// [src_rows, cols] matrix that memory holds column by column (src points
+// at the first of them), written out row by row.  In tiles, so that a
+// cache line fetched for one row serves its neighbours too.
+int copy_transposed(uint8_t* dst, const uint8_t* src, int64_t rows,
+                    int64_t cols, int64_t src_rows, int64_t itemsize);
 
 }  // namespace tft

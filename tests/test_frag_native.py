@@ -249,6 +249,202 @@ class TestNativeContract:
             t.shutdown()
 
 
+class TestStagedInPlace:
+    """ISSUE 45: a heal source reserves the buffer a fragment will be
+    served from, writes it once and commits it where it lies.  The buffer
+    is the native server's, lent to Python until the last view of it is
+    gone; ownership is what these tests hold it to."""
+
+    def _slow_reader(self, port: int, step: int, resource: str):
+        """A GET against the native server whose body is left in the
+        socket: the serve blocks in ``sendmsg`` holding its reference."""
+        import socket
+
+        s = socket.socket()
+        # asked for before connecting, so the window is small from the start
+        s.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, 1 << 16)
+        s.connect(("127.0.0.1", port))
+        s.sendall(
+            f"GET /checkpoint/{step}/{resource} HTTP/1.1\r\n"
+            f"Host: x\r\n\r\n".encode()
+        )
+        head = b""
+        while b"\r\n\r\n" not in head:
+            head += s.recv(256)
+        head, _, body = head.partition(b"\r\n\r\n")
+        assert head.startswith(b"HTTP/1.1 200")
+        length = int(head.lower().split(b"content-length:")[1].split()[0])
+        return s, body, length
+
+    def test_fetch_in_flight_across_retire_then_the_buffer_recycles(self):
+        import gc
+        import hashlib
+
+        # one fragment, far larger than the loopback's buffers
+        state = {"w": np.random.default_rng(1).standard_normal(6_000_000)
+                 .astype(np.float32)}
+        t = HTTPTransport(timeout=10.0, native=True)
+        try:
+            srv = t._frag_native
+            digest = t.send_checkpoint_streamed(
+                [1], 5, state, 10.0, fragments=1
+            )["digests"]["0"]
+            c = srv.counters()
+            assert (c["pool_misses"], c["pool_hits"]) == (1, 0)
+            sock, body, length = self._slow_reader(srv.port, 5, "frag_0")
+            # retired under the serve: the slot and its view of the buffer
+            # go, the serve's reference keeps the memory as it was
+            t.retire_checkpoint(5)
+            gc.collect()
+            assert t.staged_steps() == []
+            # the same size again while the serve still reads the first
+            # buffer: a fresh one, never the one in flight
+            t.send_checkpoint_streamed([1], 6, state, 10.0, fragments=1)
+            c = srv.counters()
+            assert (c["pool_misses"], c["pool_hits"]) == (2, 0)
+            assert c["serves"] == 0  # the first serve is still under way
+            # scribble over the second: the first must not be the same memory
+            t._staged[6].sd["frag:0"][:] = 0
+            sock.settimeout(10.0)
+            while len(body) < length:
+                chunk = sock.recv(1 << 20)
+                assert chunk, "serve ended short"
+                body += chunk
+            sock.close()
+            assert len(body) == length
+            assert hashlib.sha256(body).hexdigest() == digest
+            # the serve has let go: now, and only now, the buffer recycles
+            deadline = time.monotonic() + 5.0
+            while srv.counters()["serves"] < 1 and time.monotonic() < deadline:
+                time.sleep(0.01)
+            t.send_checkpoint_streamed([1], 7, state, 10.0, fragments=1)
+            c = srv.counters()
+            assert (c["pool_misses"], c["pool_hits"]) == (2, 1)
+            assert c["stage_copy_bytes"] == 0 and c["serve_copies"] == 0
+        finally:
+            t.shutdown()
+
+    @pytest.mark.parametrize("plane", ["native", "python"])
+    def test_reserved_unpublished_fragment_parks_never_partial(
+        self, plane, monkeypatch
+    ):
+        """A reader that asks for a fragment between its reservation and
+        its publish waits (long-poll, then 503): it never sees the bytes
+        written so far.  Published, it gets all of them."""
+        from concurrent.futures import ThreadPoolExecutor
+
+        monkeypatch.setenv(
+            "TORCHFT_FRAG_NATIVE", "1" if plane == "native" else "0"
+        )
+        payload = np.random.default_rng(2).integers(
+            0, 256, size=300_000, dtype=np.uint8
+        )
+        # the server is native either way: the python plane is the
+        # fallback a gated-off peer takes against it
+        t = HTTPTransport(timeout=10.0, native=True)
+        try:
+            base = t.metadata()
+            t.begin_streamed_checkpoint(9, {"frag:header": {"n": 1}})
+            buf = t.reserve_streamed_part(9, "frag:0", payload.nbytes)
+            assert buf.base is not None  # the native server's memory, lent
+            buf[:100_000] = payload[:100_000]  # half-written
+            t0 = time.monotonic()
+            with pytest.raises(urllib.error.HTTPError) as ei:
+                frags._raw_data_plane(
+                    base, "/checkpoint/9/frag_0", 9, "frag_0", 0.6
+                )
+            assert ei.value.code == 503
+            assert time.monotonic() - t0 >= 0.3  # it parked first
+            with ThreadPoolExecutor(1) as ex:
+                fut = ex.submit(
+                    frags.fetch_raw, base, 9, "frag_0", 10.0, "heal"
+                )
+                time.sleep(0.2)
+                assert not fut.done()
+                buf[100_000:] = payload[100_000:]
+                assert t.stage_streamed_part(9, "frag:0", buf, pooled=True) == 0
+                got = fut.result(timeout=10.0)
+            assert bytes(memoryview(got)) == payload.tobytes()
+            c = t._frag_native.counters()
+            assert c["stage_inplace_bytes"] == payload.nbytes
+            if plane == "native":
+                assert c["parked_waits"] >= 2 and c["busy_replies"] >= 1
+            else:
+                assert c["serves"] == 0  # the slot served the same memory
+        finally:
+            t.shutdown()
+
+    def test_a_lend_commits_once_whole_and_only_for_its_fragment(self):
+        """What the native side refuses to publish in place takes the
+        copy: a buffer it never lent, a lend for another fragment, a lend
+        handed back in part, a lend already committed."""
+        t = HTTPTransport(timeout=10.0, native=True)
+        try:
+            srv = t._frag_native
+            t.begin_streamed_checkpoint(3, {"frag:header": {"n": 1}})
+            own = np.arange(4096, dtype=np.uint8)
+            assert srv.stage(3, "frag_a", own) == own.nbytes
+            lent = srv.reserve(3, "frag_b", 4096)
+            lent[:] = 7
+            assert srv.stage(3, "frag_c", lent) == 4096  # another's name
+            assert srv.stage(3, "frag_b", lent[:100]) == 100  # in part
+            assert srv.stage(3, "frag_b", lent) == 0  # whole: in place
+            assert srv.stage(3, "frag_b", lent) == 4096  # once
+            assert srv.reserve(4, "frag_b", 16) is None  # no such version
+            assert srv.stage(4, "frag_b", own) is None
+            t.finish_streamed_checkpoint(3)
+            assert fetch_bytes(t.metadata(), 3, "frag_b") == lent.tobytes()
+            c = srv.counters()
+            assert c["stage_inplace_bytes"] == 4096
+            assert c["stage_copy_bytes"] == 3 * 4096 + 100
+        finally:
+            t.shutdown()
+
+    def test_concurrent_stagers_and_readers_never_cross_buffers(self):
+        """More stagers than cores on one server, each reserving, writing,
+        publishing, reading back over the native plane and retiring its
+        own versions while the others recycle the same buffer sizes: a
+        lend handed to two writers, or a buffer recycled under a reader,
+        shows as a body that is not the writer's pattern."""
+        import sys
+        from concurrent.futures import ThreadPoolExecutor
+
+        t = HTTPTransport(timeout=20.0, native=True, max_staged=64)
+        base = t.metadata()
+        n = 96 * 1024  # one size for all: every release feeds every reserve
+
+        def stager(i: int) -> int:
+            done = 0
+            for it in range(12):
+                step = 1000 * (i + 1) + it
+                t.begin_streamed_checkpoint(step, {"frag:header": {"n": 1}})
+                buf = t.reserve_streamed_part(step, "frag:0", n)
+                buf[:] = (i * 16 + it) % 251
+                assert t.stage_streamed_part(step, "frag:0", buf, pooled=True) == 0
+                del buf
+                t.finish_streamed_checkpoint(step)
+                body = fetch_bytes(base, step, "frag_0", timeout=20.0)
+                assert body == bytes([(i * 16 + it) % 251]) * n, (i, it)
+                t.retire_checkpoint(step)
+                done += 1
+            return done
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            with ThreadPoolExecutor(16) as ex:
+                futs = [ex.submit(stager, i) for i in range(16)]
+                assert [f.result(timeout=120) for f in futs] == [12] * 16
+            c = t._frag_native.counters()
+            assert c["stage_copy_bytes"] == 0 and c["serve_copies"] == 0
+            assert c["stage_inplace_bytes"] == 16 * 12 * n
+            assert c["pool_hits"] + c["pool_misses"] == 16 * 12
+            assert c["pool_misses"] <= 64  # recycled, not grown without end
+        finally:
+            sys.setswitchinterval(old)
+            t.shutdown()
+
+
 class TestNativeChaos:
     def test_kill_native_relay_mid_stripe(self, sources):
         """SIGKILL-equivalent (full shutdown: Python control + native

@@ -382,7 +382,7 @@ class TestHealStagingLifecycle:
             assert "digests" not in got_header
             fbuf = frags.fetch_raw(t.metadata(), 9, "frag_0", 2.0,
                                    role="heal")
-            assert bytes(memoryview(fbuf)) == raw
+            assert bytes(memoryview(fbuf)) == bytes(memoryview(raw))
             with pytest.raises((urllib.error.HTTPError, TimeoutError)):
                 frags.fetch_raw(t.metadata(), 9, "full", 0.3, role="heal")
         finally:
@@ -508,6 +508,268 @@ class TestHealStagingLifecycle:
                 [1], 2, state, timeout=5.0, fragments=fragments
             )
             assert mine == manifest["digests"]
+        finally:
+            t.shutdown()
+
+
+def _one_write_state(kind: str) -> dict:
+    """A state of four leaves with the leaf under test among them."""
+    import jax.numpy as jnp
+    import ml_dtypes
+
+    rng = np.random.default_rng(3)
+    w = rng.standard_normal(1031).astype(np.float32)
+    leaf = {
+        "float32": w,
+        "bfloat16": jnp.asarray(w, jnp.bfloat16),  # a jax.Array: snapshotted
+        "float8": w.astype(ml_dtypes.float8_e4m3fn),
+        "zero_d": np.float32(2.5) * np.ones((), np.float32),
+        "object": {"note": "inline in the header", "lr": 1e-3},
+        "empty": np.zeros((0, 7), np.float32),
+        # not a whole number of the sink's blocks, so a short last one
+        "over_a_block": rng.standard_normal(
+            frags._SINK_BLOCK // 4 + 12_345
+        ).astype(np.float32),
+        # as a TPU hands over a leaf whose last dimension is no multiple
+        # of 128: each matrix column by column (strides, not a copy); the
+        # first over a block, so re-ordered in several stretches of rows
+        "device_order": rng.standard_normal((577, 3001)).astype(np.float32).T,
+        "device_order_stack": rng.standard_normal((3, 37, 130)).astype(
+            ml_dtypes.bfloat16
+        ).transpose(0, 2, 1),
+        # some other order of memory: no kernel for it, numpy's copy
+        "strided": rng.standard_normal((40, 50)).astype(np.float32)[::2, ::5],
+    }[kind]
+    return {"a": w[:100].copy(), "b": leaf, "c": np.arange(9), "step": 3}
+
+
+def _fragments_by_hand(state: dict, fragments: int) -> dict:
+    """``{name: serialize(fragment)}``: the wire bytes as the old road
+    built them, through ``ser.serialize``'s ``BytesIO``."""
+    import jax
+
+    from torchft_tpu.checkpointing import serialization as ser
+
+    leaves = jax.tree_util.tree_flatten(state)[0]
+    names = frags.heal_fragment_names(len(leaves), fragments)
+    return {
+        name: ser.serialize({
+            str(slot): (
+                np.asarray(leaves[slot])
+                if isinstance(leaves[slot], jax.Array)
+                else leaves[slot]
+            )
+            for slot in frags.fragment_slots(name, len(leaves), len(names))
+        })
+        for name in names
+    }
+
+
+class TestOneWrite:
+    """ISSUE 45: a source writes each fragment's wire bytes once, into the
+    buffer they are served from (the native server's, lent; a ``bufpool``
+    buffer with no native plane), and hashes them as they land.  Nothing
+    about the bytes or the digests may differ from ``ser.serialize``."""
+
+    KINDS = (
+        "float32", "bfloat16", "float8", "zero_d", "object", "empty",
+        "over_a_block", "device_order", "device_order_stack", "strided",
+    )
+
+    @pytest.mark.parametrize("plane", ["native", "python"])
+    @pytest.mark.parametrize("kind", KINDS)
+    def test_digest_and_served_bytes_are_serialize(
+        self, kind, plane, monkeypatch
+    ):
+        import hashlib
+
+        from torchft_tpu.checkpointing import fragdata
+
+        if plane == "native" and not fragdata.available():
+            pytest.skip("native fragment library unavailable")
+        monkeypatch.setenv(
+            "TORCHFT_FRAG_NATIVE", "1" if plane == "native" else "0"
+        )
+        if plane == "python":
+            # no native library at all: numpy re-orders what lies otherwise
+            monkeypatch.setattr(fragdata, "available", lambda: False)
+        fragdata.reset_port_cache()
+        state = _one_write_state(kind)
+        if kind.startswith("device_order"):
+            assert frags._stored_swapped(state["b"]) is not None
+            assert not state["b"].flags.c_contiguous
+        want = _fragments_by_hand(state, 2)
+        t = HTTPTransport(timeout=5.0)
+        try:
+            assert (t._frag_native is not None) == (plane == "native")
+            manifest = t.send_checkpoint_streamed(
+                [1], 4, state, timeout=5.0, fragments=2
+            )
+            assert list(manifest["digests"]) == list(want)
+            # a healer's digests of the same state: the same sink, no bytes
+            assert frags.local_fragment_digests(state, 2)[1] == manifest["digests"]
+            for name, raw in want.items():
+                assert manifest["digests"][name] == hashlib.sha256(
+                    raw
+                ).hexdigest()
+                # the slot holds the ONE buffer: no bytes object beside it
+                held = t._staged[4].sd[f"frag:{name}"]
+                assert isinstance(held, np.ndarray) and held.dtype == np.uint8
+                assert bytes(memoryview(held)) == raw
+                buf = frags.fetch_raw(
+                    t.metadata(), 4, f"frag_{name}", 5.0, role="heal"
+                )
+                assert bytes(memoryview(buf)) == raw
+            if plane == "native":
+                c = t._frag_native.counters()
+                assert c["stage_copy_bytes"] == 0
+                assert c["stage_inplace_bytes"] == sum(map(len, want.values()))
+        finally:
+            t.shutdown()
+            fragdata.reset_port_cache()
+
+    @pytest.mark.parametrize("engaged", [True, False])
+    def test_copied_counts_what_was_not_staged_in_place(
+        self, engaged, monkeypatch
+    ):
+        """The counter that says the mechanism engaged: over a streamed
+        heal the native server copies nothing and ``heal_send.copied``
+        reads 0; with no buffer to reserve there every byte is copied
+        once more and both say so."""
+        from torchft_tpu.checkpointing import fragdata
+        from torchft_tpu.utils import tracing
+
+        if not fragdata.available():
+            pytest.skip("native fragment library unavailable")
+        if not engaged:
+            monkeypatch.setattr(
+                fragdata.FragDataServer, "reserve", lambda *a, **k: None
+            )
+        state = make_state()
+        t = HTTPTransport(timeout=5.0, native=True)
+        healer = HTTPTransport(timeout=5.0)
+        sink: dict = {}
+        try:
+            with tracing.phase("heal_send", sink):
+                manifest = t.send_checkpoint_streamed(
+                    [1], 6, state, timeout=5.0, fragments=4
+                )
+            wire = sum(
+                memoryview(t._staged[6].sd[f"frag:{n}"]).nbytes
+                for n in manifest["fragments"]
+            )
+            c = t._frag_native.counters()
+            assert c["stage_copy_bytes"] == (0 if engaged else wire)
+            assert c["stage_inplace_bytes"] == (wire if engaged else 0)
+            assert sink["heal_send.copied"] == (0 if engaged else wire)
+            # the names the layer metrics read are all still there
+            assert {
+                "heal_send.snapshot", "heal_send.encode", "heal_send.hash",
+                "heal_send.stage",
+            } <= set(sink)
+            assert sink["heal_send.encode"] > sink["heal_send.hash"]
+            # either way a healer gets the source's bytes
+            got, _info = healer.recv_checkpoint_striped(
+                [t.metadata()], 6, timeout=10.0
+            )
+            assert_state_equal(got, state)
+        finally:
+            healer.shutdown()
+            t.shutdown()
+
+    @pytest.mark.parametrize("itemsize", [1, 2, 4, 8])
+    def test_the_kernel_reorders_any_stretch_of_rows(self, itemsize):
+        """``fragdata.copy_transposed`` against numpy, for every item size
+        it has a kernel for, on stretches that start and end inside tiles;
+        and what ``_stored_swapped`` takes for the device's order."""
+        from torchft_tpu.checkpointing import fragdata
+
+        if not fragdata.available():
+            pytest.skip("native fragment library unavailable")
+        dtype = {1: np.uint8, 2: np.uint16, 4: np.float32, 8: np.float64}[itemsize]
+        rng = np.random.default_rng(itemsize)
+        arr = (rng.random((2, 3, 45, 203)) * 250).astype(dtype).transpose(0, 1, 3, 2)
+        assert frags._stored_swapped(arr) == (6, 203, 45)
+        want = np.ascontiguousarray(arr).reshape(6, 203, 45)
+        for matrix, row, rows in [(0, 0, 203), (5, 0, 1), (3, 64, 64), (4, 7, 190)]:
+            dst = np.zeros(rows * 45 * itemsize, np.uint8)
+            fragdata.copy_transposed(dst, arr, matrix, row, rows)
+            assert dst.tobytes() == want[matrix, row:row + rows].tobytes()
+        with pytest.raises(ValueError):
+            fragdata.copy_transposed(dst, arr, 6, 0, 190)  # no such matrix
+        with pytest.raises(ValueError):
+            fragdata.copy_transposed(dst[:-1], arr, 0, 0, 190)  # short
+        for other in (
+            want,  # C order
+            want[:, ::2],  # rows skipped
+            arr[:, :, :, ::3],  # columns skipped
+            np.zeros((0, 4), dtype).T,  # nothing
+            np.arange(5, dtype=dtype),  # one dimension
+        ):
+            assert frags._stored_swapped(other) is None
+
+    def test_iterator_hands_the_store_a_buffer_it_round_trips(self, tmp_path):
+        """``iter_heal_fragments``' second consumer: the durable store
+        writes the ``uint8`` buffer (no ``bytes`` any more) to disk and
+        loads the state back bitwise."""
+        import hashlib
+
+        from torchft_tpu.checkpointing.store import FragmentStore
+
+        state = _one_write_state("bfloat16")
+        header, it = frags.iter_heal_fragments(state, 3)
+        for name, raw, digest in it:
+            assert isinstance(raw, np.ndarray) and raw.dtype == np.uint8
+            assert raw.ndim == 1 and raw.flags.c_contiguous
+            assert digest == hashlib.sha256(raw).hexdigest()
+        store = FragmentStore(str(tmp_path))
+        manifest = store.put_state(11, state, fragments=3)
+        want = _fragments_by_hand(state, 3)
+        for name, raw in want.items():
+            assert store.fragment(11, name) == raw
+        back = FragmentStore(str(tmp_path)).load_state(store.manifest(11))
+        assert back["step"] == 3 and manifest["digests"].keys() == want.keys()
+        for k in ("a", "b", "c"):
+            assert np.asarray(back[k]).tobytes() == np.asarray(state[k]).tobytes()
+            assert np.asarray(back[k]).dtype == np.asarray(state[k]).dtype
+
+    def test_a_healer_that_knows_only_the_wire_format_heals(self):
+        """Wire and manifest are the parent commit's: a healer built
+        before this source changed reads the header, the manifest and the
+        fragments with ``deserialize`` and ``sha256`` alone (plain HTTP
+        against the control server, nothing of this tree's fetch plane)
+        and holds the source's state."""
+        import hashlib
+        import urllib.request
+
+        from torchft_tpu.checkpointing import serialization as ser
+
+        state = make_state()
+        t = HTTPTransport(timeout=5.0)
+
+        def get(resource: str) -> bytes:
+            with urllib.request.urlopen(
+                f"{t.metadata()}/checkpoint/8/{resource}", timeout=5.0
+            ) as resp:
+                return resp.read()
+
+        try:
+            t.send_checkpoint_streamed([1], 8, state, timeout=5.0, fragments=5)
+            header = ser.deserialize(get("frag_header"))
+            manifest = ser.deserialize(get("frag_manifest"))
+            assert set(header) == {
+                "wire", "fragments", "skeleton", "num_leaves", "version",
+            }
+            assert set(manifest) == set(header) | {"digests", "created_ns"}
+            assert manifest["wire"] == "f32" and manifest["version"] == 8
+            leaves = {}
+            for name in manifest["fragments"]:
+                raw = get(f"frag_{name}")
+                assert hashlib.sha256(raw).hexdigest() == manifest["digests"][name]
+                leaves.update(
+                    {int(k): v for k, v in ser.deserialize(raw).items()}
+                )
+            assert_state_equal(frags.assemble(manifest, leaves), state)
         finally:
             t.shutdown()
 
@@ -865,6 +1127,9 @@ class TestHealOpened:
         assert sources, "no survivor staged a heal"
         for ph in sources:
             assert set(send_parts) <= set(ph), sorted(ph)
+            # the bytes a source copied beyond its one write, a counter
+            # among the parts (as ``heal_diff.hidden`` is): none
+            assert ph["heal_send.copied"] == 0
             opened = sum(ph[p] for p in send_parts)
             assert 0 < opened <= ph["heal_send"]
             assert ph.get("heal_apply", 0.0) == 0.0
@@ -934,7 +1199,10 @@ class TestHealOpened:
         assert recv["attributes"]["step"] >= 2  # the kill was at step 2
         # source-side parts are children of a heal_send span, per fragment
         send_ids = {s["span_id"] for s in by["heal_send"]}
+        assert "heal_send.copied" not in by  # a counter: bytes, no span
         for name in send_parts:
+            if name == "heal_send.copied":
+                continue
             assert by[name], name
             assert {s["parent_span_id"] for s in by[name]} <= send_ids
             assert all("fragment" in s["attributes"] for s in by[name])

@@ -281,6 +281,19 @@ def get_lib() -> ctypes.CDLL:
         lib.tft_frag_stage.argtypes = [
             ctypes.c_int64, ctypes.c_int64, ctypes.c_char_p, _u8p, _i64,
         ]
+        # a buffer reserved, written by the caller, committed in place
+        # and released: addresses cross as plain integers (c_void_p)
+        lib.tft_frag_reserve.restype = ctypes.c_void_p
+        lib.tft_frag_reserve.argtypes = [
+            ctypes.c_int64, ctypes.c_int64, ctypes.c_char_p, _i64,
+        ]
+        lib.tft_frag_commit.restype = ctypes.c_int
+        lib.tft_frag_commit.argtypes = [
+            ctypes.c_int64, ctypes.c_int64, ctypes.c_char_p,
+            ctypes.c_void_p, _i64,
+        ]
+        lib.tft_frag_release.restype = ctypes.c_int
+        lib.tft_frag_release.argtypes = [ctypes.c_int64, ctypes.c_void_p]
         lib.tft_frag_finish.restype = ctypes.c_int
         lib.tft_frag_finish.argtypes = [ctypes.c_int64, ctypes.c_int64]
         lib.tft_frag_retire.restype = ctypes.c_int
@@ -308,6 +321,10 @@ def get_lib() -> ctypes.CDLL:
         lib.tft_frag_client_error.argtypes = []
         lib.tft_sha256_hex.restype = ctypes.c_int
         lib.tft_sha256_hex.argtypes = [_u8p, _i64, ctypes.c_char_p]
+        lib.tft_copy_transposed.restype = ctypes.c_int
+        lib.tft_copy_transposed.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, _i64, _i64, _i64, _i64,
+        ]
         _lib = lib
         return _lib
 

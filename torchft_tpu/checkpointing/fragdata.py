@@ -31,6 +31,7 @@ import http.client
 import json
 import threading
 import urllib.error
+import weakref
 from typing import Dict, Optional, Tuple
 from urllib.parse import urlparse
 
@@ -42,6 +43,7 @@ from torchft_tpu.utils.env import env_bool
 __all__ = [
     "FragDataServer",
     "available",
+    "copy_transposed",
     "enabled",
     "fetch_native",
     "native_sha256",
@@ -99,9 +101,11 @@ class FragDataServer:
     ``HTTPTransport`` drives it with the staging handoff contract:
     ``begin(step)`` opens a streaming version, ``stage()`` hands one raw
     payload down (the native side copies ONCE into a pooled registered
-    buffer and wakes parked long-pollers), ``finish(step)`` seals the
-    version, ``retire(step)`` drops it (non-blocking: buffers referenced
-    by in-flight serves are recycled on last deref)."""
+    buffer and wakes parked long-pollers; not at all where the payload
+    was written into a buffer ``reserve()`` lent, which is committed
+    where it lies), ``finish(step)`` seals the version, ``retire(step)``
+    drops it (non-blocking: buffers referenced by in-flight serves, or
+    still lent, are recycled on last deref)."""
 
     def __init__(self, bind_host: str = "") -> None:
         lib = _native_lib()
@@ -119,26 +123,61 @@ class FragDataServer:
     def begin(self, step: int) -> None:
         self._lib.tft_frag_begin(self._handle, int(step))
 
-    def stage(self, step: int, resource: str, value) -> bool:
-        """Mirror one raw wire-bytes payload; returns False when the
+    def reserve(
+        self, step: int, resource: str, nbytes: int
+    ) -> "Optional[np.ndarray]":
+        """Lend a pooled native buffer of ``nbytes`` for ``(step,
+        resource)`` as a writable ``uint8`` view (uninitialized; not
+        zero-filled), or ``None`` when the version is unknown here.  No
+        reader sees it until ``stage()`` is handed the view, whole, and
+        commits it where it lies.  The lend ends when the view and every
+        view of it are gone (the ``bufpool`` lease idiom): until then the
+        memory stays valid and unchanged whatever the version's fate, and
+        only then, once retired and with no serve in flight, does it
+        return to the native pool."""
+        if nbytes <= 0 or self._handle < 0:
+            return None
+        ptr = self._lib.tft_frag_reserve(
+            self._handle, int(step), resource.encode(), int(nbytes)
+        )
+        if not ptr:
+            return None
+        view = np.frombuffer(
+            (ctypes.c_uint8 * int(nbytes)).from_address(ptr), dtype=np.uint8
+        )
+        # numpy collapses a view's ``base`` to ``view`` itself (its own
+        # base is no array), so every slice or memoryview keeps it alive
+        weakref.finalize(
+            view, self._lib.tft_frag_release, self._handle, ptr
+        ).atexit = False
+        return view
+
+    def stage(self, step: int, resource: str, value) -> "Optional[int]":
+        """Mirror one raw wire-bytes payload; returns the bytes COPIED to
+        do so: 0 for a buffer ``reserve()`` lent for this fragment
+        (committed in place), its length otherwise, ``None`` when the
         version is unknown/retired (not mirrored — Python still owns
         serving it)."""
         mv = memoryview(value)
         if not mv.c_contiguous:
-            return False
+            return None
         arr = (
             np.frombuffer(mv, dtype=np.uint8)
             if mv.nbytes
             else np.empty(0, dtype=np.uint8)
         )
+        name = resource.encode()
+        # the native side knows its lends by address: anything else (and
+        # a lend made for another fragment, or not handed back whole) is
+        # refused there and takes the copy
+        if self._lib.tft_frag_commit(
+            self._handle, int(step), name, arr.ctypes.data, arr.nbytes
+        ) == 0:
+            return 0
         rc = self._lib.tft_frag_stage(
-            self._handle,
-            int(step),
-            resource.encode(),
-            _u8ptr(arr),
-            arr.nbytes,
+            self._handle, int(step), name, _u8ptr(arr), arr.nbytes
         )
-        return rc == 0
+        return arr.nbytes if rc == 0 else None
 
     def finish(self, step: int) -> None:
         self._lib.tft_frag_finish(self._handle, int(step))
@@ -281,6 +320,32 @@ def fetch_native(
         _drop_port(base)
         return None  # connection died mid-body: refetch via Python
     return buf, sha.value.decode(), float(fb.value)
+
+
+def copy_transposed(
+    dst: np.ndarray, arr: np.ndarray, matrix: int, row: int, rows: int
+) -> None:
+    """Rows ``row .. row + rows`` of matrix ``matrix`` of ``arr`` (shape
+    ``[..., R, C]``, memory holding each matrix column by column:
+    ``fragments._stored_swapped``) into ``dst`` in C order, by the native
+    tiled kernel with the interpreter's lock released.  ``dst``:
+    contiguous ``uint8`` of exactly ``rows * C * itemsize`` bytes."""
+    total, cols = arr.shape[-2:]
+    item = arr.itemsize
+    if not (
+        0 <= row and 0 < rows and row + rows <= total
+        and 0 <= matrix < arr.size // (total * cols)
+        and dst.dtype == np.uint8 and dst.flags.c_contiguous
+        and dst.nbytes == rows * cols * item and dst.flags.writeable
+    ):
+        raise ValueError("copy_transposed: rows or destination out of range")
+    rc = _native_lib().tft_copy_transposed(
+        dst.ctypes.data,
+        arr.ctypes.data + (matrix * total * cols + row) * item,
+        rows, cols, total, item,
+    )
+    if rc != 0:
+        raise ValueError(f"copy_transposed: no kernel for items of {item} B")
 
 
 def native_sha256(buf) -> "Optional[str]":

@@ -33,10 +33,12 @@ sha256 of exactly those bytes: any node can verify a fragment on receipt
 and re-serve it **verbatim** — zero decode passes — and replicas holding
 bitwise-identical state produce bitwise-identical fragments by
 construction, which is what makes cross-peer striped fetches safe.  A
-fragment may appear as ``bytes`` (encoder output), a bufpool-backed
-``uint8`` ndarray (fetch/relay passthrough), or a decoded
-``{slot: leaf}`` dict (tests/legacy); :func:`fragment_wire` normalizes
-the raw forms.
+fragment may appear as ``bytes`` (the serving publisher's encoder
+output), a ``uint8`` ndarray (bufpool-backed on fetch/relay passthrough;
+on a heal source the buffer the fragment was written into once and is
+served from, lent by the native data server where there is one), or a
+decoded ``{slot: leaf}`` dict (tests/legacy); :func:`fragment_wire`
+normalizes the raw forms.
 
 The fetch plane (persistent per-``(thread, netloc)`` HTTP/1.1
 connections, bufpool ``readinto`` receive, 503-poll retry, WAN
@@ -58,7 +60,6 @@ import http.client
 import io
 import threading
 import time
-import types
 import urllib.error
 from collections import deque
 from concurrent.futures import Future, ThreadPoolExecutor
@@ -404,21 +405,143 @@ def heal_fragment_names(num_leaves: int, fragments: int) -> "List[str]":
     return [str(i) for i in range(min(max(fragments, 1), max(num_leaves, 1)))]
 
 
+def _snapshot(leaves: "List[Any]", slots: "List[int]") -> "Dict[str, Any]":
+    """One fragment's leaves as the writer takes them, ``{str(slot):
+    leaf}``: the device leaves' one copy to the host.  A host array is laid
+    out as the device held the leaf (:func:`_stored_swapped`)."""
+    import jax
+
+    return {
+        str(slot): (
+            np.asarray(leaves[slot])
+            if isinstance(leaves[slot], jax.Array)
+            else leaves[slot]
+        )
+        for slot in slots
+    }
+
+
+def _stored_swapped(arr: np.ndarray) -> "Optional[Tuple[int, int, int]]":
+    """``(matrices, rows, cols)`` for an array of shape ``[..., rows,
+    cols]`` whose memory holds each ``[rows, cols]`` matrix column by
+    column, one matrix after another: what a TPU hands the host for a leaf
+    whose last dimension is no multiple of 128 (it tiles such a leaf by the
+    dimension before and ``np.asarray`` keeps the device's order, as
+    strides).  ``None`` for any other order."""
+    if arr.ndim < 2 or arr.size == 0:
+        return None
+    item = arr.itemsize
+    rows, cols = arr.shape[-2:]
+    lead, step = [], rows * cols * item
+    for n in reversed(arr.shape[:-2]):
+        lead.append(step)
+        step *= n
+    if arr.strides != (*reversed(lead), item, rows * item):
+        return None
+    return arr.size // (rows * cols), rows, cols
+
+
+#: The sink's block: a leaf is copied into the serving buffer and hashed
+#: this many bytes at a time, so the digest reads what the copy has just
+#: written while it is still in cache — one pass over memory for both.
+_SINK_BLOCK = 4 << 20
+
+
+class _HashedWrite:
+    """Sink of ``serialization.prepare``'s writer that feeds ``sha`` the
+    stream's bytes and, given ``buf``, lands them there at the running
+    offset: the same bytes, block by block, so copy and hash are one pass
+    over memory.  Without ``buf`` nothing is kept (a healer's digests of
+    its own state).  The copy, the update and the native re-ordering all
+    release the interpreter's lock for blocks of this size: a source
+    encodes beside the other groups' threads of its process.
+
+    ``write_array`` is the writer's hook for a leaf in any order of
+    memory.  One that lies as the device held it (:func:`_stored_swapped`)
+    is re-ordered by the native kernel, a block of rows at a time,
+    straight to where its bytes go (``buf``, or a scratch block when
+    nothing is kept) — where ``np.ascontiguousarray`` writes the whole
+    leaf a second time, into a temporary nobody has touched, at 0.5 GB/s
+    on a v5e's host."""
+
+    __slots__ = ("_sha", "_buf", "_off", "_scratch")
+
+    def __init__(self, sha: Any, buf: "Optional[np.ndarray]" = None) -> None:
+        self._sha, self._buf, self._off = sha, buf, 0
+        self._scratch: "Optional[np.ndarray]" = None
+
+    def _next(self, nbytes: int) -> np.ndarray:
+        """Where the stream's next ``nbytes`` (a block at most) land."""
+        if self._buf is not None:
+            self._off += nbytes
+            return self._buf[self._off - nbytes:self._off]
+        if self._scratch is None:
+            self._scratch = np.empty(_SINK_BLOCK, np.uint8)
+        return self._scratch[:nbytes]
+
+    def write(self, data: Any) -> None:
+        src = memoryview(data)
+        if not src.nbytes:
+            return  # an empty leaf: frombuffer refuses an empty buffer
+        if self._buf is None:
+            self._sha.update(src)
+            return
+        src = np.frombuffer(src, dtype=np.uint8)
+        for lo in range(0, src.size, _SINK_BLOCK):
+            block = src[lo:lo + _SINK_BLOCK]
+            dst = self._next(block.size)
+            np.copyto(dst, block)
+            self._sha.update(dst)
+
+    def write_array(self, arr: np.ndarray) -> None:
+        swapped = None if arr.flags.c_contiguous else _stored_swapped(arr)
+        if swapped is None or not _fragdata.available():
+            # as the writer hands a plain sink its leaves' bytes
+            self.write(np.ascontiguousarray(arr).reshape(-1).view(np.uint8))
+            return
+        matrices, rows, cols = swapped
+        row = cols * arr.itemsize
+        step = max(_SINK_BLOCK // row, 1)  # rows a block
+        for m in range(matrices):
+            for r0 in range(0, rows, step):
+                n = min(step, rows - r0)
+                dst = self._next(n * row)
+                _fragdata.copy_transposed(dst, arr, m, r0, n)
+                self._sha.update(dst)
+
+
 def iter_heal_fragments(
-    state_dict: Any, fragments: "Optional[int]" = None
-) -> "Tuple[Dict[str, Any], Iterator[Tuple[str, bytes, str]]]":
+    state_dict: Any,
+    fragments: "Optional[int]" = None,
+    reserve: "Optional[Callable[[str, int], np.ndarray]]" = None,
+) -> "Tuple[Dict[str, Any], Iterator[Tuple[str, np.ndarray, str]]]":
     """Split ``state_dict`` into heal fragments.
 
     Returns ``(header, iterator)`` where ``header`` is the digest-less
     manifest prefix (available BEFORE any encoding work) and the
-    iterator lazily yields ``(name, wire_bytes, sha256)`` — each
-    ``next()`` performs that fragment's host snapshot + serialize +
-    hash, which is what lets the streamed staging overlap a healer's
-    fetch of fragment *i* with the encode of fragment *i+1*.  Each of
-    the three is timed as a part (``.snapshot``, ``.encode``, ``.hash``)
-    of whatever phase the consumer has open: ``heal_send`` on a source.
-    (A healer wants its own state's digests and no bytes:
-    :func:`local_fragment_digests`.)
+    iterator lazily yields ``(name, wire, sha256)`` — each ``next()``
+    performs that fragment's host snapshot and its ONE write, which is
+    what lets the streamed staging overlap a healer's fetch of fragment
+    *i* with the encode of fragment *i+1*.
+
+    ``wire`` is a 1-d ``uint8`` array holding the fragment's serialized
+    stream, byte for byte ``serialization.serialize(fragment)``, and the
+    digest is the sha256 of exactly those bytes.  They are written once:
+    ``serialization.prepare`` sizes the stream, ``reserve(name, nbytes)``
+    hands out the buffer they will be SERVED from (a source passes its
+    transport's ``reserve_streamed_part``; by default a fresh array, which
+    is what the durable store writes to disk), and the writer's sink
+    copies each leaf straight into it and hashes the bytes as they land
+    — no ``BytesIO``, no ``bytes``, no second copy at staging.  The
+    buffer is the consumer's from the yield on.
+
+    Each step is timed as a part of whatever phase the consumer has open
+    (``heal_send`` on a source): ``.snapshot``; ``.encode``, the one
+    pass (sizing, copy and hash; attrs ``bytes``, ``in_place=1``; one
+    span a fragment, its seconds the two stretches around the
+    reservation); ``.hash``, what hashing is left outside that pass (the
+    digest's finalisation).  (A healer wants its own state's digests and
+    no bytes: :func:`local_fragment_digests`.)
 
     Heal fragments are always ``f32`` wire (bitwise — a healed replica
     must converge exactly), leaf slots split round-robin like
@@ -440,26 +563,29 @@ def iter_heal_fragments(
         "num_leaves": len(leaves),
     }
 
-    def gen() -> "Iterator[Tuple[str, bytes, str]]":
+    def gen() -> "Iterator[Tuple[str, np.ndarray, str]]":
         for name in names:
             slots = fragment_slots(name, len(leaves), len(names))
             with _tracing.phase(".snapshot", fragment=name):
-                # the device leaves' one copy to the host: serialize then
-                # finds numpy arrays and takes views of them
-                frag = {
-                    str(slot): (
-                        np.asarray(leaves[slot])
-                        if isinstance(leaves[slot], jax.Array)
-                        else leaves[slot]
-                    )
-                    for slot in slots
-                }
-            with _tracing.phase(".encode", fragment=name):
-                raw = ser.serialize(frag)
-            del frag
-            with _tracing.phase(".hash", fragment=name, bytes=len(raw)):
-                digest = hashlib.sha256(raw).hexdigest()
-            yield name, raw, digest
+                frag = _snapshot(leaves, slots)
+            sha = hashlib.sha256()
+            p_encode = _tracing.phase(".encode", fragment=name, in_place=1)
+            with p_encode.lap():
+                total, writer = ser.prepare(frag)
+                p_encode.attrs["bytes"] = total
+            # between the stretches: the consumer times its reservation
+            wire = (
+                reserve(name, total)
+                if reserve is not None
+                else np.empty(total, np.uint8)
+            )
+            with p_encode.lap():
+                writer(_HashedWrite(sha, wire))
+            p_encode.end()
+            del frag, writer
+            with _tracing.phase(".hash", fragment=name, bytes=total):
+                digest = sha.hexdigest()
+            yield name, wire, digest
 
     return header, gen()
 
@@ -480,18 +606,36 @@ def stage_heal_checkpoint(
     after it lands), and the full manifest (with every digest) lands
     LAST, which is also what flips the slot complete.  Returns the
     manifest so the source can keep its own digests for delta
-    bookkeeping."""
-    header, frag_iter = iter_heal_fragments(state_dict, fragments)
+    bookkeeping.
+
+    Each fragment's bytes are written once, into the buffer the
+    transport will serve them from (``reserve_streamed_part``: invisible
+    to readers until staged, whole), and staging publishes that buffer
+    where it lies; the slot owns it from then on.  ``.stage`` times the
+    reservation and the publish.  What the transport copied beyond that
+    one write (its data plane could not lend a buffer, or not publish it
+    in place) is counted in bytes as ``heal_send.copied``, beside the
+    parts' seconds in the open phase's sink: it reads 0 when the
+    mechanism is engaged."""
+
+    def reserve(name: str, nbytes: int) -> np.ndarray:
+        with _tracing.phase(".stage", fragment=name, bytes=nbytes, reserve=1):
+            return transport.reserve_streamed_part(
+                step, f"frag:{name}", nbytes
+            )
+
+    header, frag_iter = iter_heal_fragments(state_dict, fragments, reserve)
     header = dict(header, version=int(step))
     transport.begin_streamed_checkpoint(
         step, {f"frag:{HEADER_FRAG}": header}, timeout=timeout
     )
     digests: "Dict[str, str]" = {}
+    copied = 0
     try:
         for name, raw, digest in frag_iter:
-            with _tracing.phase(".stage", fragment=name, bytes=len(raw)):
-                transport.stage_streamed_part(
-                    step, f"frag:{name}", raw, timeout=timeout
+            with _tracing.phase(".stage", fragment=name, bytes=raw.nbytes):
+                copied += transport.stage_streamed_part(
+                    step, f"frag:{name}", raw, pooled=True, timeout=timeout
                 )
             digests[name] = digest
     except BaseException:
@@ -502,6 +646,9 @@ def stage_heal_checkpoint(
         except Exception:  # noqa: BLE001 - teardown best-effort
             pass
         raise
+    whole = _tracing.open_phase()
+    if whole is not None and whole.sink is not None:
+        _tracing.add_seconds(whole.sink, "heal_send.copied", copied)
     manifest = dict(header, digests=digests, created_ns=time.time_ns())
     transport.stage_streamed_part(
         step, f"frag:{MANIFEST_FRAG}", manifest, timeout=timeout
@@ -530,9 +677,11 @@ def local_fragment_digests(
     Per fragment: the same host snapshot of the device leaves as
     :func:`iter_heal_fragments` takes, then ``serialization.prepare``'s
     writer streams the 8-byte length, the pickled header and the leaves'
-    buffers, in wire order, into ``sha256.update``: byte for byte the
-    digest of ``sha256(ser.serialize(frag))``, with nothing allocated
-    beyond the snapshot.  Each fragment is timed as the parts
+    buffers, in wire order, into ``sha256.update`` (the source's sink with
+    nothing to land the bytes in): byte for byte the digest of
+    ``sha256(ser.serialize(frag))``, with nothing allocated beyond the
+    snapshot and one scratch block, through which a leaf that lies as the
+    device held it is re-ordered on its way into the digest.  Each fragment is timed as the parts
     ``.snapshot`` and ``.hash`` of whatever phase the caller has open
     (``heal_diff``).  One fragment after another on the caller's thread,
     though both parts release the interpreter's lock: a healer runs this
@@ -546,19 +695,13 @@ def local_fragment_digests(
     digests: "Dict[str, str]" = {}
     for name in names:
         with _tracing.phase(".snapshot", fragment=name):
-            frag = {
-                str(slot): (
-                    np.asarray(leaves[slot])
-                    if isinstance(leaves[slot], jax.Array)
-                    else leaves[slot]
-                )
-                for slot in fragment_slots(name, len(leaves), len(names))
-            }
+            frag = _snapshot(
+                leaves, fragment_slots(name, len(leaves), len(names))
+            )
         sha = hashlib.sha256()
         with _tracing.phase(".hash", fragment=name) as p_hash:
             p_hash.attrs["bytes"], writer = ser.prepare(frag)
-            # all the writer asks of its sink is ``write``
-            writer(types.SimpleNamespace(write=sha.update))
+            writer(_HashedWrite(sha))  # nothing kept: the digest alone
         digests[name] = sha.hexdigest()
     return len(leaves), digests
 

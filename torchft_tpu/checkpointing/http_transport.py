@@ -74,8 +74,10 @@ class _Staged:
     resource is a retryable 503 (the child/client polls until the relay
     stages it — that IS the cut-through overlap) and whole-document
     resources (``full``/``metadata``/``chunk_*``) 503 too: a torn
-    version must never serve.  ``pooled`` tracks bufpool-backed buffers
-    this slot owns; they return to the pool when the slot is retired.
+    version must never serve.  ``pooled`` tracks the buffers this slot
+    owns; on retirement a bufpool-backed one returns to the pool, and a
+    view lent by the native data server (which the pool declines: it owns
+    no memory) is let go of, to be recycled there.
 
     ``grace``: streamed HEAL slots hold serialized BYTES — immutable
     copies, unlike the legacy host-array snapshot that aliases the live
@@ -599,21 +601,24 @@ class HTTPTransport(CheckpointTransport[Any]):
             except Exception:
                 logger.debug("native frag begin failed", exc_info=True)
 
-    def _native_stage(self, step: int, key: Any, value: Any) -> None:
+    def _native_stage(self, step: int, key: Any, value: Any) -> int:
+        """Mirror one raw part; returns the bytes that took a copy (0 for
+        a buffer the native server lent, published where it lies)."""
         srv = self._frag_native
         if (
             srv is None
             or not isinstance(key, str)
             or not key.startswith("frag:")
         ):
-            return
+            return 0
         raw = ser.raw_view(value)
         if raw is None:
-            return  # control parts (header/manifest dicts) stay Python
+            return 0  # control parts (header/manifest dicts) stay Python
         try:
-            srv.stage(step, "frag_" + key[len("frag:"):], raw)
+            return srv.stage(step, "frag_" + key[len("frag:"):], raw) or 0
         except Exception:
             logger.debug("native frag stage failed", exc_info=True)
+            return 0
 
     def _native_finish(self, step: int) -> None:
         if self._frag_native is not None:
@@ -695,6 +700,35 @@ class HTTPTransport(CheckpointTransport[Any]):
             self._native_stage(step, k, v)
         self._wake_stream_waiters()
 
+    def reserve_streamed_part(
+        self, step: int, key: str, nbytes: int
+    ) -> Any:
+        """The buffer part ``key`` of streaming slot ``step`` will be
+        SERVED from, handed out before its bytes exist: an uninitialized
+        1-d ``uint8`` array of ``nbytes`` for the caller to write whole
+        and pass to :meth:`stage_streamed_part` with ``pooled=True``,
+        which then publishes it without a copy.  No reader sees it before
+        that.  With the native data plane up it is a view of one of the
+        native server's pooled buffers, lent until the last view of it is
+        gone (``FragDataServer.reserve``); otherwise, or when the native
+        server cannot lend one, a ``bufpool`` buffer.  One that is never
+        staged (a torn encode) simply falls to the garbage collector."""
+        import numpy as np
+
+        from torchft_tpu.utils.bufpool import POOL
+
+        if self._frag_native is not None and key.startswith("frag:"):
+            try:
+                buf = self._frag_native.reserve(
+                    step, "frag_" + key[len("frag:"):], nbytes
+                )
+            except Exception:
+                logger.debug("native frag reserve failed", exc_info=True)
+                buf = None
+            if buf is not None:
+                return buf
+        return POOL.take(nbytes, np.uint8)
+
     def stage_streamed_part(
         self,
         step: int,
@@ -702,11 +736,16 @@ class HTTPTransport(CheckpointTransport[Any]):
         value: Any,
         pooled: bool = False,
         timeout: "Optional[float]" = None,
-    ) -> None:
+    ) -> int:
         """Add one part (``frag:<name>`` -> raw wire bytes) to a
-        streaming slot.  ``pooled=True`` transfers ownership of a
-        bufpool-backed buffer to the slot (returned to the pool on
-        retirement).  Raises ``KeyError`` when the slot was evicted
+        streaming slot.  ``pooled=True`` transfers ownership of the
+        buffer to the slot: a bufpool-backed one returns to the pool on
+        retirement; a view the native server lent
+        (:meth:`reserve_streamed_part`) is dropped there, which ends the
+        lend once no serve of the Python plane still reads it.  The slot
+        serves ``value`` itself, and so does the native mirror when it
+        lent the buffer; any other raw part it copies once.  Returns the
+        bytes so copied.  Raises ``KeyError`` when the slot was evicted
         mid-stream (version window overrun by newer publishes)."""
         with self._staged_lock.w_lock(timeout=timeout or self._lock_timeout):
             staged = self._staged.get(step)
@@ -717,8 +756,9 @@ class HTTPTransport(CheckpointTransport[Any]):
             staged.sd[key] = value
             if pooled:
                 staged.pooled.append(value)
-        self._native_stage(step, key, value)
+        copied = self._native_stage(step, key, value)
         self._wake_stream_waiters()
+        return copied
 
     def finish_streamed_checkpoint(
         self, step: int, timeout: "Optional[float]" = None
@@ -1350,6 +1390,11 @@ class HTTPTransport(CheckpointTransport[Any]):
             return list(self._staged)
 
     def shutdown(self, wait: bool = True) -> None:
+        # The slots go first: a fragment staged in place is a view of the
+        # native server's memory, lent until the last view is dropped.
+        # Dropped, not released: a serve still in flight keeps its own
+        # reference, and with it the memory, until it ends.
+        self._staged = {}
         if self._frag_native is not None:
             try:
                 self._frag_native.shutdown()

@@ -34,13 +34,7 @@ def _flatten(state_dict: Any) -> Tuple[Any, List[Any]]:
 def _leaf_meta(leaf: Any) -> Tuple[Dict[str, Any], Optional[np.ndarray]]:
     if isinstance(leaf, (np.ndarray, jax.Array)) or np.isscalar(leaf) is False and hasattr(leaf, "__array__"):
         arr = np.asarray(leaf)
-        # Record shape BEFORE ascontiguousarray: it promotes 0-d to (1,),
-        # which would corrupt pytree leaf shapes on the receiving side.
-        shape = arr.shape
-        return (
-            {"kind": "array", "shape": shape, "dtype": str(arr.dtype)},
-            np.ascontiguousarray(arr),
-        )
+        return {"kind": "array", "shape": arr.shape, "dtype": str(arr.dtype)}, arr
     return {"kind": "object", "value": leaf}, None
 
 
@@ -51,7 +45,10 @@ def prepare(
 
     Returns ``(total_bytes, writer)`` where ``writer(out)`` streams the
     payload without materializing it (buffers are written directly) — the
-    zero-copy path for serving multi-GB checkpoints.
+    zero-copy path for serving multi-GB checkpoints.  All ``writer`` asks
+    of ``out`` is ``write``; an ``out`` with ``write_array`` gets each
+    array leaf as it is, in whatever order its memory is in, in place of
+    its bytes (``fragments._HashedWrite``).
 
     ``chunk_indices`` restricts to a subset of leaf slots (for round-robin
     chunked transport, reference http_transport.py:288-299); the skeleton is
@@ -74,12 +71,22 @@ def prepare(
     def writer(out: BinaryIO) -> None:
         out.write(_HEADER.pack(len(header)))
         out.write(header)
+        # a sink that lays a leaf out itself, from whatever order its
+        # memory is in, is handed the array (``write_array``); any other
+        # gets the leaf's bytes in C order, through a copy where they are
+        # not (the shape went into the header above: ascontiguousarray
+        # promotes 0-d to (1,))
+        put = getattr(out, "write_array", None)
         for buf in buffers:
-            if buf is not None:
+            if buf is None:
+                continue
+            if put is not None:
+                put(buf)
+            else:
                 # uint8 view, not memoryview.cast: ml_dtypes (bfloat16, fp8 —
                 # the TPU training dtypes) have no buffer-protocol format
                 # char and would raise in cast("B").
-                out.write(buf.reshape(-1).view(np.uint8))
+                out.write(np.ascontiguousarray(buf).reshape(-1).view(np.uint8))
 
     return total, writer
 

@@ -89,7 +89,7 @@ def _fsync_dir(path: str) -> None:
         os.close(fd)
 
 
-def _atomic_write(path: str, data: bytes) -> None:
+def _atomic_write(path: str, data: "bytes | memoryview") -> None:
     """tmp + flush + fsync + ``os.replace`` — a reader never observes a
     half-written file under the final name."""
     tmp = f"{path}.tmp{os.getpid()}.{threading.get_ident()}"
@@ -162,9 +162,9 @@ class FragmentStore:
         path = self.blob_path(digest)
         if os.path.exists(path):
             return 0
-        data = bytes(memoryview(raw))
+        data = memoryview(raw)  # bytes, or the encoder's uint8 buffer
         _atomic_write(path, data)
-        return len(data)
+        return data.nbytes
 
     def read_blob(self, digest: str) -> Optional[bytes]:
         """Read one blob, verifying its bytes still hash to the digest

@@ -116,17 +116,19 @@ PHASE_PARTS = (
     "ring.wire.send",  # handing the send to the sender thread, then what is left of it once the receive has returned
     "ring.reduce",  # the in-place ufunc between exchanges
     "ring.unpack",  # the division in place, cast back, split, unflatten
-    # fragments.iter_heal_fragments, per fragment, on the source
+    # fragments.iter_heal_fragments / stage_heal_checkpoint, per fragment,
+    # on the source: each fragment's wire bytes are written ONCE
     "heal_send.snapshot",  # device leaves to host numpy
-    "heal_send.encode",  # serialization.serialize
-    "heal_send.hash",  # sha256
-    "heal_send.stage",  # stage_streamed_part, the native mirror included
+    "heal_send.encode",  # the one pass: serialization.prepare, then its writer copies each leaf into the buffer the fragment is served from and feeds sha256 the same bytes, block by block
+    "heal_send.hash",  # what hashing is left outside that pass: the digest's finalisation
+    "heal_send.stage",  # reserving that buffer (the native server's, lent; a bufpool one without it) and publishing it where it lies
+    "heal_send.copied",  # no span: BYTES the transport copied beyond the one write; 0 when every fragment was staged in place
     # fragments.local_fragment_digests, per fragment, on the transport's
     # digest thread: begun while the healer waits for the manifest, under a
     # heal_diff that opens when the manifest is in (so the parts may
     # outweigh their whole)
     "heal_diff.snapshot",
-    "heal_diff.hash",  # serialization.prepare's writer into sha256.update: no bytes built
+    "heal_diff.hash",  # serialization.prepare's writer into the source's sink with nothing kept: no bytes built
     "heal_diff.hidden",  # no span: of those two, the seconds ended when the manifest came
     # inside fetch_raw: long-poll until the source staged the manifest
     "heal_manifest.wait",
@@ -1677,8 +1679,12 @@ class Manager:
         quorum round trip), ``pg_configure`` (collective reconfigure on
         quorum change), ``heal_send`` (staging a live checkpoint for a
         recovering peer; per fragment ``heal_send.snapshot`` device→host,
-        ``.encode`` serialize, ``.hash`` sha256, ``.stage`` hand-over to
-        the transport), ``heal_manifest`` (fetch of the primary's manifest;
+        ``.encode`` the one write of its wire bytes into the buffer that
+        serves them, hashed as they land, ``.hash`` the digest's
+        finalisation, ``.stage`` reserving and publishing that buffer;
+        ``heal_send.copied`` is no seconds but the bytes copied beyond
+        that write, 0 when every fragment was staged in place),
+        ``heal_manifest`` (fetch of the primary's manifest;
         ``heal_manifest.wait`` is the long-poll inside it while the source
         is still encoding), ``heal_diff`` (what the healer's digests of its
         own state, in the source's layout, still cost once the manifest is
