@@ -397,10 +397,15 @@ class TestPhase:
     def test_a_part_has_parts_the_same_way(self, span_file):
         """ISSUE 38, ``ring.wire`` as the PG worker opens it: inside
         ``.wire`` a plain ``.arrive`` once and lapped ``.wait`` / ``.recv``
-        over the exchanges, the reduce between them excluded from the
-        wire and from all of them.  The names go through the dot rule, the
-        spans hang off the wire's, the seconds land in ring's sink and add
-        up to the wire."""
+        over the messages.  Since ISSUE 46 ``.reduce`` is only the
+        stretches in which the wire stood still for a reduce (here one
+        after each message: the receiver held back for ``scratch``); they
+        are excluded from the wire and from all its parts, so the parts
+        still add up to the wire and the wire and the reduce to no more
+        than the ring.  What the reducer did under the wire is seconds in
+        the same sink, ``ring.reduce.hidden``, and no span.  The names go
+        through the dot rule, the spans hang off the wire's, the seconds
+        land in ring's sink."""
         path, _ = span_file
         sink, seen = {}, []
         with tracing.phase("ring", sink, step=7, observe=lambda n, s: seen.append(n)):
@@ -418,12 +423,14 @@ class TestPhase:
                         time.sleep(0.002)
                     with reduce.lap():
                         time.sleep(0.003)
+                tracing.add_seconds(reduce.sink, "ring.reduce.hidden", 0.5)
                 wire.exclude(reduce.end())
             assert wait.end() == sink["ring.wire.wait"]
             assert recv.end() == sink["ring.wire.recv"]
             assert tracing.phase(".send").end() == 0.0  # no lap: no record
         tracing.uninstall_tracer()
         by = {s["name"]: s for s in _spans(path)}
+        assert sink.pop("ring.reduce.hidden") == 0.5  # seconds, no span
         assert set(by) == set(sink) == {
             "ring", "ring.reduce", "ring.wire", "ring.wire.arrive",
             "ring.wire.wait", "ring.wire.recv",

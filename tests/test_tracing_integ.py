@@ -233,7 +233,10 @@ class TestRingOpened:
     a two-group ``ProcessGroupTCP`` step at a size where ``ring`` takes
     tens of milliseconds."""
 
-    N = 2_000_000  # 8 MB of float32 in one solo bucket + a coalesced one
+    # 36 MB of float32 in one solo bucket (a chunk of 18 MB: two slices
+    # since ISSUE 46) + a coalesced one; large enough that what a loaded
+    # host adds between the parts, some 7 ms a run, stays under a tenth
+    N = 9_000_000
 
     def _replica(self, replica_id: int, addr: str, steps: int) -> dict:
         params = {
@@ -280,6 +283,9 @@ class TestRingOpened:
             results = [f.result(timeout=120) for f in futs]
         tracing.uninstall_tracer()
         named = {p for p in PHASE_PARTS if p.startswith("ring.")}
+        # seconds beside the parts and no stretch of ring's wall (ISSUE 46):
+        # there when a chunk moved in slices, and never a span
+        named.remove("ring.reduce.hidden")
         # ring's own parts, and the parts of one of them (ISSUE 38)
         wire_parts = {p for p in named if p.startswith("ring.wire.")}
         ring_parts = named - wire_parts
@@ -303,6 +309,7 @@ class TestRingOpened:
             assert 0.5 * phases["ring.wire"] <= inside <= phases["ring.wire"]
         # in the trace every part is a child of a ring span and lies in it
         spans = _load_spans(trace_file)
+        assert not [s for s in spans if s["name"] == "ring.reduce.hidden"]
         by_id = {s["span_id"]: s for s in spans}
         parts = [s for s in spans if s["name"] in ring_parts]
         assert {s["name"] for s in parts} == ring_parts

@@ -109,13 +109,14 @@ PHASE_PARTS = (
     "ring.queue",  # submit until the worker picks the op up
     "ring.d2h",  # held by the device→host leg: the relayout's dispatch, then a bucket's starts and its wait
     "ring.pack",  # bucket concat, pad-in of a widened leaf or a tail, copy of a host leaf
-    "ring.wire",  # the wall of a bucket's 2(w-1) exchanges less the reduces between them; its four parts:
-    "ring.wire.arrive",  # first exchange of the op: until the previous rank's first byte, i.e. until it reached the ring
-    "ring.wire.wait",  # every later exchange: until the peer's next message starts (it is in the ring, late with a chunk)
-    "ring.wire.recv",  # a message's header and payload coming in
-    "ring.wire.send",  # handing the send to the sender thread, then what is left of it once the receive has returned
-    "ring.reduce",  # the in-place ufunc between exchanges
-    "ring.unpack",  # the division in place, cast back, split, unflatten
+    "ring.wire",  # the wall of a bucket's two streams of 2(w-1) messages, the first header sought to the last byte in and out, less what stood still for a reduce (ring.reduce); its four parts, the receiving role's:
+    "ring.wire.arrive",  # first message of the op: until the previous rank's first byte, i.e. until it reached the ring
+    "ring.wire.wait",  # every later message: until it starts (the peer is in the ring, late with a chunk): a stall between two messages
+    "ring.wire.recv",  # a message's header and payload coming in, slice by slice; a stall between two slices of a message is here
+    "ring.wire.send",  # handing the stream to the sender thread, then what is left of it once the last byte is in
+    "ring.reduce",  # the seconds the wire stood still for a reduce or a division: a one-slice chunk's between two messages, the receiver held back for scratch, the wait for the reducer once the last byte is in; attrs slices (a message) and hidden (slices reduced under the wire)
+    "ring.reduce.hidden",  # no span: the seconds of reduce and division that ran on the reducer thread while the next slice was coming in
+    "ring.unpack",  # cast back of a widened leaf, split, unflatten (the division is the reduce's: each rank divides its own chunk before the allgather)
     # fragments.iter_heal_fragments / stage_heal_checkpoint, per fragment,
     # on the source: each fragment's wire bytes are written ONCE
     "heal_send.snapshot",  # device leaves to host numpy

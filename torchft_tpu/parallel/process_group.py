@@ -200,6 +200,14 @@ def _divide(a: np.ndarray, divisor: "Optional[int]") -> np.ndarray:
     return np.asarray(a / divisor, dtype=a.dtype)
 
 
+def _divide_in_place(a: np.ndarray, divisor: "Optional[int]") -> None:
+    """:func:`_divide` of a slice of a buffer its caller owns, whatever the
+    dtype: what an integer's division made beside it is written back."""
+    divided = _divide(a, divisor)
+    if divided is not a:
+        a[...] = divided
+
+
 def _allreduce_alone(
     arrays: "List[Any]", divisor: "Optional[int]", kept_leaves: Any
 ) -> "List[Any]":
@@ -531,6 +539,155 @@ class _PGAborted(RuntimeError):
     pass
 
 
+class _RingStream:
+    """What the three roles of one bucket's ring share (:meth:`ProcessGroupTCP.
+    _allreduce_one`): the slices of the previous rank's stream, in its
+    order, message after message, handed from role to role as tokens.
+
+    The receiver (the PG worker) says a slice has :meth:`land`-ed; the
+    reducer takes the reduce-scatter's slices in turn (:meth:`reduce_all`, on
+    a thread of its own where a message has more than one; the receiver
+    itself, there and then, where it has one: the whole-chunk ring, which
+    hands nothing over); the sender (:meth:`gate`) pushes slice ``k`` of its
+    next message once slice ``k`` of the last one in is reduced or, in the
+    allgather, has landed.  A message of the reduce-scatter lands in
+    ``scratch``, a chunk long, so the receiver will :meth:`hold` back a slice
+    whose place is not reduced yet: the one place where the wire waits for a
+    host pass, entered as ``stalled`` (``ring.reduce``) like the one-slice
+    reduce and the worker's wait for the reducer at the end
+    (:meth:`drain`).  What the reducer did while the next slice was still
+    coming in is ``hidden``.
+
+    A hand-off is one token in a queue and wakes the one role that waits
+    for it.  The first error of any role is the ring's; every wait ends
+    with it, or at the deadline."""
+
+    def __init__(
+        self,
+        slices: int,
+        slice_bytes: int,
+        messages: int,
+        reduce_slice: "Callable[[int], None]",
+        stalled: Any,
+        deadline: float,
+    ) -> None:
+        self.slices = slices  # of one message
+        self.slice_bytes = slice_bytes
+        self.reduces = messages // 2 * slices  # the reduce-scatter's slices
+        self.forwards = (messages - 1) * slices  # all but the last message's
+        self.deadline = deadline
+        self.landed = 0  # the receiver's count
+        self.reduced = 0  # the reducer's
+        self.hidden = 0  # of those, reduced before the next one was in
+        self.hidden_s = 0.0
+        self.error: "Optional[BaseException]" = None
+        self._reduce_slice = reduce_slice
+        self._stalled = stalled
+        self._lock = _lockcheck.lock("pg.tcp.ring_stream")
+        # receiver -> reducer: a slice of the reduce-scatter is in
+        self._to_reduce: "queue.SimpleQueue[bool]" = queue.SimpleQueue()
+        # reducer -> receiver: a slice of ``scratch`` may be overwritten
+        self._free: "queue.SimpleQueue[bool]" = queue.SimpleQueue()
+        # -> sender: a slice may go on, reduced (from the reducer) or, in
+        # the allgather, as it landed (from the receiver)
+        self._final = {
+            "reduced": queue.SimpleQueue(),
+            "landed": queue.SimpleQueue(),
+        }
+
+    def fail(self, exc: BaseException) -> BaseException:
+        """``exc`` ends the ring unless something has already; returns
+        what did."""
+        with self._lock:
+            if self.error is None:
+                self.error = exc
+        for q in (self._to_reduce, self._free, *self._final.values()):
+            q.put(False)
+        return self.error
+
+    def _take(self, q: "queue.SimpleQueue[bool]") -> None:
+        try:
+            token = q.get(timeout=max(self.deadline - time.monotonic(), 0.001))
+        except queue.Empty:
+            raise TimeoutError("ring slice not ready by the deadline") from None
+        if not token:
+            raise _PGAborted("ring failed in another role")
+
+    def _reduce_next(self, under_the_wire: bool) -> None:
+        g = self.reduced
+        t0 = time.perf_counter()
+        self._reduce_slice(g)
+        seconds = time.perf_counter() - t0
+        self.reduced = g + 1
+        # the wire was not kept waiting: the next slice was still coming
+        if under_the_wire and self.landed <= g + 1:
+            self.hidden += 1
+            self.hidden_s += seconds
+        self._final["reduced"].put(True)
+
+    # -- the receiver
+    def hold(self) -> None:
+        """Before a slice is read: it may not overwrite a slice of
+        ``scratch`` that is not reduced."""
+        if self.error is not None:
+            raise _PGAborted("ring failed in another role")
+        if 1 < self.slices <= self.landed < self.reduces:
+            # (booked only where the token is not there yet)
+            with self._stalled if self._free.empty() else _UNTIMED:
+                self._take(self._free)
+
+    def land(self) -> None:
+        self.landed = g = self.landed + 1
+        if g > self.reduces:
+            if g <= self.forwards:
+                self._final["landed"].put(True)
+        elif self.slices > 1:
+            self._to_reduce.put(True)
+        else:
+            with self._stalled:
+                self._reduce_next(under_the_wire=False)
+
+    def drain(self, reducer: "Optional[Future]") -> None:
+        """After the last byte is in: what is left to reduce."""
+        if reducer is not None:
+            with self._stalled:
+                reducer.result(
+                    timeout=max(self.deadline - time.monotonic(), 0.001) + 1.0
+                )
+
+    # -- the reducer
+    def reduce_all(self) -> None:
+        try:
+            for _ in range(self.reduces):
+                self._take(self._to_reduce)
+                self._reduce_next(under_the_wire=True)
+                self._free.put(True)
+        except BaseException as e:  # noqa: BLE001 - the ring's, raised by the worker
+            self.fail(e)
+            raise
+
+    # -- the sender
+    def gate(self, message: int) -> "Optional[Callable[[int], None]]":
+        """What outgoing ``message`` waits for before its first ``end``
+        bytes go: the slices of the message that came in before it,
+        reduced (reduce-scatter) or landed (allgather).  The first goes at
+        once, from the source."""
+        if message == 0:
+            return None
+        q = self._final[
+            "reduced" if (message - 1) * self.slices < self.reduces else "landed"
+        ]
+        taken = 0
+
+        def ready(end: int) -> None:
+            nonlocal taken
+            while taken * self.slice_bytes < end:
+                self._take(q)
+                taken += 1
+
+        return ready
+
+
 class NotParticipatingError(RuntimeError):
     """Raised by ``ManagedProcessGroup.rank()`` when the replica has no rank
     in the current quorum (it is healing or excluded).  Contrast with the
@@ -621,6 +778,7 @@ class ProcessGroupTCP(ProcessGroup):
         self._lock = _lockcheck.lock("pg.tcp.state")
         self._worker: Optional[threading.Thread] = None
         self._sender: "Optional[concurrent_futures.ThreadPoolExecutor]" = None
+        self._reducer: "Optional[concurrent_futures.ThreadPoolExecutor]" = None
         self._queue: "queue.Queue[Optional[Tuple[int, Callable[[], Any], Future]]]" = (
             queue.Queue()
         )
@@ -628,9 +786,10 @@ class ProcessGroupTCP(ProcessGroup):
     def _bind_metrics(self) -> None:
         """``torchft_ring_buffers_total`` children by pool hit,
         ``torchft_ring_leaves_prefetched_total`` children by whether the
-        copy was ready, ``torchft_ring_peer_wait_seconds_total`` children by
-        which wait it was, and ``torchft_ring_leaves_kept_total``, under the
-        stable replica id."""
+        copy was ready, ``torchft_ring_slices_total`` children by whether
+        the reduce hid under the wire, ``torchft_ring_peer_wait_seconds_total``
+        children by which wait it was, and ``torchft_ring_leaves_kept_total``,
+        under the stable replica id."""
         self._metric_replica_id = _stable_replica_id(self._replica_id)
         self._m_leaves_kept = _metrics.RING_LEAVES_KEPT.labels(
             replica_id=self._metric_replica_id
@@ -648,6 +807,13 @@ class ProcessGroupTCP(ProcessGroup):
                 result="hit" if hit else "miss",
             )
             for hit in (True, False)
+        }
+        self._m_ring_slices = {
+            hidden: _metrics.RING_SLICES.labels(
+                replica_id=self._metric_replica_id,
+                hidden="1" if hidden else "0",
+            )
+            for hidden in (True, False)
         }
         self._m_peer_wait = {
             kind: _metrics.RING_PEER_WAIT.labels(
@@ -825,6 +991,10 @@ class ProcessGroupTCP(ProcessGroup):
             self._sender = concurrent_futures.ThreadPoolExecutor(
                 max_workers=1, thread_name_prefix="pg_tcp_sender"
             )
+            # (its thread starts with the first ring that moves in slices)
+            self._reducer = concurrent_futures.ThreadPoolExecutor(
+                max_workers=1, thread_name_prefix="pg_tcp_reducer"
+            )
             self._worker = threading.Thread(
                 target=self._worker_loop,
                 args=(gen, self._queue),
@@ -856,10 +1026,13 @@ class ProcessGroupTCP(ProcessGroup):
             # After this, _submit fails fast instead of enqueueing into limbo.
             self._worker = None
             sender, self._sender = self._sender, None
-        if sender is not None:
-            # don't wait: a sendall stuck on a dead peer unwedges itself when
-            # the socket close (above) fails it
-            sender.shutdown(wait=False)
+            reducer, self._reducer = self._reducer, None
+        for role in (sender, reducer):
+            if role is not None:
+                # don't wait: a sendall stuck on a dead peer unwedges itself
+                # when the socket close (above) fails it, and the ring's
+                # reducer with the ring
+                role.shutdown(wait=False)
         # Fail any ops still sitting in the retired queue so no Work handle
         # is left unresolved (a hang is worse than an error in FT code).
         while True:
@@ -1047,7 +1220,19 @@ class ProcessGroupTCP(ProcessGroup):
             off += got
             self._flight_progress(got)
 
-    def _send_msg(self, dst: int, tag: int, array: np.ndarray, deadline: float) -> None:
+    def _send_msg(
+        self,
+        dst: int,
+        tag: int,
+        array: np.ndarray,
+        deadline: float,
+        step: "Optional[int]" = None,
+        ready: "Optional[Callable[[int], None]]" = None,
+    ) -> None:
+        """One tagged array to ``dst``.  A payload that is still being made
+        (a ring's chunk, reduced slice by slice) goes in pieces of ``step``
+        bytes, each once ``ready(end)`` has returned: the payload's first
+        ``end`` bytes are final.  The bytes on the wire are the same."""
         peer = self._peer(dst)
         array = np.ascontiguousarray(array)
         header = pickle.dumps(
@@ -1058,8 +1243,12 @@ class ProcessGroupTCP(ProcessGroup):
             deadline_mono=deadline,
         )
         wan = dst in self._inter_peers
+        if ready is not None and array.nbytes:
+            # a message starts when its first piece is final: until then the
+            # peer waits for the message, not in it
+            ready(1)
         t0 = time.perf_counter()
-        shaper_wait = 0.0
+        shaper_wait = starved = 0.0
         if wan and self._rtt_s > 0.0:
             # First-byte latency of the WAN model: once per MESSAGE,
             # before any byte moves, independent of the bandwidth debt
@@ -1082,31 +1271,34 @@ class ProcessGroupTCP(ProcessGroup):
             # buffer-protocol format char and raise in cast(). The payload
             # still goes to the kernel straight from the array's buffer.
             view = memoryview(array.reshape(-1).view(np.uint8))
-            if bucket is None:
-                peer.sock.sendall(view)
-            else:
+            piece = step or len(view)
+            if bucket is not None:
                 # shaped path: pace in 1 MB chunks so the bucket's sleeps
                 # interleave with the peer's compute at sub-fragment
                 # granularity (a single consume() of a GB payload would
                 # model a link with GB-deep switch buffers)
-                chunk_len = 1 << 20
-                for off in range(0, len(view), chunk_len):
-                    chunk = view[off : off + chunk_len]
+                piece = min(piece, 1 << 20)
+            for off in range(0, len(view), piece):
+                chunk = view[off : off + piece]
+                if ready is not None:
+                    waited = time.perf_counter()
+                    ready(off + len(chunk))
+                    starved += time.perf_counter() - waited
+                if bucket is not None:
                     shaper_wait += bucket.consume(len(chunk))
-                    peer.sock.settimeout(
-                        max(deadline - time.monotonic(), 0.001)
-                    )
-                    peer.sock.sendall(chunk)
+                peer.sock.settimeout(max(deadline - time.monotonic(), 0.001))
+                peer.sock.sendall(chunk)
         # Passive link-state measurement (utils/linkstats.py): every
         # completed send is one sample — bytes + wall on the reduction
         # plane, first-byte = the modeled RTT leg.  Shaper waits are
         # additionally attributed per peer host (worst-K label tier).
+        # What the send waited for its own payload is no time of the link's.
         label, is_local = self._link_labels.get(dst, ("unknown", not wan))
         _linkstats.record(
             label,
             "reduction",
             8 + len(header) + array.nbytes,
-            time.perf_counter() - t0,
+            time.perf_counter() - t0 - starved,
             first_byte_s=self._rtt_s if (wan and self._rtt_s > 0.0) else 0.0,
             local=is_local,
         )
@@ -1123,6 +1315,7 @@ class ProcessGroupTCP(ProcessGroup):
         out: "Optional[np.ndarray]" = None,
         head: Any = _UNTIMED,
         body: Any = _UNTIMED,
+        stream: "Optional[_RingStream]" = None,
     ) -> np.ndarray:
         """Receive one tagged array; ``out`` receives in place (zero-alloc
         fast path for ring steps — reference pg_transport in-place recv
@@ -1131,7 +1324,10 @@ class ProcessGroupTCP(ProcessGroup):
         The two places a receive blocks are entered as ``head`` (until the
         message's first 8 bytes are here: the wait for the peer) and
         ``body`` (the rest of it: the bytes); a ring hands in the parts of
-        its ``ring.wire``, everyone else nothing."""
+        its ``ring.wire``, everyone else nothing.  A ring's message is read
+        a slice of its ``stream`` at a time: each is asked for
+        (:meth:`_RingStream.hold`, outside ``body``) and said to have
+        landed; anyone else's payload is read whole."""
         peer = self._peer(src)
         with head:
             # record the blocked-on peer BEFORE the header read: a wedged
@@ -1169,12 +1365,21 @@ class ProcessGroupTCP(ProcessGroup):
                         f" shape/dtype imply {out.nbytes}"
                     )
             self._flight_io(recv_bytes=nbytes)
-            if nbytes:
-                # uint8 view for ml_dtypes compat (see _send_msg)
-                self._read_into_sock(
-                    peer.sock, memoryview(out.reshape(-1).view(np.uint8)), deadline
-                )
+            # uint8 view for ml_dtypes compat (see _send_msg)
+            view = memoryview(out.reshape(-1).view(np.uint8))
+        if stream is None:
+            with body:
+                self._read_into_sock(peer.sock, view, deadline)
             return out
+        # (an empty message is one empty slice)
+        for off in range(0, max(nbytes, 1), stream.slice_bytes or 1):
+            stream.hold()
+            with body:
+                self._read_into_sock(
+                    peer.sock, view[off : off + stream.slice_bytes], deadline
+                )
+            stream.land()
+        return out
 
     def _exchange(
         self,
@@ -1185,50 +1390,36 @@ class ProcessGroupTCP(ProcessGroup):
         recv_tag: int,
         deadline: float,
         recv_out: "Optional[np.ndarray]" = None,
-        head: Any = _UNTIMED,
-        body: Any = _UNTIMED,
-        tail: Any = _UNTIMED,
     ) -> np.ndarray:
         """Simultaneous send+recv without deadlocking on full TCP buffers.
 
-        Ring steps send and receive concurrently; pushing the send to the
-        persistent sender thread keeps both directions draining even when
-        payloads exceed socket buffer sizes.
-
-        The stretches of the calling thread's wall are entered as ``head``
-        and ``body`` (:meth:`_recv_msg`) and, twice, ``tail``: what the
-        send costs this thread, handing it over and then what is left of
-        it once the receive has returned.  The sender thread is timed by
-        none of them: a send that overlaps the receive is no part of the
-        caller's wall.
+        Pushing the send to the persistent sender thread keeps both
+        directions draining even when payloads exceed socket buffer sizes.
+        (A ring allreduce streams its messages itself: :meth:`_allreduce_one`.)
         """
         sender = self._sender
         if sender is None:
             raise _PGAborted("process group not configured/running")
-        with tail:
-            send_fut = sender.submit(
-                self._send_msg, send_dst, send_tag, send_array, deadline
-            )
+        send_fut = sender.submit(
+            self._send_msg, send_dst, send_tag, send_array, deadline
+        )
         send_err: "Optional[BaseException]" = None
         try:
-            received = self._recv_msg(
-                recv_src, recv_tag, deadline, out=recv_out, head=head, body=body
-            )
+            received = self._recv_msg(recv_src, recv_tag, deadline, out=recv_out)
         finally:
             # always reap the send: the socket stream must never be left
             # mid-write when the next step starts (a recv error still
             # propagates; it takes precedence over any send error)
-            with tail:
-                try:
-                    send_fut.result(
-                        timeout=max(deadline - time.monotonic(), 0.001) + 1.0
-                    )
-                except concurrent_futures.TimeoutError:
-                    send_err = TimeoutError(
-                        "collective send did not complete by deadline"
-                    )
-                except BaseException as e:  # noqa: BLE001 - re-raised below
-                    send_err = e
+            try:
+                send_fut.result(
+                    timeout=max(deadline - time.monotonic(), 0.001) + 1.0
+                )
+            except concurrent_futures.TimeoutError:
+                send_err = TimeoutError(
+                    "collective send did not complete by deadline"
+                )
+            except BaseException as e:  # noqa: BLE001 - re-raised below
+                send_err = e
         if send_err is not None:
             raise send_err
         return received
@@ -1292,6 +1483,13 @@ class ProcessGroupTCP(ProcessGroup):
     # concat/split memcpy costs more than the saved round trips, so big
     # leaves ring solo (zero-copy path).
     BUCKET_BYTES = 4 * 1024 * 1024
+
+    # A ring moves a chunk in slices, each reduced and sent on while the next
+    # one comes in (``_allreduce_one``): ``SLICES`` of them, fewer where a
+    # slice would hold less than ``SLICE_BYTES``, and one, the chunk whole,
+    # below twice that.
+    SLICES = 8
+    SLICE_BYTES = 8 * 1024 * 1024
 
     @classmethod
     def _plan_buckets(
@@ -1473,7 +1671,21 @@ class ProcessGroupTCP(ProcessGroup):
           device leaf held in another order of dimensions arrives flat:
           :func:`_to_host`);
         - the division (``REDUCE_AVG``, the Manager's participant count)
-          is one in-place pass over the buffer, none for a divisor of 1.
+          is applied once, by the rank that ends the reduce-scatter with a
+          chunk fully reduced, to that chunk before the allgather ships
+          it: every rank divides 1/w of the buffer, none for a divisor of
+          1, and all hold the same bits.
+
+        The 2(w-1) messages a rank receives and the 2(w-1) it sends are two
+        streams, and a chunk of at least two ``SLICE_BYTES`` moves through
+        them in slices (:class:`_RingStream`): this thread reads the
+        previous rank's stream message after message, the reducer thread
+        reduces (and, in the last step, divides) a slice while the next one
+        comes in, and the sender thread pushes a slice of the next message
+        on as soon as it is final.  The messages, their order, headers and
+        payloads are those of the whole-chunk ring, which is the case of
+        one slice a message: reduced by this thread between two messages,
+        with nothing handed to the reducer.
         """
         w, r = self._world, self._rank
         acc_dtype = _accumulation_dtype(array.dtype)
@@ -1517,22 +1729,48 @@ class ProcessGroupTCP(ProcessGroup):
             scratch = _pool.take(chunk, acc_dtype)
 
         nxt, prv = (r + 1) % w, (r - 1) % w
-        # ring.wire is the wall of the 2(w-1) exchanges less the reduces
-        # between them; ring.reduce accumulates over its w-1 stretches
-        reduce = _tracing.phase(".reduce")
-        # ring.wire, opened where an exchange blocks this thread.  The wait
-        # for a message's first bytes is the peer's: in the op's first
-        # exchange, which depends on nothing the previous rank received, it
-        # is how much later than this rank that one reached the ring
-        # (``.arrive``, once an op: a plain phase, so also an annotation
-        # beside the device trace); in every later one the peer is in the
-        # ring and late with this chunk (``.wait``).  ``.recv`` is the
-        # message's bytes coming in, ``.send`` what this rank's send costs
-        # this thread: handing it to the sender, then what is left of it
-        # once the receive has returned.  The last three accumulate, like
+        # slices a message, as even as they come, and the elements of one
+        slices = min(
+            max(chunk * acc_dtype.itemsize // self.SLICE_BYTES, 1), self.SLICES
+        )
+        span = -(-chunk // slices)
+
+        def reduce_slice(g: int) -> None:
+            # slice g of the stream: of the reduce-scatter's message ``step``
+            step, k = divmod(g, slices)
+            idx = (r - step - 1) % w
+            at = slice(k * span, (k + 1) * span)
+            out = chunks[idx][at]
+            reduce_into(own[idx][at], scratch[at], out=out)
+            if step == w - 2:  # fully reduced, and this rank's to ship
+                _divide_in_place(out, divisor)
+
+        # ring.reduce is the seconds in which the wire stood still for a
+        # reduce or a division: the one-slice reduce between two messages,
+        # the receiver held back for ``scratch``, the wait for the reducer
+        # once the last byte is in.  What the reducer did under the wire is
+        # ring.reduce.hidden: seconds, no span.
+        reduce = _tracing.phase(".reduce", slices=slices, hidden=0)
+        stream = _RingStream(
+            slices, span * acc_dtype.itemsize, 2 * (w - 1), reduce_slice,
+            reduce.lap(), deadline,
+        )
+        # ring.wire is the wall of the bucket's two streams, the first
+        # header sought to the last byte in and out, less what is booked
+        # as ring.reduce.  Its parts are this thread's, the receiving
+        # role's.  The wait for a message's first bytes is the peer's: for
+        # the op's first message, which depends on nothing the previous
+        # rank received, it is how much later than this rank that one
+        # reached the ring (``.arrive``, once an op: a plain phase, so also
+        # an annotation beside the device trace); for every later one the
+        # peer is in the ring and late with this chunk (``.wait``).
+        # ``.recv`` is a message's bytes coming in, a stall between two of
+        # its slices included; ``.send`` what the sending costs this
+        # thread: handing the stream to the sender, then what is left of it
+        # once the last byte is in.  The last three accumulate, like
         # ``.reduce``: one span a part and bucket.  They are made as the
         # wire's before its wall starts and booked after it has ended, so
-        # that the wall holds the exchanges and little else.
+        # that the wall holds the streams and little else.
         wire = _tracing.phase(
             ".wire", bytes=2 * (w - 1) * chunk * acc_dtype.itemsize
         )
@@ -1542,36 +1780,61 @@ class ProcessGroupTCP(ProcessGroup):
             recv = _tracing.phase(".recv")
             send = _tracing.phase(".send")
         head, body, tail = wait.lap(), recv.lap(), send.lap()
+        # reduce-scatter: after w-1 messages chunk (r+1)%w is fully reduced;
+        # allgather of the reduced chunks, received straight into place
+        incoming = [(100 + step, scratch) for step in range(w - 1)] + [
+            (200 + step, chunks[(r - step) % w]) for step in range(w - 1)
+        ]
+        outgoing = [
+            (100 + step, chunks[(r - step) % w] if step else own[r])
+            for step in range(w - 1)
+        ] + [(200 + step, chunks[(r - step + 1) % w]) for step in range(w - 1)]
+        sender, reducer = self._sender, self._reducer
+        if sender is None or reducer is None:
+            raise _PGAborted("process group not configured/running")
+        sending: "Optional[Future]" = None
+        reducing: "Optional[Future]" = None
         try:
             with wire:
-                # ring reduce-scatter: after w-1 steps, chunk (r+1)%w is
-                # fully reduced
-                for step in range(w - 1):
-                    send_idx = (r - step) % w
-                    recv_idx = (r - step - 1) % w
-                    self._exchange(
-                        nxt, 100 + step,
-                        chunks[send_idx] if step else own[send_idx],
-                        prv, 100 + step, deadline, recv_out=scratch,
-                        head=head if step or arrive is None else arrive,
-                        body=body, tail=tail,
+                with tail:
+                    sending = sender.submit(
+                        self._ring_send, stream, nxt, outgoing, deadline
                     )
-                    with reduce.lap():
-                        reduce_into(
-                            own[recv_idx], scratch, out=chunks[recv_idx]
+                    if slices > 1:
+                        reducing = reducer.submit(stream.reduce_all)
+                for m, (tag, out) in enumerate(incoming):
+                    self._recv_msg(
+                        prv, tag, deadline, out=out,
+                        head=head if m or arrive is None else arrive,
+                        body=body, stream=stream,
+                    )
+                stream.drain(reducing)
+                with tail:
+                    sent, _ = concurrent_futures.wait(
+                        [sending],
+                        timeout=max(deadline - time.monotonic(), 0.001) + 1.0,
+                    )
+                    if not sent:
+                        raise TimeoutError(
+                            "collective send did not complete by deadline"
                         )
-                # ring allgather of the reduced chunks, received straight
-                # into place
-                for step in range(w - 1):
-                    send_idx = (r - step + 1) % w
-                    recv_idx = (r - step) % w
-                    self._exchange(
-                        nxt, 200 + step, chunks[send_idx], prv, 200 + step,
-                        deadline, recv_out=chunks[recv_idx],
-                        head=head, body=body, tail=tail,
+                    sending.result()
+                reduce.attrs["hidden"] = stream.hidden
+                if stream.hidden and reduce.sink is not None:
+                    _tracing.add_seconds(
+                        reduce.sink, "ring.reduce.hidden", stream.hidden_s
                     )
                 wire.exclude(reduce.end())
+        except BaseException as e:  # noqa: BLE001 - the ring's first error is raised
+            raise stream.fail(e)
         finally:
+            # no role outlives the ring: the reducer reads ``scratch`` and
+            # the sender must not be left mid-message (their errors are the
+            # stream's already)
+            concurrent_futures.wait(
+                [role for role in (sending, reducing) if role is not None],
+                timeout=max(deadline - time.monotonic(), 0.001) + 1.0,
+            )
             # of a ring that failed too: how long it waited for a peer that
             # never came is what its operator asks first
             recv.end()
@@ -1579,13 +1842,32 @@ class ProcessGroupTCP(ProcessGroup):
             self._m_peer_wait["wait"].inc(wait.end())
             if arrive is not None:
                 self._m_peer_wait["arrive"].inc(arrive.seconds)
+            self._m_ring_slices[True].inc(stream.hidden)
+            self._m_ring_slices[False].inc(stream.reduced - stream.hidden)
             _pool.give(scratch)
         with _tracing.phase(".unpack", bytes=array.nbytes):
             # a leaf that widened is cast back into a new array, and the
             # ring buffer's lease ends here
-            return np.asarray(
-                _divide(buf[:n], divisor), dtype=array.dtype
-            ).reshape(array.shape)
+            return np.asarray(buf[:n], dtype=array.dtype).reshape(array.shape)
+
+    def _ring_send(
+        self,
+        stream: _RingStream,
+        dst: int,
+        outgoing: "List[Tuple[int, np.ndarray]]",
+        deadline: float,
+    ) -> None:
+        """The sending role of a ring, on the sender thread: message after
+        message to the next rank, each slice as soon as it is final."""
+        try:
+            for m, (tag, array) in enumerate(outgoing):
+                self._send_msg(
+                    dst, tag, array, deadline,
+                    step=stream.slice_bytes, ready=stream.gate(m),
+                )
+        except BaseException as e:  # noqa: BLE001 - the ring's, raised by the worker
+            stream.fail(e)
+            raise
 
     def allgather(self, array: Any) -> Work:
         np_array = _as_numpy(array)

@@ -1176,6 +1176,19 @@ RING_BUFFERS = counter(
     "(miss); hit / (hit + miss) over a step is the ring-buffer reuse share",
     ("replica_id", "result"),
 )
+RING_SLICES = counter(
+    "torchft_ring_slices_total",
+    "Slices of reduce-scatter messages that ProcessGroupTCP reduced in a plain "
+    "allreduce at world size > 1 (parallel/process_group.py: a chunk of at "
+    "least two SLICE_BYTES moves through the ring in slices, reduced on a thread "
+    "of their own while the next one comes in), by whether the slice's "
+    "reduce had ended before the next slice of the stream had landed "
+    "(hidden=1: the wire was not kept waiting) or not (hidden=0: the reducer "
+    "is behind the wire, or the chunk was one slice, reduced between two "
+    "messages); 1 / (1 + 0) over a step is the share of the reduce that ran "
+    "under the wire",
+    ("replica_id", "hidden"),
+)
 RING_LEAVES_KEPT = counter(
     "torchft_ring_leaves_kept_total",
     "Leaves of a plain allreduce that a group alone (world size 1, nothing "
