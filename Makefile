@@ -4,7 +4,7 @@
 
 PYTHON ?= python
 
-.PHONY: native verify lint typecheck plan-verify test tier1 bench-wan trace-smoke reshard-smoke serve-smoke bench-serving bench-serving-depth bench-serving-native serve-soak ha-smoke bench-ha heal-smoke bench-heal links-smoke cold-restore-smoke bench-cold-restore fragments-smoke
+.PHONY: native verify lint typecheck plan-verify test tier1 trace-smoke reshard-smoke serve-smoke serve-soak ha-smoke heal-smoke links-smoke cold-restore-smoke fragments-smoke
 
 native:
 	$(MAKE) -C native
@@ -67,31 +67,6 @@ serve-smoke:
 serve-soak:
 	JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/test_serving.py -q -m "slow"
 
-# Serving bench alone: sustained checkpoints/sec + client fetch p50/p99
-# at stub-client load with a chaos kill of a tree node mid-fetch; ends
-# with the same < 1.5 KB compact-summary JSON line as the full bench.
-bench-serving:
-	JAX_PLATFORMS=cpu $(PYTHON) bench.py --serving
-
-# Streaming-relay depth axis alone (ISSUE 14): publish->leaf latency
-# over a fanout-1 relay chain at depth {1,2,3} x simulated RTT
-# {0,10,50} ms, whole-payload store-and-forward vs cut-through fragment
-# streaming + the single-fragment delta rows (docs/benchmarks.md);
-# ends with the same < 1.5 KB compact-summary JSON line as the full
-# bench.
-bench-serving-depth:
-	JAX_PLATFORMS=cpu $(PYTHON) bench.py --serving-depth
-
-# Native-vs-python fragment data plane (ISSUE 20): same shaped relay
-# chain as bench-serving-depth at depth {3,4} x RTT {0,10} ms, each
-# cell run once with TORCHFT_FRAG_NATIVE=0 (pure Python HTTP plane)
-# and once =1 (C++ writev serve / GIL-free receive), plus a striped
-# heal leg; reports per-plane publish->leaf p50/p99, bitwise payload
-# equality, native serve/fallback counters, and the p99 speedup
-# headline recorded in docs/benchmarks.md §9.
-bench-serving-native:
-	JAX_PLATFORMS=cpu $(PYTHON) bench.py --serving-native
-
 # Coordination-plane HA round trip alone: 3 lighthouse subprocesses,
 # SIGKILL the active leader mid-quorum-round and mid-serving-fetch —
 # the fleet re-quorums with monotone term-prefixed quorum ids, serving
@@ -99,12 +74,6 @@ bench-serving-native:
 # (docs/architecture.md "Coordination-plane HA").
 ha-smoke:
 	JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/test_ha.py tests/test_ha_integ.py -q -m "not slow"
-
-# HA failover bench alone: leader-kill -> next-quorum latency over an
-# in-process 3-peer fleet; ends with the same < 1.5 KB compact-summary
-# JSON line as the full bench.
-bench-ha:
-	JAX_PLATFORMS=cpu $(PYTHON) bench.py --ha-failover
 
 # Striped-heal round trip alone (ISSUE 15): streamed fragment staging,
 # multi-source striping with per-fragment failover (kill a stripe source
@@ -114,13 +83,6 @@ bench-ha:
 heal-smoke:
 	JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/test_heal_striped.py tests/test_golden_fixtures.py -q -m "not slow"
 
-# Striped-heal bench alone: heal wire time striped across {1,2,4}
-# sources x RTT {0,10,50} ms on shaped per-source uplinks + the
-# delta-rejoin row (docs/benchmarks.md §8); ends with the same < 1.5 KB
-# compact-summary JSON line as the full bench.
-bench-heal:
-	JAX_PLATFORMS=cpu $(PYTHON) bench.py --heal
-
 # Durable-store round trip alone (ISSUE 17): store unit surface (dedup,
 # torn-blob digest verify, cut selection, spiller, durable.py on the
 # store), whole-fleet SIGKILL cold restore with bitwise resume, the
@@ -129,13 +91,6 @@ bench-heal:
 # store").
 cold-restore-smoke:
 	JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/test_store.py tests/test_cold_restore.py tests/test_golden_fixtures.py -q -m "not slow"
-
-# Durable-store bench alone: spill wall, content-addressed dedup bytes,
-# cold-restore wall striped over {1,2} disks + the warm delta row
-# (docs/benchmarks.md); ends with the same < 1.5 KB compact-summary
-# JSON line as the full bench.
-bench-cold-restore:
-	JAX_PLATFORMS=cpu $(PYTHON) bench.py --cold-restore
 
 # Fleet link-state plane round trip alone: passive estimator accuracy
 # on a shaped topology (closed-loop vs the declared RTT/Gbps), the
@@ -155,9 +110,3 @@ links-smoke:
 # "Fragment provenance plane").
 fragments-smoke:
 	JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/test_provenance.py -q -m "not slow"
-
-# WAN sweep alone: flat vs hierarchical int8 DiLoCo at simulated
-# 0/10/50 ms inter-host RTT (docs/benchmarks.md §WAN); ends with the
-# same < 1.5 KB compact-summary JSON line as the full bench.
-bench-wan:
-	JAX_PLATFORMS=cpu $(PYTHON) bench.py --wan

@@ -51,8 +51,8 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-# The flagship widths — the one configuration this repo sizes for a v5e
-# (bench.py takes them from here).  Never cut; depth and batch are per leg.
+# The flagship widths — the one configuration this repo sizes for a v5e.
+# Never cut; depth and batch are per leg.
 WIDTHS = dict(
     vocab_size=32000, d_model=1536, n_heads=6, n_kv_heads=3, d_ff=4096,
     max_seq_len=1024,

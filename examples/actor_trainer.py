@@ -161,8 +161,7 @@ class TrainerActor(Actor):
             # thread-actor constraint: the manager must be shut down here or
             # its server/heartbeat threads would leak into the shared
             # process. True kill -9 chaos (no teardown at all) lives in the
-            # process-isolated paths: launcher.kill_replica, punisher.py,
-            # and bench.py.
+            # process-isolated paths: launcher.kill_replica and punisher.py.
             manager.shutdown()
 
     def status(self) -> "Dict[str, Any]":

@@ -769,8 +769,8 @@ class TestTraceLedger:
         assert cats.get("straggler-wait", 0.0) == 0.0
 
     def test_bench_vocabulary_matches(self):
-        """bench.py's per-leg dominant field uses this module's mapping —
-        pin the vocabulary so the tail stays joinable with the ledger."""
+        """``dominant_contributor`` names a step's cost in the ledger's
+        categories — pin the vocabulary so reports stay joinable with it."""
         assert diagnose.dominant_contributor(
             {"quorum_rpc": 1.0, "ring": 5.0}
         ) == "wire"

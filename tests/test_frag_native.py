@@ -2,7 +2,7 @@
 
 Contract layer: bitwise serve with zero user-space copies server-side
 (allocation/copy counters), pool-miss-flat republish idiom, GIL-free
-receive+digest (budget test), the ``TORCHFT_FRAG_NATIVE`` gate, the
+receive+digest (budget test), the ``fragdata.enabled`` gate, the
 ``/nativeport`` discovery route, and per-fetch Python fallback for
 unmirrored resources.
 
@@ -12,8 +12,9 @@ the native path is rejected by the digest-of-record (source treated
 dead, provenance hop verdict ``mismatch``); a mixed native<->python
 fleet interoperates bitwise.
 
-Everything here requires the native library; the suite skips cleanly
-where the ``.so`` cannot build.
+Everything here requires the native library, and fails where it cannot
+be loaded: the coordination core is the same ``.so``, so a tree without
+it runs nothing.
 """
 
 import threading
@@ -32,13 +33,9 @@ from torchft_tpu.utils import faults
 from torchft_tpu.utils import flightrecorder as fr
 from torchft_tpu.utils.faults import FaultRule
 
-pytestmark = pytest.mark.skipif(
-    not fragdata.available(), reason="native fragment library unavailable"
-)
-
-
 @pytest.fixture(autouse=True)
 def clean_slate():
+    assert fragdata.available(), "the native library could not be loaded"
     faults.FAULTS.configure([], seed=0)
     fragdata.reset_port_cache()
     yield
@@ -78,6 +75,27 @@ def stage_raw(transport: HTTPTransport, step: int, parts: dict) -> None:
     transport.finish_streamed_checkpoint(step)
 
 
+def python_only_transport(**kw) -> HTTPTransport:
+    """A node without the native data plane (one peer of a mixed fleet):
+    built while the plane reads as absent."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(fragdata, "enabled", lambda: False)
+        return HTTPTransport(**kw)
+
+
+def served(transport: HTTPTransport, at_least: int) -> dict:
+    """The native server's counters once it has booked ``at_least``
+    serves: it counts a serve after its last byte is sent, which the
+    client that already holds the bytes does not wait for."""
+    deadline = time.monotonic() + 5.0
+    while (
+        transport._frag_native.counters()["serves"] < at_least
+        and time.monotonic() < deadline
+    ):
+        time.sleep(0.01)
+    return transport._frag_native.counters()
+
+
 def fetch_bytes(base: str, step: int, resource: str, timeout=5.0) -> bytes:
     buf = frags.fetch_raw(base, step, resource, timeout=timeout)
     return bytes(memoryview(buf).cast("B"))
@@ -88,7 +106,7 @@ def sources():
     """Three native-armed transports stream-staging the SAME state at
     step 5 — bitwise-replicated heal sources over the native plane."""
     state = make_state()
-    transports = [HTTPTransport(timeout=10.0, native=True) for _ in range(3)]
+    transports = [HTTPTransport(timeout=10.0) for _ in range(3)]
     threads = [
         threading.Thread(
             target=t.send_checkpoint_streamed,
@@ -110,14 +128,14 @@ class TestNativeContract:
         payload = np.random.default_rng(0).integers(
             0, 256, size=1 << 20, dtype=np.uint8
         ).tobytes()
-        t = HTTPTransport(timeout=10.0, native=True)
+        t = HTTPTransport(timeout=10.0)
         try:
             assert t._frag_native is not None
             base = t.metadata()
             stage_raw(t, 7, {"w0": payload})
             for _ in range(3):
                 assert fetch_bytes(base, 7, "frag_w0") == payload
-            c = t._frag_native.counters()
+            c = served(t, 3)
             # steady-state serve is pure writev out of the staged pooled
             # buffer: the ONE copy in the plane is at stage time
             assert c["serves"] >= 3
@@ -132,7 +150,7 @@ class TestNativeContract:
         version warms the pool every restage is a pool hit — the bufpool
         miss-flat idiom, natively."""
         sizes = [1 << 16, 1 << 16, 1 << 18]
-        t = HTTPTransport(timeout=10.0, native=True)
+        t = HTTPTransport(timeout=10.0)
         try:
             srv = t._frag_native
             assert srv is not None
@@ -152,9 +170,9 @@ class TestNativeContract:
             t.shutdown()
 
     def test_gate_off_forces_python_path(self, monkeypatch):
-        monkeypatch.setenv("TORCHFT_FRAG_NATIVE", "0")
         payload = b"x" * 4096
-        t = HTTPTransport(timeout=10.0, native=True)
+        t = HTTPTransport(timeout=10.0)
+        monkeypatch.setattr(fragdata, "enabled", lambda: False)
         try:
             stage_raw(t, 2, {"w0": payload})
             assert fetch_bytes(t.metadata(), 2, "frag_w0") == payload
@@ -169,7 +187,7 @@ class TestNativeContract:
         mirrored natively: the native 404 falls back to the Python
         serializer for THAT fetch — and the fallback is flight-recorded
         so a fleet on the slow path is visible post-mortem."""
-        t = HTTPTransport(timeout=10.0, native=True)
+        t = HTTPTransport(timeout=10.0)
         try:
             raw = b"r" * 2048
             t.begin_streamed_checkpoint(9, {"frag:header": {"n": 1}})
@@ -185,13 +203,13 @@ class TestNativeContract:
                 and r.get("resource") == "frag_obj"
             ]
             assert ops, "fallback fetch not flight-recorded"
-            assert t._frag_native.counters()["serves"] == 1
+            assert served(t, 1)["serves"] == 1
         finally:
             t.shutdown()
 
     def test_nativeport_discovery_route(self):
-        armed = HTTPTransport(timeout=5.0, native=True)
-        plain = HTTPTransport(timeout=5.0, native=False)
+        armed = HTTPTransport(timeout=5.0)
+        plain = python_only_transport(timeout=5.0)
         try:
             armed_url = (
                 f"http://127.0.0.1:{armed._server.server_address[1]}"
@@ -217,7 +235,7 @@ class TestNativeContract:
         begin/body calls, so a pure-Python ticker makes real progress
         during the native wait.  A GIL-holding receive would freeze it."""
         payload = b"g" * (1 << 20)
-        t = HTTPTransport(timeout=10.0, native=True)
+        t = HTTPTransport(timeout=10.0)
         try:
             stage_raw(t, 1, {"w0": payload})
             base = t.metadata()
@@ -283,7 +301,7 @@ class TestStagedInPlace:
         # one fragment, far larger than the loopback's buffers
         state = {"w": np.random.default_rng(1).standard_normal(6_000_000)
                  .astype(np.float32)}
-        t = HTTPTransport(timeout=10.0, native=True)
+        t = HTTPTransport(timeout=10.0)
         try:
             srv = t._frag_native
             digest = t.send_checkpoint_streamed(
@@ -333,15 +351,14 @@ class TestStagedInPlace:
         written so far.  Published, it gets all of them."""
         from concurrent.futures import ThreadPoolExecutor
 
-        monkeypatch.setenv(
-            "TORCHFT_FRAG_NATIVE", "1" if plane == "native" else "0"
-        )
         payload = np.random.default_rng(2).integers(
             0, 256, size=300_000, dtype=np.uint8
         )
         # the server is native either way: the python plane is the
-        # fallback a gated-off peer takes against it
-        t = HTTPTransport(timeout=10.0, native=True)
+        # fallback a peer without the native plane takes against it
+        t = HTTPTransport(timeout=10.0)
+        if plane == "python":
+            monkeypatch.setattr(fragdata, "enabled", lambda: False)
         try:
             base = t.metadata()
             t.begin_streamed_checkpoint(9, {"frag:header": {"n": 1}})
@@ -378,7 +395,7 @@ class TestStagedInPlace:
         """What the native side refuses to publish in place takes the
         copy: a buffer it never lent, a lend for another fragment, a lend
         handed back in part, a lend already committed."""
-        t = HTTPTransport(timeout=10.0, native=True)
+        t = HTTPTransport(timeout=10.0)
         try:
             srv = t._frag_native
             t.begin_streamed_checkpoint(3, {"frag:header": {"n": 1}})
@@ -409,7 +426,7 @@ class TestStagedInPlace:
         import sys
         from concurrent.futures import ThreadPoolExecutor
 
-        t = HTTPTransport(timeout=20.0, native=True, max_staged=64)
+        t = HTTPTransport(timeout=20.0, max_staged=64)
         base = t.metadata()
         n = 96 * 1024  # one size for all: every release feeds every reserve
 
@@ -536,9 +553,9 @@ class TestNativeChaos:
         any mix."""
         state = make_state()
         transports = [
-            HTTPTransport(timeout=10.0, native=True),
-            HTTPTransport(timeout=10.0, native=False),
-            HTTPTransport(timeout=10.0, native=True),
+            HTTPTransport(timeout=10.0),
+            python_only_transport(timeout=10.0),
+            HTTPTransport(timeout=10.0),
         ]
         try:
             threads = [
@@ -581,7 +598,7 @@ class TestNativeChaos:
         takes the transport-error path: the fetch falls back to Python
         for that attempt and still lands the right bytes."""
         payload = b"d" * 8192
-        t = HTTPTransport(timeout=10.0, native=True)
+        t = HTTPTransport(timeout=10.0)
         try:
             stage_raw(t, 6, {"w0": payload})
             base = t.metadata()

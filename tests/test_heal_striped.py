@@ -585,11 +585,7 @@ class TestOneWrite:
 
         from torchft_tpu.checkpointing import fragdata
 
-        if plane == "native" and not fragdata.available():
-            pytest.skip("native fragment library unavailable")
-        monkeypatch.setenv(
-            "TORCHFT_FRAG_NATIVE", "1" if plane == "native" else "0"
-        )
+        assert fragdata.available(), "the native library could not be loaded"
         if plane == "python":
             # no native library at all: numpy re-orders what lies otherwise
             monkeypatch.setattr(fragdata, "available", lambda: False)
@@ -639,14 +635,13 @@ class TestOneWrite:
         from torchft_tpu.checkpointing import fragdata
         from torchft_tpu.utils import tracing
 
-        if not fragdata.available():
-            pytest.skip("native fragment library unavailable")
+        assert fragdata.available(), "the native library could not be loaded"
         if not engaged:
             monkeypatch.setattr(
                 fragdata.FragDataServer, "reserve", lambda *a, **k: None
             )
         state = make_state()
-        t = HTTPTransport(timeout=5.0, native=True)
+        t = HTTPTransport(timeout=5.0)
         healer = HTTPTransport(timeout=5.0)
         sink: dict = {}
         try:
@@ -684,8 +679,7 @@ class TestOneWrite:
         and what ``_stored_swapped`` takes for the device's order."""
         from torchft_tpu.checkpointing import fragdata
 
-        if not fragdata.available():
-            pytest.skip("native fragment library unavailable")
+        assert fragdata.available(), "the native library could not be loaded"
         dtype = {1: np.uint8, 2: np.uint16, 4: np.float32, 8: np.float64}[itemsize]
         rng = np.random.default_rng(itemsize)
         arr = (rng.random((2, 3, 45, 203)) * 250).astype(dtype).transpose(0, 1, 3, 2)

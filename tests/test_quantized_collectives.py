@@ -516,8 +516,7 @@ class TestFp8VsInt8Accuracy:
     into as fragments diverge — int8's uniform grid burns its 8 bits on
     the outlier range and fp8's exponent grid wins decisively.  On
     well-conditioned (near-Gaussian) rows int8 keeps the better RMSE, so
-    int8 stays the default wire.  docs/benchmarks.md carries the
-    measured table this test pins."""
+    int8 stays the default wire."""
 
     @staticmethod
     def _codec_err(a: np.ndarray, wire: str) -> "tuple[float, float]":

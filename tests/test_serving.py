@@ -514,7 +514,7 @@ class TestServingChaos:
 # ---------------------------------------------------------------------------
 
 
-def _chain_tier(n_relays, fragments=4, wire="f32", stream=None,
+def _chain_tier(n_relays, fragments=4, wire="f32", stream=True,
                 poll=0.02):
     """fanout=1 lighthouse + publisher + a CHAIN of n relays (depth
     0..n-1): the deep-tree shape the cut-through path exists for."""
@@ -645,9 +645,8 @@ class TestStreamingRelay:
             _teardown(lh, pub, reps)
 
     def test_flat_mode_roundtrip_still_works(self):
-        """TORCHFT_SERVING_STREAM=0 (stream=False) keeps the whole-
-        payload store-and-forward path functional — the depth-bench
-        baseline — and its decode histogram leg is NON-zero."""
+        """``stream=False`` keeps the whole-payload store-and-forward
+        path functional, and its decode histogram leg is NON-zero."""
         from torchft_tpu.utils import metrics as _m
 
         dec0 = _m.SERVING_RELAY_DECODE.labels(mode="flat").get()

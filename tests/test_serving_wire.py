@@ -3,7 +3,7 @@ satellites).
 
 Leg 1: the serving fetch/relay paths honor the training-side wire model
 (``TORCHFT_WIRE_RTT_MS`` / ``TORCHFT_WIRE_GBPS`` scoped by
-``TORCHFT_TOPOLOGY``) via serving/wire.py — including the shaped-link
+``TORCHFT_TOPOLOGY``) via utils/wire.py — including the shaped-link
 test pinning that fetch p99 stays bounded at 50 ms RTT.
 
 Leg 2: ``ServingClient(pin_version=..., min_version=...)`` — pin-hit,
@@ -23,7 +23,7 @@ from torchft_tpu.checkpointing.http_transport import HTTPTransport
 from torchft_tpu.coordination import LighthouseServer
 from torchft_tpu.serving import WeightPublisher, ServingClient, fetch_resource
 from torchft_tpu.serving import payload as _payload
-from torchft_tpu.serving import wire as _wire
+from torchft_tpu.utils import wire as _wire
 
 
 def _state(seed: int):

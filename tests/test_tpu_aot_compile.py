@@ -4,7 +4,8 @@ No chip is attached here: the TPU compiler builds for a DESCRIBED v5e:2x2
 topology and raises what the chip's compiler would raise (a block not
 aligned to the tiling, too much VMEM, ...), which interpret-mode tests
 cannot see.  A compile that passes is not a chip run — chip_smoke.py is.
-Skipped where the topology cannot be described (no libtpu)."""
+Skipped only where there is no libtpu to import; with it, a topology that
+cannot be described is a failure."""
 
 import os
 
@@ -34,10 +35,11 @@ def one_chip():
 
     from torchft_tpu.utils.compile_cache import compile_cache_disabled
 
+    pytest.importorskip("libtpu", reason="no libtpu, so no TPU compiler here")
     try:
         topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - any failure to describe = no compiler
-        pytest.skip(f"cannot describe a v5e topology here: {e!r}")
+    except Exception as e:  # noqa: BLE001 - the compiler is here and did not answer
+        pytest.fail(f"libtpu is here but cannot describe a v5e topology: {e!r}")
     # an AOT entry could be written to a persistent cache but never read
     # back without a chip: keep these compiles out of it
     with compile_cache_disabled():

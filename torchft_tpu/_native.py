@@ -269,8 +269,7 @@ def get_lib() -> ctypes.CDLL:
 
         # Native zero-copy fragment data plane (native/fragserver.{h,cc}).
         # Server lifecycle + the staging mirror HTTPTransport drives, and
-        # the two-phase GIL-free fetch client fragments.py dispatches to
-        # behind the TORCHFT_FRAG_NATIVE gate.
+        # the two-phase GIL-free fetch client fragments.py dispatches to.
         lib.tft_frag_server_create.restype = ctypes.c_int64
         lib.tft_frag_server_create.argtypes = [ctypes.c_char_p, ctypes.c_int]
         lib.tft_frag_server_port.restype = ctypes.c_int

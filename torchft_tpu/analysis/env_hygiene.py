@@ -27,7 +27,7 @@ pass polices the declarative form, which is also the greppable one.
 from __future__ import annotations
 
 import ast
-from typing import Iterable, List, Tuple
+from typing import Iterable, Iterator, List, Tuple
 
 from torchft_tpu.analysis.core import (
     Finding,
@@ -91,7 +91,11 @@ class _Visitor(QualnameVisitor):
         self.path = path
         self.consts = consts
         self.findings: "List[Finding]" = []
-        self.torchft_knobs: "List[Tuple[str, int, str]]" = []  # (name, line, qual)
+        # (name, line, qual, twin): ``twin`` when the read is the ``else``
+        # arm of ``arg if arg is not None else env_x(...)``, a second way
+        # in for a value that already has an argument
+        self.torchft_knobs: "List[Tuple[str, int, str, bool]]" = []
+        self._in_twin_arm = 0
 
     def _resolve(self, arg: "ast.AST | None") -> "str | None":
         val = const_str(arg)
@@ -118,6 +122,21 @@ class _Visitor(QualnameVisitor):
                 self._check_knob(name, node.lineno)
         self.generic_visit(node)
 
+    def visit_IfExp(self, node: ast.IfExp) -> None:  # noqa: N802
+        self.visit(node.test)
+        self.visit(node.body)
+        test = node.test
+        twin = (
+            isinstance(test, ast.Compare)
+            and len(test.ops) == 1
+            and isinstance(test.ops[0], ast.IsNot)
+            and isinstance(test.comparators[0], ast.Constant)
+            and test.comparators[0].value is None
+        )
+        self._in_twin_arm += twin
+        self.visit(node.orelse)
+        self._in_twin_arm -= twin
+
     def _flag_direct(self, node: ast.AST, kind: str) -> None:
         self.findings.append(
             Finding(
@@ -136,7 +155,9 @@ class _Visitor(QualnameVisitor):
 
     def _check_knob(self, name: str, line: int) -> None:
         if name.startswith("TORCHFT_"):
-            self.torchft_knobs.append((name, line, self.qualname))
+            self.torchft_knobs.append(
+                (name, line, self.qualname, self._in_twin_arm > 0)
+            )
             return
         if name.startswith(_EXTERNAL_PREFIXES) or name in _EXTERNAL_NAMES:
             return
@@ -156,9 +177,7 @@ class _Visitor(QualnameVisitor):
         )
 
 
-def run(project: Project) -> "Iterable[Finding]":
-    out: "List[Finding]" = []
-    docs = project.docs_text()
+def _visited(project: Project) -> "Iterator[_Visitor]":
     for path in project.py_files:
         if path.replace("\\", "/").endswith(_EXEMPT_FILE_SUFFIX):
             continue
@@ -167,8 +186,26 @@ def run(project: Project) -> "Iterable[Finding]":
             continue
         visitor = _Visitor(project, path, module_str_constants(tree))
         visitor.visit(tree)
+        yield visitor
+
+
+def knob_reads(project: Project) -> "List[Tuple[str, str, int, bool]]":
+    """Every ``TORCHFT_*`` helper read of the project as ``(name, file,
+    line, twin)`` — the census tests/test_knobs.py pins."""
+    return [
+        (name, project.rel(v.path), line, twin)
+        for v in _visited(project)
+        for name, line, _qual, twin in v.torchft_knobs
+    ]
+
+
+def run(project: Project) -> "Iterable[Finding]":
+    out: "List[Finding]" = []
+    docs = project.docs_text()
+    for visitor in _visited(project):
+        path = visitor.path
         out.extend(visitor.findings)
-        for name, line, qual in visitor.torchft_knobs:
+        for name, line, _qual, _twin in visitor.torchft_knobs:
             if name not in docs:
                 out.append(
                     Finding(

@@ -26,9 +26,9 @@ over any IR regardless of which plane produced it.
 :func:`reference_serving_plan` is the pure-Python mirror of the native
 BFS slot-queue (``rpc_serving_plan``) so C++ and Python can never
 drift on tree shape — the cross-language parity test pins them to each
-other.  :func:`stripe_roster` / :func:`stripe_source_cohort` are the
-one copy of the first-K roster math ``manager.py`` previously inlined
-twice.
+other.  :func:`stripe_roster` / :func:`stripe_source_cohort`, the one
+copy of the first-K roster math, live in ``coordination.py`` with the
+roster's other pure math and are re-exported here for the verifier.
 
 Everything here is stdlib-only and import-light: the lint/verify tier
 and the live runtime hooks both load it, and a plan is validated in
@@ -41,6 +41,7 @@ from __future__ import annotations
 from dataclasses import dataclass, replace
 from typing import Any, Dict, Iterable, List, Mapping, Sequence, Tuple
 
+from torchft_tpu.coordination import stripe_roster, stripe_source_cohort
 from torchft_tpu.ops import topology as topo_mod
 
 __all__ = [
@@ -411,53 +412,6 @@ def reference_serving_plan(
 # ---------------------------------------------------------------------------
 # Adapter 3: heal stripe assignment (checkpointing striped fetch)
 # ---------------------------------------------------------------------------
-
-
-def stripe_roster(
-    participants: Sequence[Any],
-    max_step: int,
-    primary_index: int,
-    max_sources: int,
-) -> List[str]:
-    """The healer's stripe-candidate pick: addresses of the first
-    ``max_sources - 1`` max-step roster entries beyond the primary, in
-    replica-rank order.  The ONE copy of the math ``manager.py``'s
-    ``_resolve_stripe_sources`` and the IR adapter both consume — the
-    healer and the verifier can not disagree on who stripes."""
-
-    out: List[str] = []
-    for i, p in enumerate(participants):
-        if not isinstance(p, dict):
-            continue
-        if i == primary_index:
-            continue
-        if p.get("step", -1) != max_step:
-            continue
-        addr = str(p.get("address") or "")
-        if addr:
-            out.append(addr)
-        if len(out) >= max_sources - 1:
-            break
-    return out
-
-def stripe_source_cohort(
-    participants: Sequence[Any],
-    max_step: int,
-    max_sources: int,
-) -> List[str]:
-    """Replica ids of the first ``max_sources`` max-step participants in
-    roster order — the superset any healer's :func:`stripe_roster` pick
-    can reach, computed identically on every peer (the source side's
-    "should I stage fragments?" test)."""
-
-    out: List[str] = []
-    for p in participants:
-        if not isinstance(p, dict) or p.get("step") != max_step:
-            continue
-        out.append(str(p.get("replica_id") or ""))
-        if len(out) >= max_sources:
-            break
-    return out
 
 
 def _fragment_slot_runs(

@@ -18,10 +18,10 @@ Wiring:
 - the Python HTTP server advertises the native data port at
   ``/nativeport`` (404 = this node serves fragments from Python only);
 - ``fragments.fetch_raw`` dispatches raw ``frag_*`` GETs through
-  :func:`fetch_native` behind the ``TORCHFT_FRAG_NATIVE`` gate (default
-  on when the ``.so`` is present), falling back to the Python path on
-  any native miss — Mock transports, non-mirrored resources, and
-  gated-off peers keep working unchanged.
+  :func:`fetch_native` wherever the library has the plane
+  (:func:`enabled`), falling back to the Python path on any native miss
+  — Mock transports, non-mirrored resources, and peers without a native
+  port keep working unchanged.
 """
 
 from __future__ import annotations
@@ -38,7 +38,6 @@ from urllib.parse import urlparse
 import numpy as np
 
 from torchft_tpu.utils.bufpool import POOL
-from torchft_tpu.utils.env import env_bool
 
 __all__ = [
     "FragDataServer",
@@ -79,12 +78,9 @@ def available() -> bool:
 
 
 def enabled() -> bool:
-    """The ``TORCHFT_FRAG_NATIVE`` gate: default on when the native
-    library is present; ``0`` forces the pure-Python data plane (Mock
-    transports, mixed-fleet interop, fallback tests).  Read per call so
-    tests can flip the knob without reimporting."""
-    if not env_bool("TORCHFT_FRAG_NATIVE", True):
-        return False
+    """Whether raw fragments ride the native data plane: wherever the
+    library has it.  Called per use, under its own name, so the tests of
+    the Python fallback can patch it off."""
     return available()
 
 

@@ -85,8 +85,8 @@ from torchft_tpu.utils import flightrecorder as _flightrec
 from torchft_tpu.utils import linkstats as _linkstats
 from torchft_tpu.utils import metrics as _metrics
 from torchft_tpu.utils import tracing as _tracing
+from torchft_tpu.utils import wire as _wire
 from torchft_tpu.utils.bufpool import POOL
-from torchft_tpu.utils.env import env_int
 from torchft_tpu.utils.retry import RetryPolicy
 
 __all__ = [
@@ -396,9 +396,12 @@ def decode_payload(
 
 #: Fragments a heal checkpoint is split into (the stripe/delta unit).
 #: More fragments = finer striping + finer deltas but more per-fragment
-#: message overhead; both heal endpoints read the count from the header,
-#: so the knob only needs to be set on the sources.
+#: message overhead; healers read the count from the source's header.
 DEFAULT_HEAL_FRAGMENTS = 8
+
+#: Concurrent fragment fetches per stripe source (each rides its own
+#: persistent connection).
+HEAL_PARALLEL = 2
 
 
 def heal_fragment_names(num_leaves: int, fragments: int) -> "List[str]":
@@ -550,9 +553,7 @@ def iter_heal_fragments(
     import jax
 
     if fragments is None:
-        fragments = env_int(
-            "TORCHFT_HEAL_FRAGMENTS", DEFAULT_HEAL_FRAGMENTS, minimum=1
-        )
+        fragments = DEFAULT_HEAL_FRAGMENTS
     leaves, treedef = jax.tree_util.tree_flatten(state_dict)
     skeleton = jax.tree_util.tree_unflatten(treedef, list(range(len(leaves))))
     names = heal_fragment_names(len(leaves), fragments)
@@ -800,23 +801,12 @@ def _count_fetch_bytes(role: str, nbytes: int) -> None:
         _metrics.SERVING_FETCH_BYTES.labels(role=role).inc(nbytes)
 
 
-_wire_mod: "Optional[Any]" = None
-
-
 def _charge_wire(base: str, nbytes: int) -> float:
-    # WAN wire model (serving/wire.py): one RTT + bytes/rate of source-
+    # WAN wire model (utils/wire.py): one RTT + bytes/rate of source-
     # uplink bucket debt per fetch message crossing the topology
-    # boundary.  Lazily bound: checkpointing must stay importable
-    # without dragging the serving package in at module-import time
-    # (serving's own modules alias THIS module).  Returns the seconds
-    # charged so the link-state plane can fold the modeled WAN cost into
-    # its passive goodput estimate.
-    global _wire_mod
-    if _wire_mod is None:
-        from torchft_tpu.serving import wire as _w
-
-        _wire_mod = _w
-    return _wire_mod.get_shaper().charge(base, nbytes)
+    # boundary.  Returns the seconds charged so the link-state plane can
+    # fold the modeled WAN cost into its passive goodput estimate.
+    return _wire.get_shaper().charge(base, nbytes)
 
 
 #: per-thread first-byte latency of the most recent _request_once (the
@@ -829,8 +819,8 @@ def _record_link(base: str, nbytes: int, seconds: float) -> None:
     (utils/linkstats.py): bytes + whole-message wall (shaper charge
     included — the modeled WAN cost IS the link cost) + first-byte
     latency (connection RTT + the shaper's modeled first-byte leg)."""
-    shaper = _wire_mod.get_shaper()
-    host = _wire_mod.source_host(base) or "unknown"
+    shaper = _wire.get_shaper()
+    host = _wire.source_host(base) or "unknown"
     fb = getattr(_fb_local, "seconds", 0.0) + shaper.first_byte_s(base)
     _linkstats.record(
         host,
@@ -995,10 +985,10 @@ def wire_digest(buf) -> str:
 def _raw_data_plane(
     base: str, path: str, version: int, resource: str, timeout: float
 ) -> np.ndarray:
-    """Route one raw fragment GET: native data plane when armed
-    (``TORCHFT_FRAG_NATIVE``), Python HTTP otherwise and on any native
-    miss.  The miss fallback is what keeps Mock transports, gated-off
-    peers, and non-mirrored resources (manifests, legacy docs) working
+    """Route one raw fragment GET: native data plane where the library
+    has it, Python HTTP otherwise and on any native miss.  The miss
+    fallback is what keeps Mock transports, peers without a native
+    port, and non-mirrored resources (manifests, legacy docs) working
     unchanged — and it is recorded so a fleet silently running the slow
     path shows up in the flight recorder."""
     headers: "Optional[Dict[str, str]]" = None
@@ -1149,20 +1139,15 @@ def fetch_serialized(
 class FragmentFetcher:
     """Bounded-parallel pipelined fragment fetcher.
 
-    ``parallel`` (default ``TORCHFT_SERVING_PARALLEL``) raw fetches ride
-    persistent per-thread connections concurrently; results come back in
-    SUBMISSION order so the consumer's verify/decode/stage of fragment
-    *i* overlaps the wire of fragments *i+1..i+K*.
+    ``parallel`` raw fetches ride persistent per-thread connections
+    concurrently; results come back in SUBMISSION order so the consumer's
+    verify/decode/stage of fragment *i* overlaps the wire of fragments *i+1..i+K*.
     """
 
     def __init__(
-        self, parallel: "Optional[int]" = None, role: str = "client"
+        self, parallel: int = 4, role: str = "client"
     ) -> None:
-        self._parallel = (
-            parallel
-            if parallel is not None
-            else env_int("TORCHFT_SERVING_PARALLEL", 4, minimum=1)
-        )
+        self._parallel = parallel
         self._role = role
         self._pool: "Optional[ThreadPoolExecutor]" = None
         self._lock = threading.Lock()
@@ -1289,7 +1274,6 @@ def striped_fetch(
     names: "List[str]",
     deadline: float,
     digests: "Optional[Dict[str, str]]" = None,
-    parallel: "Optional[int]" = None,
     source_budget: "Optional[float]" = None,
     role: str = "heal",
     on_buf: "Optional[Callable[[str, np.ndarray, str], None]]" = None,
@@ -1307,7 +1291,7 @@ def striped_fetch(
     the one whose manifest defines truth); the rest are max-step quorum
     peers whose state is bitwise-replicated, so any fragment they serve
     must hash to the primary's digest.  Work assignment is dynamic (a
-    shared work queue, ``parallel`` concurrent fetches per source):
+    shared work queue, :data:`HEAL_PARALLEL` concurrent fetches a source):
     faster uplinks finish more fragments, a dead/slow/poisoned source's
     fragments fail over to the survivors, and the fetch only fails when
     EVERY source has been exhausted for some fragment.
@@ -1329,8 +1313,6 @@ def striped_fetch(
     """
     if not sources:
         raise StripeError("striped fetch: no sources")
-    if parallel is None:
-        parallel = env_int("TORCHFT_HEAL_PARALLEL", 2, minimum=1)
     stripes = [_Stripe(s, i == 0) for i, s in enumerate(sources)]
     frag_index = {name: i for i, name in enumerate(names)}
 
@@ -1458,7 +1440,7 @@ def striped_fetch(
 
     threads: "List[threading.Thread]" = []
     for si, stripe in enumerate(stripes):
-        for w in range(max(min(parallel, len(names)), 1)):
+        for w in range(max(min(HEAL_PARALLEL, len(names)), 1)):
             t = threading.Thread(
                 target=_worker, args=(stripe,),
                 name=f"tft_heal_stripe{si}_{w}", daemon=True,

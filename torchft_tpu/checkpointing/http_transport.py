@@ -52,6 +52,11 @@ logger = logging.getLogger(__name__)
 #: plus one superseded generation of each.
 _MAX_STAGED = 4
 
+#: Per-fragment budget on NON-primary stripe sources: a dead/slow/unstaged
+#: peer costs a heal at most this before its fragments fail over (the
+#: primary and the last survivor get the whole remaining deadline).
+HEAL_FAILOVER_S = 2.0
+
 _FETCH_POLICY = RetryPolicy(
     name="transport.http.fetch",
     base_delay=0.05,
@@ -472,7 +477,6 @@ class HTTPTransport(CheckpointTransport[Any]):
         num_chunks: int = 0,
         state_dict_fn: "Optional[Callable[[], Any]]" = None,
         max_staged: int = _MAX_STAGED,
-        native: "Optional[bool]" = None,
     ) -> None:
         self._lock_timeout = timeout
         self._num_chunks = num_chunks
@@ -519,12 +523,11 @@ class HTTPTransport(CheckpointTransport[Any]):
         # serves payload bytes via writev out of pooled buffers, GIL-free.
         # Python keeps every control decision — plans, manifests, staging
         # lifecycle, telemetry — and advertises the data port at
-        # ``/nativeport``.  ``native=None`` follows the
-        # TORCHFT_FRAG_NATIVE gate; any create failure degrades this node
-        # to python-only serving (the mirror is an accelerator, never a
-        # correctness dependency).
+        # ``/nativeport``.  On where the library has the plane; any create
+        # failure degrades this node to python-only serving (the mirror is
+        # an accelerator, never a correctness dependency).
         self._frag_native: "Optional[_fragdata.FragDataServer]" = None
-        if _fragdata.enabled() if native is None else bool(native):
+        if _fragdata.enabled():
             try:
                 self._frag_native = _fragdata.FragDataServer()
             except Exception:
@@ -823,9 +826,9 @@ class HTTPTransport(CheckpointTransport[Any]):
         and the digest manifest lands last.  Returns the manifest.
 
         The step protocol calls this instead of :meth:`send_checkpoint`
-        when streamed heal is enabled (``TORCHFT_HEAL_STREAM``); the
-        staged document serves the same ``frag_*`` resources the serving
-        tier uses, so the whole fragment fetch plane applies."""
+        when the transport carries the fragment protocol
+        (``supports_striped_heal``); the staged document serves the same
+        ``frag_*`` resources the serving tier uses, so the whole fragment fetch plane applies."""
         from torchft_tpu.checkpointing import fragments as frags
 
         _faults.check("transport.send", step=step)
@@ -846,7 +849,7 @@ class HTTPTransport(CheckpointTransport[Any]):
         step: int,
         timeout: float,
         local_state_fn: "Optional[Callable[[], Any]]" = None,
-        delta: "Optional[bool]" = None,
+        delta: bool = True,
         plane: str = "heal",
     ) -> "tuple[Any, dict]":
         """Striped multi-source heal receive (ISSUE 15).
@@ -865,8 +868,8 @@ class HTTPTransport(CheckpointTransport[Any]):
 
         Two modes:
 
-        - **delta** (``TORCHFT_HEAL_DELTA``, on, and a local state
-          snapshot is available): fetch the primary's digest manifest,
+        - **delta** (``delta``, the default, and a local state snapshot
+          is available): fetch the primary's digest manifest,
           hash the local state into the same fragment layout, and fetch
           ONLY the fragments whose digest moved — rejoin wire scales
           with the update delta, not model size.  Every fetched
@@ -914,7 +917,6 @@ class HTTPTransport(CheckpointTransport[Any]):
         from torchft_tpu.checkpointing import fragments as frags
         from torchft_tpu.ops.codec_pool import merged_seconds
         from torchft_tpu.utils.bufpool import POOL
-        from torchft_tpu.utils.env import env_bool, env_float
 
         _faults.check("transport.recv", step=step)
         if not sources:
@@ -935,11 +937,7 @@ class HTTPTransport(CheckpointTransport[Any]):
             1, thread_name_prefix="tft_heal_digest"
         ) as digester:
             local_state, into = self._build_into_map(local_state_fn)
-            use_delta = (
-                delta
-                if delta is not None
-                else env_bool("TORCHFT_HEAL_DELTA", True)
-            ) and local_state is not None
+            use_delta = delta and local_state is not None
             local_leaves = (
                 jax.tree_util.tree_flatten(local_state)[0] if use_delta else []
             )
@@ -1107,13 +1105,10 @@ class HTTPTransport(CheckpointTransport[Any]):
 
             with _tracing.phase("heal_wire", timed) as p_wire:
                 p_decode = _tracing.phase("heal_decode", timed)
-                failover_s = env_float(
-                    "TORCHFT_HEAL_FAILOVER_S", 2.0, minimum=0.05
-                )
                 stats = frags.striped_fetch(
                     sources, step, changed, deadline,
                     digests=manifest.get("digests") if use_delta else None,
-                    source_budget=failover_s,
+                    source_budget=HEAL_FAILOVER_S,
                     on_buf=_decode,
                     plane=plane,
                 )

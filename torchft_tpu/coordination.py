@@ -34,7 +34,6 @@ from torchft_tpu.utils import flightrecorder as _flightrec
 from torchft_tpu.utils import linkstats as _linkstats
 from torchft_tpu.utils import metrics as _metrics
 from torchft_tpu.utils import tracing as _tracing
-from torchft_tpu.utils.env import env_bool
 from torchft_tpu.utils.retry import RetryPolicy
 
 __all__ = [
@@ -322,20 +321,6 @@ class _RpcClient:
         # is off or the step is unsampled — the disabled path is one
         # module-global check (budget-tested in tests/test_tracing.py).
         traceparent = _tracing.current_traceparent()
-        # Opt-in WAN realism for coordination RPCs (TORCHFT_WIRE_RPC=1):
-        # one serving-wire-model charge per round trip — first-byte RTT
-        # across the TORCHFT_TOPOLOGY boundary (payloads are sub-KB, so
-        # bandwidth debt is noise; nbytes=0 skips the bucket).  Scope:
-        # the Python client side only — native peer-to-peer traffic
-        # (lease exchanges, C++ heartbeats) is in-process and unshaped.
-        # Default off (one env test per call; the bench flips it
-        # mid-process, so it cannot be latched at import); the serving
-        # import resolves lazily only when enabled.
-        charged = 0.0
-        if env_bool("TORCHFT_WIRE_RPC", False):
-            from torchft_tpu.serving import wire as _serving_wire
-
-            charged = _serving_wire.get_shaper().charge(self._addr, 0)
         with self._lock:  # tft-lint: allow(lock-discipline)
             for attempt in range(attempts):
                 if self._sock is None:
@@ -360,19 +345,15 @@ class _RpcClient:
                     # rpc-plane link sample: one RTT per round trip (the
                     # whole wall IS first-byte — sub-KB payloads carry no
                     # bandwidth signal, so goodput stays unestimated on
-                    # this plane).  A shaped (TORCHFT_WIRE_RPC) call to a
-                    # local host keys under a WAN pseudo-host so the
-                    # modeled link never averages into the local fabric.
-                    rtt = charged + (time.perf_counter() - t0)
-                    wan_local = charged > 0.0 and self._link_local
+                    # this plane).
+                    rtt = time.perf_counter() - t0
                     _linkstats.record(
-                        self._link_host + "#wan" if wan_local
-                        else self._link_host,
+                        self._link_host,
                         "rpc",
                         len(payload) + len(reply),
                         rtt,
                         first_byte_s=rtt,
-                        local=self._link_local and charged == 0.0,
+                        local=self._link_local,
                     )
                     break
                 except (OSError, ConnectionError) as e:
@@ -698,15 +679,13 @@ class LighthouseServer(_NativeServer):
         join_timeout_ms: int = 100,
         quorum_tick_ms: int = 100,
         heartbeat_timeout_ms: int = 5000,
-        status_page_size: "Optional[int]" = None,
-        straggler_topk: "Optional[int]" = None,
-        timeline_ring: "Optional[int]" = None,
-        serving_fanout: "Optional[int]" = None,
+        status_page_size: int = 16,
+        straggler_topk: int = 8,
+        timeline_ring: int = 256,
+        serving_fanout: int = 2,
         peers: "Optional[Sequence[str] | str]" = None,
-        lease_timeout_ms: "Optional[int]" = None,
+        lease_timeout_ms: int = 1000,
     ) -> None:
-        from torchft_tpu.utils.env import env_int
-
         host, _, port = bind.rpartition(":")
         # Coordination-plane HA: ``peers`` names the OTHER lighthouse
         # peers of the replicated coordination plane (list or comma
@@ -730,23 +709,13 @@ class LighthouseServer(_NativeServer):
             # fleet-scale status plane sizing (docs/observability.md):
             # rows per /status.json + dashboard page, worst-K straggler
             # export, and the cluster step-timeline ring length
-            status_page_size
-            if status_page_size is not None
-            else env_int("TORCHFT_STATUS_PAGE_SIZE", 16, minimum=1),
-            straggler_topk
-            if straggler_topk is not None
-            else env_int("TORCHFT_STRAGGLER_TOPK", 8, minimum=1),
-            timeline_ring
-            if timeline_ring is not None
-            else env_int("TORCHFT_TIMELINE_RING", 256, minimum=1),
+            status_page_size,
+            straggler_topk,
+            timeline_ring,
             # weight-serving distribution-tree arity (serving_plan RPC)
-            serving_fanout
-            if serving_fanout is not None
-            else env_int("TORCHFT_SERVING_FANOUT", 2, minimum=1),
+            serving_fanout,
             peers_csv.encode(),
-            lease_timeout_ms
-            if lease_timeout_ms is not None
-            else env_int("TORCHFT_LIGHTHOUSE_LEASE_MS", 1000, minimum=40),
+            lease_timeout_ms,
         )
         super().__init__(handle)
         self._metrics_cb: Any = None
@@ -1026,7 +995,7 @@ class LighthouseClient:
         The same document as ``GET /status.json``: row arrays
         (``heartbeats``, ``stragglers``, ``prev_quorum.participants``)
         are paginated — ``page``/``per_page`` select a slice (defaults:
-        page 0 of the server's ``TORCHFT_STATUS_PAGE_SIZE``), ``replica``
+        page 0 of the server's ``status_page_size``), ``replica``
         shards every array down to one replica id.  Fleet-wide truth is
         always present regardless of page: ``*_total`` counts, ``pages``,
         ``max_step``, and ``summary`` (counts + the worst-K stragglers by
@@ -1325,6 +1294,54 @@ class StoreClient:
     def close(self) -> None:
         """Close the underlying connection; the client is unusable after."""
         self._client.close()
+
+
+def stripe_roster(
+    participants: Sequence[Any],
+    max_step: int,
+    primary_index: int,
+    max_sources: int,
+) -> List[str]:
+    """The healer's stripe-candidate pick: addresses of the first
+    ``max_sources - 1`` max-step roster entries beyond the primary, in
+    replica-rank order.  The ONE copy of the math ``manager.py``'s
+    ``_resolve_stripe_sources`` and the plan verifier both consume — the
+    healer and the verifier can not disagree on who stripes."""
+
+    out: List[str] = []
+    for i, p in enumerate(participants):
+        if not isinstance(p, dict):
+            continue
+        if i == primary_index:
+            continue
+        if p.get("step", -1) != max_step:
+            continue
+        addr = str(p.get("address") or "")
+        if addr:
+            out.append(addr)
+        if len(out) >= max_sources - 1:
+            break
+    return out
+
+
+def stripe_source_cohort(
+    participants: Sequence[Any],
+    max_step: int,
+    max_sources: int,
+) -> List[str]:
+    """Replica ids of the first ``max_sources`` max-step participants in
+    roster order — the superset any healer's :func:`stripe_roster` pick
+    can reach, computed identically on every peer (the source side's
+    "should I stage fragments?" test)."""
+
+    out: List[str] = []
+    for p in participants:
+        if not isinstance(p, dict) or p.get("step") != max_step:
+            continue
+        out.append(str(p.get("replica_id") or ""))
+        if len(out) >= max_sources:
+            break
+    return out
 
 
 def compute_quorum_results(

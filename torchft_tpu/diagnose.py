@@ -119,9 +119,7 @@ SLOW_LINK_RATIO = 4.0
 # couple of unlucky transfers are noise, not a slow wire
 SLOW_LINK_MIN_SAMPLES = 8
 
-#: protocol-phase name -> critical-path ledger cost category.  The same
-#: mapping bench.py uses for its per-leg dominant-contributor field, so
-#: the bench tail and the trace ledger speak one vocabulary.
+#: protocol-phase name -> critical-path ledger cost category
 PHASE_CATEGORY = {
     "quorum_wait": "straggler-wait",
     "quorum_rpc": "protocol",
@@ -197,7 +195,7 @@ def ledger_categories(phase_times: "Dict[str, Any]") -> "Dict[str, float]":
 
 def dominant_contributor(phase_times: "Dict[str, Any]") -> "Optional[str]":
     """The ledger category that ate the most time, or None on empty/zero
-    input — the one-word answer bench legs and the per-step ledger give."""
+    input — the one-word answer the per-step ledger gives."""
     cats = ledger_categories(phase_times)
     if not cats or max(cats.values()) <= 0.0:
         return None

@@ -1,9 +1,9 @@
 """LighthouseFleet: N in-process lighthouse peers with leased leadership.
 
-The test/bench/smoke harness for coordination-plane HA: picks N free
-ports, starts N native ``LighthouseServer`` peers wired to each other,
-and exposes the leader/term introspection plus targeted kills the chaos
-tests and ``bench.py --ha-failover`` drive.  Production deployments run
+The test/smoke harness for coordination-plane HA: picks N free ports,
+starts N native ``LighthouseServer`` peers wired to each other, and
+exposes the leader/term introspection plus targeted kills the chaos
+tests drive.  Production deployments run
 one ``python -m torchft_tpu.lighthouse --peers ...`` process per node
 instead — the wire behavior is identical.
 """

@@ -43,9 +43,8 @@ def main(argv=None) -> None:
                    help="coordination-plane HA: the FULL lighthouse peer "
                         "list (host1:p,host2:p,...); this peer's own entry "
                         "is dropped by bind port")
-    p.add_argument("--lease-timeout-ms", type=int, default=None,
-                   help="leadership lease duration (default "
-                        "$TORCHFT_LIGHTHOUSE_LEASE_MS or 1000)")
+    p.add_argument("--lease-timeout-ms", type=int, default=1000,
+                   help="leadership lease duration")
     args = p.parse_args(argv)
 
     bind_host, _, bind_port = args.bind.rpartition(":")

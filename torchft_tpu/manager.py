@@ -50,7 +50,14 @@ import numpy as np
 from torchft_tpu.checkpointing import provenance as provenance
 from torchft_tpu.checkpointing import store as fragment_store
 from torchft_tpu.checkpointing.transport import CheckpointTransport
-from torchft_tpu.coordination import ManagerClient, ManagerServer, StoreClient, StoreServer
+from torchft_tpu.coordination import (
+    ManagerClient,
+    ManagerServer,
+    StoreClient,
+    StoreServer,
+    stripe_roster,
+    stripe_source_cohort,
+)
 from torchft_tpu.parallel.process_group import ProcessGroup, REDUCE_AVG, REDUCE_SUM
 from torchft_tpu.parallel.work import Work, completed_work
 from torchft_tpu.utils import faults as faults
@@ -58,7 +65,7 @@ from torchft_tpu.utils import flightrecorder as flightrec
 from torchft_tpu.utils import linkstats as linkstats
 from torchft_tpu.utils import metrics as metrics
 from torchft_tpu.utils import tracing as tracing
-from torchft_tpu.utils.env import env_bool, env_float, env_int, env_str
+from torchft_tpu.utils.env import env_float, env_int, env_str
 from torchft_tpu.utils.logging import ReplicaLogger, log_event
 from torchft_tpu.utils.retry import RetryPolicy
 from torchft_tpu.utils.rwlock import RWLock
@@ -839,10 +846,7 @@ class Manager:
         # the fragment protocol (the flag must be literally True so
         # duck-typed test doubles keep the legacy path).
         streamed_heal = (
-            env_bool("TORCHFT_HEAL_STREAM", True)
-            and getattr(
-                self._checkpoint_transport, "supports_striped_heal", False
-            )
+            getattr(self._checkpoint_transport, "supports_striped_heal", False)
             is True
         )
 
@@ -1039,11 +1043,8 @@ class Manager:
         the superset every healer's ``_resolve_stripe_sources`` pick
         (first ``max_sources - 1`` entries after excluding its primary)
         can reach, computed from the same roster on every peer — via
-        the plan layer's one copy of the first-K math (ISSUE 19:
-        ``tft-verify --scenario plan`` checks the structure this
-        produces)."""
-        from torchft_tpu.analysis.plan_ir import stripe_source_cohort
-
+        the one copy of the first-K math (ISSUE 19: ``tft-verify
+        --scenario plan`` checks the structure this produces)."""
         max_sources = env_int("TORCHFT_HEAL_SOURCES", 4, minimum=1)
         return self._replica_id in stripe_source_cohort(
             quorum.participants, quorum.max_step, max_sources
@@ -1064,11 +1065,8 @@ class Manager:
         use), in parallel and best-effort: an unreachable peer just
         shrinks the stripe.  Bounded by ``TORCHFT_HEAL_SOURCES``
         (total sources including the primary).  The candidate pick is
-        the plan layer's :func:`~torchft_tpu.analysis.plan_ir.
-        stripe_roster` — the same math the tft-plan verifier and the
-        source-side cohort test consume."""
-        from torchft_tpu.analysis.plan_ir import stripe_roster
-
+        :func:`~torchft_tpu.coordination.stripe_roster` — the same math
+        the tft-plan verifier and the source-side cohort test consume."""
         max_sources = env_int("TORCHFT_HEAL_SOURCES", 4, minimum=1)
         candidates = stripe_roster(
             quorum.participants,

@@ -5,7 +5,7 @@ native/quant.cc) is a pure memory-bandwidth kernel whose rows are
 independent — per-row absmax, per-row scale.  A single Python thread can
 therefore only ever use one core of it; this module fans a chunk's rows
 across a small process-wide :class:`~concurrent.futures.ThreadPoolExecutor`
-(``TORCHFT_QUANT_THREADS`` workers, default ``min(cores, 8)``), and the
+(``min(cores, 8)`` workers), and the
 native kernels release the GIL for the duration of each block, so the
 codec scales across cores for BOTH wire formats (int8 and the fp8 RNE
 encode / LUT decode leg).
@@ -18,10 +18,9 @@ tasks stamp with busy intervals — merged at the end into the true
 codec-busy wall, the ``C`` of the overlap-efficiency gauge
 ``torchft_quant_overlap_efficiency`` (docs/observability.md).
 
-The pool is sized once, at first use (``TORCHFT_QUANT_THREADS`` is read
-then); it is shared by every collective and replica rank hosted in the
-process, which keeps total codec concurrency at the machine's core
-budget instead of multiplying per rank.
+The pool is sized once, at first use; it is shared by every collective
+and replica rank hosted in the process, which keeps total codec
+concurrency at the machine's core budget instead of multiplying per rank.
 """
 
 from __future__ import annotations
@@ -32,8 +31,6 @@ import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Callable, List, Optional, Tuple
 
-from torchft_tpu.utils.env import env_int
-
 # Below this many rows a block is not worth a task handoff (~20 us of
 # executor overhead vs ~10 us/64-row-block of codec at 2048 cols).
 MIN_BLOCK_ROWS = 64
@@ -43,10 +40,8 @@ _executor_lock = threading.Lock()
 
 
 def pool_threads() -> int:
-    """Configured codec worker count (``TORCHFT_QUANT_THREADS``)."""
-    return env_int(
-        "TORCHFT_QUANT_THREADS", min(os.cpu_count() or 1, 8), minimum=1
-    )
+    """Codec worker count: the cores, up to 8."""
+    return min(os.cpu_count() or 1, 8)
 
 
 def get_executor(lane: str = "tx") -> ThreadPoolExecutor:

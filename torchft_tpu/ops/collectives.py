@@ -18,9 +18,9 @@ pipeline compressed collectives (PAPERS.md):
 - quantize(chunk i+1)  ∥  alltoall(chunk i)  ∥  reduce-requant(chunk i-1)
   ∥  allgather/dequant of earlier chunks;
 - the codec itself is row-blocked across a small worker pool
-  (``TORCHFT_QUANT_THREADS``, ops/codec_pool.py) driving the GIL-releasing
-  native kernels (native/quant.cc row-range entry points), so both wire
-  formats scale across cores;
+  (ops/codec_pool.py) driving the GIL-releasing native kernels
+  (native/quant.cc row-range entry points), so both wire formats scale
+  across cores;
 - wire buffers, accumulators and reduced pieces cycle through
   ``utils/bufpool.POOL`` — after the first collective of a given shape,
   steady-state allocation is zero.
@@ -116,8 +116,7 @@ def _resolve_chunk_rows(slice_rows: int, cols: int) -> int:
     bandwidth-delay product: growing chunks to hide per-message RTT
     also serializes the codec behind the wire (the overlap r5 built the
     pipeline for), and the latency bill is the hierarchical plan's to
-    cut — by sending fewer inter-host messages, not bigger ones
-    (docs/benchmarks.md §3d)."""
+    cut — by sending fewer inter-host messages, not bigger ones."""
     rows = env_int("TORCHFT_QUANT_CHUNK_ROWS", 0, minimum=0)
     if rows <= 0:
         rows = max(_AUTO_CHUNK_PAYLOAD_BYTES // max(cols, 1), 1)

@@ -32,12 +32,15 @@ from torchft_tpu.utils import flightrecorder as _flightrec
 from torchft_tpu.utils import metrics as _metrics
 from torchft_tpu.utils import tracing as _tracing
 from torchft_tpu.utils.bufpool import POOL
-from torchft_tpu.utils.env import env_float
 from torchft_tpu.utils.retry import RetryPolicy
 
 logger = logging.getLogger(__name__)
 
 __all__ = ["ServingClient", "fetch_resource"]
+
+#: Per-source failover bound of a serving fetch (client and relay): a
+#: dead source costs at most this before the fetch moves on.
+FAILOVER_S = 2.0
 
 
 class _NoServableNodes(RuntimeError):
@@ -80,8 +83,7 @@ class ServingClient:
     Args:
         lighthouse_addr: serving-tier discovery endpoint.
         plan_ttl: seconds a fetched plan is trusted before re-asking the
-            lighthouse (default ``TORCHFT_SERVING_PLAN_TTL_S``); any
-            fetch failure refreshes immediately.
+            lighthouse; any fetch failure refreshes immediately.
         client_id: spreads initial source choice across clients (leaves
             are rotated by its hash) so a client fleet does not dogpile
             one leaf.
@@ -101,7 +103,7 @@ class ServingClient:
     def __init__(
         self,
         lighthouse_addr: str,
-        plan_ttl: "Optional[float]" = None,
+        plan_ttl: float = 2.0,
         client_id: "Optional[str]" = None,
         pin_version: "Optional[int]" = None,
         min_version: int = 0,
@@ -109,11 +111,7 @@ class ServingClient:
         from torchft_tpu.coordination import LighthouseClient
 
         self._client = LighthouseClient(lighthouse_addr)
-        self._plan_ttl = (
-            plan_ttl
-            if plan_ttl is not None
-            else env_float("TORCHFT_SERVING_PLAN_TTL_S", 2.0, minimum=0.0)
-        )
+        self._plan_ttl = plan_ttl
         # Stable rotation seed: hash() varies per process under
         # PYTHONHASHSEED, which would land a RESTARTED client on a
         # different leaf — a sha256 digest keeps the spread deterministic
@@ -128,9 +126,7 @@ class ServingClient:
         self._frag_fetcher = _fetcher.FragmentFetcher(role="client")
         # non-final sources are capped at the failover bound (a killed
         # server costs seconds, not the fetch deadline)
-        self._failover_s = env_float(
-            "TORCHFT_SERVING_FAILOVER_S", 2.0, minimum=0.05
-        )
+        self._failover_s = FAILOVER_S
         self._plan: "Optional[Dict[str, Any]]" = None
         self._plan_at = 0.0
         # previous decoded version for delta fetches

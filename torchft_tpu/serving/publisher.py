@@ -25,11 +25,13 @@ from torchft_tpu.utils import faults as _faults
 from torchft_tpu.utils import flightrecorder as _flightrec
 from torchft_tpu.utils import metrics as _metrics
 from torchft_tpu.utils import tracing as _tracing
-from torchft_tpu.utils.env import env_float, env_int, env_str
 
 logger = logging.getLogger(__name__)
 
 __all__ = ["WeightPublisher"]
+
+#: Staged weight versions a serving node (publisher or relay) retains.
+STAGED_VERSIONS = 4
 
 
 class WeightPublisher:
@@ -41,14 +43,12 @@ class WeightPublisher:
             + discovery address); without it the publisher is a
             standalone staging server reachable by explicit address.
         replica_id: serving-member id (defaults to ``publisher``).
-        wire: payload wire format — ``f32`` or ``int8`` (default from
-            ``TORCHFT_SERVING_QUANT``, f32 when unset).
+        wire: payload wire format — ``f32`` or ``int8``.
         fragments: fragments per payload (the delta-fetch unit; align
-            with the DiLoCo fragment count).  Default
-            ``TORCHFT_SERVING_FRAGMENTS``.
+            with the DiLoCo fragment count).
         max_versions: staged versions retained; a publish burst never
             retires a version inside this window while clients still
-            fetch it.  Default ``TORCHFT_SERVING_VERSIONS``.
+            fetch it.
         store: optional durable :class:`~torchft_tpu.checkpointing.
             store.FragmentStore` — each published document's fragments
             (already-encoded wire bytes + digest manifest) also spill to
@@ -60,28 +60,16 @@ class WeightPublisher:
         self,
         lighthouse_addr: "Optional[str]" = None,
         replica_id: str = "publisher",
-        wire: "Optional[str]" = None,
-        fragments: "Optional[int]" = None,
-        max_versions: "Optional[int]" = None,
-        heartbeat_interval: "Optional[float]" = None,
+        wire: str = _payload.WIRE_F32,
+        fragments: int = 1,
+        max_versions: int = STAGED_VERSIONS,
+        heartbeat_interval: float = 0.5,
         store: "Optional[Any]" = None,
     ) -> None:
         self._store = store
-        self._wire = wire if wire is not None else (
-            env_str("TORCHFT_SERVING_QUANT") or _payload.WIRE_F32
-        )
-        self._fragments = (
-            fragments
-            if fragments is not None
-            else env_int("TORCHFT_SERVING_FRAGMENTS", 1, minimum=1)
-        )
-        self._transport = HTTPTransport(
-            max_staged=(
-                max_versions
-                if max_versions is not None
-                else env_int("TORCHFT_SERVING_VERSIONS", 4, minimum=1)
-            ),
-        )
+        self._wire = wire
+        self._fragments = fragments
+        self._transport = HTTPTransport(max_staged=max_versions)
         self._replica_id = replica_id
         # _version = newest successfully STAGED version (the advertised
         # latest); _reserved = newest version number minted — reserved
@@ -109,14 +97,9 @@ class WeightPublisher:
             from torchft_tpu.coordination import LighthouseClient
 
             self._client = LighthouseClient(lighthouse_addr)
-            interval = (
-                heartbeat_interval
-                if heartbeat_interval is not None
-                else env_float("TORCHFT_SERVING_HB_S", 0.5, minimum=0.01)
-            )
             self._hb_thread = threading.Thread(
                 target=self._hb_loop,
-                args=(interval,),
+                args=(heartbeat_interval,),
                 name="tft_serving_pub_hb",
                 daemon=True,
             )

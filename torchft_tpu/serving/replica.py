@@ -12,8 +12,8 @@ publisher/root source, so a killed interior node degrades depth, never
 availability.
 
 The pull is a **cut-through fragment stream** (ISSUE 14, default;
-``TORCHFT_SERVING_STREAM=0`` restores the whole-payload
-store-and-forward path): the relay fetches the ``frag_manifest`` doc
+``stream=False`` keeps the whole-payload store-and-forward path): the
+relay fetches the ``frag_manifest`` doc
 first, then streams fragments one at a time and restages each the
 moment its publisher-computed sha256 verifies — a child at depth *d*
 overlaps its pull of fragment *i* with this node's pull of fragment
@@ -53,12 +53,13 @@ from torchft_tpu.checkpointing.http_transport import HTTPTransport
 from torchft_tpu.ops.codec_pool import merged_seconds
 from torchft_tpu.serving import fetcher as _fetcher
 from torchft_tpu.serving import payload as _payload
+from torchft_tpu.serving.client import FAILOVER_S
+from torchft_tpu.serving.publisher import STAGED_VERSIONS
 from torchft_tpu.utils import faults as _faults
 from torchft_tpu.utils import flightrecorder as _flightrec
 from torchft_tpu.utils import metrics as _metrics
 from torchft_tpu.utils import tracing as _tracing
 from torchft_tpu.utils.bufpool import POOL
-from torchft_tpu.utils.env import env_bool, env_float, env_int
 
 logger = logging.getLogger(__name__)
 
@@ -74,16 +75,11 @@ class ServingReplica:
             ids determines the synthesized tree position.
         capacity: max children this node accepts (0 = the lighthouse's
             configured fanout).
-        max_versions: staged versions retained (default
-            ``TORCHFT_SERVING_VERSIONS``).
-        poll_interval: heartbeat + version-poll cadence in seconds
-            (default ``TORCHFT_SERVING_POLL_S``).
-        fetch_timeout: per-pull deadline (default
-            ``TORCHFT_SERVING_FETCH_TIMEOUT_S``).
-        stream: cut-through fragment streaming (default
-            ``TORCHFT_SERVING_STREAM``, on); off = whole-payload
-            store-and-forward (the pre-ISSUE-14 path, kept for the
-            depth-axis bench comparison).
+        max_versions: staged versions retained.
+        poll_interval: heartbeat + version-poll cadence in seconds.
+        fetch_timeout: per-pull deadline in seconds.
+        stream: cut-through fragment streaming; off = whole-payload
+            store-and-forward.
     """
 
     def __init__(
@@ -91,43 +87,24 @@ class ServingReplica:
         lighthouse_addr: str,
         replica_id: "Optional[str]" = None,
         capacity: int = 0,
-        max_versions: "Optional[int]" = None,
-        poll_interval: "Optional[float]" = None,
-        fetch_timeout: "Optional[float]" = None,
-        stream: "Optional[bool]" = None,
+        max_versions: int = STAGED_VERSIONS,
+        poll_interval: float = 0.2,
+        fetch_timeout: float = 30.0,
+        stream: bool = True,
     ) -> None:
         from torchft_tpu.coordination import LighthouseClient
 
         self._replica_id = replica_id or f"serve_{uuid.uuid4().hex[:8]}"
         self._capacity = int(capacity)
         self._client = LighthouseClient(lighthouse_addr)
-        self._transport = HTTPTransport(
-            max_staged=(
-                max_versions
-                if max_versions is not None
-                else env_int("TORCHFT_SERVING_VERSIONS", 4, minimum=1)
-            ),
-        )
-        self._poll = (
-            poll_interval
-            if poll_interval is not None
-            else env_float("TORCHFT_SERVING_POLL_S", 0.2, minimum=0.01)
-        )
-        self._fetch_timeout = (
-            fetch_timeout
-            if fetch_timeout is not None
-            else env_float("TORCHFT_SERVING_FETCH_TIMEOUT_S", 30.0, minimum=0.1)
-        )
+        self._transport = HTTPTransport(max_staged=max_versions)
+        self._poll = poll_interval
+        self._fetch_timeout = fetch_timeout
         # Per-source failover bound: a dead source costs at most this
         # before the pull moves on (the LAST candidate gets the full
         # remaining deadline, so a slow-but-alive fleet still completes).
-        self._failover_s = env_float("TORCHFT_SERVING_FAILOVER_S", 2.0,
-                                     minimum=0.05)
-        self._stream = (
-            stream
-            if stream is not None
-            else env_bool("TORCHFT_SERVING_STREAM", True)
-        )
+        self._failover_s = FAILOVER_S
+        self._stream = stream
         self._frag_fetcher = _fetcher.FragmentFetcher(role="relay")
         self._lock = threading.Lock()
         self._version = 0

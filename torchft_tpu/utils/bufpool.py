@@ -24,11 +24,11 @@ caller gets back as the reduced gradients): an UNINITIALIZED 1-d array
 whose memory returns to the pool by itself when it and every view of it
 have been dropped — a slice kept by user code, a ``jax.device_put`` still
 reading it, a sender thread still writing it to a socket all hold it.
-Leased memory is kept outside ``TORCHFT_BUFPOOL_MB``: the pool keeps at
+Leased memory is kept outside ``max_bytes``: the pool keeps at
 most as many bytes of it as the program itself had on lease at once,
 which is what a steady state asks for again (four replica groups in one
 process lease four gradients), and sizes nobody asked for lately go
-first.  ``TORCHFT_BUFPOOL_MB=0`` turns both off.
+first.  ``max_bytes=0`` turns both off.
 """
 
 from __future__ import annotations
@@ -42,11 +42,7 @@ import numpy as np
 
 
 class BufferPool:
-    def __init__(self, max_bytes: "int | None" = None) -> None:
-        if max_bytes is None:
-            from torchft_tpu.utils.env import env_int
-
-            max_bytes = env_int("TORCHFT_BUFPOOL_MB", 2048, minimum=0) << 20
+    def __init__(self, max_bytes: int = 2048 << 20) -> None:
         self.max_bytes = max_bytes
         self._free: "Dict[Tuple[int, str], List[np.ndarray]]" = {}
         self._held = 0

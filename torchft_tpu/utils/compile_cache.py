@@ -1,8 +1,8 @@
 """Persistent XLA compilation cache, placed from outside.
 
 A cold compile of the flagship train step costs tens of seconds on a TPU
-and every process pays it again.  Entry points (``chip_smoke.py``,
-``bench.py``, the example trainers) call :func:`enable_compile_cache`
+and every process pays it again.  Entry points (``chip_smoke.py``, the
+example trainers) call :func:`enable_compile_cache`
 before their first jit so a second run of the same program on the same
 machine loads instead of compiling.
 

@@ -2,7 +2,7 @@
 
 One source of truth for host-locality decisions: the HA peer-list
 self-exclusion (``ha/endpoints.exclude_self``) and the serving-tier
-wire shaper's intra-host exemption (``serving/wire.py``) must agree on
+wire shaper's intra-host exemption (``utils/wire.py``) must agree on
 what "local" means, or a host addressed one way would be excluded from
 its own peer list while the same address is shaped as WAN traffic.
 """

@@ -1135,7 +1135,7 @@ SERVING_WIRE_WAIT = counter(
     "torchft_serving_wire_wait_seconds_total",
     "Seconds serving-tier fetches slept to honor the WAN wire model "
     "(TORCHFT_WIRE_RTT_MS + TORCHFT_WIRE_GBPS across the "
-    "TORCHFT_TOPOLOGY boundary; serving/wire.py), by source peer host — "
+    "TORCHFT_TOPOLOGY boundary; utils/wire.py), by source peer host — "
     "worst-K bounded tier (TORCHFT_LINK_TOPK names + 'other'); the "
     "unlabeled aggregate is the process total",
     ("peer",),

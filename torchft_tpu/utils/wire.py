@@ -1,12 +1,12 @@
-"""Serving-tier WAN wire model (ROADMAP "serving-tier WAN realism").
+"""WAN wire model of the fragment fetch plane (heal and serving).
 
 The training-side shaper (parallel/process_group.py) models the WAN with
 two decoupled legs — ``TORCHFT_WIRE_RTT_MS``, a per-MESSAGE first-byte
 latency, and ``TORCHFT_WIRE_GBPS``, a shared egress token bucket — both
 scoped to messages that cross the ``TORCHFT_TOPOLOGY`` boundary.  This
-module applies the SAME model to the serving tier's fetch/relay HTTP
-pulls, so serving benches and soaks price multi-region distribution
-realistically instead of at loopback speed.
+module applies the SAME model to the fragment plane's HTTP pulls (a
+heal's striped fetch, the serving tier's fetch/relay), so soaks price
+multi-region distribution realistically instead of at loopback speed.
 
 Boundary rule: the serving tier has no rank grid, so the topology
 boundary is tested by HOST — with a declared (non-flat)
@@ -30,6 +30,7 @@ import time
 from typing import Iterable, Optional, Tuple
 from urllib.parse import urlparse
 
+from torchft_tpu.utils import linkstats as _linkstats
 from torchft_tpu.utils import metrics as _metrics
 from torchft_tpu.utils.env import env_float, env_str
 from torchft_tpu.utils.hostident import local_host_identities
@@ -46,6 +47,10 @@ def source_host(source: str) -> str:
     return host or "127.0.0.1"
 
 
+#: Token-bucket depth of one source's uplink (the PG shaper's burst).
+BURST_BYTES = 4 << 20
+
+
 class WireShaper:
     """One shaped serving link: per-message RTT + per-SOURCE token
     buckets.
@@ -57,8 +62,7 @@ class WireShaper:
     deployment) shape independently — which is what lets the depth-axis
     bench see cut-through relays of a chain forwarding concurrently
     instead of serializing every hop through one process-wide bucket.
-    ``burst_bytes`` (``TORCHFT_WIRE_BURST_MB``) is each uplink's bucket
-    depth.
+    :data:`BURST_BYTES` is each uplink's bucket depth.
     """
 
     def __init__(
@@ -67,7 +71,6 @@ class WireShaper:
         gbps: float,
         topology_spec: str,
         local_hosts: "Optional[Iterable[str]]" = None,
-        burst_bytes: int = 4 << 20,
     ) -> None:
         self._rtt_s = max(rtt_ms, 0.0) / 1e3
         self._rate = max(gbps, 0.0) * 1e9  # decimal GB/s, like the PG
@@ -75,7 +78,6 @@ class WireShaper:
         self._local = (
             frozenset(local_hosts) if local_hosts else local_host_identities()
         )
-        self._burst = max(int(burst_bytes), 1)
         # source address -> [tokens, last refill time]
         self._buckets: "dict[str, list[float]]" = {}
         self._lock = threading.Lock()
@@ -109,11 +111,11 @@ class WireShaper:
                 bucket = self._buckets.get(source)
                 if bucket is None:
                     bucket = self._buckets[source] = [
-                        float(self._burst), time.monotonic(),
+                        float(BURST_BYTES), time.monotonic(),
                     ]
                 now = time.monotonic()
                 bucket[0] = min(
-                    float(self._burst),
+                    float(BURST_BYTES),
                     bucket[0] + (now - bucket[1]) * self._rate,
                 )
                 bucket[1] = now
@@ -126,8 +128,6 @@ class WireShaper:
             # per-host-pair attribution: shaped waits and the passively
             # measured goodput (utils/linkstats.py) join on the same
             # peer-host key; the worst-K label tier bounds cardinality
-            from torchft_tpu.utils import linkstats as _linkstats
-
             _metrics.SERVING_WIRE_WAIT.labels(
                 peer=_linkstats.LINKS.peer_topk_label(
                     source_host(source) or "unknown"
@@ -138,7 +138,7 @@ class WireShaper:
 
 _shaper_lock = threading.Lock()
 _shaper: "Optional[WireShaper]" = None
-_shaper_key: "Optional[Tuple[float, float, str, float]]" = None
+_shaper_key: "Optional[Tuple[float, float, str]]" = None
 
 
 def get_shaper() -> WireShaper:
@@ -150,13 +150,9 @@ def get_shaper() -> WireShaper:
         env_float("TORCHFT_WIRE_RTT_MS", 0.0),
         env_float("TORCHFT_WIRE_GBPS", 0.0),
         env_str("TORCHFT_TOPOLOGY", "") or "",
-        env_float("TORCHFT_WIRE_BURST_MB", 4.0, minimum=0.001),
     )
     with _shaper_lock:
         if _shaper is None or key != _shaper_key:
-            _shaper = WireShaper(
-                key[0], key[1], key[2],
-                burst_bytes=int(key[3] * (1 << 20)),
-            )
+            _shaper = WireShaper(*key)
             _shaper_key = key
         return _shaper
