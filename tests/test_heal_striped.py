@@ -1031,7 +1031,7 @@ class TestHealOpened:
         from torchft_tpu.parallel.process_group import ProcessGroupTCP
         from torchft_tpu.utils.faults import InjectedFault
 
-        for _ in range(3):
+        for attempt in range(3):
             params = {f"w{i}": np.zeros(self.N, np.float32) for i in range(6)}
 
             def load_state_dict(sd, params=params):
@@ -1055,6 +1055,12 @@ class TestHealOpened:
             # of two again: it fails here instead of asking for one forever
             alone_by = time.monotonic() + 60.0
             try:
+                if attempt == 0 and out.get("all_built") is not None:
+                    # under the whole suite's load a replica whose thread
+                    # starts 100 ms late misses the first quorum of two and is
+                    # healed into the fleet: a survivor that has applied a
+                    # heal.  Every manager exists before any asks for a quorum.
+                    out["all_built"].wait(timeout=60)
                 while manager.current_step() < steps:
                     step = manager.current_step()
                     if time.monotonic() > alone_by:
@@ -1097,7 +1103,9 @@ class TestHealOpened:
         lighthouse = LighthouseServer(
             min_replicas=2, join_timeout_ms=100, heartbeat_timeout_ms=1000
         )
-        out: dict = {}
+        import threading
+
+        out: dict = {"all_built": threading.Barrier(3)}
         try:
             faults.FAULTS.configure(
                 [FaultRule(site="train.step", replica="replica_1", step=2)]

@@ -107,6 +107,39 @@ def test_flash_attention_compiles_at_the_cells_shapes(one_chip, monkeypatch, cel
     assert hlo.count('custom_call_target="tpu_custom_call"') == (1 if direction == "fwd" else 2)
 
 
+@pytest.mark.parametrize("strict", [False, True], ids=["block-causal", "strictly"])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_attention_under_a_block_mask_compiles_at_the_cells_shapes(one_chip, monkeypatch, direction, strict):
+    """``sdar-ddp1-steady``: 4 rows x 32 heads of 128, 4096 tokens, blocks of
+    4: the mask's ``rem`` and the strict form's empty-row guard on the
+    diagonal tile compile for the chip, under kernel names of their own."""
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    bh, t, d, block = 128, 4096, 128, (4, strict)
+    scale = 1.0 / math.sqrt(d)
+    qkv, row = ((bh, t, d), jnp.bfloat16), ((bh, t), jnp.float32)
+    if direction == "fwd":
+        hlo = _compile(lambda q, k, v: fa._fwd(q, k, v, scale, True, block=block), qkv, qkv, qkv,
+                       sharding=one_chip)
+    else:
+        hlo = _compile(
+            lambda q, k, v, lse, do, delta: fa._bwd(q, k, v, None, lse, do, scale, True, delta=delta, block=block),
+            qkv, qkv, qkv, row, qkv, row, sharding=one_chip)
+    assert hlo.count('custom_call_target="tpu_custom_call"') == (1 if direction == "fwd" else 2)
+
+
+def test_the_block_diffusion_composition_compiles(one_chip, monkeypatch):
+    """One layer's attention of the cell whole, forward and backward: six
+    kernel calls, and the own block's dense part beside them as fusions."""
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    q, kv = ((2, 8192, 32, 128), jnp.bfloat16), ((2, 8192, 4, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return fa.flash_block_diffusion(q, k, v, 4).astype(jnp.float32).sum()
+
+    hlo = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, kv, kv, sharding=one_chip)
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 6
+
+
 @pytest.mark.parametrize("kernel", ["quantize", "dequantize", "reduce"])
 def test_int8_codec_kernel_compiles(one_chip, kernel):
     rows, cols = FRAG_ROWS, FRAG_COLS

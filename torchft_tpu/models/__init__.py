@@ -13,9 +13,14 @@ layer without its shared expert, a head tied to the embedding) and a sparse
 decoder with experts in every layer (``mellum``: windowed beside global
 attention with a rotary table for each kind, the global one YaRN-scaled; the
 same expert layer routed by a softmax over all published experts, holding
-more of them than a token chooses, no shared expert)."""
+more of them than a token chooses, no shared expert) and a sparse decoder
+trained as a block-diffusion model (``sdar``: the Qwen3-MoE block under a step
+that runs a row twice, noised beside clean, under a three-part mask at block
+granularity through ``ops/flash_attention.py`` ``flash_block_diffusion``; a
+loss over the masked positions, weighted by the row's noise level, without a
+shift; the noise a pure function of the row and a seed)."""
 
-from torchft_tpu.models import afmoe, cnn, joyai, kimi_linear, lfm2, mellum, mla, mlp, transformer
+from torchft_tpu.models import afmoe, cnn, joyai, kimi_linear, lfm2, mellum, mla, mlp, sdar, transformer
 from torchft_tpu.models.transformer import (
     TransformerConfig,
     init_params,
@@ -34,6 +39,7 @@ __all__ = [
     "mellum",
     "mla",
     "mlp",
+    "sdar",
     "transformer",
     "TransformerConfig",
     "init_params",

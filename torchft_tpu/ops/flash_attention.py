@@ -41,6 +41,25 @@ Standard FlashAttention-2 scheme, fwd + bwd:
   calls are named
   ``_fwd_window_kernel`` / ``_bwd_kv_window_kernel`` / ``_bwd_q_window_kernel``
   in the compiled program, so a trace tells them from the global ones.
+- a mask at block granularity (``block=b``: query ``i`` sees key ``j`` iff
+  ``i // b >= j // b``; ``strict=True``: ``>``): the causal call's tile walk
+  as it is, since a tile's and a sub-block's rows are whole blocks, with the
+  cut tiles' mask reading blocks (:func:`_visible`).  Under the strict form
+  the rows of block 0 see no key at all: the forward's empty-row guard, until
+  then a window's older edge's, stands on the diagonal tile's first piece
+  (``o`` 0, ``lse`` ~ -inf, no NaN forward or backward).  Named
+  ``_<stem>_block_kernel`` / ``_<stem>_block_strict_kernel``
+  (:func:`_kernel_name`).  ``block=None`` traces to the program it always
+  did.
+- block diffusion (:func:`flash_block_diffusion`): a row run twice, noised
+  beside clean, ``2T`` positions under a three-part mask of which a quarter
+  of the ``[2T, 2T]`` plane is live.  The plane is never formed: the clean
+  copy is one block-causal call, the noised copy one strictly block-causal
+  call on the clean keys whose partial result is merged by log-sum-exp
+  (:func:`merge_partials`, the helper the ring's shards merge through too)
+  with the dense ``[T / b, b, b]`` scores of its own block, and one backward
+  runs both parts under the merged ``lse`` and ``delta``
+  (:func:`_flash_own_block`), as the ring's does across shards.
 - dtypes: matmuls run in the input dtype (bf16 on TPU) with f32
   accumulation; softmax statistics and accumulators are f32 scratch.
 
@@ -143,21 +162,29 @@ def _query_tile(j, step, band, n, by_place=False):
     return jnp.minimum(j + step, n - 1)
 
 
-def _visible(rq, rk, window):
+def _visible(rq, rk, window, block=None):
     """The causal mask of query rows ``rq`` on key rows ``rk``, and under a
-    window its older edge."""
+    window its older edge.  ``block`` = ``(size, strict)``: the mask at block
+    granularity, ``rq // size >= rk // size`` (a query sees its own block
+    whole and every block before it) or, ``strict``, ``>`` (the blocks before
+    its own alone): the key lies before the end, or the start, of the query's
+    block."""
+    if block is not None:
+        size, strict = block
+        start = rq - jax.lax.rem(rq, jnp.int32(size))  # the query's block's first row
+        return rk < (start if strict else start + size)
     if window is None:
         return rq >= rk
     return jnp.logical_and(rq >= rk, rq - rk < window)
 
 
-def _live(shape, ahead, window, by_key=False):
+def _live(shape, ahead, window, by_key=False, block=None):
     """The mask of a piece of scores whose first query lies ``ahead`` rows
-    after its first key; ``by_key``: keys down the rows, queries along the
-    lanes."""
+    after its first key (under ``block`` a multiple of the block's size);
+    ``by_key``: keys down the rows, queries along the lanes."""
     rq = ahead + jax.lax.broadcasted_iota(jnp.int32, shape, 1 if by_key else 0)
     rk = jax.lax.broadcasted_iota(jnp.int32, shape, 0 if by_key else 1)
-    return _visible(rq, rk, window)
+    return _visible(rq, rk, window, block)
 
 
 def _exact_scale(scale: float) -> bool:
@@ -165,6 +192,18 @@ def _exact_scale(scale: float) -> bool:
     power of two (heads of 64 and 256).  Then ``(q * scale) @ k`` is
     ``(q @ k) * scale`` bit for bit, and the scale leaves the pairs."""
     return math.frexp(scale)[0] == 0.5
+
+
+def _kernel_name(stem: str, window, block) -> str:
+    """What a call is named in the compiled program, so that a trace tells the
+    calls apart (the benchmark finds them by these names,
+    ``benchmarks/families/*.py``): ``_fwd_kernel`` / ``_bwd_kv_kernel`` /
+    ``_bwd_q_kernel`` of a global call, ``_<stem>_window_kernel`` of a windowed
+    one, ``_<stem>_block_kernel`` under a block mask and
+    ``_<stem>_block_strict_kernel`` under its strict form."""
+    if block is not None:
+        return f"_{stem}_block_strict_kernel" if block[1] else f"_{stem}_block_kernel"
+    return f"_{stem}_kernel" if window is None else f"_{stem}_window_kernel"
 
 
 # ---------------------------------------------------------------------------
@@ -267,12 +306,16 @@ def _pieces(blk, sub, ahead, window, by_key=False):
     return tuple(pieces)
 
 
-def _can_be_empty(piece, window) -> bool:
+def _can_be_empty(piece, window, block=None) -> bool:
     """Whether a query row of a piece of :func:`_pieces` may have no live key
-    in it: its first row's newest key or its last row's oldest lies outside."""
+    in it: its first row's newest key or its last row's oldest lies outside;
+    under a strict block mask the piece's first block of queries, where no
+    whole block of the piece's keys lies before it."""
     rows, cols, ahead = piece
     if ahead is None:
         return False
+    if block is not None:
+        return block[1] and ahead < block[0]
     last_row, last_col = rows.stop - rows.start - 1, cols.stop - cols.start - 1
     return ahead < 0 or (window is not None and ahead + last_row - window >= last_col)
 
@@ -367,6 +410,7 @@ def call_tiles(name: str) -> "Optional[dict]":
 def _fwd_kernel(
     offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s,
     *, scale, causal, blk_q, blk_k, window=None, cut=None, sub=None, fold=False,
+    block=None,
 ):
     """offs_ref: SMEM int32 [2] = (q_offset, k_offset) GLOBAL positions of
     this call's first query/key row — the ring composition runs the kernel
@@ -378,7 +422,9 @@ def _fwd_kernel(
     ``cut`` (:func:`_cut_tiles`; None: not :func:`_by_place`): what a tile
     needs follows from ``i`` and ``j``; ``sub``: the blocks a cut tile is
     computed in.  ``fold``: the scale
-    is exact and goes onto the query rows, not the scores."""
+    is exact and goes onto the query rows, not the scores.  ``block``
+    (:func:`_visible`): the mask reads blocks; the tiles' places are the
+    causal ones."""
     i = pl.program_id(1)
     step = pl.program_id(2)
     nj = pl.num_programs(2)
@@ -396,7 +442,7 @@ def _fwd_kernel(
         # whether a row may have seen no key yet: decided at run time (offsets),
         # or a piece of a window's older edge
         guard = ahead is not None and (
-            cut is None or _can_be_empty((rows, cols, ahead), window))
+            cut is None or _can_be_empty((rows, cols, ahead), window, block))
         q = q_ref[0, rows]
         if fold:
             q = q * scale
@@ -409,7 +455,7 @@ def _fwd_kernel(
         if not fold:
             s = s * scale
         if ahead is not None:
-            s = jnp.where(_live(s.shape, ahead, window), s, _NEG_INF)
+            s = jnp.where(_live(s.shape, ahead, window, block=block), s, _NEG_INF)
         m_prev = m_s[rows, :1]
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -421,7 +467,8 @@ def _fwd_kernel(
             # offsets (ring chunks where q precedes every k) get an exact
             # zero-weight chunk rather than relying on the combiner's
             # exp-underflow to hide it.  By place only a window's older edge
-            # can hold such a row: elsewhere a row has seen key 0 or its own.
+            # and a strict block mask's first block can hold such a row:
+            # elsewhere a row has seen key 0 or its own.
             p = jnp.where(m_new > _NEG_INF / 2, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
         lanes = (p.shape[0], _LANE)
@@ -457,6 +504,7 @@ def _fwd(
     causal: bool,
     offsets: "Optional[jax.Array]" = None,
     window: "Optional[int]" = None,
+    block: "Optional[Tuple[int, bool]]" = None,
 ) -> "Tuple[jax.Array, jax.Array]":
     bh, tq, d = q3.shape
     tk, dv = k3.shape[1], v3.shape[2]  # values may be narrower than q/k
@@ -474,6 +522,7 @@ def _fwd(
             _fwd_kernel, scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k,
             window=window, cut=_cut_tiles(band, blk_q, window) if by_place else None,
             sub=_sub_block("fwd", blk_q, d, dv), fold=_exact_scale(scale),
+            block=block,
         ),
         grid=grid,
         in_specs=[
@@ -502,7 +551,7 @@ def _fwd(
         interpret=_interpret(),
         # the benchmark finds the three kernels in a trace by these names
         # (benchmarks/families/*.py FLASH_KERNELS)
-        name="_fwd_kernel" if window is None else "_fwd_window_kernel",
+        name=_kernel_name("fwd", window, block),
     )(offsets.astype(jnp.int32), q3, k3, v3)
     return o, lse[:, 0]
 
@@ -515,7 +564,7 @@ def _fwd(
 def _bwd_kv_kernel(
     offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal, blk_q, blk_k,
-    window=None, cut=None, sub=None, fold=False,
+    window=None, cut=None, sub=None, fold=False, block=None,
 ):
     """Keys down the rows, queries along the lanes: the scores are formed as
     ``k qᵀ``, so ``pᵀ`` and ``dsᵀ`` stand as the two products into ``dv`` and
@@ -546,7 +595,7 @@ def _bwd_kv_kernel(
             st = st * scale
         pt = jnp.exp(st - lse_ref[0, :, rows])
         if ahead is not None:
-            pt = jnp.where(_live(pt.shape, ahead, window, by_key=True), pt, 0.0)
+            pt = jnp.where(_live(pt.shape, ahead, window, by_key=True, block=block), pt, 0.0)
         dv_acc[cols] = dv_acc[cols] + jax.lax.dot_general(
             pt.astype(q.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -579,7 +628,7 @@ def _bwd_kv_kernel(
 def _bwd_q_kernel(
     offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref, dq_acc, *, scale, causal, blk_q, blk_k, window=None,
-    cut=None, sub=None, fold=False,
+    cut=None, sub=None, fold=False, block=None,
 ):
     i = pl.program_id(1)  # Q block (outer)
     step = pl.program_id(2)  # K/V block (inner, accumulated)
@@ -606,7 +655,7 @@ def _bwd_q_kernel(
         # lse, delta: [1, rows] lane vectors -> columns (Mosaic relayout)
         p = jnp.exp(s - lse_ref[0, :, rows].reshape(-1, 1))
         if ahead is not None:
-            p = jnp.where(_live(p.shape, ahead, window), p, 0.0)
+            p = jnp.where(_live(p.shape, ahead, window, block=block), p, 0.0)
         dp = jax.lax.dot_general(
             do_ref[0, rows], v_ref[0, cols], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -635,18 +684,19 @@ def _bwd(
     offsets: "Optional[jax.Array]" = None,
     delta: "Optional[jax.Array]" = None,
     window: "Optional[int]" = None,
+    block: "Optional[Tuple[int, bool]]" = None,
 ) -> "Tuple[jax.Array, jax.Array, jax.Array]":
     if delta is None:
         # delta_i = rowsum(dO * O): tiny elementwise pass, plain XLA
         delta = jnp.sum(
             do3.astype(jnp.float32) * o3.astype(jnp.float32), axis=-1
         )
-    args = (q3, k3, v3, lse, do3, delta, scale, causal, offsets, window)
+    args = (q3, k3, v3, lse, do3, delta, scale, causal, offsets, window, block)
     dk, dv = _bwd_kv(*args)
     return _bwd_q(*args), dk, dv
 
 
-def _bwd_operands(q3, k3, v3, lse, do3, delta, scale, causal, offsets, window):
+def _bwd_operands(q3, k3, v3, lse, do3, delta, scale, causal, offsets, window, block):
     """What the two backward calls share: the tiles, the kernels' static
     arguments and their operands (row statistics as ``[bh, 1, t]``)."""
     d, d_v = q3.shape[2], v3.shape[2]
@@ -655,7 +705,7 @@ def _bwd_operands(q3, k3, v3, lse, do3, delta, scale, causal, offsets, window):
     static = dict(
         scale=scale, causal=causal, blk_q=blk, blk_k=blk_kk, window=window,
         cut=_cut_tiles(band, blk, window) if by_place else None,
-        fold=_exact_scale(scale),
+        fold=_exact_scale(scale), block=block,
     )
     if offsets is None:
         offsets = jnp.zeros((2,), jnp.int32)
@@ -665,11 +715,11 @@ def _bwd_operands(q3, k3, v3, lse, do3, delta, scale, causal, offsets, window):
     return blk, blk_kk, band, static, operands
 
 
-def _bwd_kv(q3, k3, v3, lse, do3, delta, scale, causal, offsets, window):
+def _bwd_kv(q3, k3, v3, lse, do3, delta, scale, causal, offsets, window, block):
     bh, tq, d = q3.shape
     tk, d_v = k3.shape[1], v3.shape[2]
     blk, blk_kk, band, static, operands = _bwd_operands(
-        q3, k3, v3, lse, do3, delta, scale, causal, offsets, window
+        q3, k3, v3, lse, do3, delta, scale, causal, offsets, window, block
     )
     n = tq // blk
 
@@ -704,15 +754,15 @@ def _bwd_kv(q3, k3, v3, lse, do3, delta, scale, causal, offsets, window):
             pltpu.VMEM((blk_kk, d_v), jnp.float32),
         ],
         interpret=_interpret(),
-        name="_bwd_kv_kernel" if window is None else "_bwd_kv_window_kernel",
+        name=_kernel_name("bwd_kv", window, block),
     )(*operands)
 
 
-def _bwd_q(q3, k3, v3, lse, do3, delta, scale, causal, offsets, window):
+def _bwd_q(q3, k3, v3, lse, do3, delta, scale, causal, offsets, window, block):
     bh, tq, d = q3.shape
     tk, d_v = k3.shape[1], v3.shape[2]
     blk, blk_kk, band, static, operands = _bwd_operands(
-        q3, k3, v3, lse, do3, delta, scale, causal, offsets, window
+        q3, k3, v3, lse, do3, delta, scale, causal, offsets, window, block
     )
 
     def kv_of(ii, step):
@@ -737,7 +787,7 @@ def _bwd_q(q3, k3, v3, lse, do3, delta, scale, causal, offsets, window):
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), q3.dtype),
         scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)],
         interpret=_interpret(),
-        name="_bwd_q_kernel" if window is None else "_bwd_q_window_kernel",
+        name=_kernel_name("bwd_q", window, block),
     )(*operands)
 
 
@@ -746,40 +796,53 @@ def _bwd_q(q3, k3, v3, lse, do3, delta, scale, causal, offsets, window):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, scale, causal, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, scale, causal, window, block):
     """``[B, T, H, D]`` x 2, ``[B, T, H, Dv]`` -> ``[B, T, H, Dv]``; ``k`` and
     ``v`` carry the query's heads."""
     b, _, h, _ = q.shape
-    o3, _ = _fwd(_to3(q), _to3(k), _to3(v), scale, causal, window=window)
+    o3, _ = _fwd(_to3(q), _to3(k), _to3(v), scale, causal, window=window, block=block)
     return _from3(o3, b, h)
 
 
-def _flash_fwd(q, k, v, scale, causal, window):
-    b, t, h, _ = q.shape
-    o3, lse = _fwd(_to3(q), _to3(k), _to3(v), scale, causal, window=window)
-    # ``o`` is named as the rows a block reads it in, ``[B, T, H Dv]``: the
-    # forward pass lays those out anyway, and a stack of the kernel's own
-    # ``[bh, T, 64]`` would be padded to 128 lanes and hold twice its bytes.
-    # The primal result is read from the named rows, so that a checkpoint
-    # which saves the name needs no second ``o``.
+def _name_results(o3, lse, b, h, tk, d, window):
+    """Names a forward call's two results for a checkpoint to keep, and writes
+    the call's shapes beside them (``FLASH_CALL_NAME``).  ``o`` is named as the
+    rows a block reads it in, ``[B, T, H Dv]``: the forward pass lays those out
+    anyway, and a stack of the kernel's own ``[bh, T, 64]`` would be padded to
+    128 lanes and hold twice its bytes.  The primal result is read from the
+    named rows, so that a checkpoint which saves the name needs no second
+    ``o``."""
+    t, dv = o3.shape[1], o3.shape[2]
     o = checkpoint_name(_from3(o3, b, h).reshape(b, t, -1), FLASH_OUT_NAME)
-    shapes = (b * h, t, k.shape[1], q.shape[3], v.shape[3], window or 0)
+    shapes = (b * h, t, tk, d, dv, window or 0)
     lse = checkpoint_name(lse, ":".join(map(str, (FLASH_CALL_NAME, *shapes))))
-    lse = checkpoint_name(lse, FLASH_LSE_NAME)
+    return o, checkpoint_name(lse, FLASH_LSE_NAME)
+
+
+def _delta(do, o):
+    """``delta_i = rowsum(dO * O)`` as ``[bh, T]``, from the rows as they were
+    kept (``[B, T, H Dv]``)."""
+    b, t, h, _ = do.shape
+    return jnp.sum(
+        do.astype(jnp.float32) * o.reshape(do.shape).astype(jnp.float32), axis=-1
+    ).transpose(0, 2, 1).reshape(b * h, t)
+
+
+def _flash_fwd(q, k, v, scale, causal, window, block):
+    b, t, h, _ = q.shape
+    o3, lse = _fwd(_to3(q), _to3(k), _to3(v), scale, causal, window=window, block=block)
+    o, lse = _name_results(o3, lse, b, h, k.shape[1], q.shape[3], window)
     return o.reshape(b, t, h, -1), (q, k, v, o, lse)
 
 
-def _flash_bwd(scale, causal, window, res, do):
+def _flash_bwd(scale, causal, window, block, res, do):
     q, k, v, o, lse = res
-    b, t, h, _ = q.shape
-    # delta_i = rowsum(dO * O), from the rows as they were kept
-    delta = jnp.sum(
-        do.astype(jnp.float32) * o.reshape(do.shape).astype(jnp.float32), axis=-1
-    ).transpose(0, 2, 1).reshape(b * h, t)
+    b, _, h, _ = q.shape
+    delta = _delta(do, o)
     dq, dk, dv = _bwd(
         _to3(q), _to3(k), _to3(v), None, lse, _to3(do), scale, causal,
-        delta=delta, window=window,
+        delta=delta, window=window, block=block,
     )
     return _from3(dq, b, h), _from3(dk, b, h), _from3(dv, b, h)
 
@@ -789,7 +852,8 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,
-    window: "Optional[int]" = None,
+    window: "Optional[int]" = None, block: "Optional[int]" = None,
+    strict: bool = False,
 ) -> jax.Array:
     """Tiled fused causal attention, ``[B, T, H, D] -> [B, T, H, Dv]``.
 
@@ -801,6 +865,10 @@ def flash_attention(
     and keys of 192 against values of 128); the scale is ``D ** -0.5``.
     ``window``: query ``i`` sees key ``j`` iff ``0 <= i - j < window`` (causal
     self-attention only); the kernels then walk the band's tiles alone.
+    ``block``: the causal mask at block granularity, query ``i`` sees key
+    ``j`` iff ``i // block >= j // block`` (``strict``: ``>``; the rows of
+    block 0 then see no key and come back as zeros); the same tile walk as
+    the causal call's, under kernel names of its own (:func:`_kernel_name`).
     """
     b, t, h, d = q.shape
     if window is not None:
@@ -816,10 +884,153 @@ def flash_attention(
         )
     k, v = _expand_gqa(k, v, h)
     scale = 1.0 / math.sqrt(d)
-    return _flash(q, k, v, scale, causal, window)
+    return _flash(q, k, v, scale, causal, window, _block_mask(block, strict, causal, window, t, k.shape[1]))
 
 
-__all__ = ["flash_attention", "tile_kinds"]
+def _block_mask(block, strict, causal, window, tq, tk) -> "Optional[Tuple[int, bool]]":
+    """``(size, strict)`` as the kernels take a block mask, or None.  A block
+    divides a sub-block's 128 rows, so that the mask cuts the tiles the causal
+    mask cuts; queries and keys are as many, and there is no window."""
+    if block is None:
+        if strict:
+            raise ValueError("strict is the strict form of a block mask: give block")
+        return None
+    if not causal or window is not None or tq != tk or block < 1 or _LANE % block:
+        raise ValueError(
+            f"a block mask needs causal attention without a window, as many keys as "
+            f"queries and a block that divides {_LANE}, got block {block}"
+        )
+    return (int(block), bool(strict))
+
+
+# ---------------------------------------------------------------------------
+# block diffusion: a row run twice, noised beside clean
+# ---------------------------------------------------------------------------
+
+
+def merge_partials(o1, lse1, o2, lse2):
+    """Two partial attention results over disjoint key sets (``o`` ``[..., T,
+    Dv]`` normalised over its own keys, ``lse`` ``[..., T]`` its log-sum-exp)
+    as one softmax over both sets: ``(o, lse)`` in float32.  A side that saw
+    no key (``o`` 0, ``lse`` ~ -inf) gets weight exactly 0.  The ring's shards
+    (:func:`_ring_flash_fwd_impl`) and a block's own keys beside the blocks
+    before it (:func:`_flash_own_block`) merge through this."""
+    m = jnp.maximum(lse1, lse2)
+    w1 = jnp.exp(lse1 - m)
+    w2 = jnp.exp(lse2 - m)
+    denom = jnp.maximum(w1 + w2, 1e-30)
+    o = (
+        o1.astype(jnp.float32) * (w1 / denom)[..., None]
+        + o2.astype(jnp.float32) * (w2 / denom)[..., None]
+    )
+    return o, m + jnp.log(denom)
+
+
+def _own_blocks(x3, size, by_key):
+    """``[bh, T, D] -> [bh, T / size, size, 1, D]`` as queries, ``[bh, T /
+    size, 1, size, D]`` as keys (``by_key``), in float32: a block's rows
+    against its own."""
+    bh, t, d = x3.shape
+    shape = (bh, t // size, 1, size, d) if by_key else (bh, t // size, size, 1, d)
+    return x3.astype(jnp.float32).reshape(shape)
+
+
+def _own_scores(q3, k3, scale, size):
+    """Scores of every query on the keys of its own block: float32 ``[bh, T /
+    size, size (query), size (key)]``, a sum over the head's width on the
+    vector unit (products of ``size`` x ``size`` are no work for the matrix
+    unit)."""
+    return jnp.sum(_own_blocks(q3, size, False) * _own_blocks(k3, size, True), axis=-1) * scale
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _flash_own_block(q, k, v, k_own, v_own, scale, size):
+    """Queries ``[B, T, H, D]`` on two key sets under one softmax: ``k`` /
+    ``v`` (``[B, T, H, D | Dv]``, another copy of the row) under the strict
+    block mask, through the kernels; ``k_own`` / ``v_own`` (the queries' own
+    copy) inside the query's block, both directions, as dense ``[T / size,
+    size, size]`` scores a head.  The two partial results are merged by their
+    log-sum-exp (:func:`merge_partials`), and the backward runs both parts
+    under the merged ``lse`` and ``delta``, as the ring's does across shards.
+    The rows of block 0 see no key of the first set (the kernel gives them
+    ``o`` 0 and ``lse`` ~ -inf): the merge weighs that side 0."""
+    return _flash_own_block_fwd(q, k, v, k_own, v_own, scale, size)[0]
+
+
+def _flash_own_block_fwd(q, k, v, k_own, v_own, scale, size):
+    b, t, h, d = q.shape
+    q3 = _to3(q)
+    o_before, lse_before = _fwd(q3, _to3(k), _to3(v), scale, True, block=(size, True))
+    s = _own_scores(q3, _to3(k_own), scale, size)
+    lse_own = jax.nn.logsumexp(s, axis=-1)
+    p = jnp.exp(s - lse_own[..., None])
+    o_own = jnp.sum(p[..., None] * _own_blocks(_to3(v_own), size, True), axis=3)
+    o3, lse = merge_partials(
+        o_before, lse_before, o_own.reshape(b * h, t, -1), lse_own.reshape(b * h, t))
+    o, lse = _name_results(o3.astype(q.dtype), lse, b, h, t, d, None)
+    return o.reshape(b, t, h, -1), (q, k, v, k_own, v_own, o, lse)
+
+
+def _flash_own_block_bwd(scale, size, res, do):
+    q, k, v, k_own, v_own, o, lse = res
+    b, t, h, _ = q.shape
+    q3, do3, delta = _to3(q), _to3(do), _delta(do, o)
+    dq, dk, dv = _bwd(
+        q3, _to3(k), _to3(v), None, lse, do3, scale, True, delta=delta, block=(size, True))
+    # the own block's part, dense, under the same merged statistics
+    k_own3, v_own3 = _to3(k_own), _to3(v_own)
+    by_block = (b * h, t // size, size, 1)
+    p = jnp.exp(_own_scores(q3, k_own3, scale, size) - lse.reshape(by_block))
+    do5 = _own_blocks(do3, size, False)
+    dp = jnp.sum(do5 * _own_blocks(v_own3, size, True), axis=-1)
+    ds = (p * (dp - delta.reshape(by_block)) * scale)[..., None]
+    dq_own = jnp.sum(ds * _own_blocks(k_own3, size, True), axis=3).reshape(q3.shape)
+    dk_own = jnp.sum(ds * _own_blocks(q3, size, False), axis=2).reshape(k_own3.shape)
+    dv_own = jnp.sum(p[..., None] * do5, axis=2).reshape(v_own3.shape)
+    dq = (dq.astype(jnp.float32) + dq_own).astype(q.dtype)
+    return (_from3(dq, b, h), _from3(dk, b, h), _from3(dv, b, h),
+            _from3(dk_own.astype(k_own.dtype), b, h), _from3(dv_own.astype(v_own.dtype), b, h))
+
+
+_flash_own_block.defvjp(_flash_own_block_fwd, _flash_own_block_bwd)
+
+
+def flash_block_diffusion(q: jax.Array, k: jax.Array, v: jax.Array, block: int) -> jax.Array:
+    """Attention of a block-diffusion training step: ``k`` and ``v`` ``[B, 2T,
+    Hkv, D | Dv]`` hold a row twice, positions ``0..T-1`` its noised copy,
+    ``T..2T-1`` its clean copy, token ``i`` at ``i`` and ``T + i``; with
+    ``blk(p) = (p mod T) // block`` a query sees
+
+    - noised on noised: the keys of its own block, both directions;
+    - noised on clean: the blocks before its own (``blk(q) > blk(k)``);
+    - clean on clean: block-causal (``blk(q) >= blk(k)``);
+    - clean on noised: nothing.
+
+    ``q`` holds both copies' queries (``[B, 2T, H, D] -> [B, 2T, H, Dv]``) or
+    the noised copy's alone (``[B, T, H, D] -> [B, T, H, Dv]``: a last layer,
+    whose clean copy feeds keys and values only).  Of the ``[2T, 2T]`` plane a
+    quarter is live and the plane is never formed: the clean copy is one
+    block-causal call (the causal tile walk, the diagonal tiles' mask reading
+    blocks), the noised copy one strictly block-causal call on the clean keys
+    merged with its own block's dense ``[T / block, block, block]`` scores
+    (:func:`_flash_own_block`).  No dead tile is computed or fetched.  GQA K/V
+    are broadcast up; ``T % 128 == 0``."""
+    b, tq, h, d = q.shape
+    t = k.shape[1] // 2
+    if k.shape[1] % 2 or tq not in (t, 2 * t) or h % k.shape[2]:
+        raise ValueError("k and v hold a row twice (2T positions), q its noised copy's queries or "
+                         "both copies', kv heads dividing the query's")
+    mask = _block_mask(block, False, True, None, t, t)
+    k, v = _expand_gqa(k, v, h)
+    scale = 1.0 / math.sqrt(d)
+    noised = _flash_own_block(q[:, :t], k[:, t:], v[:, t:], k[:, :t], v[:, :t], scale, mask[0])
+    if tq == t:
+        return noised
+    clean = _flash(q[:, t:], k[:, t:], v[:, t:], scale, True, None, mask)
+    return jnp.concatenate([noised, clean], axis=1)
+
+
+__all__ = ["flash_attention", "flash_block_diffusion", "merge_partials", "tile_kinds"]
 
 
 # ---------------------------------------------------------------------------
@@ -883,15 +1094,7 @@ def _ring_flash_fwd_impl(q, k, v, axis_name, causal):
         offs = jnp.stack([idx * tq, kv_idx * tk]).astype(jnp.int32)
         o_s, lse_s = _fwd(q3, _to3(ke), _to3(ve), scale, causal, offs)
         # blockwise softmax combination over chunks (f32)
-        m = jnp.maximum(lse, lse_s)
-        w1 = jnp.exp(lse - m)
-        w2 = jnp.exp(lse_s - m)
-        denom = jnp.maximum(w1 + w2, 1e-30)
-        o3 = (
-            o3.astype(jnp.float32) * (w1 / denom)[..., None]
-            + o_s.astype(jnp.float32) * (w2 / denom)[..., None]
-        )
-        lse = m + jnp.log(denom)
+        o3, lse = merge_partials(o3, lse, o_s, lse_s)
         perm = [(r, (r + 1) % size) for r in range(size)]
         kc = jax.lax.ppermute(kc, axis_name, perm)
         vc = jax.lax.ppermute(vc, axis_name, perm)

@@ -1317,6 +1317,22 @@ MOE_TOKENS_UNROUTED = counter(
     "shared expert alone), by layer",
     ("layer",),
 )
+DIFFUSION_MASKED_SHARE = gauge(
+    "torchft_diffusion_masked_share",
+    "Share of the most recent batch's positions that a block-diffusion "
+    "training step masked (the noised copy's mask tokens over B T); fed from "
+    "models/sdar.py's jitted routing_stats through record_routing_stats when "
+    "a caller asks, never inside a training step",
+    (),
+)
+DIFFUSION_NOISE_LEVEL = gauge(
+    "torchft_diffusion_noise_level",
+    "Masking probability p_b of each row of the most recent batch of a "
+    "block-diffusion training step (a pure function of the row's tokens and "
+    "the configuration's noise_seed; a masked position's loss weighs 1 / p_b); "
+    "fed with torchft_diffusion_masked_share",
+    ("row",),
+)
 LOSS_DEPTH = gauge(
     "torchft_loss_depth",
     "Most recent loss of each prediction depth of a model trained with a "
