@@ -17,7 +17,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from torchft_tpu.models import kimi_linear
 from torchft_tpu.ops import flash_attention as fa
+from torchft_tpu.ops import kda
 from torchft_tpu.ops import pallas_quant as pq
 
 # chip_smoke.py's shapes: flagship attention (6 heads of 256, T 1024, bf16)
@@ -160,3 +162,67 @@ def test_int8_codec_kernel_compiles(one_chip, kernel):
             sharding=one_chip,
         )
     assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_the_delta_rule_kernels_compile_at_the_cells_shapes(one_chip, direction):
+    """``kimi-linear-ddp1-steady``: 4 rows of 4096 steps, 32 heads of 128,
+    chunks of 64, laid out as the model hands them."""
+    b, t, h, d, chunk = 4, 4096, 32, 128, 64
+    hb = kda._HEADS_A_STEP
+    wide, f32 = ((b, t, h * d), jnp.bfloat16), jnp.float32
+    args = [wide, wide, wide, ((b, t, h * d), f32), ((b, h // hb, t, hb), f32)]
+    if direction == "fwd":
+        fn = lambda *a: kda._kda_fwd_kernel_call(*a, heads=h, chunk=chunk, interpret=False)
+    else:
+        fn = lambda *a: kda._kda_bwd_kernel_call(*a, heads=h, chunk=chunk, interpret=False)
+        args += [((b, h, t // chunk, d, d), jnp.bfloat16), wide]
+    assert _compile(fn, *args, sharding=one_chip).count('custom_call_target="tpu_custom_call"') == 1
+
+
+# layers -> the layer bodies with a KDA layer that `layer_plan` makes of them
+KDA_BODIES = {5: 3,    # the cell's cut: layer 1 loose, layers 2-3 one scan of two, layer 4 MLA, layer 5 loose
+              9: 4}    # layer 1 loose, then a scan of two over KDA KDA MLA KDA
+
+
+@pytest.mark.parametrize("layers", KDA_BODIES)
+def test_a_program_lowers_each_delta_rule_kernel_once(one_chip, monkeypatch, layers):
+    """The guard on ``setup_s``: a ``pallas_call`` is turned into a Mosaic
+    module each time a program's lowering meets one it has not met, and no
+    compile cache holds that work.  The grad step calls the forward kernel in
+    every KDA body's forward and again under remat, the backward kernel in
+    every body's backward; behind their ``jit``s each is traced once, so every
+    call is the same equation and the lowering makes the module once
+    (``jax`` 0.9 caches a primitive's lowering by its parameters and copies it
+    to the other sites), whatever the number of bodies."""
+    from jax._src.pallas.mosaic import pallas_call_registration as registration
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    cfg = kimi_linear.KimiLinearConfig(
+        vocab_size=256, d_model=128, n_layers=layers,
+        kda_layers=tuple(n for n in range(1, layers + 1) if n % 4),
+        full_attn_layers=tuple(n for n in range(1, layers + 1) if not n % 4),
+        first_k_dense=1, n_heads=2, kda_heads=4, kda_head_dim=128, kda_gate_rank=32, kv_lora_rank=32,
+        qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32, d_ff=256, d_expert=64,
+        n_routed_experts=16, experts_per_token=4, held_experts=(0, 1, 2, 3), attn_impl="dense")
+    plan = kimi_linear.layer_plan(kimi_linear.layer_kinds(cfg))
+    assert sum(sum(1 for kind in pattern if kind[0] == "kda") for pattern, _ in plan) == KDA_BODIES[layers]
+
+    made = []
+    real = registration.pallas_call_tpu_lowering_rule
+
+    def counted(ctx, *args, **params):
+        made.append(params["name"])
+        return real(ctx, *args, **params)
+
+    monkeypatch.setattr(registration, "pallas_call_tpu_lowering_rule", counted)
+    shapes = jax.eval_shape(lambda key: kimi_linear.init_params(key, cfg), jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes)
+    tokens = jax.ShapeDtypeStruct((2, 256), jnp.int32, sharding=one_chip)
+    text = kimi_linear.make_grad_step(cfg).lower(params, tokens).as_text()
+    assert sorted(made) == ["_kda_bwd_kernel", "_kda_fwd_kernel"]
+    # and both are in the module (the copies: one for each form JAX's
+    # differentiation gives a `jit`, not one a call)
+    assert 1 <= text.count('kernel_name = "_kda_bwd_kernel"') <= 2
+    assert 1 <= text.count('kernel_name = "_kda_fwd_kernel"') <= 2 * KDA_BODIES[layers]

@@ -59,7 +59,7 @@ from torchft_tpu.models.transformer import (
     _rms_norm,
     _swiglu,
 )
-from torchft_tpu.ops.kda import kda_chunked
+from torchft_tpu.ops.kda import kda
 
 Params = Dict[str, Any]
 Kind = Tuple[str, str]  # (attention, ffn) of one layer
@@ -295,13 +295,9 @@ def _kda_attention(h: jax.Array, p: Params, cfg: KimiLinearConfig) -> jax.Array:
         g = log_decay((h @ p["f_a"].astype(act)) @ p["f_b"].astype(act), p["a_log"], p["dt_bias"])
         beta = jax.nn.sigmoid((h @ p["b_proj"].astype(act)).astype(f32))
     with jax.named_scope("kda"):
-        # a row of the batch at a time, each under its own checkpoint: the
-        # chunked form's intermediates (several times q, k, v, g in float32)
-        # then live for one row, not for the batch
-        def one_row(x):
-            return kda_chunked(*(leaf[None] for leaf in x), chunk=cfg.kda_chunk)[0]
-
-        o = jax.lax.map(jax.checkpoint(one_row), (q, k, v, g, beta))
+        # the whole batch: the kernels keep a chunk's insides in VMEM (where
+        # the op falls back to the XLA form it walks the rows itself)
+        o = kda(q, k, v, g, beta, chunk=cfg.kda_chunk)
     with jax.named_scope("kda.proj"):
         o = gated_norm(o, (h @ p["g_a"].astype(act)) @ p["g_b"].astype(act), p["o_norm"])
         return o.reshape(b, t, nh * dh) @ p["wo"].astype(act)

@@ -1342,6 +1342,15 @@ LOSS_DEPTH = gauge(
     "inside a training step",
     ("replica_id", "depth"),
 )
+KDA_CALLS = counter(
+    "torchft_kda_calls_total",
+    "Calls of the chunked delta rule (ops/kda.py kda) by the path they took, "
+    "counted as a program is traced (a scanned layer body once, however many "
+    "layers it stands for): kernels = the Pallas kernels (a TPU, keys and "
+    "values of whole lanes); chunked = the XLA form, off the TPU or at head "
+    "widths that are no multiple of 128",
+    ("path",),
+)
 REMAT_KEPT_BYTES = gauge(
     "torchft_remat_kept_bytes",
     "Bytes of the flash kernel's forward results (the attention output and "
