@@ -316,11 +316,14 @@ int tft_frag_begin(int64_t h, int64_t step) {
   return s == nullptr ? -1 : s->begin(step);
 }
 
+// `sha_hex` (NULL = unknown): the payload's sha256, what a conditional
+// GET of the fragment is held against.
 int tft_frag_stage(int64_t h, int64_t step, const char* resource,
-                   const uint8_t* data, int64_t len) {
+                   const uint8_t* data, int64_t len, const char* sha_hex) {
   tft::FragServer* s = find_frag(h);
   if (s == nullptr || resource == nullptr || len < 0) return -1;
-  return s->stage(step, resource, data, static_cast<size_t>(len));
+  return s->stage(step, resource, data, static_cast<size_t>(len),
+                  sha_hex ? sha_hex : "");
 }
 
 // Staging without the copy (FragServer::reserve / commit / release): a
@@ -335,10 +338,11 @@ uint8_t* tft_frag_reserve(int64_t h, int64_t step, const char* resource,
 }
 
 int tft_frag_commit(int64_t h, int64_t step, const char* resource,
-                    const uint8_t* ptr, int64_t len) {
+                    const uint8_t* ptr, int64_t len, const char* sha_hex) {
   tft::FragServer* s = find_frag(h);
   if (s == nullptr || resource == nullptr || len < 0) return -1;
-  return s->commit(step, resource, ptr, static_cast<size_t>(len));
+  return s->commit(step, resource, ptr, static_cast<size_t>(len),
+                   sha_hex ? sha_hex : "");
 }
 
 int tft_frag_release(int64_t h, const uint8_t* ptr) {
@@ -376,12 +380,16 @@ int tft_frag_inject(int64_t h, const char* mode, int64_t param_ms,
 // ctypes releases the GIL around both calls, so the byte-moving +
 // digest phase never touches the interpreter).  begin returns the HTTP
 // status (200/404/503) or -1 on transport error (tft_frag_client_error).
+// `unless` (NULL = no condition): a sha256 hex; 304 = the source staged
+// the fragment under that digest, and no body follows.
 int tft_frag_fetch_begin(const char* addr, int64_t step,
                          const char* resource, int64_t timeout_ms,
-                         int64_t* content_len, double* first_byte_s) {
+                         const char* unless, int64_t* content_len,
+                         double* first_byte_s) {
   if (addr == nullptr || resource == nullptr) return -1;
   return tft::frag_fetch_begin(addr, step, resource, timeout_ms,
-                               content_len, first_byte_s);
+                               content_len, first_byte_s,
+                               unless ? unless : "");
 }
 
 int tft_frag_fetch_body(uint8_t* buf, int64_t cap, char* sha_hex_out,

@@ -546,6 +546,7 @@ void FragServer::pool_give_locked(FragBuf& buf) {
   if (slot.size() < kPoolPerSizeCap) slot.push_back(std::move(buf.data));
   buf.data.reset();
   buf.len = 0;
+  buf.sha_hex.clear();
 }
 
 void FragServer::deref(const std::shared_ptr<FragBuf>& buf) {
@@ -574,7 +575,8 @@ int FragServer::begin(int64_t step) {
 }
 
 int FragServer::stage(int64_t step, const std::string& resource,
-                      const uint8_t* data, size_t len) {
+                      const uint8_t* data, size_t len,
+                      const std::string& sha_hex) {
   std::shared_ptr<FragBuf> buf;
   {
     std::lock_guard<std::mutex> g(mu_);
@@ -591,6 +593,7 @@ int FragServer::stage(int64_t step, const std::string& resource,
     pool_give_locked(*buf);
     return -1;
   }
+  buf->sha_hex = sha_hex;
   publish_locked(it->second, resource, buf);
   counters_.stage_copy_bytes += static_cast<int64_t>(len);
   return 0;
@@ -611,7 +614,8 @@ uint8_t* FragServer::reserve(int64_t step, const std::string& resource,
 }
 
 int FragServer::commit(int64_t step, const std::string& resource,
-                       const uint8_t* ptr, size_t len) {
+                       const uint8_t* ptr, size_t len,
+                       const std::string& sha_hex) {
   std::lock_guard<std::mutex> g(mu_);
   auto lit = lent_.find(ptr);
   // `retired` on a lend: in no version now (never committed, or replaced
@@ -623,6 +627,7 @@ int FragServer::commit(int64_t step, const std::string& resource,
   auto it = versions_.find(step);
   if (it == versions_.end()) return -1;  // retired while it was written
   lit->second.buf->retired = false;
+  lit->second.buf->sha_hex = sha_hex;
   publish_locked(it->second, resource, lit->second.buf);
   counters_.stage_inplace_bytes += static_cast<int64_t>(len);
   return 0;
@@ -680,6 +685,7 @@ Json FragServer::counters_json() const {
   out["serve_copies"] = c.serve_copies;
   out["serve_bytes"] = c.serve_bytes;
   out["serves"] = c.serves;
+  out["same_replies"] = c.same_replies;
   out["parked_waits"] = c.parked_waits;
   out["busy_replies"] = c.busy_replies;
   out["miss_replies"] = c.miss_replies;
@@ -719,6 +725,14 @@ bool FragServer::reply_simple(int fd, int status, const std::string& body) {
      << "Connection: keep-alive\r\n\r\n"
      << body;
   std::string s = os.str();
+  return write_all(fd, s.data(), s.size(), now_ms() + kServeTimeoutMs,
+                   nullptr);
+}
+
+bool FragServer::reply_same(int fd, const std::string& sha_hex) {
+  std::string s = "HTTP/1.1 304 Not Modified\r\nETag: \"" + sha_hex +
+                  "\"\r\nContent-Length: 0\r\n"
+                  "Connection: keep-alive\r\n\r\n";
   return write_all(fd, s.data(), s.size(), now_ms() + kServeTimeoutMs,
                    nullptr);
 }
@@ -775,6 +789,9 @@ bool FragServer::handle_http_keepalive(int fd,
   // can afford us to hold a not-yet-staged fragment before 503.  Absent
   // header keeps the legacy 250 ms window (mixed-fleet peers).
   int64_t poll_ms = kLongPollMs;
+  // A conditional GET (`If-None-Match: "<sha256 hex>"`): the asker holds
+  // bytes of that digest and wants the fragment only if it is another.
+  std::string unless;
   {
     std::string lower = request_head;
     std::transform(lower.begin(), lower.end(), lower.begin(),
@@ -787,6 +804,13 @@ bool FragServer::handle_http_keepalive(int fd,
       }
       poll_ms = std::max<int64_t>(
           0, std::min<int64_t>(poll_ms, kLongPollCapMs));
+    }
+    hp = lower.find("\r\nif-none-match:");
+    if (hp != std::string::npos) {
+      // the value's hex digits, whatever quotes or blanks surround them
+      for (size_t i = hp + 16; i < lower.size() && lower[i] != '\r'; ++i)
+        if (std::isxdigit(static_cast<unsigned char>(lower[i])))
+          unless.push_back(lower[i]);
     }
   }
 
@@ -875,6 +899,13 @@ bool FragServer::handle_http_keepalive(int fd,
       }
     }
     if (waited) ++counters_.parked_waits;
+    if (!unless.empty() && buf->sha_hex == unless) {
+      // staged under the asker's digest: "same", and no body
+      ++counters_.same_replies;
+      lk.unlock();
+      deref(buf);
+      return reply_same(fd, unless);
+    }
   }
   return serve_frag(fd, buf);
 }
@@ -978,7 +1009,8 @@ int64_t parse_content_length(const std::string& head) {
 
 int frag_fetch_begin(const std::string& addr, int64_t step,
                      const std::string& resource, int64_t timeout_ms,
-                     int64_t* content_len, double* first_byte_s) {
+                     int64_t* content_len, double* first_byte_s,
+                     const std::string& unless) {
   if (g_cli.pending.fd >= 0) {
     // a begin without its body/abort is a caller bug; recover by
     // dropping the wedged connection
@@ -997,6 +1029,7 @@ int frag_fetch_begin(const std::string& addr, int64_t step,
                     "\r\nConnection: keep-alive\r\n";
   if (poll_ms > 0)
     req += "X-TFT-Poll-Ms: " + std::to_string(poll_ms) + "\r\n";
+  if (!unless.empty()) req += "If-None-Match: \"" + unless + "\"\r\n";
   req += "\r\n";
   for (int attempt = 0; attempt < 2; ++attempt) {
     bool fresh = false;
@@ -1045,7 +1078,8 @@ int frag_fetch_begin(const std::string& addr, int64_t step,
       if (content_len) *content_len = length;
       return 200;
     }
-    // small control body (404/503 text): drain it, keep the connection
+    // small control body (404/503 text; none after a 304): drain it,
+    // keep the connection
     char scratch[256];
     int64_t left = length;
     while (left > 0) {

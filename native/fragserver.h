@@ -19,7 +19,13 @@
 //   * complete version + missing fragment -> 404 (the fragment was
 //     never raw-staged natively; Python owns it);
 //   * unknown/retired version -> 404 (Python decides: store-serve,
-//     legacy encode, or a real miss).
+//     legacy encode, or a real miss);
+//   * a request that carries `If-None-Match: "<sha256 hex>"` is parked
+//     and looked up like any other, and answered 304 with no body when
+//     the fragment was staged under that digest (a delta healer holds
+//     those bytes already); the bytes, as ever, when it was staged under
+//     another digest or under none.  A request without the header is
+//     answered exactly as before.
 // All responses are keep-alive: the client pipelines fetches over one
 // persistent connection per (thread, endpoint).
 #pragma once
@@ -45,6 +51,7 @@ struct FragBuf {
   size_t len = 0;                   // its capacity = the payload's length
   int refs = 0;
   bool retired = false;
+  std::string sha_hex;  // the payload's sha256 as its stager gave it, or ""
 };
 
 struct FragCounters {
@@ -55,6 +62,7 @@ struct FragCounters {
   int64_t serve_copies = 0;      // must stay 0: serve is pure writev
   int64_t serve_bytes = 0;
   int64_t serves = 0;
+  int64_t same_replies = 0;  // 304: the asker's digest was the fragment's
   int64_t parked_waits = 0;  // long-polls that actually waited
   int64_t busy_replies = 0;  // 503 retryable-busy answers
   int64_t miss_replies = 0;  // 404 fall-back-to-Python answers
@@ -72,8 +80,11 @@ class FragServer : public RpcServer {
   // return 0 on success, -1 on unknown/retired step (mirror of the
   // Python staging KeyError — callers treat it as "not mirrored").
   int begin(int64_t step);
+  // `sha_hex`: the payload's sha256 where the stager knows it (a heal
+  // source hashes each fragment in the pass that writes it); what a
+  // conditional GET is held against.  "" = unknown: always the bytes.
   int stage(int64_t step, const std::string& resource, const uint8_t* data,
-            size_t len);
+            size_t len, const std::string& sha_hex = "");
   int finish(int64_t step);
   int retire(int64_t step);
 
@@ -89,7 +100,7 @@ class FragServer : public RpcServer {
   // pool only when the lender AND every in-flight serve have let go.
   uint8_t* reserve(int64_t step, const std::string& resource, size_t len);
   int commit(int64_t step, const std::string& resource, const uint8_t* ptr,
-             size_t len);
+             size_t len, const std::string& sha_hex = "");
   int release(const uint8_t* ptr);
 
   FragCounters counters() const;
@@ -125,6 +136,7 @@ class FragServer : public RpcServer {
                       const std::shared_ptr<FragBuf>& buf);
   void deref(const std::shared_ptr<FragBuf>& buf);
   bool reply_simple(int fd, int status, const std::string& body);
+  bool reply_same(int fd, const std::string& sha_hex);
   bool serve_frag(int fd, const std::shared_ptr<FragBuf>& buf);
 
   mutable std::mutex mu_;
@@ -149,14 +161,18 @@ class FragServer : public RpcServer {
 //   frag_fetch_begin  -> request on a per-(thread, endpoint) persistent
 //                        connection; parses the response head; returns
 //                        the HTTP status (200/404/503) or -1 transport
-//                        error, with content length out.
+//                        error, with content length out.  With `unless`
+//                        (a sha256 hex) the request is conditional and
+//                        304 = the source staged the fragment under that
+//                        digest: no body follows.
 //   frag_fetch_body   -> drains the body straight into the caller's
 //                        buffer and computes sha256 over it in-place.
 // A begin that returned 200 MUST be followed by exactly one body/abort.
 
 int frag_fetch_begin(const std::string& addr, int64_t step,
                      const std::string& resource, int64_t timeout_ms,
-                     int64_t* content_len, double* first_byte_s);
+                     int64_t* content_len, double* first_byte_s,
+                     const std::string& unless = "");
 int frag_fetch_body(uint8_t* buf, int64_t cap, char* sha_hex_out /*65B*/,
                     int64_t timeout_ms);
 void frag_fetch_abort();

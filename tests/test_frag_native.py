@@ -462,6 +462,166 @@ class TestStagedInPlace:
             t.shutdown()
 
 
+class TestConditionalGet:
+    """ISSUE 51: "send ``frag_n`` unless it hashes to <my digest of n>".
+    A fragment GET that carries ``If-None-Match`` parks like any other
+    until the fragment lands, and is then answered "same" (304, no body)
+    when the fragment was staged under that digest, with the bytes
+    otherwise.  A GET that carries no condition is answered as ever."""
+
+    PAYLOAD = np.random.default_rng(5).integers(
+        0, 256, size=200_000, dtype=np.uint8
+    )
+
+    @staticmethod
+    def digest(payload) -> str:
+        import hashlib
+
+        return hashlib.sha256(memoryview(payload)).hexdigest()
+
+    @pytest.mark.parametrize("plane", ["native", "python"])
+    def test_parks_then_same_without_a_body_or_the_bytes(
+        self, plane, monkeypatch
+    ):
+        from concurrent.futures import ThreadPoolExecutor
+
+        payload, sha = self.PAYLOAD, self.digest(self.PAYLOAD)
+        other = self.digest(b"some other bytes")
+        # the server is native either way: the python plane is the
+        # fallback a peer without the native plane takes against it
+        t = HTTPTransport(timeout=10.0)
+        if plane == "python":
+            monkeypatch.setattr(fragdata, "enabled", lambda: False)
+        try:
+            base = t.metadata()
+            t.begin_streamed_checkpoint(9, {"frag:header": {"n": 1}})
+            c0 = t._frag_native.counters()
+            with ThreadPoolExecutor(2) as ex:
+                same = ex.submit(
+                    frags.fetch_raw, base, 9, "frag_0", 10.0, "heal",
+                    None, sha,
+                )
+                differs = ex.submit(
+                    frags.fetch_raw, base, 9, "frag_0", 10.0, "heal",
+                    None, other,
+                )
+                time.sleep(0.3)
+                # both parked at the source: the fragment has not landed
+                assert not same.done() and not differs.done()
+                t.stage_streamed_part(9, "frag:0", payload, digest=sha)
+                assert same.result(timeout=10.0) is None
+                got = differs.result(timeout=10.0)
+            assert bytes(memoryview(got)) == payload.tobytes()
+            # a fragment staged under no digest: the bytes, whatever is asked
+            t.stage_streamed_part(9, "frag:1", payload)
+            got = frags.fetch_raw(base, 9, "frag_1", 10.0, "heal", unless=sha)
+            assert bytes(memoryview(got)) == payload.tobytes()
+            # and a restage under none forgets the one it had
+            t.stage_streamed_part(9, "frag:0", payload)
+            got = frags.fetch_raw(base, 9, "frag_0", 10.0, "heal", unless=sha)
+            assert bytes(memoryview(got)) == payload.tobytes()
+            if plane == "native":
+                c = served(t, c0["serves"] + 3)
+                assert c["parked_waits"] - c0["parked_waits"] == 2
+                assert c["same_replies"] - c0["same_replies"] == 1
+                assert c["serves"] - c0["serves"] == 3
+                assert c["serve_bytes"] - c0["serve_bytes"] == 3 * payload.nbytes
+                assert c["serve_copies"] == 0
+            else:
+                assert t._frag_native.counters()["serves"] == c0["serves"]
+                recs = [
+                    r for r in fr.RECORDER.snapshot()
+                    if r.get("op") == "checkpoint.http.send"
+                    and r.get("resource") in ("frag_0", "frag_1")
+                ]
+                assert [r.get("bytes") for r in recs].count(0) == 1
+                assert sum(1 for r in recs if r.get("same")) == 1
+        finally:
+            t.shutdown()
+
+    @pytest.mark.parametrize("plane", ["native", "python"])
+    def test_on_the_wire_304_keeps_the_connection_and_200_is_as_ever(
+        self, plane
+    ):
+        """The two answers as a plain HTTP client sees them, on either
+        server: a 304 with the digest as ETag, no body, the connection
+        kept; and, WITHOUT the condition, exactly the response there was
+        before there were conditions."""
+        import http.client
+        from urllib.parse import urlparse
+
+        payload, sha = self.PAYLOAD, self.digest(self.PAYLOAD)
+        t = HTTPTransport(timeout=10.0)
+        try:
+            stage = dict(digest=sha)
+            t.begin_streamed_checkpoint(9, {"frag:header": {"n": 1}})
+            t.stage_streamed_part(9, "frag:0", payload, **stage)
+            t.finish_streamed_checkpoint(9)
+            u = urlparse(t.metadata())
+            port = t._frag_native.port if plane == "native" else u.port
+            conn = http.client.HTTPConnection(u.hostname, port, timeout=5.0)
+            conn.request(
+                "GET", "/checkpoint/9/frag_0",
+                headers={"If-None-Match": f'"{sha}"'},
+            )
+            resp = conn.getresponse()
+            assert resp.status == 304 and resp.read() == b""
+            assert resp.getheader("ETag") == f'"{sha}"'
+            # the same connection, no condition: today's response
+            conn.request("GET", "/checkpoint/9/frag_0")
+            resp = conn.getresponse()
+            assert resp.status == 200
+            assert resp.read() == payload.tobytes()
+            names = {k.lower() for k, _ in resp.getheaders()}
+            assert resp.getheader("Content-Length") == str(payload.nbytes)
+            assert resp.getheader("Content-Type") == "application/octet-stream"
+            assert "etag" not in names
+            if plane == "native":
+                assert names == {"content-type", "content-length", "connection"}
+            else:
+                assert names == {
+                    "server", "date", "content-type", "content-length"
+                }
+            # a condition that is another digest: the same 200
+            conn.request(
+                "GET", "/checkpoint/9/frag_0",
+                headers={"If-None-Match": '"' + "0" * 64 + '"'},
+            )
+            resp = conn.getresponse()
+            assert resp.status == 200 and resp.read() == payload.tobytes()
+            conn.close()
+        finally:
+            t.shutdown()
+
+    def test_a_spilled_version_answers_same_from_its_manifest(self, tmp_path):
+        """Restore: a version served from the durable store knows every
+        fragment's digest (its manifest), so a restorer that holds the
+        bytes is told "same" without the blob being read."""
+        from torchft_tpu.checkpointing.store import FragmentStore
+
+        state = make_state(leaves=4)
+        store = FragmentStore(str(tmp_path / "store"))
+        t = HTTPTransport(timeout=10.0)
+        try:
+            manifest = store.put_state(3, state, fragments=2)
+            parts = {
+                name: (store.fragment(3, name), sha)
+                for name, sha in manifest["digests"].items()
+            }
+            t.attach_store(store)
+            base = t.metadata()
+            for name, (raw, sha) in parts.items():
+                assert frags.fetch_raw(
+                    base, 3, f"frag_{name}", 5.0, "heal", unless=sha
+                ) is None
+                got = frags.fetch_raw(
+                    base, 3, f"frag_{name}", 5.0, "heal", unless="0" * 64
+                )
+                assert bytes(memoryview(got)) == bytes(memoryview(raw))
+        finally:
+            t.shutdown()
+
+
 class TestNativeChaos:
     def test_kill_native_relay_mid_stripe(self, sources):
         """SIGKILL-equivalent (full shutdown: Python control + native

@@ -279,6 +279,7 @@ def get_lib() -> ctypes.CDLL:
         lib.tft_frag_stage.restype = ctypes.c_int
         lib.tft_frag_stage.argtypes = [
             ctypes.c_int64, ctypes.c_int64, ctypes.c_char_p, _u8p, _i64,
+            ctypes.c_char_p,
         ]
         # a buffer reserved, written by the caller, committed in place
         # and released: addresses cross as plain integers (c_void_p)
@@ -289,7 +290,7 @@ def get_lib() -> ctypes.CDLL:
         lib.tft_frag_commit.restype = ctypes.c_int
         lib.tft_frag_commit.argtypes = [
             ctypes.c_int64, ctypes.c_int64, ctypes.c_char_p,
-            ctypes.c_void_p, _i64,
+            ctypes.c_void_p, _i64, ctypes.c_char_p,
         ]
         lib.tft_frag_release.restype = ctypes.c_int
         lib.tft_frag_release.argtypes = [ctypes.c_int64, ctypes.c_void_p]
@@ -305,7 +306,7 @@ def get_lib() -> ctypes.CDLL:
         ]
         lib.tft_frag_fetch_begin.restype = ctypes.c_int
         lib.tft_frag_fetch_begin.argtypes = [
-            ctypes.c_char_p, _i64, ctypes.c_char_p, _i64,
+            ctypes.c_char_p, _i64, ctypes.c_char_p, _i64, ctypes.c_char_p,
             ctypes.POINTER(_i64), ctypes.POINTER(ctypes.c_double),
         ]
         lib.tft_frag_fetch_body.restype = ctypes.c_int

@@ -41,6 +41,8 @@ from torchft_tpu.utils.bufpool import POOL
 
 __all__ = [
     "FragDataServer",
+    "STREAMING_HEADER",
+    "StillStreaming",
     "available",
     "copy_transposed",
     "enabled",
@@ -82,6 +84,21 @@ def enabled() -> bool:
     library has it.  Called per use, under its own name, so the tests of
     the Python fallback can patch it off."""
     return available()
+
+
+#: the header that marks such a 503 on the Python plane (the native
+#: server answers 503 for a streaming version alone)
+STREAMING_HEADER = "X-TFT-Streaming"
+
+
+class StillStreaming(urllib.error.HTTPError):
+    """The retryable 503 of a source that IS staging the asked version and
+    has not reached the asked fragment yet (its long-poll ran out first).
+    Retried like any 503; to a striped healer it is also a sign of life,
+    which the 503 of a node that has staged nothing is not."""
+
+    def __init__(self, url: str, msg: str = "still streaming") -> None:
+        super().__init__(url, 503, msg, None, None)  # type: ignore[arg-type]
 
 
 def _u8ptr(arr: np.ndarray):
@@ -148,12 +165,15 @@ class FragDataServer:
         ).atexit = False
         return view
 
-    def stage(self, step: int, resource: str, value) -> "Optional[int]":
+    def stage(
+        self, step: int, resource: str, value, digest: "Optional[str]" = None
+    ) -> "Optional[int]":
         """Mirror one raw wire-bytes payload; returns the bytes COPIED to
         do so: 0 for a buffer ``reserve()`` lent for this fragment
         (committed in place), its length otherwise, ``None`` when the
         version is unknown/retired (not mirrored — Python still owns
-        serving it)."""
+        serving it).  ``digest``: the payload's sha256 where the stager
+        knows it, what a conditional GET is held against."""
         mv = memoryview(value)
         if not mv.c_contiguous:
             return None
@@ -163,15 +183,16 @@ class FragDataServer:
             else np.empty(0, dtype=np.uint8)
         )
         name = resource.encode()
+        sha = digest.encode() if digest else None
         # the native side knows its lends by address: anything else (and
         # a lend made for another fragment, or not handed back whole) is
         # refused there and takes the copy
         if self._lib.tft_frag_commit(
-            self._handle, int(step), name, arr.ctypes.data, arr.nbytes
+            self._handle, int(step), name, arr.ctypes.data, arr.nbytes, sha
         ) == 0:
             return 0
         rc = self._lib.tft_frag_stage(
-            self._handle, int(step), name, _u8ptr(arr), arr.nbytes
+            self._handle, int(step), name, _u8ptr(arr), arr.nbytes, sha
         )
         return arr.nbytes if rc == 0 else None
 
@@ -261,8 +282,12 @@ def _resolve_port(base: str, timeout: float) -> "Optional[int]":
 
 
 def fetch_native(
-    base: str, version: int, resource: str, timeout: float
-) -> "Optional[Tuple[np.ndarray, str, float]]":
+    base: str,
+    version: int,
+    resource: str,
+    timeout: float,
+    unless: "Optional[str]" = None,
+) -> "Optional[Tuple[Optional[np.ndarray], str, float]]":
     """Try the native data plane for one raw fragment GET.
 
     Returns ``(pooled uint8 buffer, sha256 hex, first_byte_seconds)`` on
@@ -270,9 +295,12 @@ def fetch_native(
     path (peer has no native server, the fragment isn't mirrored there,
     or the data connection failed — a transport error also invalidates
     the cached port so a stale mapping cannot pin the slow path).
-    Raises ``urllib.error.HTTPError(503)`` for retryable-busy (the
-    cut-through long-poll contract) — exactly the exception surface the
-    fragment retry policy already handles."""
+    With ``unless`` (a sha256 hex) the GET is conditional: a source that
+    staged the fragment under that digest answers "same" with no body,
+    and the buffer returned is ``None``.
+    Raises :class:`StillStreaming` (an ``HTTPError`` 503) for
+    retryable-busy (the cut-through long-poll contract) — exactly the
+    exception surface the fragment retry policy already handles."""
     port = _resolve_port(base, timeout)
     if port is None:
         return None
@@ -287,17 +315,18 @@ def fetch_native(
         int(version),
         resource.encode(),
         timeout_ms,
+        unless.encode() if unless else None,
         ctypes.byref(n),
         ctypes.byref(fb),
     )
     if rc == 503:
-        raise urllib.error.HTTPError(
+        # the native server answers 503 for a streaming version alone
+        raise StillStreaming(
             f"{base}/checkpoint/{version}/{resource}",
-            503,
             "native fragment still streaming",
-            None,  # type: ignore[arg-type]
-            None,
         )
+    if rc == 304 and unless:
+        return None, unless, float(fb.value)
     if rc < 0:
         _drop_port(base)
         return None  # transport error: Python path decides (it shares

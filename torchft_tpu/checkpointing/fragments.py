@@ -109,6 +109,7 @@ __all__ = [
     "heal_fragment_names",
     "iter_heal_fragments",
     "stage_heal_checkpoint",
+    "iter_local_fragment_digests",
     "local_fragment_digests",
     "maybe_decode_heal_doc",
     # fetch plane
@@ -118,6 +119,7 @@ __all__ = [
     "close_connections",
     "striped_fetch",
     "StripeError",
+    "StillStreaming",
 ]
 
 WIRE_F32 = "f32"
@@ -200,7 +202,15 @@ class _ViewReader(io.RawIOBase):
 
     def readinto(self, b: Any) -> int:
         n = min(len(b), len(self._view) - self._off)
-        b[:n] = self._view[self._off:self._off + n]
+        if n:
+            # numpy's copy and not a memoryview's slice assignment, which
+            # holds the interpreter's lock for the whole leaf (100 ms for
+            # 100 MB): a healer decodes beside its sources' encode, whose
+            # pass takes the lock back for every block (PERF.md, PR 51)
+            np.copyto(
+                np.frombuffer(b, np.uint8, n),
+                np.frombuffer(self._view, np.uint8, n, self._off),
+            )
         self._off += n
         return n
 
@@ -600,14 +610,17 @@ def stage_heal_checkpoint(
 ) -> "Dict[str, Any]":
     """Stage ``state_dict`` for heal as a CUT-THROUGH fragment stream.
 
-    The digest-less header is staged first (healers fetch it and start
-    striping immediately), each fragment is staged the moment it
-    encodes (healer wire overlaps source snapshot/encode — the
-    transport's fragment long-poll hands each one out one round trip
-    after it lands), and the full manifest (with every digest) lands
-    LAST, which is also what flips the slot complete.  Returns the
-    manifest so the source can keep its own digests for delta
-    bookkeeping.
+    The digest-less header is staged first (every healer fetches it and
+    starts striping at once, whether or not it has state of its own to
+    compare), each fragment is staged the moment it encodes, UNDER ITS
+    DIGEST, known at that moment (healer wire overlaps source
+    snapshot/encode — the transport's fragment long-poll hands each one
+    out one round trip after it lands, or answers a healer that asked for
+    it "unless it hashes to mine" with "same" and no body), and the full
+    manifest (with every digest) lands LAST, which is also what flips the
+    slot complete: a healer fetches it when its stripe has drained and
+    holds everything it took or kept against it.  Returns the manifest so
+    the source can keep its own digests for delta bookkeeping.
 
     Each fragment's bytes are written once, into the buffer the
     transport will serve them from (``reserve_streamed_part``: invisible
@@ -636,7 +649,8 @@ def stage_heal_checkpoint(
         for name, raw, digest in frag_iter:
             with _tracing.phase(".stage", fragment=name, bytes=raw.nbytes):
                 copied += transport.stage_streamed_part(
-                    step, f"frag:{name}", raw, pooled=True, timeout=timeout
+                    step, f"frag:{name}", raw, pooled=True, timeout=timeout,
+                    digest=digest,
                 )
             digests[name] = digest
     except BaseException:
@@ -666,14 +680,16 @@ def stage_heal_checkpoint(
     return manifest
 
 
-def local_fragment_digests(
+def iter_local_fragment_digests(
     state_dict: Any, fragments: int
-) -> "Tuple[int, Dict[str, str]]":
+) -> "Iterator[Tuple[str, str]]":
     """Hash ``state_dict`` IN PLACE (no wire bytes built, no staging) into
-    the heal fragment layout and return ``(num_leaves, {name: sha256})`` —
-    the delta-heal diff base: a rejoiner whose fragment hashes to the same
-    digest as the source's already holds those bytes bitwise and skips
-    their wire entirely.
+    the heal fragment layout, yielding ``(name, sha256)`` A FRAGMENT AT A
+    TIME, in the layout's order — the delta-heal diff base: a rejoiner
+    whose fragment hashes to the same digest as the source's already holds
+    those bytes bitwise and skips their wire entirely, and it can say so
+    of fragment *n* (a conditional GET, :func:`fetch_raw`'s ``unless``)
+    while it is still hashing *n + 1*.
 
     Per fragment: the same host snapshot of the device leaves as
     :func:`iter_heal_fragments` takes, then ``serialization.prepare``'s
@@ -683,17 +699,17 @@ def local_fragment_digests(
     ``sha256(ser.serialize(frag))``, with nothing allocated beyond the
     snapshot and one scratch block, through which a leaf that lies as the
     device held it is re-ordered on its way into the digest.  Each fragment is timed as the parts
-    ``.snapshot`` and ``.hash`` of whatever phase the caller has open
-    (``heal_diff``).  One fragment after another on the caller's thread,
+    ``.snapshot`` and ``.hash`` of whatever phase the consumer has open
+    (``heal_diff``).  One fragment after another on the consumer's thread,
     though both parts release the interpreter's lock: a healer runs this
-    beside its sources' encode, and three or four threads of it slowed
-    the sources by 0.2-1.0 s on a v5e's host, to end sooner inside a wait
-    that hides one thread's work whole (PERF.md section 6, PR 42)."""
+    beside its sources' encode and its own fetch, and three or four
+    threads of it slowed the sources by 0.2-1.0 s on a v5e's host, to end
+    sooner than one thread, which stays ahead of a source's pass as it is
+    (PERF.md section 6, PR 42 and PR 51)."""
     import jax
 
     leaves = jax.tree_util.tree_flatten(state_dict)[0]
     names = heal_fragment_names(len(leaves), fragments)
-    digests: "Dict[str, str]" = {}
     for name in names:
         with _tracing.phase(".snapshot", fragment=name):
             frag = _snapshot(
@@ -703,8 +719,21 @@ def local_fragment_digests(
         with _tracing.phase(".hash", fragment=name) as p_hash:
             p_hash.attrs["bytes"], writer = ser.prepare(frag)
             writer(_HashedWrite(sha))  # nothing kept: the digest alone
-        digests[name] = sha.hexdigest()
-    return len(leaves), digests
+        del frag, writer
+        yield name, sha.hexdigest()
+
+
+def local_fragment_digests(
+    state_dict: Any, fragments: int
+) -> "Tuple[int, Dict[str, str]]":
+    """``(num_leaves, {name: sha256})``: every digest of
+    :func:`iter_local_fragment_digests`, once all are taken."""
+    import jax
+
+    return (
+        len(jax.tree_util.tree_flatten(state_dict)[0]),
+        dict(iter_local_fragment_digests(state_dict, fragments)),
+    )
 
 
 def maybe_decode_heal_doc(doc: Any) -> Any:
@@ -809,7 +838,8 @@ def _charge_wire(base: str, nbytes: int) -> float:
     return _wire.get_shaper().charge(base, nbytes)
 
 
-#: per-thread first-byte latency of the most recent _request_once (the
+#: per-thread first-byte latency of the most recent _request_once, and
+#: (``body_from``) the ``perf_counter`` at which that answer began (the
 #: fetch planes are thread-confined, like the keep-alive connections)
 _fb_local = threading.local()
 
@@ -831,6 +861,11 @@ def _record_link(base: str, nbytes: int, seconds: float) -> None:
         local=not shaper.crosses_boundary(base),
     )
 
+
+#: what a conditional fragment GET and a "streaming, not yet" 503 are
+#: known by, on both planes (``native/fragserver.cc`` answers the first
+#: and raises the second itself, through ``fragdata.fetch_native``)
+StillStreaming = _fragdata.StillStreaming
 
 _conns = threading.local()
 
@@ -878,10 +913,13 @@ def _request_once(
     extra_headers: "Optional[Dict[str, str]]" = None,
 ) -> http.client.HTTPResponse:
     """One GET over the cached keep-alive connection; returns the live
-    200 response (the caller consumes the body).  Raises
-    ``urllib.error.HTTPError`` on non-200 (503 = retryable
-    not-yet-staged, drained so the connection stays reusable) and
-    ``ConnectionError`` / ``OSError`` on transport failure."""
+    200 response (the caller consumes the body), or the 304 that answers
+    a request carrying ``If-None-Match`` (no body).  Raises
+    ``urllib.error.HTTPError`` on anything else (503 = retryable
+    not-yet-staged, drained so the connection stays reusable;
+    :class:`StillStreaming` where the source says it is staging the
+    version) and ``ConnectionError`` / ``OSError`` on transport
+    failure."""
     conn = _conn_for(base, timeout)
     headers = dict(extra_headers) if extra_headers else {}
     traceparent = _tracing.current_traceparent()
@@ -894,10 +932,19 @@ def _request_once(
         # observed first-byte latency of this request (headers arrived);
         # the link-state plane adds the shaper's modeled RTT on top
         _fb_local.seconds = time.perf_counter() - t0
+        if resp.status == 304 and "If-None-Match" in headers:
+            resp.read()
+            return resp
         if resp.status != 200:
             body = resp.read()  # drain so the connection could be reused
             if resp.will_close:
                 _drop_conn(base)
+            if resp.status == 503 and resp.headers.get(
+                _fragdata.STREAMING_HEADER
+            ):
+                raise StillStreaming(
+                    f"{base}{path}", body[:200].decode("utf-8", "replace")
+                )
             raise urllib.error.HTTPError(
                 f"{base}{path}",
                 resp.status,
@@ -918,9 +965,12 @@ def _request_once(
 def _get_raw_once(
     base: str, path: str, timeout: float,
     extra_headers: "Optional[Dict[str, str]]" = None,
-) -> np.ndarray:
-    """One GET returning a POOLED uint8 buffer the caller owns."""
+) -> "Optional[np.ndarray]":
+    """One GET returning a POOLED uint8 buffer the caller owns; ``None``
+    for the "same" that answers a conditional request."""
     resp = _request_once(base, path, timeout, extra_headers)
+    if resp.status == 304:
+        return None
     try:
         n = int(resp.headers.get("Content-Length") or 0)
         buf = POOL.take(n, np.uint8)
@@ -983,15 +1033,23 @@ def wire_digest(buf) -> str:
 
 
 def _raw_data_plane(
-    base: str, path: str, version: int, resource: str, timeout: float
-) -> np.ndarray:
+    base: str,
+    path: str,
+    version: int,
+    resource: str,
+    timeout: float,
+    unless: "Optional[str]" = None,
+) -> "Optional[np.ndarray]":
     """Route one raw fragment GET: native data plane where the library
     has it, Python HTTP otherwise and on any native miss.  The miss
     fallback is what keeps Mock transports, peers without a native
     port, and non-mirrored resources (manifests, legacy docs) working
     unchanged — and it is recorded so a fleet silently running the slow
-    path shows up in the flight recorder."""
-    headers: "Optional[Dict[str, str]]" = None
+    path shows up in the flight recorder.  ``unless``: see
+    :func:`fetch_raw`; ``None`` comes back for "same"."""
+    headers: "Dict[str, str]" = (
+        {"If-None-Match": f'"{unless}"'} if unless else {}
+    )
     if resource.startswith("frag_"):
         # Client-driven cut-through park (X-TFT-Poll-Ms): ask the server
         # to hold a not-yet-staged fragment as long as our own budget
@@ -1000,18 +1058,23 @@ def _raw_data_plane(
         # margin keeps the park ending before our socket deadline.
         poll_ms = int(min(max(timeout * 1000 - 150, 0), 5000))
         if poll_ms > 0:
-            headers = {"X-TFT-Poll-Ms": str(poll_ms)}
+            headers["X-TFT-Poll-Ms"] = str(poll_ms)
         # Not the header: a control part, never mirrored to the native
         # server (``HTTPTransport._native_stage``), which takes a name it
         # does not hold in a streaming version for a fragment still to
         # land and parks the request, 503 after 503, until the version is
         # complete — the one resource staged to be read BEFORE that.
         if _fragdata.enabled() and resource != f"frag_{HEADER_FRAG}":
-            got = _fragdata.fetch_native(base, version, resource, timeout)
+            t_asked = time.perf_counter()
+            got = _fragdata.fetch_native(
+                base, version, resource, timeout, unless
+            )
             if got is not None:
                 buf, sha_hex, first_byte_s = got
                 _fb_local.seconds = first_byte_s
-                _note_native_digest(buf, sha_hex)
+                _fb_local.body_from = t_asked + first_byte_s
+                if buf is not None:
+                    _note_native_digest(buf, sha_hex)
                 return buf
             _flightrec.record(
                 "fragment.native_fallback",
@@ -1019,7 +1082,12 @@ def _raw_data_plane(
                 resource=resource,
                 source=base,
             )
-    return _get_raw_once(base, path, timeout, headers)
+    t_asked = time.perf_counter()
+    buf = _get_raw_once(base, path, timeout, headers or None)
+    # when the answer began: what came before is the source's park, not
+    # the wire (``_request_once`` timed the first byte)
+    _fb_local.body_from = t_asked + getattr(_fb_local, "seconds", 0.0)
+    return buf
 
 
 def fetch_raw(
@@ -1029,10 +1097,21 @@ def fetch_raw(
     timeout: float,
     role: str = "client",
     frag_index: "Optional[int]" = None,
-) -> np.ndarray:
+    unless: "Optional[str]" = None,
+    on_retry: "Optional[Callable[[BaseException, int, float], None]]" = None,
+) -> "Optional[np.ndarray]":
     """Fetch one staged resource as raw wire bytes (POOLED uint8 buffer —
     the caller owns giving it back or staging it), with the 503-poll
     retry, the WAN wire-model charge, and per-fragment telemetry.
+
+    ``unless`` (a sha256 hex) makes the GET conditional: "send it unless
+    it hashes to this" (``If-None-Match``).  A source that staged the
+    fragment under that digest answers "same" with no body, and ``None``
+    is returned; one that staged it under another digest, or knows none
+    (or not the header), sends the bytes as ever.  Parked and retried
+    like any request.  ``on_retry`` sees every error the policy retries
+    (``RetryPolicy.run``): a :class:`StillStreaming` among them is the
+    source saying it is alive.
 
     ``role`` selects the telemetry identity: serving roles consult the
     ``serving.frag`` chaos site and record ``serving.frag``; ``"heal"``
@@ -1052,10 +1131,20 @@ def fetch_raw(
             step=frag_index if frag_index is not None else version,
         )
         t = max(budget if budget is not None else 0.001, 0.001)
-        return _raw_data_plane(base, path, version, resource, t)
+        return _raw_data_plane(base, path, version, resource, t, unless)
 
     t0p = time.perf_counter()
-    buf = policy.run(attempt, timeout=max(timeout, 0.001), op=site)
+    buf = policy.run(
+        attempt, timeout=max(timeout, 0.001), op=site, on_retry=on_retry
+    )
+    if buf is None:
+        # "same": a round trip and no payload; nothing for the wire
+        # model, the link estimate or the byte counters to weigh
+        _flightrec.record(
+            record, start_ns=t0_ns, step=version, resource=resource,
+            bytes=0, same=1, source=base, role=role,
+        )
+        return None
     wall_s = time.perf_counter() - t0p
     wall_s += _charge_wire(base, buf.nbytes)
     _record_link(base, buf.nbytes, wall_s)
@@ -1278,9 +1367,11 @@ def striped_fetch(
     role: str = "heal",
     on_buf: "Optional[Callable[[str, np.ndarray, str], None]]" = None,
     plane: str = "heal",
+    unless: "Optional[Callable[[str], Optional[str]]]" = None,
 ) -> "Dict[str, Any]":
     """Fetch ``names`` striped across ``sources`` in parallel with
-    per-fragment failover.
+    per-fragment failover.  One path for every healer; what it can put
+    into a request and what a source has staged so far decide the rest.
 
     ``plane`` is the provenance-plane identity of these transfers
     (``heal`` for live heals, ``restore`` when the stripe sources are
@@ -1296,20 +1387,45 @@ def striped_fetch(
     fragments fail over to the survivors, and the fetch only fails when
     EVERY source has been exhausted for some fragment.
 
+    The sources may still be STAGING the version (a heal begins at the
+    header): a request for a fragment not yet encoded parks at the source
+    and is answered the moment it lands.  ``source_budget`` therefore
+    bounds what a non-primary source may cost WITHOUT A SIGN OF LIFE, not
+    how long its encode may take: its "streaming, not yet" answer
+    (:class:`StillStreaming`, when a long-poll runs out) renews the
+    budget, while a refused connection, silence, or the 503 of a node that
+    has staged nothing does not, and marks it dead at the bound as ever.
+
+    ``unless(name)`` gives the caller's own digest of fragment ``name``
+    (it may block until that is known; ``None``: none), and the request
+    becomes conditional (:func:`fetch_raw`): a source that staged the
+    fragment under that digest answers "same" and no body crosses the
+    wire; ``on_buf`` is not called and the name is reported in ``same``
+    with the source that said so.  Bytes that arrive all the same and
+    hash to the caller's digest (a source that knows no digests) are
+    given back and reported there too.  THE CALLER VERIFIES a "same"
+    against the primary's manifest, as it does ``hashes``: a source may
+    lie.
+
     With ``digests``, each fragment is verified the moment it lands
-    (mismatch = dead source, fragment requeued — delta-heal mode);
-    without, the caller verifies later against the sha256 handed to
-    ``on_buf`` (full-heal mode: the manifest lands after the stream).
+    (mismatch = dead source, fragment requeued: the repair pass of a
+    heal, a restore from disks); without, the caller verifies later
+    against the sha256 handed to ``on_buf`` (the manifest lands after
+    the stream).
 
     ``on_buf(name, pooled_buffer, sha256)`` is invoked on the CALLER
     thread for each completed fragment, in arrival order — decode of
-    fragment *i* overlaps the wire of every in-flight stripe.  Buffer
-    ownership transfers to the callback.
+    fragment *i* overlaps the wire of every in-flight stripe and the
+    sources' encode of the ones after.  Buffer ownership transfers to
+    the callback.
 
     Returns stats: ``{"wire_bytes", "failovers", "spans", "hashes",
-    "sources_used"}`` — ``sources_used`` is the set of source addresses
-    that actually delivered at least one fragment (a degraded stripe is
-    visible as fewer used sources than configured).
+    "sources_used", "same", "landed", "dead"}`` — ``sources_used`` is
+    the set of source addresses that actually delivered at least one
+    fragment (a degraded stripe is visible as fewer used sources than
+    configured), ``landed`` each delivered fragment's ``(source, bytes,
+    time.time_ns() it was whole here)``, ``dead`` the sources given up
+    on.
     """
     if not sources:
         raise StripeError("striped fetch: no sources")
@@ -1323,7 +1439,7 @@ def striped_fetch(
     cv = threading.Condition()
     work: "deque[str]" = deque(names)
     done: "Set[str]" = set()
-    out_q: "deque[Tuple[str, np.ndarray, str, Tuple[float, float]]]" = deque()
+    out_q: "deque[Tuple[str, Optional[np.ndarray], str]]" = deque()
     last_err: "List[BaseException]" = []
     stopped = False
     failovers = 0
@@ -1331,6 +1447,8 @@ def striped_fetch(
     inflight = 0
     spans: "List[Tuple[float, float]]" = []
     hashes: "Dict[str, str]" = {}
+    same: "Dict[str, str]" = {}
+    landed: "Dict[str, Tuple[str, int, int]]" = {}
     sources_used: "Set[str]" = set()
 
     def _alive_locked() -> int:
@@ -1346,6 +1464,21 @@ def striped_fetch(
             failovers += 1
             _metrics.HEAL_FRAG_FAILOVERS.inc()
         cv.notify_all()
+
+    def _budget_locked(stripe: "_Stripe") -> float:
+        # Non-primary sources are capped so a dead one costs the
+        # failover bound, not the whole heal; the primary (and the last
+        # stripe standing) gets the full remaining deadline — striping
+        # must never make the heal LESS available than the
+        # single-source path it replaced.
+        remaining = deadline - time.monotonic()
+        if (
+            source_budget is not None
+            and not stripe.is_primary
+            and _alive_locked() > 1
+        ):
+            return min(source_budget, remaining)
+        return remaining
 
     # the caller's per-step trace context rides into the worker threads
     # so every heal.frag span (and the traceparent header the source's
@@ -1366,39 +1499,71 @@ def striped_fetch(
                         break
                     # idle but not finished: a failing peer may requeue
                     cv.wait(0.02)
-                remaining = deadline - time.monotonic()
-                # Non-primary sources are capped so a dead one costs the
-                # failover bound, not the whole heal; the primary (and
-                # the last stripe standing) gets the full remaining
-                # deadline — striping must never make the heal LESS
-                # available than the single-source path it replaced.
-                budget = remaining
-                if (
-                    source_budget is not None
-                    and not stripe.is_primary
-                    and _alive_locked() > 1
-                ):
-                    budget = min(source_budget, remaining)
-            if budget <= 0:
-                with cv:
-                    _fail_locked(
-                        stripe, name,
-                        TimeoutError("striped fetch: deadline expired"),
-                    )
-                return
+            # the caller's own digest of this fragment, when it has one:
+            # waited for here, outside the lock (its digests come a
+            # fragment at a time, in the order the queue hands them out)
+            mine = unless(name) if unless is not None else None
             t0 = time.perf_counter()
-            try:
-                buf = fetch_raw(
-                    stripe.base, step, f"frag_{name}",
-                    timeout=budget, role=role,
-                    frag_index=frag_index[name],
-                )
-            except Exception as e:  # noqa: BLE001 - per-fragment failover
+            while True:
                 with cv:
-                    _fail_locked(stripe, name, e)
-                return
-            sha = wire_digest(buf)
+                    if stopped:
+                        inflight -= 1
+                        return
+                    budget = _budget_locked(stripe)
+                if budget <= 0:
+                    with cv:
+                        _fail_locked(
+                            stripe, name,
+                            TimeoutError("striped fetch: deadline expired"),
+                        )
+                    return
+                streaming: "List[BaseException]" = []
+                try:
+                    buf = fetch_raw(
+                        stripe.base, step, f"frag_{name}",
+                        timeout=budget, role=role,
+                        frag_index=frag_index[name],
+                        unless=mine,
+                        on_retry=lambda e, _n, _d: (
+                            streaming.append(e)
+                            if isinstance(e, StillStreaming) else None
+                        ),
+                    )
+                    break
+                except Exception as e:  # noqa: BLE001 - per-fragment failover
+                    if streaming:
+                        # within this budget the source said "streaming,
+                        # not yet": it is staging the version and has not
+                        # reached this fragment.  Alive: ask again
+                        continue
+                    with cv:
+                        _fail_locked(stripe, name, e)
+                    return
             fb_ms = getattr(_fb_local, "seconds", 0.0) * 1e3
+            sha = wire_digest(buf) if buf is not None else mine or ""
+            if buf is not None and sha == mine:
+                # a source that knows no digest for it sent what the
+                # caller holds already: as good as its "same"
+                _prov.note_hop(
+                    _prov.frag_id("heal", name), step, stripe.base, plane,
+                    verdict="ok", nbytes=buf.nbytes, first_byte_ms=fb_ms,
+                )
+                with cv:
+                    wire_bytes += buf.nbytes
+                POOL.give(buf)
+                buf = None
+            if buf is None:
+                # "same": no transfer, so no hop in the audit either
+                with cv:
+                    inflight -= 1
+                    if not stopped and name not in done:
+                        done.add(name)
+                        same[name] = stripe.base
+                        out_q.append((name, None, sha))
+                    cv.notify_all()
+                    if stopped:
+                        return
+                continue
             if digests is not None and digests.get(name, sha) != sha:
                 # poisoned/diverged source: its bytes must never land in
                 # the healed state — treat exactly like a dead source
@@ -1433,9 +1598,16 @@ def striped_fetch(
                 done.add(name)
                 wire_bytes += buf.nbytes
                 sources_used.add(stripe.base)
-                spans.append((t0, time.perf_counter()))
+                # busy from the answer's first byte on: a request parked
+                # at a source that had not staged the fragment yet is the
+                # source's encode, not this wire
+                spans.append((
+                    max(t0, getattr(_fb_local, "body_from", t0)),
+                    time.perf_counter(),
+                ))
                 hashes[name] = sha
-                out_q.append((name, buf, sha, spans[-1]))
+                landed[name] = (stripe.base, buf.nbytes, time.time_ns())
+                out_q.append((name, buf, sha))
                 cv.notify_all()
 
     threads: "List[threading.Thread]" = []
@@ -1467,8 +1639,10 @@ def striped_fetch(
                             f"{len(names) - delivered} fragment(s) missing"
                         )
                     cv.wait(0.05)
-                name, buf, sha, _span = out_q.popleft()
+                name, buf, sha = out_q.popleft()
             delivered += 1
+            if buf is None:
+                continue  # "same": nothing crossed, nothing to decode
             if on_buf is not None:
                 on_buf(name, buf, sha)
             else:
@@ -1482,12 +1656,16 @@ def striped_fetch(
         # drain anything that landed after the consumer stopped
         with cv:
             while out_q:
-                _name, buf, _sha, _span = out_q.popleft()
-                POOL.give(buf)
+                _name, buf, _sha = out_q.popleft()
+                if buf is not None:
+                    POOL.give(buf)
     return {
         "wire_bytes": wire_bytes,
         "failovers": failovers,
         "spans": spans,
         "hashes": hashes,
         "sources_used": sources_used,
+        "same": same,
+        "landed": landed,
+        "dead": {s.base for s in stripes if not s.alive},
     }

@@ -13,9 +13,11 @@ Striped heal (ISSUE 15, docs/architecture.md "Striped heal"): heal
 snapshots can instead stage as a cut-through fragment stream
 (``send_checkpoint_streamed`` — header first, digest manifest last) and
 a healer stripes disjoint fragment ranges across every max-step quorum
-peer (``recv_checkpoint_striped`` — per-fragment failover, delta diffs,
-decode overlapping wire into retained ``into=`` buffers), all over the
-shared fragment plane (``checkpointing/fragments.py``).
+peer (``recv_checkpoint_striped`` — from the header on, while the sources
+encode; per-fragment failover; a fragment it holds already asked for
+"unless it hashes to mine"; decode overlapping wire into retained
+``into=`` buffers; the manifest last, and everything held against it),
+all over the shared fragment plane (``checkpointing/fragments.py``).
 """
 
 from __future__ import annotations
@@ -52,9 +54,11 @@ logger = logging.getLogger(__name__)
 #: plus one superseded generation of each.
 _MAX_STAGED = 4
 
-#: Per-fragment budget on NON-primary stripe sources: a dead/slow/unstaged
+#: Per-fragment budget on NON-primary stripe sources: a dead/silent/unstaged
 #: peer costs a heal at most this before its fragments fail over (the
-#: primary and the last survivor get the whole remaining deadline).
+#: primary and the last survivor get the whole remaining deadline).  A
+#: source that is still staging the version renews it with every
+#: "streaming, not yet" it answers (``fragments.striped_fetch``).
 HEAL_FAILOVER_S = 2.0
 
 _FETCH_POLICY = RetryPolicy(
@@ -91,9 +95,13 @@ class _Staged:
     before retiring, which keeps a striped healer's multi-request fetch
     window open across the sources' commit instead of tearing it at the
     first fast peer's ``should_commit``.
+
+    ``digests``: the sha256 of a raw part where its stager gave one (a
+    heal source knows each fragment's at the moment it stages it): what a
+    conditional fragment GET (``If-None-Match``) is held against.
     """
 
-    __slots__ = ("sd", "num_chunks", "complete", "pooled", "grace")
+    __slots__ = ("sd", "num_chunks", "complete", "pooled", "grace", "digests")
 
     def __init__(
         self,
@@ -107,6 +115,7 @@ class _Staged:
         self.complete = complete
         self.pooled: "List[Any]" = []
         self.grace = grace
+        self.digests: "dict[str, str]" = {}
 
     def release(self) -> None:
         from torchft_tpu.utils.bufpool import POOL
@@ -148,13 +157,18 @@ class _Handler(BaseHTTPRequestHandler):
     def log_message(self, fmt: str, *args: Any) -> None:  # quiet
         logger.debug("http: " + fmt, *args)
 
-    def _retry_later(self, message: str) -> None:
+    def _retry_later(self, message: str, streaming: bool = False) -> None:
         # Retryable 503 WITHOUT closing the connection (``send_error``
         # sends ``Connection: close``): the cut-through pollers re-ask
         # the same keep-alive connection every few ms — a reconnect per
         # poll would dominate the poll itself at WAN RTTs.
+        # ``streaming``: this node IS staging the version and has not
+        # reached the fragment; marked, so that a striped healer can tell
+        # it from the 503 of a node that has staged nothing.
         body = message.encode("utf-8", "replace")
         self.send_response(503, "retry later")
+        if streaming:
+            self.send_header(_fragdata.STREAMING_HEADER, "1")
         self.send_header("Content-Type", "text/plain")
         self.send_header("Content-Length", str(len(body)))
         self.end_headers()
@@ -162,6 +176,24 @@ class _Handler(BaseHTTPRequestHandler):
             self.wfile.write(body)
         except BrokenPipeError:
             pass
+
+    def _asked_unless(self) -> "Optional[str]":
+        """The digest of a conditional fragment GET (``If-None-Match``:
+        "send it unless it hashes to this"), or None."""
+        cond = self.headers.get("If-None-Match")
+        return (cond.strip(' "') or None) if cond else None
+
+    def _send_same(self, step: int, what: str, digest: str) -> None:
+        """Answer a conditional fragment GET whose digest is the staged
+        fragment's: 304, and no body crosses the wire."""
+        t0_ns = time.time_ns()
+        self.send_response(304)
+        self.send_header("ETag", f'"{digest}"')
+        self.end_headers()
+        _flightrec.record(
+            "checkpoint.http.send", start_ns=t0_ns, step=step,
+            bytes=0, resource=what, same=1,
+        )
 
     def _send_bytes(self, body: bytes, content_type: str) -> None:
         self.send_response(200)
@@ -220,8 +252,13 @@ class _Handler(BaseHTTPRequestHandler):
                 {k: v for k, v in manifest.items() if k != "digests"}
             )
         else:
-            if store.manifest(step) is None:
+            manifest = store.manifest(step)
+            if manifest is None:
                 return False
+            unless = self._asked_unless()
+            if unless and (manifest.get("digests") or {}).get(name) == unless:
+                self._send_same(step, what, unless)
+                return True
             frag = store.fragment(step, name)
             if frag is None:
                 # Version known but this blob is torn/missing: permanent
@@ -353,15 +390,25 @@ class _Handler(BaseHTTPRequestHandler):
                     # fragment); on a streaming document it is the
                     # retryable not-yet-relayed 503 — that poll IS the
                     # cut-through overlap.
-                    frag = state_dict.get(f"frag:{what[len('frag_'):]}")
+                    key = f"frag:{what[len('frag_'):]}"
+                    frag = state_dict.get(key)
                     if frag is None:
                         if not staged.complete:
                             self._retry_later(
                                 f"fragment {what} of step {step} not "
-                                f"relayed yet"
+                                f"relayed yet",
+                                streaming=True,
                             )
                         else:
                             self.send_error(404, "unknown fragment")
+                        return
+                    # A conditional GET ("unless it hashes to mine"): a
+                    # fragment staged under that very digest is answered
+                    # "same", with no body.  Under another digest, or
+                    # under none, the bytes go out as ever.
+                    unless = self._asked_unless()
+                    if unless and staged.digests.get(key) == unless:
+                        self._send_same(step, what, unless)
                         return
                     raw = ser.raw_view(frag)
                     state_dict = frag
@@ -604,9 +651,12 @@ class HTTPTransport(CheckpointTransport[Any]):
             except Exception:
                 logger.debug("native frag begin failed", exc_info=True)
 
-    def _native_stage(self, step: int, key: Any, value: Any) -> int:
-        """Mirror one raw part; returns the bytes that took a copy (0 for
-        a buffer the native server lent, published where it lies)."""
+    def _native_stage(
+        self, step: int, key: Any, value: Any, digest: "Optional[str]" = None
+    ) -> int:
+        """Mirror one raw part, with its digest where the stager gave one;
+        returns the bytes that took a copy (0 for a buffer the native
+        server lent, published where it lies)."""
         srv = self._frag_native
         if (
             srv is None
@@ -618,7 +668,9 @@ class HTTPTransport(CheckpointTransport[Any]):
         if raw is None:
             return 0  # control parts (header/manifest dicts) stay Python
         try:
-            return srv.stage(step, "frag_" + key[len("frag:"):], raw) or 0
+            return srv.stage(
+                step, "frag_" + key[len("frag:"):], raw, digest
+            ) or 0
         except Exception:
             logger.debug("native frag stage failed", exc_info=True)
             return 0
@@ -739,9 +791,13 @@ class HTTPTransport(CheckpointTransport[Any]):
         value: Any,
         pooled: bool = False,
         timeout: "Optional[float]" = None,
+        digest: "Optional[str]" = None,
     ) -> int:
         """Add one part (``frag:<name>`` -> raw wire bytes) to a
-        streaming slot.  ``pooled=True`` transfers ownership of the
+        streaming slot.  ``digest``: the part's sha256 where the stager
+        knows it (a heal source, of each fragment): kept beside the part
+        on both planes, and a GET that asks for the part "unless it
+        hashes to" that digest is answered "same", with no body.  ``pooled=True`` transfers ownership of the
         buffer to the slot: a bufpool-backed one returns to the pool on
         retirement; a view the native server lent
         (:meth:`reserve_streamed_part`) is dropped there, which ends the
@@ -757,9 +813,13 @@ class HTTPTransport(CheckpointTransport[Any]):
                     f"streamed staging slot for step {step} was evicted"
                 )
             staged.sd[key] = value
+            if digest is None:
+                staged.digests.pop(key, None)  # a restage under no digest
+            else:
+                staged.digests[key] = digest
             if pooled:
                 staged.pooled.append(value)
-        copied = self._native_stage(step, key, value)
+        copied = self._native_stage(step, key, value, digest)
         self._wake_stream_waiters()
         return copied
 
@@ -852,7 +912,8 @@ class HTTPTransport(CheckpointTransport[Any]):
         delta: bool = True,
         plane: str = "heal",
     ) -> "tuple[Any, dict]":
-        """Striped multi-source heal receive (ISSUE 15).
+        """Striped multi-source heal receive (ISSUE 15; one path since
+        ISSUE 51).
 
         ``plane`` names the provenance plane these transfers audit
         under: ``heal`` for live heals, ``restore`` when the sources
@@ -863,58 +924,79 @@ class HTTPTransport(CheckpointTransport[Any]):
         defines truth; the rest are max-step peers whose bitwise-
         replicated state lets the healer stripe disjoint fragment
         ranges across every uplink at once.  Per-fragment failover: a
-        dead/slow/poisoned stripe source's fragments move to the
-        survivors (ultimately the primary).
+        dead/silent/poisoned stripe source's fragments move to the
+        survivors (ultimately the primary); one that is still staging is
+        neither.
 
-        Two modes:
+        One path, in the order a source stages:
 
-        - **delta** (``delta``, the default, and a local state snapshot
-          is available): fetch the primary's digest manifest,
-          hash the local state into the same fragment layout, and fetch
-          ONLY the fragments whose digest moved — rejoin wire scales
-          with the update delta, not model size.  Every fetched
-          fragment verifies against the primary digest on receipt.
-          The manifest is staged last, after the source's whole encode,
-          so the healer takes the layout from the header (staged first)
-          and hashes its state on a thread of its own DURING that
-          long-poll; when the manifest is in it joins the digests and
-          diffs.  The manifest still defines truth: early digests of a
-          layout it does not confirm are thrown away and taken again.
-          The thread is joined before the call returns or raises, and
-          an error in it is raised here.
-        - **full**: fetch the digest-less header first (served before
-          the source has encoded anything), stripe ALL fragments while
-          the source is still encoding, then verify the recorded
-          hashes against the primary's manifest (staged last) and
-          refetch any mismatch from the primary alone.
+        1. The digest-less **header**, served before the source has
+           encoded anything, gives the layout.
+        2. The **stripe** begins at once, over every fragment, while the
+           sources encode: a request for a fragment not yet staged parks
+           at its source and is answered the moment it lands.  A healer
+           that has state of the header's layout (``delta``, the default,
+           and a local snapshot) hashes it into that layout on the one
+           ``tft_heal_digest`` thread, A FRAGMENT AT A TIME, and asks for
+           fragment *n* conditionally, "send it unless it hashes to my
+           digest of *n*": a source that staged *n* under that digest
+           answers "same" and no body crosses the wire, so rejoin wire
+           scales with the update delta, not model size.  A healer with
+           no such state, or ``delta=False``, sends no conditions: the
+           only difference.  A source that knows no digests sends the
+           bytes, and what hashes to the healer's own digest is dropped
+           on arrival.
+        3. Decode of fragment *i* (straight into the retained ``into=``
+           leaf buffers) runs on this thread, under the wire of every
+           in-flight stripe and the sources' encode of the rest.
+        4. The **manifest** (every digest; staged last, so there by the
+           time the stripe drains) is fetched from the primary and
+           EVERYTHING is held against it: each fetched fragment's
+           recorded hash, and each reused local fragment's own digest,
+           must equal the primary's.  Whatever does not (a poisoned
+           source's bytes, a false "same", a decode failure, a layout
+           the manifest does not confirm) goes to the repair pass:
+           fetched again, every fragment verified against the primary's
+           digest ON RECEIPT, from the primary and the stripe sources not
+           implicated.  The returned state is bitwise the primary's.
 
-        Decode of fragment *i* (straight into the retained ``into=``
-        leaf buffers) overlaps the wire of every in-flight stripe.
+        The digest thread is joined before the call returns or raises,
+        and an error in it is raised here.
 
         Returns ``(state_dict, info)`` where ``info`` carries the phase
-        split (``phases``: ``heal_manifest``/``heal_diff``/``heal_wire``/
-        ``heal_decode``, disjoint stretches of this thread's wall time;
+        split (``phases``: ``heal_manifest``, the header's fetch;
+        ``heal_wire``, from there until the state is whole, less
+        ``heal_decode`` and ``heal_diff``, stretches of this thread's
+        wall time within it: the busy sum of the decodes, and the stretch
+        from the manifest's arrival until the digests are joined and all
+        is held against it, what the digests still cost the recovery;
         ``parts``: what lies inside them, ``heal_manifest.wait`` and
         ``heal_decode.fragment``, and the digests' work where it runs,
-        ``heal_diff.snapshot|hash``, which in delta mode begins before
-        ``heal_diff`` does: ``heal_diff`` is the stretch from the
-        manifest's arrival until the digests are complete and diffed,
-        what they still cost the recovery), mode, ``hidden`` (also the
-        part ``heal_diff.hidden``: the seconds of digest work that had
-        ended when the manifest arrived; near the work's seconds when the
-        wait hid all of it, 0 for a legacy source, full mode, or a layout
-        the manifest did not confirm), fragment counts and wire bytes.
-        Each is a ``tracing.phase``
-        timed here, when it happens; the Manager folds the seconds into
-        ``phase_times()`` and emits no span of its own for them.  Falls
-        back to the legacy single-source whole-document fetch when the
-        primary's staged document has no fragments (mixed-config
-        fleet)."""
+        ``heal_diff.snapshot|hash``, which begins at the header, long
+        before ``heal_diff`` does), ``mode`` (``delta``: conditions were
+        sent; ``full``: none; ``legacy``), ``hidden`` (also the part
+        ``heal_diff.hidden``: the seconds of digest work that had ended
+        when the manifest arrived; all of it when conditions were sent,
+        each request having waited for its digest, 0 otherwise or when
+        the manifest did not confirm the header's layout),
+        ``overlapped`` (also ``heal_wire.overlapped``, BYTES: the
+        fragment bytes that had landed here when the source made its
+        manifest, by the two hosts' wall clocks; near ``wire_bytes`` less
+        the last fragment when the stripe ran beside the source's encode,
+        0 when it began behind the manifest: a complete version, a server
+        that parks nothing), fragment counts (``changed``: those whose
+        bytes had to come) and wire bytes.  Each phase is a
+        ``tracing.phase`` timed here, when it happens; the Manager folds
+        the seconds into ``phase_times()`` and emits no span of its own
+        for them.  Falls back to the legacy single-source whole-document
+        fetch when the primary's staged document has no fragments
+        (mixed-config fleet)."""
         import urllib.error as _uerr
 
         import jax
 
         from torchft_tpu.checkpointing import fragments as frags
+        from torchft_tpu.checkpointing import provenance as _prov
         from torchft_tpu.ops.codec_pool import merged_seconds
         from torchft_tpu.utils.bufpool import POOL
 
@@ -927,9 +1009,9 @@ class HTTPTransport(CheckpointTransport[Any]):
         # them back apart, by the dot
         timed: "dict[str, float]" = {}
         info: "dict[str, Any]" = {"sources": len(sources)}
-        # ``digester``: the one thread the healer's own digests begin on
-        # while this one long-polls for the manifest; joined when the
-        # block is left, however it is left
+        # ``digester``: the one thread the healer's own digests run on,
+        # beside the stripe; joined when the block is left, however it is
+        # left
         with _flightrec.track(
             "checkpoint.http.recv", step=step, src_rank=0,
             sources=len(sources),
@@ -937,61 +1019,41 @@ class HTTPTransport(CheckpointTransport[Any]):
             1, thread_name_prefix="tft_heal_digest"
         ) as digester:
             local_state, into = self._build_into_map(local_state_fn)
-            use_delta = delta and local_state is not None
             local_leaves = (
-                jax.tree_util.tree_flatten(local_state)[0] if use_delta else []
+                jax.tree_util.tree_flatten(local_state)[0]
+                if delta and local_state is not None
+                else None
             )
             # opened when the manifest is in; its parts (the digests'
-            # ``.snapshot`` and ``.hash``) begin under it before that
+            # ``.snapshot`` and ``.hash``) run under it from the header on
             p_diff = _tracing.phase("heal_diff", timed)
             caller_ctx = _tracing.get_current()
 
-            def _digests(fragments: int) -> "dict[str, str]":
-                _tracing.set_current(caller_ctx)
-                with _tracing.under(p_diff):
-                    return frags.local_fragment_digests(
-                        local_state, fragments
-                    )[1]
-
-            def _fetch_manifest(which: str) -> "dict[str, Any]":
-                # long-poll and retries while the source has not staged
-                # it: the healer waiting for the source
-                with _tracing.phase(".wait"):
-                    buf = frags.fetch_raw(
-                        primary, step, f"frag_{which}",
-                        timeout=max(deadline - time.monotonic(), 0.001),
-                        role="heal",
-                    )
+            def _fetch_control(which: str) -> "dict[str, Any]":
+                buf = frags.fetch_raw(
+                    primary, step, f"frag_{which}",
+                    timeout=max(deadline - time.monotonic(), 0.001),
+                    role="heal",
+                )
                 try:
                     return frags.decode_manifest(buf)
                 finally:
                     POOL.give(buf)
 
-            # -- manifest phase: the primary defines truth.  The
-            # digest-less header is staged first, before the source has
-            # encoded anything: full mode stripes from it while the
-            # source encodes.  Delta needs the digests, staged last, and
-            # long-polls for them through the source's whole encode: it
-            # takes the layout from the header first and hashes its own
-            # state into it meanwhile.
-            early: "Optional[Future]" = None
-            manifest: "Optional[dict[str, Any]]" = None
+            # -- the header: the layout, staged before the source has
+            # encoded anything
+            header: "Optional[dict[str, Any]]" = None
             with _tracing.phase("heal_manifest", timed) as p_manifest:
                 try:
-                    header = _fetch_manifest(frags.HEADER_FRAG)
-                    if use_delta:
-                        if len(local_leaves) == int(header["num_leaves"]):
-                            early = digester.submit(
-                                _digests, len(header["fragments"])
-                            )
-                        manifest = _fetch_manifest(frags.MANIFEST_FRAG)
-                    else:
-                        manifest = header
+                    # long-poll and retries while the source has not
+                    # staged it: the healer waiting for the source
+                    with _tracing.phase(".wait"):
+                        header = _fetch_control(frags.HEADER_FRAG)
                 except _uerr.HTTPError as e:
                     if e.code != 404:
                         raise
                     p_manifest.cancel()  # a legacy source: no split
-            if manifest is None:
+            if header is None:
                 # Source staged a legacy whole-document snapshot (mixed
                 # config): take the classic path against the primary.
                 result = self._recv_checkpoint(
@@ -999,11 +1061,13 @@ class HTTPTransport(CheckpointTransport[Any]):
                     max(deadline - time.monotonic(), 0.001),
                 )
                 op.update(mode="legacy")
-                info.update(mode="legacy", hidden=0.0, phases={})
+                info.update(
+                    mode="legacy", hidden=0.0, overlapped=0, phases={}
+                )
                 return frags.maybe_decode_heal_doc(result), info
 
-            names = [str(n) for n in manifest["fragments"]]
-            num_leaves = int(manifest["num_leaves"])
+            names = [str(n) for n in header["fragments"]]
+            num_leaves = int(header["num_leaves"])
 
             # TORCHFT_PLAN_VERIFY: the stripe assignment is a plan —
             # validate its coverage (disjoint, exhaustive round-robin
@@ -1019,47 +1083,45 @@ class HTTPTransport(CheckpointTransport[Any]):
                                    step=step)
                 )
 
-            # -- diff phase: the local state's digests in the source's
-            # fragment layout; identical digests need no wire at all.
-            # What is timed is what the digests still cost the recovery:
-            # from the manifest's arrival until they are complete.
-            hidden = 0.0
-            with p_diff:
-                changed = list(names)
-                leaves: "dict[int, Any]" = {}
-                if use_delta and len(local_leaves) == num_leaves:
-                    if early is not None and all(
-                        header[k] == manifest[k]
-                        for k in ("fragments", "num_leaves")
-                    ):
-                        # the digest work done in the shadow of the wait:
-                        # the parts that had ended when the manifest came
-                        hidden = sum(
-                            timed.get(k, 0.0)
-                            for k in ("heal_diff.snapshot", "heal_diff.hash")
-                        )
-                        mine = early.result()
-                    else:
-                        # no early digests, or of a layout the manifest
-                        # does not confirm: the manifest defines truth
-                        mine = _digests(len(names))
-                    src_digests = manifest.get("digests") or {}
-                    changed = [
-                        n for n in names
-                        if src_digests.get(n) != mine.get(n)
-                    ]
-                    for name in names:
-                        if name not in changed:
-                            for slot in frags.fragment_slots(
-                                name, num_leaves, len(names)
+            # -- the healer's own digests, where it has state of the
+            # header's layout: handed over a fragment at a time, in the
+            # order the stripe asks for them, so that fragment n is asked
+            # for conditionally while n + 1 is still being hashed
+            mine: "dict[str, Future]" = {}
+            if local_leaves is not None and len(local_leaves) == num_leaves:
+                mine = {name: Future() for name in names}
+
+                def _digests() -> None:
+                    _tracing.set_current(caller_ctx)
+                    error: BaseException = RuntimeError(
+                        "striped heal: no digest of this fragment was taken"
+                    )
+                    try:
+                        with _tracing.under(p_diff):
+                            for name, sha in frags.iter_local_fragment_digests(
+                                local_state, len(names)
                             ):
-                                leaves[slot] = local_leaves[slot]
-            _tracing.add_seconds(timed, "heal_diff.hidden", hidden)
-            mode = "delta" if use_delta else "full"
+                                mine[name].set_result(sha)
+                    except BaseException as e:  # noqa: BLE001 - raised at the join
+                        error = e
+                    for fut in mine.values():
+                        if not fut.done():
+                            fut.set_exception(error)
+
+                digester.submit(_digests)
+
+            def _unless(name: str) -> "Optional[str]":
+                try:
+                    return mine[name].result(
+                        timeout=max(deadline - time.monotonic(), 0.0)
+                    )
+                except Exception:  # noqa: BLE001 - raised where they are joined
+                    return None  # no condition: the bytes come
 
             # -- wire + decode: striped fetch across every source,
             # decode of fragment i overlapping the wire of the rest.
             decode_failed: "List[str]" = []
+            leaves: "dict[int, Any]" = {}
 
             def _decode(name: str, buf: Any, _sha: str) -> None:
                 # heal_decode is one phase a heal, the busy sum of these
@@ -1079,13 +1141,13 @@ class HTTPTransport(CheckpointTransport[Any]):
                         else None
                     )
                     decoded = frags.decode_fragment(buf, into=sub_into)
-                    # Trust boundary: the slot keys come from the (in
-                    # full mode, not-yet-verified) fragment bytes — a
-                    # corrupt fragment claiming FOREIGN slots could
-                    # otherwise overwrite other fragments' leaves with
-                    # garbage the per-fragment repair pass would never
-                    # restore.  Anything but exactly this fragment's
-                    # round-robin slot set is a decode failure.
+                    # Trust boundary: the slot keys come from fragment
+                    # bytes the stripe has not verified yet — a corrupt
+                    # fragment claiming FOREIGN slots could otherwise
+                    # overwrite other fragments' leaves with garbage the
+                    # per-fragment repair pass would never restore.
+                    # Anything but exactly this fragment's round-robin
+                    # slot set is a decode failure.
                     expected = set(
                         frags.fragment_slots(name, num_leaves, len(names))
                     )
@@ -1097,76 +1159,125 @@ class HTTPTransport(CheckpointTransport[Any]):
                     leaves.update(decoded)
                 except Exception:  # noqa: BLE001 - repaired below
                     # Garbage that happened to land before verification
-                    # (full mode verifies AFTER the stripe): remember
+                    # (the stripe is verified AFTER it drains): remember
                     # the fragment for the digest-verified repair pass.
                     decode_failed.append(name)
                 finally:
                     POOL.give(buf)
 
+            hidden = 0.0
             with _tracing.phase("heal_wire", timed) as p_wire:
                 p_decode = _tracing.phase("heal_decode", timed)
                 stats = frags.striped_fetch(
-                    sources, step, changed, deadline,
-                    digests=manifest.get("digests") if use_delta else None,
+                    sources, step, names, deadline,
                     source_budget=HEAL_FAILOVER_S,
                     on_buf=_decode,
                     plane=plane,
+                    unless=_unless if mine else None,
                 )
                 wire_bytes = stats["wire_bytes"]
                 failovers = stats["failovers"]
                 sources_used = set(stats["sources_used"])
 
-                if not use_delta and changed:
-                    # Deferred verify: the digest manifest (staged last —
-                    # the source has finished encoding by the time the
-                    # stripe drains) checks every recorded hash.
-                    mfull = frags.fetch_raw(
-                        primary, step, f"frag_{frags.MANIFEST_FRAG}",
-                        timeout=max(deadline - time.monotonic(), 0.001),
-                        role="heal",
-                    )
-                    try:
-                        manifest = frags.decode_manifest(mfull)
-                    finally:
-                        POOL.give(mfull)
+                # -- the manifest, staged last: the source has finished
+                # encoding by the time the stripe drains.  It defines
+                # truth: every recorded hash and every reused fragment's
+                # own digest is held against it.
+                manifest = _fetch_control(frags.MANIFEST_FRAG)
                 digests = manifest.get("digests") or {}
-                bad = sorted(
-                    set(decode_failed)
-                    | {
-                        n for n in changed
-                        if n in stats["hashes"]
-                        and digests.get(n, stats["hashes"][n])
-                        != stats["hashes"][n]
-                    }
+                made_ns = int(manifest.get("created_ns") or 0)
+                overlapped = sum(
+                    nbytes for _src, nbytes, at_ns in stats["landed"].values()
+                    if at_ns <= made_ns
                 )
+                with p_diff:
+                    # joined: an error in the digests is raised here
+                    own = {name: fut.result() for name, fut in mine.items()}
+                    if all(
+                        header[k] == manifest[k]
+                        for k in ("fragments", "num_leaves")
+                    ):
+                        # the digest work done beside the stripe: the
+                        # parts that had ended when the manifest came
+                        hidden = sum(
+                            timed.get(k, 0.0)
+                            for k in ("heal_diff.snapshot", "heal_diff.hash")
+                        )
+                        reused = sorted(
+                            n for n in stats["same"]
+                            if n in digests and own.get(n) == digests[n]
+                        )
+                        # what the manifest refutes: bytes of another
+                        # digest, a "same" of a fragment that is not
+                        refuted = (set(stats["same"]) - set(reused)) | {
+                            n for n, sha in stats["hashes"].items()
+                            if digests.get(n, sha) != sha
+                        }
+                        bad = sorted(set(decode_failed) | refuted)
+                    else:
+                        # a layout the manifest does not confirm: nothing
+                        # taken or kept under the header's stands
+                        names = [str(n) for n in manifest["fragments"]]
+                        num_leaves = int(manifest["num_leaves"])
+                        leaves.clear()
+                        reused, refuted, bad = [], set(), list(names)
+                    for name in reused:
+                        for slot in frags.fragment_slots(
+                            name, num_leaves, len(names)
+                        ):
+                            leaves[slot] = local_leaves[slot]
+                _tracing.add_seconds(timed, "heal_diff.hidden", hidden)
+                _tracing.add_seconds(timed, "heal_wire.overlapped", overlapped)
                 if bad:
-                    # Repair pass: mismatched/undecodable fragments refetch
-                    # from the PRIMARY alone, digest-verified on receipt; a
-                    # decode failure here is terminal (the primary's own
-                    # bytes are truth — there is nothing left to fail over
-                    # to).
+                    # Repair pass: mismatched/undecodable fragments and
+                    # false "same"s are fetched again, digest-verified on
+                    # receipt, from the primary and the stripe sources
+                    # that neither failed nor delivered one of them; a
+                    # decode failure here is terminal (bytes of the
+                    # primary's digest are truth — there is nothing left
+                    # to fail over to).
                     _metrics.HEAL_FRAG_FAILOVERS.inc(len(bad))
                     failovers += len(bad)
                     decode_failed.clear()
+                    # who delivered each of them, or said "same" of it: the
+                    # audit names it for what the manifest refuted
+                    sent = {
+                        name: stats["landed"].get(
+                            name, (stats["same"].get(name), 0)
+                        )
+                        for name in bad
+                    }
+                    for name in sorted(refuted):
+                        _prov.note_hop(
+                            _prov.frag_id("heal", name), step, sent[name][0],
+                            plane, verdict="mismatch", nbytes=sent[name][1],
+                        )
+                    suspects = stats["dead"] | {src for src, *_ in sent.values()}
                     restats = frags.striped_fetch(
-                        [primary], step, bad, deadline,
-                        digests=digests, on_buf=_decode,
-                        plane=plane,
+                        [primary]
+                        + [s for s in sources[1:] if s not in suspects],
+                        step, bad, deadline,
+                        digests=digests, source_budget=HEAL_FAILOVER_S,
+                        on_buf=_decode, plane=plane,
                     )
                     wire_bytes += restats["wire_bytes"]
+                    failovers += restats["failovers"]
                     sources_used |= set(restats["sources_used"])
                     if decode_failed:
                         raise ValueError(
-                            f"striped heal: fragments {decode_failed} from "
-                            f"the primary verified but failed to decode"
+                            f"striped heal: fragments {decode_failed} of "
+                            f"the primary's digest failed to decode"
                         )
-                # heal_wire: the loop's wall less decode, and no less
-                # than the wire's own busy seconds (decode is a busy sum)
+                # heal_wire: the loop's wall less decode and diff, and no
+                # less than the wire's own busy seconds (decode is a busy
+                # sum; a request parked at a source is not the wire)
                 p_wire.exclude(
-                    min(
+                    p_diff.seconds
+                    + min(
                         p_decode.end(),
                         max(
-                            p_wire.elapsed() - merged_seconds(stats["spans"]),
+                            p_wire.elapsed() - p_diff.seconds
+                            - merged_seconds(stats["spans"]),
                             0.0,
                         ),
                     )
@@ -1175,6 +1286,8 @@ class HTTPTransport(CheckpointTransport[Any]):
             phases = {
                 k: v for k, v in timed.items() if not _tracing.is_part(k)
             }
+            mode = "delta" if mine else "full"
+            changed = len(names) - len(reused)
 
             _metrics.HEAL_WIRE_BYTES.labels(mode=mode).inc(wire_bytes)
             # the gauge reports sources that DELIVERED fragments, not
@@ -1183,7 +1296,7 @@ class HTTPTransport(CheckpointTransport[Any]):
             # a delta heal that fetched nothing still talked to the
             # primary for the manifest, hence the floor of 1
             _metrics.HEAL_STRIPE_SOURCES.set(max(len(sources_used), 1))
-            _metrics.HEAL_CHANGED_FRAGMENTS.set(len(changed))
+            _metrics.HEAL_CHANGED_FRAGMENTS.set(changed)
             _metrics.CHECKPOINT_DURATION.labels(
                 transport="http", direction="recv"
             ).observe(sum(phases.values()))
@@ -1191,8 +1304,6 @@ class HTTPTransport(CheckpointTransport[Any]):
             # provenance: the heal destination now holds every fragment
             # of this version (fetched AND delta-reused — reuse means
             # the local bytes already hash to the source digest)
-            from torchft_tpu.checkpointing import provenance as _prov
-
             h_ms = int(manifest.get("created_ns", 0) // 1_000_000)
             for name in names:
                 _prov.note_hold(
@@ -1202,8 +1313,9 @@ class HTTPTransport(CheckpointTransport[Any]):
             info.update(
                 mode=mode,
                 hidden=hidden,
+                overlapped=overlapped,
                 fragments=len(names),
-                changed=len(changed),
+                changed=changed,
                 wire_bytes=wire_bytes,
                 failovers=failovers,
                 sources_used=len(sources_used),
@@ -1211,7 +1323,7 @@ class HTTPTransport(CheckpointTransport[Any]):
                 parts={k: v for k, v in timed.items() if k not in phases},
             )
             op.update(
-                mode=mode, fragments=len(names), changed=len(changed),
+                mode=mode, fragments=len(names), changed=changed,
                 bytes=wire_bytes, failovers=failovers,
             )
         return state, info

@@ -91,9 +91,11 @@ PROTOCOL_PHASES = (
     "pg_configure",
     "heal_send",
     "heal_recv",
-    # striped-heal receive split (ISSUE 15): manifest fetch from the
-    # primary / local digest diff / striped fragment wire / decode into
-    # retained buffers — heal_recv stays the umbrella total.
+    # striped-heal receive split (ISSUE 15; order since ISSUE 51): the
+    # header's fetch from the primary / the striped fragment wire from
+    # there on, and within it the decode into retained buffers and, when
+    # the manifest is in, what joining the local digests and holding all
+    # against it still costs — heal_recv stays the umbrella total.
     "heal_manifest",
     "heal_diff",
     "heal_wire",
@@ -131,15 +133,16 @@ PHASE_PARTS = (
     "heal_send.hash",  # what hashing is left outside that pass: the digest's finalisation
     "heal_send.stage",  # reserving that buffer (the native server's, lent; a bufpool one without it) and publishing it where it lies
     "heal_send.copied",  # no span: BYTES the transport copied beyond the one write; 0 when every fragment was staged in place
-    # fragments.local_fragment_digests, per fragment, on the transport's
-    # digest thread: begun while the healer waits for the manifest, under a
-    # heal_diff that opens when the manifest is in (so the parts may
-    # outweigh their whole)
+    # fragments.iter_local_fragment_digests, per fragment, on the transport's
+    # digest thread: begun at the header, beside the stripe, under a
+    # heal_diff that opens when the manifest is in, after the stripe (so
+    # the parts outweigh their whole)
     "heal_diff.snapshot",
     "heal_diff.hash",  # serialization.prepare's writer into the source's sink with nothing kept: no bytes built
     "heal_diff.hidden",  # no span: of those two, the seconds ended when the manifest came
-    # inside fetch_raw: long-poll until the source staged the manifest
+    # inside fetch_raw: long-poll until the source staged the header
     "heal_manifest.wait",
+    "heal_wire.overlapped",  # no span: BYTES of fragments that had landed on the healer when the source made its manifest; 0 when the stripe began behind it
     # one per fragment decoded; heal_decode is their busy sum
     "heal_decode.fragment",
 )
@@ -1683,17 +1686,22 @@ class Manager:
         finalisation, ``.stage`` reserving and publishing that buffer;
         ``heal_send.copied`` is no seconds but the bytes copied beyond
         that write, 0 when every fragment was staged in place),
-        ``heal_manifest`` (fetch of the primary's manifest;
-        ``heal_manifest.wait`` is the long-poll inside it while the source
-        is still encoding), ``heal_diff`` (what the healer's digests of its
-        own state, in the source's layout, still cost once the manifest is
-        in: they begin during the wait, from the header's layout, on a thread
+        ``heal_manifest`` (fetch of the primary's header, the layout;
+        ``heal_manifest.wait`` is the long-poll inside it until the source
+        has staged it), ``heal_wire`` (from there until the state is whole:
+        the striped fetch, which begins at the header and runs beside the
+        sources' encode, the manifest's fetch once it drains, a repair
+        pass; the loop's wall less decode and diff;
+        ``heal_wire.overlapped`` is no seconds but the fragment bytes that
+        had landed when the source made its manifest, 0 when the stripe
+        began behind it), ``heal_decode`` (busy
+        seconds of fragment decode, one ``heal_decode.fragment`` each),
+        ``heal_diff`` (what the healer's digests of its own state, in the
+        source's layout, still cost once the manifest is in: they run from
+        the header on, a fragment at a time, beside the stripe, on a thread
         of their own, where ``heal_diff.snapshot|hash`` time the work per
         fragment; ``heal_diff.hidden`` is how much of that work had ended
-        when the manifest came, 0 where it could not begin early),
-        ``heal_wire``
-        (the striped fetch loop's wall less decode), ``heal_decode`` (busy
-        seconds of fragment decode, one ``heal_decode.fragment`` each),
+        when the manifest came, 0 where no condition was sent),
         ``heal_recv`` (what those four leave of the receive: metadata RPC,
         source resolution, reassembly; the whole receive on the legacy
         path), ``reshard`` (online-parallelism-switch
